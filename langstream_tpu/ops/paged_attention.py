@@ -65,6 +65,25 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
+# Largest q tile (block_q · heads · head_dim elements) the v5e compiler
+# accepts under its 16 MiB scoped-VMEM limit: the three f32 scratch
+# buffers, the double-buffered q/out blocks and the body's f32
+# temporaries all scale with it. H=28, D=128, block_q=128 compiles;
+# H=32 at the same block_q asks for 17.5 MiB and is refused
+# (tests/test_chip_compile.py pins both).
+_MAX_Q_TILE_ELEMS = 128 * 28 * 128
+
+
+def _max_block_q(heads: int, dim: int) -> int:
+    """Largest power-of-two q tile ≤ 128 that stays inside
+    ``_MAX_Q_TILE_ELEMS`` (power of two so the prefill buckets tile it
+    without padding)."""
+    block_q = 128
+    while block_q > 1 and block_q * heads * dim > _MAX_Q_TILE_ELEMS:
+        block_q //= 2
+    return block_q
+
+
 def _last_live_block(total, block_size: int):
     """Index of the last block holding live rows (≥0 so empty rows still
     map block 0 — fully masked, finalize emits zeros)."""
@@ -314,7 +333,7 @@ def ragged_paged_attention(
     group = heads // kv_heads
     scale = dim ** -0.5 if scale is None else scale
     quantized = k_scale is not None
-    block_q = min(block_q or 128, seq)
+    block_q = min(block_q or _max_block_q(heads, dim), seq)
     padded = -(-seq // block_q) * block_q
     if padded != seq:
         q = jnp.pad(q, ((0, 0), (0, padded - seq), (0, 0), (0, 0)))
@@ -460,7 +479,7 @@ def ragged_q_paged_attention(
     group = heads // kv_heads
     scale = dim ** -0.5 if scale is None else scale
     quantized = k_scale is not None
-    block_q = min(block_q or 8, max_q_len)
+    block_q = min(block_q or min(8, _max_block_q(heads, dim)), max_q_len)
     if max_q_len % block_q or total_q % block_q:
         raise ValueError(
             f"ragged-q spans must tile by block_q={block_q} "

@@ -172,6 +172,12 @@ def logical_to_physical(
                     used.add(candidate)
                     break
         spec.append(chosen)
+    # trailing replicated axes are dropped, as jit writes the specs of
+    # its outputs: a cache placed as (None, 'tp', None) and rethreaded as
+    # (None, 'tp') is one sharding and two jit-cache keys, and every
+    # program precompiled against the first compiles again in traffic
+    while spec and spec[-1] is None:
+        spec.pop()
     return PartitionSpec(*spec)
 
 

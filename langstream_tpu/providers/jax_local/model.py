@@ -1135,33 +1135,31 @@ def prefill_at_offset(
     windows = layer_windows(config)
     quantized = "k_scale" in cache
 
-    def write_rows(kc, new, offs):
-        # kc: [S, max_len, ...]; new: [B, T, ...] — write each row's
-        # suffix window at its offset (rank-agnostic: value leaves carry
-        # a head_dim axis, scale leaves don't). Padding positions beyond
-        # the suffix length land past ``totals`` where content is dead.
-        def body(kc, args):
+    # The stacked cache rides the layer scan as CARRY, indexed by layer —
+    # not as scanned xs → ys. A while-loop carry is updated in place;
+    # scanned outputs get a fresh stacked buffer, i.e. a second copy of
+    # the whole cache (3.5 GB of temp at 32 slots × 2048 on Qwen-2.5-7B:
+    # the v5e compiler refuses the program for HBM).
+    def write_rows(stacked, layer_index, new):
+        # stacked: [L, S, max_len, ...]; new: [B, T, ...] — write each
+        # row's suffix window at its offset (rank-agnostic: value leaves
+        # carry a head_dim axis, scale leaves don't). Padding positions
+        # beyond the suffix length land past ``totals`` where content is
+        # dead. One dynamic_update_slice per row: in place on the carry
+        # (a scatter makes XLA re-lay-out, i.e. copy, the whole cache).
+        def body(stacked, args):
             row_new, off, slot = args
-            row = jax.lax.dynamic_slice(
-                kc, (slot,) + (0,) * (kc.ndim - 1), (1,) + kc.shape[1:]
-            )[0]
-            row = jax.lax.dynamic_update_slice(
-                row, row_new.astype(row.dtype),
-                (off,) + (0,) * (row.ndim - 1),
-            )
+            start = (layer_index, slot, off) + (0,) * (stacked.ndim - 3)
             return jax.lax.dynamic_update_slice(
-                kc, row[None], (slot,) + (0,) * (kc.ndim - 1)
+                stacked, row_new.astype(stacked.dtype)[None, None], start
             ), None
 
-        kc, _ = jax.lax.scan(body, kc, (new, offs, slot_ids))
-        return kc
+        stacked, _ = jax.lax.scan(body, stacked, (new, offsets, slot_ids))
+        return stacked
 
     def layer_fn(carry, inputs):
-        x = carry
-        if quantized:
-            layer, kc, vc, ks, vs, win = inputs
-        else:
-            layer, kc, vc, win = inputs
+        x, kv = carry
+        layer, win, index = inputs
         (attn_norm, wq, wk, wv, biases, wo, post_attn, mlp_norm, post_mlp,
          mlp_weights) = layer
         normed = _norm(config, x, attn_norm)
@@ -1174,26 +1172,28 @@ def prefill_at_offset(
         softcap = config.attn_logit_softcap
         scale = _attn_scale(config)
         if quantized:
+            kc, vc, ks, vs = kv
             k_q, k_s = quantize_kv(k)
             v_q, v_s = quantize_kv(v)
-            kc = write_rows(kc, k_q, offsets)
-            ks = write_rows(ks, k_s, offsets)
-            vc = write_rows(vc, v_q, offsets)
-            vs = write_rows(vs, v_s, offsets)
+            kc = write_rows(kc, index, k_q)
+            ks = write_rows(ks, index, k_s)
+            vc = write_rows(vc, index, v_q)
+            vs = write_rows(vs, index, v_s)
             attn = chunk_attention_quant(
-                q, kc[slot_ids], ks[slot_ids], vc[slot_ids],
-                vs[slot_ids], offsets, totals,
+                q, kc[index, slot_ids], ks[index, slot_ids],
+                vc[index, slot_ids], vs[index, slot_ids], offsets, totals,
                 softcap=softcap, window=win, scale=scale,
             )
-            kv_out = (kc, vc, ks, vs)
+            kv = (kc, vc, ks, vs)
         else:
-            kc = write_rows(kc, k, offsets)
-            vc = write_rows(vc, v, offsets)
+            kc, vc = kv
+            kc = write_rows(kc, index, k)
+            vc = write_rows(vc, index, v)
             attn = chunk_attention(
-                q, kc[slot_ids], vc[slot_ids], offsets, totals,
-                softcap=softcap, window=win, scale=scale,
+                q, kc[index, slot_ids], vc[index, slot_ids], offsets,
+                totals, softcap=softcap, window=win, scale=scale,
             )
-            kv_out = (kc, vc)
+            kv = (kc, vc)
         attn = qeinsum(
             "btd,dh->bth", attn.reshape(batch, seq, config.num_heads * hd), wo
         )
@@ -1205,19 +1205,15 @@ def prefill_at_offset(
         if post_mlp is not None:
             delta = _norm(config, delta, post_mlp)
         x = x + delta
-        return x, kv_out
+        return (x, kv), None
 
-    if quantized:
-        xs = (layer_inputs, cache["k"], cache["v"],
-              cache["k_scale"], cache["v_scale"], windows)
-    else:
-        xs = (layer_inputs, cache["k"], cache["v"], windows)
-    x, kv_caches = jax.lax.scan(layer_fn, x, xs)
+    names = ("k", "v", "k_scale", "v_scale") if quantized else ("k", "v")
+    xs = (layer_inputs, windows, jnp.arange(config.num_layers))
+    (x, kv_caches), _ = jax.lax.scan(
+        layer_fn, (x, tuple(cache[name] for name in names)), xs
+    )
     out = dict(cache)
-    if quantized:
-        out["k"], out["v"], out["k_scale"], out["v_scale"] = kv_caches
-    else:
-        out["k"], out["v"] = kv_caches
+    out.update(zip(names, kv_caches))
     x = _norm(config, x, params["final_norm"])
     last = x[jnp.arange(batch), (lengths - 1).astype(jnp.int32)]  # [B, H]
     logits = _logits(config, params, last)

@@ -240,7 +240,12 @@ class JaxCompletionsService(CompletionsService):
             # config + ALREADY-LOADED weights are captured, so healing
             # never reloads a checkpoint, and precompiled variants come
             # back through the persistent XLA compile cache
+            nonlocal params
             engine = DecodeEngine(model_config, params, **engine_kwargs)
+            # keep the engine's PLACED (quantized, sharded) weights for a
+            # rebuild, not the loader's: under tp>1 those sit whole on
+            # device 0, a second copy of the model next to its shard
+            params = engine.params
             if precompile:
                 # compile every prefill/decode variant before the first
                 # request so no jit compile ever stalls live traffic

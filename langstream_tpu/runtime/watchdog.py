@@ -16,7 +16,7 @@ public counters (read-only — the watchdog NEVER touches the data plane):
 - **no progress** — work is waiting (queued/pending requests or active
   slots) but NO dispatch (decode chunk or prefill) completes for
   ``no_progress_s``: a hung dispatch, a deadlocked engine thread, a
-  dead device tunnel. The default window is generous (120 s) because a
+  lost device. The default window is generous (120 s) because a
   first-seen jit variant legitimately blocks the engine thread for the
   whole compile — engines serving big models should precompile, and
   deployments that do can lower the window.

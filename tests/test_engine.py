@@ -1488,3 +1488,38 @@ def test_admission_chunk_shortens_chunks_and_matches_serial():
             serial.stop()
 
     asyncio.run(main())
+
+
+def test_prefill_groups_stay_inside_the_token_budget(monkeypatch):
+    """A prefill dispatch carries at most MAX_PREFILL_TOKENS (rows x
+    bucket): groups split, the variant list never names a larger one, and
+    the split groups still answer like solo runs."""
+    monkeypatch.setattr(DecodeEngine, "MAX_PREFILL_TOKENS", 64)
+    config = LlamaConfig.tiny(max_seq_len=128)
+    engine = DecodeEngine(
+        config, init_params(config), max_slots=8, max_seq_len=128,
+        prefill_buckets=[16, 32],
+    )
+    assert [len(g) for g in engine._pow2_groups(list(range(7)), 16)] == [4, 2, 1]
+    assert [len(g) for g in engine._pow2_groups(list(range(7)), 32)] == [2, 2, 2, 1]
+    for fn, args in engine._variant_jobs():
+        if fn in (engine._get_prefill(16), engine._get_prefill(32)):
+            rows, bucket = args[2].shape
+            assert rows * bucket <= 64
+    engine.start()
+    try:
+        async def main():
+            prompts = [[i + 1] * 20 for i in range(8)]  # bucket 32, 8 rows
+            sampling = SamplingParams(max_new_tokens=4)
+            together = await asyncio.gather(
+                *[engine.generate(p, sampling) for p in prompts]
+            )
+            solo = [await engine.generate(p, sampling) for p in prompts]
+            assert [r.tokens for r in together] == [r.tokens for r in solo]
+            assert max(
+                entry["prefill_tokens"] for entry in engine.dispatch_log
+            ) <= 64
+
+        asyncio.run(main())
+    finally:
+        engine.stop()

@@ -1,6 +1,7 @@
 """Weight-only int8 quantization tests."""
 
 import concurrent.futures
+import os
 
 import numpy as np
 import pytest
@@ -181,25 +182,22 @@ def test_weights_cache_roundtrip(tmp_path):
     assert changed
 
 
-def test_bench_prune_compile_cache(tmp_path):
-    """bench.prune_compile_cache drops truncated zstd entries and keeps
-    whole ones (VERDICT r4 weak #2: interrupted attempts poisoned the
-    warm path)."""
-    import importlib.util
-    import os
+def test_compile_cache_placed_from_outside(monkeypatch):
+    """One rule: with JAX_COMPILATION_CACHE_DIR set the code sets
+    nothing; unset, the only value ever set is the checkout's."""
+    from langstream_tpu.runtime import compile_cache
 
-    zstandard = pytest.importorskip("zstandard")
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_mod", os.path.join(os.path.dirname(__file__), "..", "bench.py")
+    calls = []
+    monkeypatch.setattr(
+        jax.config, "update", lambda name, value: calls.append((name, value))
     )
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    payload = zstandard.ZstdCompressor().compress(b"x" * 100_000)
-    (tmp_path / "good-cache").write_bytes(payload)
-    (tmp_path / "truncated-cache").write_bytes(payload[: len(payload) // 2])
-    (tmp_path / "garbage-cache").write_bytes(b"not zstd at all")
-    bench.prune_compile_cache(str(tmp_path))
-    names = sorted(p.name for p in tmp_path.iterdir())
-    assert names == ["good-cache"]
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+    assert compile_cache.configure_compile_cache() == "/somewhere/else"
+    assert calls == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    expected = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_compile_cache",
+    )
+    assert compile_cache.configure_compile_cache() == expected
+    assert calls == [("jax_compilation_cache_dir", expected)]
