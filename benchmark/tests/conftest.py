@@ -1,0 +1,11 @@
+"""The benchmark's self-tests: run with ``JAX_PLATFORMS=cpu python -m
+pytest benchmark/tests -q`` from the root of the checkout. They are not
+part of the repo's tier-1 suite (``tests/``)."""
+
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
