@@ -43,6 +43,7 @@ from langstream_tpu.runtime.tracing import (
     TRACE_ID_HEADER,
     get_tracer,
     new_trace_id,
+    wall,
 )
 
 logger = logging.getLogger(__name__)
@@ -424,7 +425,7 @@ class GatewayServer:
         return ((REPLICA_HEADER, decision.replica_id),)
 
     def _record_route(
-        self, trace_id: str, decision, start_wall: float, dur_s: float
+        self, trace_id: str, decision, started: float, dur_s: float
     ) -> None:
         """The journey ledger's ``route`` stage on the gateway: a
         histogram sample for this /metrics surface, a ``gateway.route``
@@ -451,10 +452,11 @@ class GatewayServer:
                 "gateway.route",
                 max(0.0, dur_s),
                 trace_id=trace_id,
-                start_wall=start_wall,
+                start=started,
                 **attrs,
             )
         if flight.RECORDER.enabled:
+            start_wall = wall(started)
             flight.record(
                 "journey",
                 trace_id=trace_id,
@@ -474,7 +476,6 @@ class GatewayServer:
             gateway.produce_options.get("headers"), parameters, principal
         )
         route_t0 = time.perf_counter()
-        route_wall = time.time()
         decision = self._route_decision(value, tuple(user_headers))
         route_dur = time.perf_counter() - route_t0
         fleet_headers: Tuple[Tuple[str, str], ...] = ()
@@ -500,7 +501,7 @@ class GatewayServer:
             + fleet_headers
         )
         if self._fleet is not None:
-            self._record_route(trace_id, decision, route_wall, route_dur)
+            self._record_route(trace_id, decision, route_t0, route_dur)
         with self.tracer.span(
             "gateway.produce", trace_id=trace_id,
             gateway=gateway.id, topic=gateway.topic,

@@ -300,6 +300,7 @@ def flash_decode_attention(
     )
     return pl.pallas_call(
         kernel,
+        name="flash_decode_int8kv" if quantized else "flash_decode",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((slots, heads, dim), q.dtype),
         cost_estimate=pl.CostEstimate(

@@ -318,6 +318,7 @@ def _pallas_flash(
     )
     return pl.pallas_call(
         kernel,
+        name="flash_prefill_int8kv" if quantized else "flash_prefill",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((batch, heads, seq, dim), q.dtype),
         cost_estimate=pl.CostEstimate(

@@ -404,6 +404,10 @@ def ragged_paged_attention(
     ctx = num_blocks_table * block_size
     out = pl.pallas_call(
         kernel,
+        name=(
+            "ragged_paged_attention_int8kv" if quantized
+            else "ragged_paged_attention"
+        ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((batch, padded, heads, dim), q.dtype),
         cost_estimate=pl.CostEstimate(
@@ -575,6 +579,10 @@ def ragged_q_paged_attention(
     ctx = num_blocks_table * block_size
     out = pl.pallas_call(
         kernel,
+        name=(
+            "ragged_q_paged_attention_int8kv" if quantized
+            else "ragged_q_paged_attention"
+        ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(
             (total_q // block_q, block_q, heads, dim), q.dtype

@@ -31,8 +31,8 @@ Design (TPU-first, see SURVEY.md §7 phase 4/5):
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import dataclasses
-import functools
 import logging
 import os
 import queue
@@ -53,10 +53,38 @@ from langstream_tpu.parallel.mesh import (
 )
 from langstream_tpu.api import errors as api_errors
 from langstream_tpu.providers.jax_local import model as model_lib
-from langstream_tpu.runtime import faults, flight
+from langstream_tpu.runtime import faults, flight, tracing
 from langstream_tpu.runtime.tracing import get_tracer
 
 logger = logging.getLogger(__name__)
+
+
+def _program(kind: str, **jit_options):
+    """``jax.jit`` under a stable name of the engine's own: the
+    profiler's ``XLA Modules`` line, the compile log and the dispatch
+    path's ``PjitFunction`` then read ``jit_<kind>`` where every closure
+    used to be ``jit_run``. A name only: what is traced, donated and
+    compiled is as before. ``PROGRAM_KINDS`` lists them."""
+
+    def wrap(fn):
+        fn.__name__ = fn.__qualname__ = kind
+        return jax.jit(fn, **jit_options)
+
+    return wrap
+
+
+# every program the engine dispatches, by what it does and the cache
+# layout it serves; a trace reader tells prefills from decode chunks by
+# these prefixes (``jit_prefill_``, ``jit_decode_chunk_``)
+PROGRAM_KINDS = (
+    "init_cache_paged", "init_cache_dense",
+    "prefill_paged", "prefill_dense",
+    "prefill_offset_paged", "prefill_offset_dense",
+    "decode_chunk_paged", "decode_chunk_dense",
+    "spec_decode_chunk_paged", "spec_decode_chunk_dense",
+    "mixed_step_paged", "copy_prefix_dense", "block_copy_paged",
+    "handoff_export_paged", "handoff_import_paged", "counts_restore",
+)
 
 # live engines, for /metrics exposure (weak: a stopped engine's buffers
 # must not be pinned by the metrics path)
@@ -134,6 +162,7 @@ def engines_snapshot() -> Dict[str, float]:
     tokens = steps = chunks = 0
     session_hits = prefix_hits = prefix_tokens = 0
     decode_time = prefill_time = 0.0
+    loop_seconds = {"idle": 0.0, "admit": 0.0, "dispatch": 0.0, "emit": 0.0}
     active_slot_steps = total_slot_steps = 0
     paged_engines = 0
     kv_blocks_in_use = kv_blocks_total = 0
@@ -201,6 +230,8 @@ def engines_snapshot() -> Dict[str, float]:
         chunks += stats["decode_chunks"]
         decode_time += stats["decode_time"]
         prefill_time += stats["prefill_time"]
+        for phase_name in loop_seconds:
+            loop_seconds[phase_name] += stats[phase_name + "_time"]
         active_slot_steps += stats["active_slot_steps"]
         total_slot_steps += stats["decode_steps"] * engine.max_slots
         session_hits += stats["session_hits"]
@@ -361,6 +392,12 @@ def engines_snapshot() -> Dict[str, float]:
     out["jax_engine_decode_chunks"] = float(chunks)
     out["jax_engine_decode_time_seconds"] = round(decode_time, 6)
     out["jax_engine_prefill_time_seconds"] = round(prefill_time, 6)
+    # the engine thread's own seconds by phase, summed at the phase
+    # spans' boundaries (docs/observability.md §1)
+    for phase_name, seconds in loop_seconds.items():
+        out[
+            f'jax_engine_loop_seconds_total{{phase="{phase_name}"}}'
+        ] = round(seconds, 6)
     if steps:
         out["jax_engine_decode_ms_per_step"] = round(
             decode_time / steps * 1e3, 4
@@ -783,6 +820,7 @@ class DecodeEngine:
             widths.append(widths[-1] * 2)
         self._mixed_widths = widths
         self._admit_seq = 0
+        self._prefill_batches = 0  # numbers engine.prefill_dispatch spans
         if self.paged_kernel == "fused" and not model_lib._use_fused_paged(
             config, config.dims_per_head, config.num_heads,
             config.num_kv_heads, self.mesh,
@@ -846,12 +884,13 @@ class DecodeEngine:
                 # built whole on device 0 and then sliced, a 3.5 GB
                 # cache did not fit next to the loader's weights there
                 # (tp=4 on four real chips)
-                self.cache = jax.jit(
+                self.cache = _program(
+                    "init_cache_paged", out_shardings=cache_sharding
+                )(
                     lambda: model_lib.init_paged_cache(
                         config, self.num_blocks, self.block_size,
                         kv_quant=self.kv_quant,
-                    ),
-                    out_shardings=cache_sharding,
+                    )
                 )()
             # the jitted COW block copy pins its outputs to this layout
             # so the SPMD partitioner can never resolve the dynamic
@@ -864,12 +903,13 @@ class DecodeEngine:
             with self.mesh:
                 # owned-by: _run_loop
                 # built in place on every shard, as the paged pool is
-                self.cache = jax.jit(
+                self.cache = _program(
+                    "init_cache_dense", out_shardings=cache_sharding
+                )(
                     lambda: model_lib.init_cache(
                         config, max_slots, self.max_seq_len,
                         kv_quant=self.kv_quant,
-                    ),
-                    out_shardings=cache_sharding,
+                    )
                 )()
         self.slots = [_Slot() for _ in range(max_slots)]
         # efficiency accounting: analytical FLOPs/bytes per dispatch from
@@ -1033,8 +1073,12 @@ class DecodeEngine:
             "active_slot_steps": 0,  # sum of active slots over decode steps
             # wall-clock breakdown of everything OUTSIDE device dispatches,
             # so "unaccounted" time has a name (VERDICT r2 weak #1)
+            # (each sum is taken at its phase span's own boundaries —
+            # tracing.phase in _run_loop — so /metrics and a trace agree)
             "idle_time": 0.0,        # engine thread blocked on empty queue
             "emit_time": 0.0,        # host token bookkeeping + callbacks
+            "admit_time": 0.0,       # admission: slots, batch builds, prefill dispatches
+            "dispatch_time": 0.0,    # building and dispatching decode chunks
             # goodput ledger: tokens that reached a live caller vs tokens
             # burned on cancelled requests / eviction-induced re-prefill
             "tokens_useful": 0,
@@ -1159,7 +1203,7 @@ class DecodeEngine:
             if self.paged:
                 paged_kernel = self.paged_kernel
 
-                @functools.partial(jax.jit, donate_argnums=(1, 6))
+                @_program("prefill_paged", donate_argnums=(1, 6))
                 def run(params, cache, tokens, lengths, slot_ids, tables,
                         counts, temperature, top_k, top_p, seeds,
                         bias_ids, bias_vals):
@@ -1175,7 +1219,7 @@ class DecodeEngine:
 
             else:
 
-                @functools.partial(jax.jit, donate_argnums=(1, 5))
+                @_program("prefill_dense", donate_argnums=(1, 5))
                 def run(params, cache, tokens, lengths, slot_ids, counts,
                         temperature, top_k, top_p, seeds,
                         bias_ids, bias_vals):
@@ -1219,7 +1263,7 @@ class DecodeEngine:
             if self.paged:
                 paged_kernel = self.paged_kernel
 
-                @functools.partial(jax.jit, donate_argnums=(1, 7))
+                @_program("prefill_offset_paged", donate_argnums=(1, 7))
                 def run(params, cache, tokens, lengths, offsets, slot_ids,
                         tables, counts, temperature, top_k, top_p, seeds,
                         bias_ids, bias_vals):
@@ -1235,7 +1279,7 @@ class DecodeEngine:
 
             else:
 
-                @functools.partial(jax.jit, donate_argnums=(1, 6))
+                @_program("prefill_offset_dense", donate_argnums=(1, 6))
                 def run(params, cache, tokens, lengths, offsets, slot_ids,
                         counts, temperature, top_k, top_p, seeds,
                         bias_ids, bias_vals):
@@ -1341,7 +1385,7 @@ class DecodeEngine:
 
             if paged:
 
-                @functools.partial(jax.jit, donate_argnums=(1, 7))
+                @_program("decode_chunk_paged", donate_argnums=(1, 7))
                 def run(params, cache, tokens, lengths, active, write_mask,
                         tables, counts, temperature, top_k, top_p,
                         presence, frequency, seeds, bias_ids, bias_vals):
@@ -1353,7 +1397,7 @@ class DecodeEngine:
 
             else:
 
-                @functools.partial(jax.jit, donate_argnums=(1, 6))
+                @_program("decode_chunk_dense", donate_argnums=(1, 6))
                 def run(params, cache, tokens, lengths, active, write_mask,
                         counts, temperature, top_k, top_p,
                         presence, frequency, seeds, bias_ids, bias_vals):
@@ -1477,7 +1521,7 @@ class DecodeEngine:
 
             if paged:
 
-                @functools.partial(jax.jit, donate_argnums=(1, 6, 8))
+                @_program("spec_decode_chunk_paged", donate_argnums=(1, 6, 8))
                 def run(params, cache, tokens, lengths, active, write_mask,
                         history, tables, counts, temperature, top_k, top_p,
                         presence, frequency, seeds, bias_ids, bias_vals):
@@ -1489,7 +1533,7 @@ class DecodeEngine:
 
             else:
 
-                @functools.partial(jax.jit, donate_argnums=(1, 6, 7))
+                @_program("spec_decode_chunk_dense", donate_argnums=(1, 6, 7))
                 def run(params, cache, tokens, lengths, active, write_mask,
                         history, counts, temperature, top_k, top_p,
                         presence, frequency, seeds, bias_ids, bias_vals):
@@ -1536,7 +1580,7 @@ class DecodeEngine:
             topk = self.logprobs_topk
             paged_kernel = self.paged_kernel
 
-            @functools.partial(jax.jit, donate_argnums=(1, 9))
+            @_program("mixed_step_paged", donate_argnums=(1, 9))
             def run(params, cache, tokens, offsets, num_tokens,
                     write_mask, decode_mask, completes, tables, counts,
                     prev_sampled, chain_mask,
@@ -1600,7 +1644,7 @@ class DecodeEngine:
         fn = self._copy_fns.get(bucket)
         if fn is None:
 
-            @functools.partial(jax.jit, donate_argnums=(1,))
+            @_program("copy_prefix_dense", donate_argnums=(1,))
             def run(params, cache, src, dst, offset):
                 del params
 
@@ -1638,7 +1682,7 @@ class DecodeEngine:
         if fn is None:
             sharding = self._cache_sharding
 
-            @functools.partial(jax.jit, donate_argnums=(1,))
+            @_program("block_copy_paged", donate_argnums=(1,))
             def run(params, cache, src, dst):
                 del params
 
@@ -1699,7 +1743,7 @@ class DecodeEngine:
         fn = self._handoff_export_fns.get(width)
         if fn is None:
 
-            @jax.jit
+            @_program("handoff_export_paged")
             def run(cache, blocks):
                 return jax.tree_util.tree_map(
                     lambda c: jnp.take(c, blocks, axis=1), cache
@@ -1722,7 +1766,7 @@ class DecodeEngine:
         if fn is None:
             sharding = self._cache_sharding
 
-            @functools.partial(jax.jit, donate_argnums=(1,))
+            @_program("handoff_import_paged", donate_argnums=(1,))
             def run(params, cache, blocks, data):
                 del params
 
@@ -1757,7 +1801,6 @@ class DecodeEngine:
         if full <= 0 or not slot.blocks:
             return None
         export_t0 = time.perf_counter()
-        export_wall = time.time()
         tokens = slot.history[: full * self.block_size]
         blocks = slot.blocks[:full]
         width = self._handoff_pad(full)
@@ -1782,7 +1825,7 @@ class DecodeEngine:
             # (manifest_for_request) so the decode leg can subtract.
             # Stamped AFTER the arrays are materialized — transit
             # measures the fabric, not this replica's serialization
-            "export_ts": time.time(),
+            "export_ts": tracing.wall(time.perf_counter()),
         }
         nbytes = payload_nbytes(payload)
         self.stats["handoff_exports"] += 1
@@ -1798,7 +1841,7 @@ class DecodeEngine:
                 "engine.handoff_export",
                 time.perf_counter() - export_t0,
                 trace_id=(request.trace_id or "") if request else "",
-                start_wall=export_wall,
+                start=export_t0,
                 tokens=len(tokens),
                 blocks=full,
                 bytes=nbytes,
@@ -1822,7 +1865,7 @@ class DecodeEngine:
             if request.kv_import is None:
                 continue
             payload, request.kv_import = request.kv_import, None
-            import_start = time.time()
+            import_start = time.perf_counter()
             ok = self._import_handoff(
                 payload, trace_id=request.trace_id or ""
             )
@@ -1831,7 +1874,7 @@ class DecodeEngine:
                 # window + admission class (the later prefix-cache hit
                 # this import manufactured must not book as "hbm-hit")
                 request._jt_import = (  # type: ignore[attr-defined]
-                    import_start, time.time()
+                    import_start, time.perf_counter()
                 )
                 request._jt_admit_class = (  # type: ignore[attr-defined]
                     "handoff-import"
@@ -1846,7 +1889,6 @@ class DecodeEngine:
         size = int(payload.get("block_size", 0) or 0)
         full = len(tokens) // size if size else 0
         import_t0 = time.perf_counter()
-        import_wall = time.time()
 
         def aborted(reason: str) -> bool:
             self._waste("handoff_aborted", len(tokens))
@@ -1859,7 +1901,7 @@ class DecodeEngine:
                     "engine.handoff_import",
                     time.perf_counter() - import_t0,
                     trace_id=trace_id,
-                    start_wall=import_wall,
+                    start=import_t0,
                     tokens=len(tokens),
                     aborted=True,
                     reason=reason,
@@ -1931,7 +1973,7 @@ class DecodeEngine:
                 "engine.handoff_import",
                 time.perf_counter() - import_t0,
                 trace_id=trace_id,
-                start_wall=import_wall,
+                start=import_t0,
                 tokens=len(tokens),
                 blocks=len(chain) + len(fresh),
                 bytes=int(nbytes),
@@ -2394,11 +2436,9 @@ class DecodeEngine:
         # paged: no per-request block check needed — the constructor
         # guarantees the pool covers at least one max_seq_len sequence,
         # which bounds any single reservation
-        # span/TTFT anchors: perf_counter for durations, wall for the
-        # trace timeline (engine spans must align with gateway/runner
-        # spans recorded on other clocks)
+        # span/TTFT anchor on the process's one clock (wall time, where
+        # a dump or the cross-replica ledger needs it, is tracing.wall)
         request._submit_ts = time.perf_counter()  # type: ignore[attr-defined]
-        request._submit_wall = time.time()        # type: ignore[attr-defined]
         self._queue.put(request)
         if self._crashed is not None:
             # crashed between the check above and the put: the loop will
@@ -2494,14 +2534,19 @@ class DecodeEngine:
                         # then would add 3 ms to THAT chunk's harvest
                         # latency, taxing every running stream's TPOT for
                         # a batching benefit the next dispatch gets anyway
-                        time.sleep(0.003)
-                        self._drain_queue(block=False)
+                        with self._phase("engine.linger"):
+                            time.sleep(0.003)
+                            self._drain_queue(block=False)
                     # dispatch prefills WITHOUT blocking: they queue behind
                     # the in-flight decode chunk and overlap with the next
                     # ones; their slots join decode once harvested. (mixed
                     # mode: admission only parks the slot at its watermark
                     # — the windows ride the decode steps below)
-                    self._admit()
+                    with self._phase(
+                        "engine.admit", "admit_time",
+                        pending=len(self._pending),
+                    ):
+                        self._admit()
                     if inflight is not None:
                         # overlap: chain the next chunk off the device-side
                         # carry BEFORE blocking on this one's tokens
@@ -2515,7 +2560,7 @@ class DecodeEngine:
                             # the host-built dispatch (and is counted)
                             plan_next = self._plan_mixed_chain(inflight)
                             if isinstance(plan_next, dict):
-                                chained = self._dispatch_mixed(
+                                chained = self._dispatch_chunk(
                                     carry=inflight, plan_next=plan_next
                                 )
                             else:
@@ -2523,7 +2568,7 @@ class DecodeEngine:
                         elif self.pipeline_decode and self._can_chain(
                             inflight
                         ):
-                            chained = self._dispatch_decode(carry=inflight)
+                            chained = self._dispatch_chunk(carry=inflight)
                         self._process_decode(inflight)
                         inflight = chained
                     # pick up finished prefills; block for the oldest one
@@ -2535,7 +2580,7 @@ class DecodeEngine:
                     if inflight is None and (
                         self._any_ready() or self._any_admitting()
                     ):
-                        inflight = self._dispatch_decode()
+                        inflight = self._dispatch_chunk()
                         if not self.pipeline_decode or (
                             inflight.get("mixed") and not self.mixed_carry
                         ):
@@ -2570,6 +2615,18 @@ class DecodeEngine:
             self._fail_all_pending()
             raise
 
+    @contextlib.contextmanager
+    def _phase(self, name: str, stat: Optional[str] = None, **attributes):
+        """One phase of the loop: its span (``tracing.phase``) and, at
+        the same two boundaries, its running sum in ``self.stats``."""
+        started = time.perf_counter()
+        try:
+            with tracing.phase(name, self.tracer, **attributes) as span:
+                yield span
+        finally:
+            if stat is not None:
+                self.stats[stat] += time.perf_counter() - started
+
     def _any_active(self) -> bool:
         return any(slot.active for slot in self.slots)
 
@@ -2591,11 +2648,8 @@ class DecodeEngine:
                 # mixed step's inter-dispatch gap would measure idle
                 # time, not the per-step host tax (see _process_mixed)
                 self._last_mixed_end = 0.0
-                started = time.perf_counter()
-                try:
+                with self._phase("engine.wait_for_work", "idle_time"):
                     item = self._queue.get(timeout=0.05)
-                finally:
-                    self.stats["idle_time"] += time.perf_counter() - started
             else:
                 item = self._queue.get_nowait()
             if item is not None:
@@ -3430,7 +3484,9 @@ class DecodeEngine:
         # path (cold, mixed, session, handoff) stamps the queue→prefill
         # boundary and the admission class (unless an earlier stage —
         # handoff import, host promotion — already classified it)
-        request._admit_wall = time.time()  # type: ignore[attr-defined]
+        request._assigned_ts = (  # type: ignore[attr-defined]
+            time.perf_counter()
+        )
         if getattr(request, "_jt_admit_class", None) is None:
             request._jt_admit_class = (  # type: ignore[attr-defined]
                 "hbm-hit" if reused > 0 else "cold"
@@ -3531,85 +3587,92 @@ class DecodeEngine:
         while decode chunks for already-running slots continue."""
         faults.check("dispatch_error")
         for group in self._pow2_groups(batch, bucket):
-            started = time.perf_counter()
-            size = len(group)
-            tokens = np.zeros((size, bucket), dtype=np.int32)
-            lengths = np.zeros((size,), dtype=np.int32)
-            slot_ids = np.zeros((size,), dtype=np.int32)
-            for row, (index, request) in enumerate(group):
-                prompt = request.prompt_tokens
-                tokens[row, : len(prompt)] = prompt
-                lengths[row] = len(prompt)
-                slot_ids[row] = index
-                self._assign_slot(index, request)
-                self.slots[index].prefilling = True
-            run = self._get_prefill(bucket)
-            temperature, top_k, top_p, seeds = self._sampling_arrays(
-                [request for _, request in group]
-            )
-            bias_ids, bias_vals = self._bias_rows(
-                [request for _, request in group]
-            )
-            # ONE host-args list feeds both the mirror record and the
-            # dispatch, so the replayed argument order cannot drift
-            host_args = [
-                tokens, lengths, slot_ids,
-                temperature, top_k, top_p, seeds, bias_ids, bias_vals,
-            ]
-            paged_args = (
-                (self._block_tables[slot_ids],) if self.paged else ()
-            )
-            if self.mirror is not None:
-                self._check_mirror_layout()
-                # paged dispatches ship their block-table rows in
-                # dispatch-arg position (small int32 host metadata — no
-                # D2H of pool data); the follower's replay rebuilds the
-                # exact argument tuple from engine.paged
-                self.mirror.publish(
-                    "prefill", {"bucket": bucket},
-                    [*host_args[:3], *paged_args, *host_args[3:]],
+            with self._prefill_phase(
+                "cold", bucket, [index for index, _ in group]
+            ) as batch_id:
+                started = time.perf_counter()
+                size = len(group)
+                tokens = np.zeros((size, bucket), dtype=np.int32)
+                lengths = np.zeros((size,), dtype=np.int32)
+                slot_ids = np.zeros((size,), dtype=np.int32)
+                for row, (index, request) in enumerate(group):
+                    prompt = request.prompt_tokens
+                    tokens[row, : len(prompt)] = prompt
+                    lengths[row] = len(prompt)
+                    slot_ids[row] = index
+                    self._assign_slot(index, request)
+                    self.slots[index].prefilling = True
+                run = self._get_prefill(bucket)
+                temperature, top_k, top_p, seeds = self._sampling_arrays(
+                    [request for _, request in group]
                 )
-            self.cache, self._counts, sampled, lps, tops = run(
-                self.params, self.cache, *host_args[:3], *paged_args,
-                self._counts, *host_args[3:],
-            )
-            self.stats["prefill_calls"] += 1
-            self.stats["prefill_time"] += time.perf_counter() - started
-            # modeled prefill work (cumulative prefill MFU denominator
-            # is prefill_time, which also absorbs the harvest wait)
-            dispatch_flops = sum(
-                self.cost_model.prefill_flops(len(r.prompt_tokens))
-                for _, r in group
-            )
-            self.stats["prefill_flops"] += dispatch_flops
-            # goodput ledger: bucket-rounding ghosts — positions the
-            # padded [size, bucket] dispatch computes past each prompt's
-            # end (up to ~2x a prompt's FLOPs at the worst bucket edge;
-            # the mixed path caps the same waste at width−1 per window)
-            live = sum(len(r.prompt_tokens) for _, r in group)
-            self._waste("prefill_padding", size * bucket - live)
-            self._log_dispatch(
-                "prefill", tokens=live, rows=size, wall=0.0,
-                prefill_tokens=live,
-            )
-            flight.record(
-                "prefill",
-                bucket=bucket,
-                batch=size,
-                warm=False,
-                reused_tokens=0,
-                wall_ms=round((time.perf_counter() - started) * 1e3, 3),
-                queue_depth=len(self._pending),
-                flops=dispatch_flops,
-            )
-            self._prefill_inflight.append({
-                "group": [(index, request) for index, request in group],
-                "sampled": sampled,
-                "lps": lps,
-                "tops": tops,
-                "reused": {},
-                "started": started,
-            })
+                bias_ids, bias_vals = self._bias_rows(
+                    [request for _, request in group]
+                )
+                # ONE host-args list feeds both the mirror record and the
+                # dispatch, so the replayed argument order cannot drift
+                host_args = [
+                    tokens, lengths, slot_ids,
+                    temperature, top_k, top_p, seeds, bias_ids, bias_vals,
+                ]
+                paged_args = (
+                    (self._block_tables[slot_ids],) if self.paged else ()
+                )
+                if self.mirror is not None:
+                    self._check_mirror_layout()
+                    # paged dispatches ship their block-table rows in
+                    # dispatch-arg position (small int32 host metadata — no
+                    # D2H of pool data); the follower's replay rebuilds the
+                    # exact argument tuple from engine.paged
+                    self.mirror.publish(
+                        "prefill", {"bucket": bucket},
+                        [*host_args[:3], *paged_args, *host_args[3:]],
+                    )
+                self._stamp_dispatch(
+                    [request for _, request in group], batch_id, bucket
+                )
+                self.cache, self._counts, sampled, lps, tops = run(
+                    self.params, self.cache, *host_args[:3], *paged_args,
+                    self._counts, *host_args[3:],
+                )
+                self.stats["prefill_calls"] += 1
+                self.stats["prefill_time"] += time.perf_counter() - started
+                # modeled prefill work (cumulative prefill MFU denominator
+                # is prefill_time, which also absorbs the harvest wait)
+                dispatch_flops = sum(
+                    self.cost_model.prefill_flops(len(r.prompt_tokens))
+                    for _, r in group
+                )
+                self.stats["prefill_flops"] += dispatch_flops
+                # goodput ledger: bucket-rounding ghosts — positions the
+                # padded [size, bucket] dispatch computes past each prompt's
+                # end (up to ~2x a prompt's FLOPs at the worst bucket edge;
+                # the mixed path caps the same waste at width−1 per window)
+                live = sum(len(r.prompt_tokens) for _, r in group)
+                self._waste("prefill_padding", size * bucket - live)
+                self._log_dispatch(
+                    "prefill", tokens=live, rows=size, wall=0.0,
+                    prefill_tokens=live,
+                )
+                flight.record(
+                    "prefill",
+                    bucket=bucket,
+                    batch=size,
+                    warm=False,
+                    reused_tokens=0,
+                    wall_ms=round((time.perf_counter() - started) * 1e3, 3),
+                    queue_depth=len(self._pending),
+                    flops=dispatch_flops,
+                )
+                self._prefill_inflight.append({
+                    "group": [(index, request) for index, request in group],
+                    "sampled": sampled,
+                    "lps": lps,
+                    "tops": tops,
+                    "reused": {},
+                    "started": started,
+                    "batch": batch_id,
+                })
 
     def _prefill_warm_batch(
         self,
@@ -3624,79 +3687,86 @@ class DecodeEngine:
         Non-blocking, like :meth:`_prefill_batch`."""
         faults.check("dispatch_error")
         for group in self._pow2_groups(batch, bucket):
-            started = time.perf_counter()
-            size = len(group)
-            tokens = np.zeros((size, bucket), dtype=np.int32)
-            lengths = np.zeros((size,), dtype=np.int32)
-            offsets = np.zeros((size,), dtype=np.int32)
-            slot_ids = np.zeros((size,), dtype=np.int32)
-            for row, (index, request, reused) in enumerate(group):
-                suffix = request.prompt_tokens[reused:]
-                tokens[row, : len(suffix)] = suffix
-                lengths[row] = len(suffix)
-                offsets[row] = reused
-                slot_ids[row] = index
-                self._assign_slot(index, request, reused)
-                self.slots[index].prefilling = True
-            run = self._get_prefill_offset(bucket)
-            temperature, top_k, top_p, seeds = self._sampling_arrays(
-                [request for _, request, _ in group]
-            )
-            bias_ids, bias_vals = self._bias_rows(
-                [request for _, request, _ in group]
-            )
-            host_args = [
-                tokens, lengths, offsets, slot_ids,
-                temperature, top_k, top_p, seeds, bias_ids, bias_vals,
-            ]
-            paged_args = (
-                (self._block_tables[slot_ids],) if self.paged else ()
-            )
-            if self.mirror is not None:
-                self._check_mirror_layout()
-                self.mirror.publish(
-                    "prefill_offset", {"bucket": bucket},
-                    [*host_args[:4], *paged_args, *host_args[4:]],
+            with self._prefill_phase(
+                "warm", bucket, [index for index, _, _ in group]
+            ) as batch_id:
+                started = time.perf_counter()
+                size = len(group)
+                tokens = np.zeros((size, bucket), dtype=np.int32)
+                lengths = np.zeros((size,), dtype=np.int32)
+                offsets = np.zeros((size,), dtype=np.int32)
+                slot_ids = np.zeros((size,), dtype=np.int32)
+                for row, (index, request, reused) in enumerate(group):
+                    suffix = request.prompt_tokens[reused:]
+                    tokens[row, : len(suffix)] = suffix
+                    lengths[row] = len(suffix)
+                    offsets[row] = reused
+                    slot_ids[row] = index
+                    self._assign_slot(index, request, reused)
+                    self.slots[index].prefilling = True
+                run = self._get_prefill_offset(bucket)
+                temperature, top_k, top_p, seeds = self._sampling_arrays(
+                    [request for _, request, _ in group]
                 )
-            self.cache, self._counts, sampled, lps, tops = run(
-                self.params, self.cache, *host_args[:4], *paged_args,
-                self._counts, *host_args[4:],
-            )
-            self.stats["warm_prefill_calls"] += 1
-            self.stats["prefill_time"] += time.perf_counter() - started
-            dispatch_flops = sum(
-                self.cost_model.prefill_flops(
-                    len(r.prompt_tokens) - reused, offset=reused
+                bias_ids, bias_vals = self._bias_rows(
+                    [request for _, request, _ in group]
                 )
-                for _, r, reused in group
-            )
-            self.stats["prefill_flops"] += dispatch_flops
-            live = sum(
-                len(r.prompt_tokens) - reused for _, r, reused in group
-            )
-            self._waste("prefill_padding", size * bucket - live)
-            self._log_dispatch(
-                "prefill", tokens=live, rows=size, wall=0.0,
-                prefill_tokens=live,
-            )
-            flight.record(
-                "prefill",
-                bucket=bucket,
-                batch=size,
-                warm=True,
-                reused_tokens=int(sum(r for _, _, r in group)),
-                wall_ms=round((time.perf_counter() - started) * 1e3, 3),
-                queue_depth=len(self._pending),
-                flops=dispatch_flops,
-            )
-            self._prefill_inflight.append({
-                "group": [(index, request) for index, request, _ in group],
-                "sampled": sampled,
-                "lps": lps,
-                "tops": tops,
-                "reused": {index: reused for index, _, reused in group},
-                "started": started,
-            })
+                host_args = [
+                    tokens, lengths, offsets, slot_ids,
+                    temperature, top_k, top_p, seeds, bias_ids, bias_vals,
+                ]
+                paged_args = (
+                    (self._block_tables[slot_ids],) if self.paged else ()
+                )
+                if self.mirror is not None:
+                    self._check_mirror_layout()
+                    self.mirror.publish(
+                        "prefill_offset", {"bucket": bucket},
+                        [*host_args[:4], *paged_args, *host_args[4:]],
+                    )
+                self._stamp_dispatch(
+                    [request for _, request, _ in group], batch_id, bucket
+                )
+                self.cache, self._counts, sampled, lps, tops = run(
+                    self.params, self.cache, *host_args[:4], *paged_args,
+                    self._counts, *host_args[4:],
+                )
+                self.stats["warm_prefill_calls"] += 1
+                self.stats["prefill_time"] += time.perf_counter() - started
+                dispatch_flops = sum(
+                    self.cost_model.prefill_flops(
+                        len(r.prompt_tokens) - reused, offset=reused
+                    )
+                    for _, r, reused in group
+                )
+                self.stats["prefill_flops"] += dispatch_flops
+                live = sum(
+                    len(r.prompt_tokens) - reused for _, r, reused in group
+                )
+                self._waste("prefill_padding", size * bucket - live)
+                self._log_dispatch(
+                    "prefill", tokens=live, rows=size, wall=0.0,
+                    prefill_tokens=live,
+                )
+                flight.record(
+                    "prefill",
+                    bucket=bucket,
+                    batch=size,
+                    warm=True,
+                    reused_tokens=int(sum(r for _, _, r in group)),
+                    wall_ms=round((time.perf_counter() - started) * 1e3, 3),
+                    queue_depth=len(self._pending),
+                    flops=dispatch_flops,
+                )
+                self._prefill_inflight.append({
+                    "group": [(index, request) for index, request, _ in group],
+                    "sampled": sampled,
+                    "lps": lps,
+                    "tops": tops,
+                    "reused": {index: reused for index, _, reused in group},
+                    "started": started,
+                    "batch": batch_id,
+                })
 
     def _prefill_long(
         self, index: int, request: GenerationRequest, reused: int
@@ -3725,9 +3795,27 @@ class DecodeEngine:
         tail_bucket = _bucket(total - position, self.prefill_buckets)
         # shift the tail window left so offset + bucket == total
         windows.append((max(0, total - tail_bucket), tail_bucket))
+        with self._prefill_phase("long", tail_bucket, [index]) as batch_id:
+            self._dispatch_long(
+                index, request, reused, windows, batch_id
+            )
+
+    def _dispatch_long(
+        self,
+        index: int,
+        request: GenerationRequest,
+        reused: int,
+        windows: List[Tuple[int, int]],
+        batch_id: int,
+    ) -> None:
+        prompt = request.prompt_tokens
+        total = len(prompt)
         started = time.perf_counter()
         temperature, top_k, top_p, seeds = self._sampling_arrays([request])
         bias_ids, bias_vals = self._bias_rows([request])
+        self._stamp_dispatch(
+            [request], batch_id, windows[-1][1]
+        )
         for step, (offset, bucket) in enumerate(windows):
             chunk = prompt[offset:offset + bucket]
             tokens = np.zeros((1, bucket), dtype=np.int32)
@@ -3763,6 +3851,7 @@ class DecodeEngine:
                     "tops": tops,
                     "reused": {index: reused} if reused else {},
                     "started": started,
+                    "batch": batch_id,
                 })
         self.stats["warm_prefill_calls" if reused else "prefill_calls"] += 1
         self.stats["prefill_time"] += time.perf_counter() - started
@@ -3785,6 +3874,32 @@ class DecodeEngine:
             self._log_dispatch(
                 "prefill", tokens=taught, rows=1,
                 wall=0.0, prefill_tokens=taught,
+            )
+
+    @contextlib.contextmanager
+    def _prefill_phase(self, kind: str, bucket: int, slot_ids: List[int]):
+        """One prefill dispatch (batch build and jit call) as a child
+        span of ``engine.admit``; yields the batch's number, which its
+        requests' ring records carry too (``runtime/journey.py``)."""
+        self._prefill_batches += 1
+        with self._phase(
+            "engine.prefill_dispatch",
+            kind=kind, bucket=bucket, rows=len(slot_ids),
+            batch=self._prefill_batches,
+            slots=":".join(map(str, slot_ids)),
+        ):
+            yield self._prefill_batches
+
+    @staticmethod
+    def _stamp_dispatch(
+        requests: List[GenerationRequest], batch_id: int, bucket: int
+    ) -> None:
+        """The instant just before a prefill's jit call, on every
+        request it carries (a finished leg's ``dispatched``)."""
+        now = time.perf_counter()
+        for request in requests:
+            request._dispatched = (  # type: ignore[attr-defined]
+                now, batch_id, bucket
             )
 
     def _check_mirror_layout(self) -> None:
@@ -3816,70 +3931,73 @@ class DecodeEngine:
                 is_ready = getattr(sampled, "is_ready", None)
                 if is_ready is not None and not is_ready():
                     return
-            wait_started = time.perf_counter()
-            firsts = np.asarray(sampled)
-            lps = np.asarray(record["lps"])
-            tops = record.get("tops")
-            if tops is not None:
-                tops = (np.asarray(tops[0]), np.asarray(tops[1]))
-            self.stats["prefill_time"] += time.perf_counter() - wait_started
-            age = time.perf_counter() - record["started"]
-            if self.tracer.enabled:
-                now_pc = time.perf_counter()
-                for index, request in record["group"]:
-                    submit_ts = getattr(
-                        request, "_submit_ts", record["started"]
-                    )
-                    submit_wall = getattr(
-                        request, "_submit_wall", time.time()
-                    )
-                    dispatch_wall = submit_wall + (
-                        record["started"] - submit_ts
-                    )
-                    tid = request.trace_id or ""
-                    self.tracer.event(
-                        "engine.admission",
-                        max(0.0, record["started"] - submit_ts),
-                        trace_id=tid,
-                        start_wall=submit_wall,
-                        slot=index,
-                    )
-                    reused = record.get("reused", {}).get(index, 0)
-                    self.tracer.event(
-                        "engine.prefill",
-                        max(0.0, now_pc - record["started"]),
-                        trace_id=tid,
-                        start_wall=dispatch_wall,
-                        slot=index,
-                        prompt_tokens=len(request.prompt_tokens),
-                        # cache-served prefix vs actually-prefilled span:
-                        # the acceptance evidence that a prefix-cache hit
-                        # shrank this request's prefill work
-                        reused_tokens=reused,
-                        prefill_tokens=len(request.prompt_tokens) - reused,
-                        ttft_ms=round((now_pc - submit_ts) * 1e3, 3),
-                    )
-            for row, (index, request) in enumerate(record["group"]):
-                self.slots[index].prefilling = False
-                if request.replay_tokens:
-                    # resurrected session: fast-forward through the
-                    # accepted history instead of emitting the prefill's
-                    # own sample (see _resume_replay)
-                    self._resume_replay(
-                        index, request,
-                        reused=record.get("reused", {}).get(index, 0),
-                    )
-                else:
-                    self._emit_token(
-                        index, int(firsts[row]), float(lps[row]),
-                        top=(
-                            (tops[0][row].tolist(), tops[1][row].tolist())
-                            if tops is not None else None
-                        ),
-                    )
-                request._prefill_time = age  # type: ignore[attr-defined]
+            with self._phase(
+                "engine.harvest_prefills",
+                rows=len(record["group"]), batch=record["batch"],
+            ):
+                self._harvest_record(record)
             self._prefill_inflight.pop(0)
             block = False  # only the oldest is worth waiting for
+
+    def _harvest_record(self, record: Dict[str, Any]) -> None:
+        """The oldest prefill dispatch: wait for its first tokens, then
+        hand each to its slot."""
+        wait_started = time.perf_counter()
+        firsts = np.asarray(record["sampled"])
+        lps = np.asarray(record["lps"])
+        tops = record.get("tops")
+        if tops is not None:
+            tops = (np.asarray(tops[0]), np.asarray(tops[1]))
+        self.stats["prefill_time"] += time.perf_counter() - wait_started
+        age = time.perf_counter() - record["started"]
+        if self.tracer.enabled:
+            now_pc = time.perf_counter()
+            for index, request in record["group"]:
+                submit_ts = getattr(
+                    request, "_submit_ts", record["started"]
+                )
+                tid = request.trace_id or ""
+                self.tracer.event(
+                    "engine.admission",
+                    max(0.0, record["started"] - submit_ts),
+                    trace_id=tid,
+                    start=submit_ts,
+                    slot=index,
+                )
+                reused = record.get("reused", {}).get(index, 0)
+                self.tracer.event(
+                    "engine.prefill",
+                    max(0.0, now_pc - record["started"]),
+                    trace_id=tid,
+                    start=record["started"],
+                    slot=index,
+                    prompt_tokens=len(request.prompt_tokens),
+                    # cache-served prefix vs actually-prefilled span:
+                    # the acceptance evidence that a prefix-cache hit
+                    # shrank this request's prefill work
+                    reused_tokens=reused,
+                    prefill_tokens=len(request.prompt_tokens) - reused,
+                    ttft_ms=round((now_pc - submit_ts) * 1e3, 3),
+                )
+        for row, (index, request) in enumerate(record["group"]):
+            self.slots[index].prefilling = False
+            if request.replay_tokens:
+                # resurrected session: fast-forward through the
+                # accepted history instead of emitting the prefill's
+                # own sample (see _resume_replay)
+                self._resume_replay(
+                    index, request,
+                    reused=record.get("reused", {}).get(index, 0),
+                )
+            else:
+                self._emit_token(
+                    index, int(firsts[row]), float(lps[row]),
+                    top=(
+                        (tops[0][row].tolist(), tops[1][row].tolist())
+                        if tops is not None else None
+                    ),
+                )
+            request._prefill_time = age  # type: ignore[attr-defined]
 
     def _resume_replay(
         self, index: int, request: GenerationRequest, reused: int = 0
@@ -3948,7 +4066,7 @@ class DecodeEngine:
         fn = self._counts_restore_fn
         if fn is None:
 
-            @jax.jit
+            @_program("counts_restore")
             def run(counts, index, row):
                 return (
                     jax.lax.dynamic_update_slice(
@@ -3991,6 +4109,30 @@ class DecodeEngine:
             if slot.length + 1 + 2 * budget >= self.max_seq_len:
                 return False
         return True
+
+    def _dispatch_chunk(
+        self,
+        carry: Optional[Dict[str, Any]] = None,
+        plan_next: Optional[Dict[str, Any]] = None,
+    ) -> Dict[str, Any]:
+        """The run loop's one way to a decode dispatch (a chunk, or with
+        ``plan_next`` a chained mixed step), under its phase span."""
+        with self._phase("engine.dispatch_decode", "dispatch_time") as span:
+            if plan_next is not None:
+                record = self._dispatch_mixed(
+                    carry=carry, plan_next=plan_next
+                )
+            else:
+                record = self._dispatch_decode(carry=carry)
+            span.set(
+                steps=record["steps"],
+                active=(
+                    record["n_decode"] if record.get("mixed")
+                    else int(record["active"].sum())
+                ),
+                chained=int(carry is not None),
+            )
+        return record
 
     def _dispatch_decode(
         self, carry: Optional[Dict[str, Any]] = None
@@ -4528,12 +4670,35 @@ class DecodeEngine:
         }
 
     def _process_mixed(self, inflight: Dict[str, Any]) -> None:
-        sampled = np.asarray(inflight["sampled"])
-        lps = np.asarray(inflight["lps"])
-        tops = inflight.get("out_tops")
-        if tops is not None:
-            tops = (np.asarray(tops[0]), np.asarray(tops[1]))
-        ended = time.perf_counter()
+        with self._phase("engine.wait_chunk", steps=1):
+            sampled = np.asarray(inflight["sampled"])
+            lps = np.asarray(inflight["lps"])
+            tops = inflight.get("out_tops")
+            if tops is not None:
+                tops = (np.asarray(tops[0]), np.asarray(tops[1]))
+        with self._emit_span():
+            self._account_mixed(
+                inflight, sampled, lps, tops, time.perf_counter()
+            )
+        # chaos: deterministic engine-thread death AFTER this step's
+        # tokens reached their callers (same point as _process_decode)
+        faults.check("engine_thread_crash")
+
+    @contextlib.contextmanager
+    def _emit_span(self):
+        """A harvested chunk's bookkeeping and its tokens' hand-over as
+        ONE ``engine.emit`` span (tokens as an attribute, no span a
+        token), timed into ``emit_time`` at the same boundaries."""
+        with self._phase("engine.emit", "emit_time") as span:
+            before = self.stats["tokens_generated"]
+            yield
+            span.set(tokens=self.stats["tokens_generated"] - before)
+
+    def _account_mixed(
+        self, inflight: Dict[str, Any], sampled, lps, tops, ended: float
+    ) -> None:
+        """A harvested mixed step's bookkeeping and its tokens' hand-over
+        (the body of its ``engine.emit`` span)."""
         wall = ended - inflight["started"]
         decode_mask = inflight["decode_mask"]
         completes = inflight["completes"]
@@ -4595,7 +4760,7 @@ class DecodeEngine:
             self.tracer.event(
                 "engine.decode_chunk",
                 wall,
-                start_wall=time.time() - wall,
+                start=inflight["started"],
                 trace_ids=inflight["trace_ids"],
                 steps=1,
                 active=n_decode,
@@ -4634,7 +4799,6 @@ class DecodeEngine:
                 chained=1 if inflight.get("chained") else 0,
                 gap_ms=round(gap_ms, 3),
             )
-        emit_started = time.perf_counter()
         stale_rows = 0
         for i, slot in enumerate(self.slots):
             if slot.epoch != inflight["epochs"][i] or not slot.active:
@@ -4672,7 +4836,7 @@ class DecodeEngine:
                         "engine.prefill",
                         max(0.0, ended - slot.prefill_t0),
                         trace_id=request.trace_id or "",
-                        start_wall=time.time() - (ended - slot.prefill_t0),
+                        start=slot.prefill_t0,
                         slot=i,
                         prompt_tokens=len(request.prompt_tokens),
                         reused_tokens=slot.prefill_reused,
@@ -4696,10 +4860,6 @@ class DecodeEngine:
         if stale_rows:
             self._waste("carry_invalidated", stale_rows)
             self._note_carry_invalidation("stale_row", stale_rows)
-        self.stats["emit_time"] += time.perf_counter() - emit_started
-        # chaos: deterministic engine-thread death AFTER this step's
-        # tokens reached their callers (same point as _process_decode)
-        faults.check("engine_thread_crash")
 
     def _process_decode(self, inflight: Dict[str, Any]) -> None:
         if inflight.get("mixed"):
@@ -4708,16 +4868,34 @@ class DecodeEngine:
         # step's gap should not span the decode chunks in between
         self._last_mixed_end = 0.0
         steps = inflight["steps"]
-        active = inflight["active"]
-        spec = self.spec
         # plain: [S, steps]; spec: [S, steps, B] with a True-prefix
         # valid mask per (slot, step) — 1..B tokens per step
-        out_host = np.asarray(inflight["out_tokens"])
-        lps_host = np.asarray(inflight["out_lps"])
-        tops = inflight.get("out_tops")
-        if tops is not None:  # ([S, steps, K] ids, [S, steps, K] lps)
-            tops = (np.asarray(tops[0]), np.asarray(tops[1]))
-        ended = time.perf_counter()
+        with self._phase("engine.wait_chunk", steps=steps):
+            out_host = np.asarray(inflight["out_tokens"])
+            lps_host = np.asarray(inflight["out_lps"])
+            tops = inflight.get("out_tops")
+            if tops is not None:  # ([S, steps, K] ids, [S, steps, K] lps)
+                tops = (np.asarray(tops[0]), np.asarray(tops[1]))
+        with self._emit_span():
+            self._account_decode(
+                inflight, out_host, lps_host, tops, time.perf_counter()
+            )
+        # chaos: deterministic engine-thread death AFTER this chunk's
+        # tokens reached their callers — the supervisor must resurrect
+        # every live session from exactly this point, and the resumed
+        # continuation must match the uncrashed oracle bitwise
+        faults.check("engine_thread_crash")
+
+    def _account_decode(
+        self, inflight: Dict[str, Any], out_host, lps_host, tops,
+        ended: float,
+    ) -> None:
+        """A harvested chunk's bookkeeping and the per-token loop (the
+        body of its ``engine.emit`` span: ONE span a chunk, none a
+        token)."""
+        steps = inflight["steps"]
+        active = inflight["active"]
+        spec = self.spec
         wall = ended - inflight["started"]
         n_active = int(active.sum())
         drafted_total = accepted_total = 0
@@ -4800,7 +4978,7 @@ class DecodeEngine:
             self.tracer.event(
                 "engine.decode_chunk",
                 wall,
-                start_wall=time.time() - wall,
+                start=inflight["started"],
                 trace_ids=inflight["trace_ids"],
                 steps=steps,
                 active=n_active,
@@ -4844,7 +5022,6 @@ class DecodeEngine:
                 ),
                 **kv_fields,
             )
-        emit_started = time.perf_counter()
         for i, slot in enumerate(self.slots):
             if not active[i]:
                 continue
@@ -4888,12 +5065,6 @@ class DecodeEngine:
                         if tops is not None else None
                     ),
                 )
-        self.stats["emit_time"] += time.perf_counter() - emit_started
-        # chaos: deterministic engine-thread death AFTER this chunk's
-        # tokens reached their callers — the supervisor must resurrect
-        # every live session from exactly this point, and the resumed
-        # continuation must match the uncrashed oracle bitwise
-        faults.check("engine_thread_crash")
 
     def _emit_token(
         self, index: int, token: int, logprob: float = 0.0, top=None
@@ -4902,14 +5073,10 @@ class DecodeEngine:
         slot = self.slots[index]
         request = slot.request
         if not slot.generated:
-            # first token: TTFT anchor for the request span / flight log
-            # (wall twin anchors the journey ledger's prefill→decode
-            # stage boundary on the cross-replica timeline)
+            # first token: TTFT anchor for the request span, the flight
+            # log and the journey's prefill→decode boundary
             request._first_token_ts = (  # type: ignore[attr-defined]
                 time.perf_counter()
-            )
-            request._first_token_wall = (  # type: ignore[attr-defined]
-                time.time()
             )
         slot.generated.append(token)
         slot.logprobs.append(logprob)
@@ -4992,13 +5159,12 @@ class DecodeEngine:
         if self.slo is not None:
             self.slo.tick()
         if self.tracer.enabled or flight.RECORDER.enabled:
-            submit_wall = getattr(request, "_submit_wall", time.time())
             tid = request.trace_id or ""
             self.tracer.event(
                 "engine.request",
                 max(0.0, now_pc - submit_ts),
                 trace_id=tid,
-                start_wall=submit_wall,
+                start=submit_ts,
                 slot=index,
                 prompt_tokens=len(request.prompt_tokens),
                 tokens=len(generated),
@@ -5035,13 +5201,13 @@ class DecodeEngine:
                     # disaggregation prefill leg: serialize the chain
                     # just published, while the slot's refs still pin
                     # it (no eviction race inside this finish)
-                    export_start = time.time()
+                    export_start = time.perf_counter()
                     result.kv_handoff = self._export_handoff(
                         slot, request
                     )
                     if result.kv_handoff is not None:
                         request._jt_export = (  # type: ignore[attr-defined]
-                            export_start, time.time()
+                            export_start, time.perf_counter()
                         )
             if request.session_id is not None:
                 slot.session_id = request.session_id
@@ -5083,7 +5249,7 @@ class DecodeEngine:
             slot.history = None
             slot.length = 0
         self._emit_journey(
-            index, request, reason, len(generated), ttft_ms
+            index, request, reason, len(generated), ttft_ms, now_pc
         )
         if request.future is not None:
             self._post_future(request, result)
@@ -5095,18 +5261,44 @@ class DecodeEngine:
         reason: str,
         tokens: int,
         ttft_ms: float,
+        now: float,
     ) -> None:
-        """Assemble this leg's journey stages (wall clock, tiled by
-        StageBuilder construction), feed the per-stage histograms and
-        SLO blame — always — and emit the ``journey`` flight record +
-        per-stage trace events when those sinks are enabled."""
-        now_wall = time.time()
-        submit_wall = getattr(request, "_submit_wall", now_wall)
-        admit_wall = getattr(request, "_admit_wall", submit_wall)
-        first_wall = getattr(request, "_first_token_wall", None)
+        """Assemble this leg's journey from the request's instants (all
+        on ``time.perf_counter()``; ``now`` is the finish): keep them in
+        the process-wide ring, tile the stages (wall time, since legs of
+        other replicas join them; StageBuilder clamps), feed the
+        per-stage histograms and SLO blame — always — and emit the
+        ``journey`` flight record + per-stage trace events when those
+        sinks are enabled."""
+        submit = getattr(request, "_submit_ts", now)
+        assigned = getattr(request, "_assigned_ts", submit)
+        dispatched, batch, bucket = getattr(
+            request, "_dispatched", (None, None, None)
+        )
+        first = getattr(request, "_first_token_ts", None)
         import_window = getattr(request, "_jt_import", None)
         export_window = getattr(request, "_jt_export", None)
         admit_class = getattr(request, "_jt_admit_class", None) or "cold"
+        journey_ledger.record_leg(
+            trace_id=request.trace_id or "",
+            session_id=request.session_id or "",
+            slot=index,
+            submit=submit,
+            assigned=assigned,
+            dispatched=dispatched,
+            batch=batch,
+            bucket=bucket,
+            first_token=first,
+            finish=now,
+            admit_class=admit_class,
+            prompt_tokens=len(request.prompt_tokens),
+            tokens=tokens,
+            finish_reason=reason,
+        )
+        wall = tracing.wall
+        submit_wall, admit_wall = wall(submit), wall(assigned)
+        first_wall = wall(first) if first is not None else None
+        now_wall = wall(now)
         builder = journey_ledger.StageBuilder()
         if request.handoff_export_ts is not None:
             # decode leg of a disaggregated request: the prefill
@@ -5118,11 +5310,12 @@ class DecodeEngine:
         builder.add(
             "queue",
             submit_wall,
-            import_window[0] if import_window else admit_wall,
+            wall(import_window[0]) if import_window else admit_wall,
         )
         if import_window:
             builder.add(
-                "handoff_import", import_window[0], import_window[1]
+                "handoff_import",
+                wall(import_window[0]), wall(import_window[1]),
             )
         builder.add(
             "admit", admit_wall, admit_wall, admit_class=admit_class
@@ -5132,7 +5325,7 @@ class DecodeEngine:
             admit_wall,
             first_wall if first_wall is not None else admit_wall,
         )
-        decode_end = export_window[0] if export_window else now_wall
+        decode_end = wall(export_window[0]) if export_window else now_wall
         builder.add(
             "decode",
             first_wall if first_wall is not None else admit_wall,
@@ -5140,17 +5333,17 @@ class DecodeEngine:
         )
         if export_window:
             builder.add(
-                "handoff_export", export_window[0], export_window[1]
+                "handoff_export",
+                wall(export_window[0]), wall(export_window[1]),
             )
         builder.add(
             "finish",
-            export_window[1] if export_window else decode_end,
+            wall(export_window[1]) if export_window else decode_end,
             now_wall,
             finish_reason=reason,
         )
         stages = builder.stages
         journey_ledger.observe_stages(stages)
-        first_ref = first_wall
         if self.slo is not None and self.slo.targets_s:
             ttft_target = self.slo.targets_s.get("ttft")
             if (
@@ -5159,7 +5352,7 @@ class DecodeEngine:
             ):
                 self.slo.attribute(
                     "ttft",
-                    journey_ledger.blame_stage(stages, first_ref, "ttft"),
+                    journey_ledger.blame_stage(stages, first_wall, "ttft"),
                 )
             tpot_target = self.slo.targets_s.get("tpot")
             if (
@@ -5170,7 +5363,7 @@ class DecodeEngine:
             ):
                 self.slo.attribute(
                     "tpot",
-                    journey_ledger.blame_stage(stages, first_ref, "tpot"),
+                    journey_ledger.blame_stage(stages, first_wall, "tpot"),
                 )
         if not (self.tracer.enabled or flight.RECORDER.enabled):
             return
@@ -5195,7 +5388,7 @@ class DecodeEngine:
                     f"engine.journey.{stage['stage']}",
                     stage["end"] - stage["start"],
                     trace_id=tid,
-                    start_wall=stage["start"],
+                    start=stage["start"] - tracing.CLOCK_OFFSET,
                     slot=index,
                     replica=replica,
                 )

@@ -576,6 +576,7 @@ def _embed(config: LlamaConfig, params, tokens: jnp.ndarray) -> jnp.ndarray:
     return x
 
 
+@jax.named_scope("mlp")
 def _mlp_block(
     config: LlamaConfig,
     normed: jnp.ndarray,
@@ -610,6 +611,7 @@ def _mlp_block(
     return out, jnp.zeros((), dtype=jnp.float32)
 
 
+@jax.named_scope("head")
 def _logits(config: LlamaConfig, params, x):
     if config.tie_embeddings:
         head = params["embedding"].T.astype(x.dtype)
@@ -637,6 +639,7 @@ def _flash_path(config, q, mesh):
     return flash_ok, tp_sharded
 
 
+@jax.named_scope("attention")
 def _prefill_attn(config, q, k, v, mask, mesh=None, window=None):
     """Flash kernel on TPU for long MXU-aligned prompts, XLA einsum path
     otherwise (CPU tests, short prompts, odd head dims, softcap/window
@@ -693,6 +696,7 @@ def _decode_flash_path(config, q, kc, mesh):
     return flash_ok, tp_sharded
 
 
+@jax.named_scope("attention")
 def _decode_attn(config, q, kc, vc, lengths, mesh=None, window=None):
     """Decode attention: length-aware Pallas kernel on TPU for long
     allocated caches (HBM traffic ∝ live context — the XLA einsum
@@ -725,6 +729,7 @@ def _decode_attn(config, q, kc, vc, lengths, mesh=None, window=None):
     return decode_attention(q, kc, vc, lengths, **family)
 
 
+@jax.named_scope("attention")
 def _decode_attn_quant(config, q, kc, ks, vc, vs, lengths, mesh=None,
                        window=None):
     """Int8-cache twin of :func:`_decode_attn`."""
@@ -751,6 +756,7 @@ def _decode_attn_quant(config, q, kc, ks, vc, vs, lengths, mesh=None,
     return decode_attention_quant(q, kc, ks, vc, vs, lengths, **family)
 
 
+@jax.named_scope("attention")
 def _prefill_attn_quant(config, q, k_q, k_s, v_q, v_s, lengths, mesh=None,
                         window=None):
     """Quantized-cold-prefill twin of :func:`_prefill_attn`: int8 flash
@@ -830,6 +836,7 @@ def _mixed_block_q(width: int) -> int:
     return 8 if width % 8 == 0 else width
 
 
+@jax.named_scope("attention")
 def _paged_attn(config, q, k_pool, v_pool, tables, starts, totals, *,
                 window, kernel, mesh=None, q_lens=None):
     """Paged attention dispatch, ONE seam for all the ragged cases:
@@ -908,6 +915,7 @@ def _paged_attn(config, q, k_pool, v_pool, tables, starts, totals, *,
     )
 
 
+@jax.named_scope("attention")
 def _paged_attn_quant(config, q, k_pool, k_scale, v_pool, v_scale, tables,
                       starts, totals, *, window, kernel, mesh=None,
                       q_lens=None):
@@ -1086,21 +1094,23 @@ def prefill(
         widths = [(0, 0), (0, 0), (0, pad)] + [(0, 0)] * (array.ndim - 3)
         return jnp.pad(array, widths)
 
+    @jax.named_scope("cache_write")
+    def write(name, new):
+        return cache[name].at[:, slot_ids].set(
+            pad_rows(new).astype(cache[name].dtype)
+        )
+
     out = dict(cache)
     if quantized:
         # grouped (k, v, k_scale, v_scale) — the ordering every
         # quantized scan in this module uses
         new_k, new_v, k_scale, v_scale = layer_kv
-        out["k_scale"] = cache["k_scale"].at[:, slot_ids].set(pad_rows(k_scale))
-        out["v_scale"] = cache["v_scale"].at[:, slot_ids].set(pad_rows(v_scale))
+        out["k_scale"] = write("k_scale", k_scale)
+        out["v_scale"] = write("v_scale", v_scale)
     else:
         new_k, new_v = layer_kv
-    out["k"] = cache["k"].at[:, slot_ids].set(
-        pad_rows(new_k).astype(cache["k"].dtype)
-    )
-    out["v"] = cache["v"].at[:, slot_ids].set(
-        pad_rows(new_v).astype(cache["v"].dtype)
-    )
+    out["k"] = write("k", new_k)
+    out["v"] = write("v", new_v)
     return out, _last_token_logits(config, params, x, lengths)
 
 
@@ -1140,6 +1150,7 @@ def prefill_at_offset(
     # scanned outputs get a fresh stacked buffer, i.e. a second copy of
     # the whole cache (3.5 GB of temp at 32 slots × 2048 on Qwen-2.5-7B:
     # the v5e compiler refuses the program for HBM).
+    @jax.named_scope("cache_write")
     def write_rows(stacked, layer_index, new):
         # stacked: [L, S, max_len, ...]; new: [B, T, ...] — write each
         # row's suffix window at its offset (rank-agnostic: value leaves
@@ -1179,20 +1190,22 @@ def prefill_at_offset(
             ks = write_rows(ks, index, k_s)
             vc = write_rows(vc, index, v_q)
             vs = write_rows(vs, index, v_s)
-            attn = chunk_attention_quant(
-                q, kc[index, slot_ids], ks[index, slot_ids],
-                vc[index, slot_ids], vs[index, slot_ids], offsets, totals,
-                softcap=softcap, window=win, scale=scale,
-            )
+            with jax.named_scope("attention"):
+                attn = chunk_attention_quant(
+                    q, kc[index, slot_ids], ks[index, slot_ids],
+                    vc[index, slot_ids], vs[index, slot_ids], offsets, totals,
+                    softcap=softcap, window=win, scale=scale,
+                )
             kv = (kc, vc, ks, vs)
         else:
             kc, vc = kv
             kc = write_rows(kc, index, k)
             vc = write_rows(vc, index, v)
-            attn = chunk_attention(
-                q, kc[index, slot_ids], vc[index, slot_ids], offsets,
-                totals, softcap=softcap, window=win, scale=scale,
-            )
+            with jax.named_scope("attention"):
+                attn = chunk_attention(
+                    q, kc[index, slot_ids], vc[index, slot_ids], offsets,
+                    totals, softcap=softcap, window=win, scale=scale,
+                )
             kv = (kc, vc)
         attn = qeinsum(
             "btd,dh->bth", attn.reshape(batch, seq, config.num_heads * hd), wo
@@ -1259,6 +1272,7 @@ def paged_prefill(
     valid = jnp.arange(seq)[None, :] < lengths[:, None]
     zeros = jnp.zeros((batch,), jnp.int32)
 
+    @jax.named_scope("cache_write")
     def write(pool, new, scale=False):
         return _constrain_kv_shard(
             jax.vmap(
@@ -1314,6 +1328,7 @@ def paged_prefill_at_offset(
     windows = layer_windows(config)
     quantized = "k_scale" in cache
 
+    @jax.named_scope("cache_write")
     def write(pool, new, scale=False):
         return _constrain_kv_shard(
             paged_write_rows(pool, new, block_tables, offsets, mask),
@@ -1415,6 +1430,7 @@ def paged_decode_step(
     windows = layer_windows(config)
     quantized = "k_scale" in cache
 
+    @jax.named_scope("cache_write")
     def write(pool, new, scale=False):
         return _constrain_kv_shard(
             paged_write_rows(
@@ -1514,6 +1530,7 @@ def decode_step(
     windows = layer_windows(config)
     quantized = "k_scale" in cache
 
+    @jax.named_scope("cache_write")
     def write(c, pos, new, enabled):
         return c.at[pos].set(jnp.where(enabled, new, c[pos]))
 
@@ -1638,6 +1655,7 @@ def verify_step(
     # a clamped dynamic_update_slice would silently overwrite live rows
     write_pos = jnp.where(wmask, positions, max_len)
 
+    @jax.named_scope("cache_write")
     def write_rows(kc, new):
         return kc.at[rows, write_pos].set(
             new.astype(kc.dtype), mode="drop"
@@ -1665,18 +1683,20 @@ def verify_step(
             ks = write_rows(ks, k_s)
             vc = write_rows(vc, v_q)
             vs = write_rows(vs, v_s)
-            attn = chunk_attention_quant(
-                q, kc, ks, vc, vs, offsets, totals,
-                softcap=softcap, window=win, scale=scale,
-            )
+            with jax.named_scope("attention"):
+                attn = chunk_attention_quant(
+                    q, kc, ks, vc, vs, offsets, totals,
+                    softcap=softcap, window=win, scale=scale,
+                )
             kv_out = (kc, vc, ks, vs)
         else:
             kc = write_rows(kc, k)
             vc = write_rows(vc, v)
-            attn = chunk_attention(
-                q, kc, vc, offsets, totals,
-                softcap=softcap, window=win, scale=scale,
-            )
+            with jax.named_scope("attention"):
+                attn = chunk_attention(
+                    q, kc, vc, offsets, totals,
+                    softcap=softcap, window=win, scale=scale,
+                )
             kv_out = (kc, vc)
         attn = qeinsum(
             "sbd,dh->sbh", attn.reshape(slots, seq, config.num_heads * hd), wo
@@ -1742,6 +1762,7 @@ def paged_verify_step(
     windows = layer_windows(config)
     quantized = "k_scale" in cache
 
+    @jax.named_scope("cache_write")
     def write(pool, new, scale=False):
         return _constrain_kv_shard(
             paged_write_rows(pool, new, block_tables, offsets, wmask),
@@ -1859,6 +1880,7 @@ def paged_mixed_step(
     windows = layer_windows(config)
     quantized = "k_scale" in cache
 
+    @jax.named_scope("cache_write")
     def write(pool, new, scale=False):
         return _constrain_kv_shard(
             paged_write_rows(pool, new, block_tables, offsets, wmask),

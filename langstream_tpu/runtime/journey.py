@@ -29,6 +29,15 @@ where the previous ended), so the stages tile the leg's wall clock by
 construction; across legs the export stamp chains the prefill leg's
 end to the decode leg's transit start.
 
+Clock: inside a process every instant is ``time.perf_counter()``; the
+stages of a flight record are wall time (that plus
+``tracing.CLOCK_OFFSET``, taken once a process) because legs of other
+replicas join them. The last ``LEGS.maxlen`` finished legs also stay in
+memory with their raw instants (:func:`record_leg`,
+:func:`finished_legs`), so a reader in the process — the benchmark's
+``spans.py``, a debug endpoint — can lay them on a profiler trace after
+the engine that served them is gone.
+
 Blame semantics: a TTFT violation is attributed to the stage with the
 largest overlap of the window [journey start, first token]; a TPOT
 violation to the largest overlap of [first token, journey end]. An
@@ -41,7 +50,10 @@ from __future__ import annotations
 import json
 import os
 import threading
-from typing import Any, Dict, Iterable, List, Mapping, Optional, Sequence, Tuple
+from collections import deque
+from typing import (
+    Any, Deque, Dict, Iterable, List, Mapping, Optional, Sequence, Tuple,
+)
 
 from langstream_tpu.api.metrics import Histogram
 
@@ -91,6 +103,30 @@ def observe_stages(stages: Iterable[Mapping[str, Any]]) -> None:
             histogram.observe(
                 max(0.0, float(stage["end"]) - float(stage["start"]))
             )
+
+
+# finished legs, process-wide and bounded (it outlives the engine). One
+# dict a finished request with its instants on ``time.perf_counter()``:
+# ``submit``, ``assigned`` (the slot), ``dispatched`` (just before its
+# prefill's jit call; None on the mixed path, which has no dispatch of
+# its own), ``first_token`` (harvested; None if it never came),
+# ``finish``; and ``batch`` (its ``engine.prefill_dispatch`` span's
+# number), ``bucket``, ``admit_class``, ``prompt_tokens``, ``tokens``,
+# ``finish_reason``, ``slot``, ``session_id``, ``trace_id``.
+LEGS: Deque[Dict[str, Any]] = deque(maxlen=4096)
+
+
+def record_leg(**leg: Any) -> None:
+    LEGS.append(leg)  # one append a finished request; the deque drops the oldest
+
+
+def finished_legs() -> List[Dict[str, Any]]:
+    """A copy, oldest first (safe beside an engine thread that appends)."""
+    while True:
+        try:
+            return list(LEGS)
+        except RuntimeError:  # mutated during iteration: take it again
+            continue
 
 
 class StageBuilder:

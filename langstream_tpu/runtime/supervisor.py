@@ -228,7 +228,6 @@ class EngineSupervisor:
                 return  # stale hook (already superseded) or terminal
             self.state = "rebuilding"
             started = time.perf_counter()
-            started_wall = time.time()
             now = time.monotonic()
             while (
                 self._restart_times
@@ -356,7 +355,7 @@ class EngineSupervisor:
         self.tracer.event(
             "engine.recovery",
             recovery_s,
-            start_wall=started_wall,
+            start=started,
             reason=reason,
             sessions=resurrected,
             replayed=replayed,

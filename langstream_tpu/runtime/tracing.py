@@ -5,17 +5,24 @@ ABSENT" — observability there is Prometheus counters + a periodic stats
 dump, AgentRunner.java:598-618). This is a net-new subsystem of the TPU
 build, in two layers:
 
-1. **Span tracing** (any platform): lightweight in-process spans with
-   wall-time + monotonic durations, parent links, and per-record
-   attributes, kept in a bounded ring buffer per :class:`Tracer` and
-   exportable as Chrome ``trace_event`` JSON (load in
-   ``chrome://tracing`` / Perfetto). The runner wraps each hot-loop
+1. **Span tracing** (any platform): lightweight in-process spans on
+   ONE clock (``time.perf_counter``; wall time is that plus
+   :data:`CLOCK_OFFSET`, taken once a process), parent links, and
+   per-record attributes, kept in a bounded ring buffer per
+   :class:`Tracer` and exportable as Chrome ``trace_event`` JSON (load
+   in ``chrome://tracing`` / Perfetto). The runner wraps each hot-loop
    phase (read / process / write / commit) in spans when given a tracer.
 
 2. **XLA device profiling** (TPU/CPU): :func:`profile` wraps
    ``jax.profiler.trace`` to capture an xplane trace of everything the
    devices ran — the tool for MXU utilization and HBM stalls. Written
    to a TensorBoard-compatible directory.
+
+:func:`phase` joins the two: one call site a boundary enters a
+``jax.profiler.TraceAnnotation`` (so any profiler session shows the span
+on the host's lane beside the device's ops, on the profiler's clock) and,
+when the component's tracer is enabled, records the same interval as a
+:class:`Span`.
 
 Overhead when disabled: a single ``if`` per call site (module-level
 no-op tracer).
@@ -42,6 +49,17 @@ from typing import Any, Deque, Dict, Iterator, List, Optional, Sequence
 TRACE_ID_HEADER = "langstream-trace-id"
 
 
+# the process's one clock is ``time.perf_counter()``; where an instant
+# has to leave the process (the cross-replica journey ledger, the Chrome
+# dump's ``ts``) its wall time is that plus this offset, taken once
+CLOCK_OFFSET = time.time() - time.perf_counter()
+
+
+def wall(instant: float) -> float:
+    """Wall time (epoch seconds) of a ``time.perf_counter()`` instant."""
+    return instant + CLOCK_OFFSET
+
+
 def new_trace_id() -> str:
     return uuid.uuid4().hex
 
@@ -54,8 +72,8 @@ def trace_dir() -> str:
 
 class Span:
     __slots__ = (
-        "name", "trace_id", "span_id", "parent_id", "start_wall",
-        "start_ns", "duration_ns", "attributes",
+        "name", "trace_id", "span_id", "parent_id", "start_ns",
+        "duration_ns", "attributes",
     )
 
     def __init__(
@@ -70,10 +88,13 @@ class Span:
         self.trace_id = trace_id
         self.span_id = span_id
         self.parent_id = parent_id
-        self.start_wall = time.time()
         self.start_ns = time.perf_counter_ns()
         self.duration_ns: Optional[int] = None
         self.attributes: Dict[str, Any] = attributes or {}
+
+    @property
+    def start_wall(self) -> float:
+        return wall(self.start_ns / 1e9)
 
     def to_dict(self) -> Dict[str, Any]:
         return {
@@ -143,13 +164,13 @@ class Tracer:
         name: str,
         duration_s: float,
         *,
+        start: float,
         trace_id: str = "",
-        start_wall: Optional[float] = None,
         **attributes: Any,
     ) -> None:
-        """Record an already-completed span from measurements taken
-        elsewhere (the engine thread times its phases itself — a
-        contextmanager around multi-iteration device work would lie)."""
+        """Record an already-completed span from instants taken
+        elsewhere (a request's stages are known only once it finishes).
+        ``start`` is its ``time.perf_counter()`` instant."""
         if not self.enabled:
             return
         span = Span(
@@ -159,8 +180,7 @@ class Tracer:
             parent_id=None,
             attributes=attributes,
         )
-        if start_wall is not None:
-            span.start_wall = start_wall
+        span.start_ns = int(start * 1e9)
         span.duration_ns = max(0, int(duration_s * 1e9))
         with self._lock:
             self._spans.append(span)
@@ -218,6 +238,56 @@ class _NoopSpan:
 
 _NOOP_SPAN = _NoopSpan()
 
+_ANNOTATION = None  # jax.profiler.TraceAnnotation, resolved on first use
+
+
+class phase:
+    """One boundary, one call site::
+
+        with tracing.phase("engine.emit", self.tracer, chunk=7) as span:
+            ...
+            span.set(tokens=emitted)
+
+    Enters a ``jax.profiler.TraceAnnotation`` — with no profiler session
+    that is the annotation's check of one flag — and, when ``tracer`` is
+    enabled, records the same interval as a :class:`Span` with its true
+    start. Attributes reach both; the profiler cuts a value at a comma,
+    so lists are joined with ``:``. ``set`` adds what is known only at
+    the end."""
+
+    __slots__ = ("_annotation", "_recording", "_span")
+
+    def __init__(
+        self, name: str, tracer: "Tracer" = None, **attributes: Any
+    ) -> None:
+        global _ANNOTATION
+        if _ANNOTATION is None:
+            import jax
+
+            _ANNOTATION = jax.profiler.TraceAnnotation
+        self._annotation = _ANNOTATION(name, **attributes)
+        self._recording = (
+            tracer.span(name, **attributes)
+            if tracer is not None and tracer.enabled else None
+        )
+        self._span = None
+
+    def __enter__(self) -> "phase":
+        self._annotation.__enter__()
+        if self._recording is not None:
+            self._span = self._recording.__enter__()
+        return self
+
+    def set(self, **attributes: Any) -> None:
+        self._annotation.set_metadata(**attributes)
+        if self._span is not None:
+            self._span.attributes.update(attributes)
+
+    def __exit__(self, *exc_info: Any) -> None:
+        if self._recording is not None:
+            self._recording.__exit__(*exc_info)
+        self._annotation.__exit__(*exc_info)
+
 
 class NoopTracer(Tracer):
     """Shared do-nothing tracer (the default when tracing is off)."""
@@ -261,7 +331,7 @@ def get_tracer(component: str) -> Tracer:
 def dump_all(directory: Optional[str] = None) -> List[str]:
     """Write one Chrome-trace JSON per registered tracer into the trace
     dir; file names carry the component and pid so a multi-pod run's
-    dumps never collide and ``trace_merge`` can label them."""
+    dumps never collide and ``langstream-tpu trace`` can label them."""
     directory = directory or trace_dir()
     if not directory:
         return []
@@ -282,7 +352,7 @@ def dump_all(directory: Optional[str] = None) -> List[str]:
 
 
 # ---------------------------------------------------------------------- #
-# cross-pod trace merging (tools/trace_merge.py + `langstream-tpu trace`)
+# cross-pod trace merging (`langstream-tpu trace`)
 # ---------------------------------------------------------------------- #
 def collect_trace_files(paths: Sequence[str]) -> List[str]:
     """Expand dirs into their ``*.json`` dumps; keep files as given."""
@@ -347,9 +417,8 @@ def run_trace_merge(
     trace_id: Optional[str] = None,
     list_ids: bool = False,
 ) -> List[str]:
-    """The one CLI body behind ``langstream-tpu trace`` AND
-    ``tools/trace_merge.py``: expand paths, list ids or write the merged
-    timeline, return the status lines to print."""
+    """The CLI body behind ``langstream-tpu trace``: expand paths, list
+    ids or write the merged timeline, return the status lines to print."""
     files = collect_trace_files(paths)
     if not files:
         raise SystemExit(f"no trace dumps under {list(paths)}")

@@ -228,3 +228,63 @@ def test_ragged_paged_sharded_compiles(tp_mesh):
         )
 
     assert "tpu_custom_call" in _compiled_text(fn, *shapes)
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8kv"])
+def test_every_pallas_call_carries_its_name(one_chip, quant):
+    """A kernel's ``name`` reaches the lowered text (``kernel_name``) and
+    with it the HLO instruction the device trace shows, so a reduction can
+    find a kernel by name (ISSUE 26). Lowered for the described chip: the
+    CPU cannot lower a Mosaic kernel."""
+    heads, kv_heads = QWEN
+    s = functools.partial(_spec, sharding=one_chip)
+    suffix = "_int8kv" if quant else ""
+    kv_dtype = jnp.int8 if quant else jnp.bfloat16
+
+    def scaled(fn, n):
+        def call(*args):
+            extra = args[n:]
+            kw = {"k_scale": extra[0], "v_scale": extra[1]} if extra else {}
+            return fn(*args[:n], **kw)
+        return call
+
+    prefill = [
+        s((2, 1024, heads, D), jnp.bfloat16),
+        s((2, 1024, kv_heads, D), kv_dtype),
+        s((2, 1024, kv_heads, D), kv_dtype),
+        s((2,), jnp.int32),
+    ] + ([s((2, 1024, kv_heads), jnp.float32)] * 2 if quant else [])
+    cases = {
+        "flash_prefill": (
+            lambda q, k, v, lengths, *sc: flash_prefill_attention(
+                q, k, v, lengths=lengths,
+                **({"k_scale": sc[0], "v_scale": sc[1]} if sc else {}),
+            ),
+            prefill,
+        ),
+        "flash_decode": (
+            scaled(flash_decode_attention, 4),
+            _decode_shapes(s, heads, kv_heads, quant),
+        ),
+        "ragged_paged_attention": (
+            scaled(ragged_paged_attention, 6),
+            _paged_shapes(s, 4, 64, heads, kv_heads, quant),
+        ),
+    }
+    if not quant:
+        cases["ragged_q_paged_attention"] = (
+            lambda q, kp, vp, tables, starts, lengths, qoffs:
+                ragged_q_paged_attention(
+                    q, kp, vp, tables, starts, lengths, qoffs, max_q_len=64
+                ),
+            [
+                s((32 * 64, heads, D), jnp.bfloat16),
+                s((POOL, BLOCK, kv_heads, D), jnp.bfloat16),
+                s((POOL, BLOCK, kv_heads, D), jnp.bfloat16),
+                s((32, 2048 // BLOCK), jnp.int32),
+                s((32,), jnp.int32), s((32,), jnp.int32), s((32,), jnp.int32),
+            ],
+        )
+    for name, (fn, shapes) in cases.items():
+        text = jax.jit(fn).lower(*shapes).as_text()
+        assert f'kernel_name = "{name}{suffix}"' in text, name

@@ -75,6 +75,12 @@ def _sample_exposition() -> str:
         # fleet layer (ISSUE 11): the admission backlog the router's
         # least-queue fallback and the autoscaler's pressure math read
         "jax_engine_queue_depth": 2.0,
+        # the engine thread's seconds by phase (ISSUE 26), summed at the
+        # phase spans' own boundaries
+        'jax_engine_loop_seconds_total{phase="idle"}': 12.5,
+        'jax_engine_loop_seconds_total{phase="admit"}': 0.75,
+        'jax_engine_loop_seconds_total{phase="dispatch"}': 0.25,
+        'jax_engine_loop_seconds_total{phase="emit"}': 3.5,
         # request-journey ledger (ISSUE 20): per-stage SLO blame —
         # violating requests counted by their dominant journey stage
         'jax_engine_slo_blame_total{kind="ttft",stage="queue"}': 2.0,
@@ -150,6 +156,9 @@ def _sample_exposition() -> str:
             "jax_engine_queue_depth":
                 "requests waiting for a decode slot (submit queue +"
                 " admission pending); the fleet routing/scaling signal",
+            "jax_engine_loop_seconds_total":
+                "engine thread seconds by loop phase (idle, admit,"
+                " dispatch, emit), at the phase spans' boundaries",
             "jax_engine_slo_blame_total":
                 "SLO-violating requests by kind (ttft/tpot) and the"
                 " journey stage that dominated the violated window",
@@ -444,9 +453,9 @@ def test_trace_merge_cli_tool(tmp_path):
     ])
     out_path = tmp_path / "merged.json"
     result = subprocess.run(
-        [sys.executable, os.path.join(REPO, "tools", "trace_merge.py"),
+        [sys.executable, "-m", "langstream_tpu", "trace",
          str(tmp_path), "-o", str(out_path)],
-        capture_output=True, text=True, timeout=120,
+        capture_output=True, text=True, timeout=120, cwd=REPO,
     )
     assert result.returncode == 0, result.stderr
     with open(out_path) as handle:
@@ -455,9 +464,9 @@ def test_trace_merge_cli_tool(tmp_path):
         e.get("name") == "sink.write" for e in merged["traceEvents"]
     )
     listing = subprocess.run(
-        [sys.executable, os.path.join(REPO, "tools", "trace_merge.py"),
+        [sys.executable, "-m", "langstream_tpu", "trace",
          str(tmp_path), "--list"],
-        capture_output=True, text=True, timeout=120,
+        capture_output=True, text=True, timeout=120, cwd=REPO,
     )
     assert "ccc" in listing.stdout and "runner" in listing.stdout
 

@@ -1,0 +1,303 @@
+"""The engine loop's phase spans, its program names and the ring of
+finished legs (ISSUE 26): one clock, one call site a boundary, readable
+from inside the process. CPU backend, tiny engine."""
+
+import asyncio
+import glob
+import os
+import time
+
+import jax
+import pytest
+
+from langstream_tpu.providers.jax_local import engine as engine_lib
+from langstream_tpu.providers.jax_local.engine import (
+    DecodeEngine,
+    SamplingParams,
+)
+from langstream_tpu.providers.jax_local.model import LlamaConfig, init_params
+from langstream_tpu.runtime import journey, tracing
+
+# docs/observability.md §1: the spans that tile the engine thread, and the
+# one child
+TILING = {
+    "engine.wait_for_work", "engine.linger", "engine.admit",
+    "engine.dispatch_decode", "engine.wait_chunk", "engine.emit",
+    "engine.harvest_prefills",
+}
+CHILDREN = {"engine.prefill_dispatch"}
+
+
+def make_engine(**options):
+    config = LlamaConfig.tiny(max_seq_len=128)
+    options.setdefault("max_slots", 4)
+    options.setdefault("prefill_buckets", [16, 32])
+    return DecodeEngine(
+        config, init_params(config), max_seq_len=128, **options
+    )
+
+
+@pytest.fixture(scope="module")
+def engine():
+    engine = make_engine(decode_chunk=16)
+    engine.start()
+    yield engine
+    engine.stop()
+
+
+def generate(engine, prompts, tokens, **kwargs):
+    async def main():
+        return await asyncio.gather(*[
+            engine.generate(
+                prompt, SamplingParams(max_new_tokens=tokens), **kwargs
+            )
+            for prompt in prompts
+        ])
+
+    return asyncio.run(main())
+
+
+def profiled(engine, tmp_path, tag, tokens):
+    """Serve two requests of ``tokens`` output tokens under the profiler;
+    returns the ``engine.*`` host events by thread line, and how many
+    decode chunks the engine harvested meanwhile."""
+    log_dir = str(tmp_path / tag)
+    chunks = engine.stats["decode_chunks"]
+    with tracing.profile(log_dir):
+        generate(engine, [[1, 2, 3, 4], [9, 8, 7]], tokens)
+    chunks = engine.stats["decode_chunks"] - chunks
+    path = glob.glob(
+        os.path.join(log_dir, "**", "*.xplane.pb"), recursive=True
+    )[0]
+    data = jax.profiler.ProfileData.from_file(path)
+    lines = []
+    for plane in data.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            events = [
+                (e.name, e.start_ns, e.start_ns + e.duration_ns,
+                 {str(k): v for k, v in e.stats})
+                for e in line.events if e.name.startswith("engine.")
+            ]
+            if events:
+                lines.append(sorted(events, key=lambda e: (e[1], -e[2])))
+    return lines, chunks
+
+
+# ------------------------------------------------------------------ #
+# (1) under jax.profiler the host plane holds the spans, tiled
+# ------------------------------------------------------------------ #
+def test_profiler_holds_tiled_phase_spans(engine, tmp_path):
+    generate(engine, [[1, 2, 3, 4], [9, 8, 7]], 8)  # compile every shape
+    generate(engine, [[1, 2, 3, 4], [9, 8, 7]], 64)
+    short, short_chunks = profiled(engine, tmp_path, "short", 8)
+    long, long_chunks = profiled(engine, tmp_path, "long", 64)
+    for lines in (short, long):
+        # one thread makes them all: the engine's
+        assert len(lines) == 1
+        events = lines[0]
+        assert {name for name, *_ in events} <= TILING | CHILDREN
+        top = [e for e in events if e[0] in TILING]
+        # the tiling spans do not overlap; a child lies inside an admit
+        for before, after in zip(top, top[1:]):
+            assert before[2] <= after[1], (before, after)
+        admits = [e for e in top if e[0] == "engine.admit"]
+        for child in (e for e in events if e[0] in CHILDREN):
+            assert any(a[1] <= child[1] and child[2] <= a[2] for a in admits)
+            assert {"kind", "bucket", "rows", "batch", "slots"} <= set(child[3])
+        # between the first and the last, they cover the thread's time
+        covered = sum(end - start for _, start, end, _ in top)
+        assert covered >= 0.95 * (top[-1][2] - top[0][1])
+    names = lambda lines: [e[0] for e in lines[0]]  # noqa: E731
+    for lines, chunks in ((short, short_chunks), (long, long_chunks)):
+        # ONE emit span a harvested chunk, whatever it emitted, with the
+        # tokens as an attribute; one dispatch span a chunk, with steps
+        assert names(lines).count("engine.emit") == chunks
+        assert names(lines).count("engine.wait_chunk") == chunks
+        assert names(lines).count("engine.dispatch_decode") == chunks
+    emitted = sum(
+        int(e[3]["tokens"]) for e in long[0] if e[0] == "engine.emit"
+    )
+    assert emitted == 2 * 63  # the first tokens came from the harvest
+    assert all(
+        int(e[3]["steps"]) > 0 for e in long[0]
+        if e[0] == "engine.dispatch_decode"
+    )
+    # the count follows the chunks, not the tokens: eight times the tokens
+    # bring a few spans a chunk more and none a token
+    busy = lambda lines: sum(  # noqa: E731
+        1 for name in names(lines) if name != "engine.wait_for_work"
+    )
+    assert long_chunks > short_chunks
+    a_chunk = (busy(long) - busy(short)) / (long_chunks - short_chunks)
+    assert a_chunk <= 5
+    assert busy(long) < 2 * 56
+
+
+# ------------------------------------------------------------------ #
+# (2) no session, no trace directory: nothing is stored
+# ------------------------------------------------------------------ #
+def test_a_phase_stores_nothing_with_tracing_off(engine, monkeypatch):
+    monkeypatch.delenv("LANGSTREAM_TRACE_DIR", raising=False)
+    assert tracing.get_tracer("engine") is tracing.NOOP
+    assert engine.tracer is tracing.NOOP
+    generate(engine, [[5, 6, 7]], 4)
+    with tracing.phase("engine.test", tracing.NOOP, rows=3) as span:
+        span.set(tokens=7)
+    with tracing.phase("engine.test"):
+        pass
+    assert tracing.NOOP.spans() == []
+
+
+def test_a_phase_records_a_span_with_its_true_start():
+    tracer = tracing.Tracer("test")
+    before = time.perf_counter_ns()
+    with tracing.phase("engine.test", tracer, rows=3) as span:
+        inside = time.perf_counter_ns()
+        time.sleep(0.002)
+        span.set(tokens=7)
+    (recorded,) = tracer._spans
+    assert before <= recorded.start_ns <= inside
+    assert recorded.duration_ns >= 2e6
+    assert recorded.attributes == {"rows": 3, "tokens": 7}
+    # wall time is the one clock plus the process's one offset
+    assert recorded.start_wall == pytest.approx(
+        recorded.start_ns / 1e9 + tracing.CLOCK_OFFSET
+    )
+    assert abs(tracing.wall(time.perf_counter()) - time.time()) < 0.5
+    # an event from instants taken elsewhere sits where they say
+    tracer.event("engine.request", 0.25, start=10.0)
+    assert tracer._spans[-1].start_ns == 10_000_000_000
+    assert tracer._spans[-1].duration_ns == 250_000_000
+
+
+# ------------------------------------------------------------------ #
+# (3) the ring of finished legs
+# ------------------------------------------------------------------ #
+INSTANTS = ("submit", "assigned", "dispatched", "first_token", "finish")
+
+
+def test_one_ring_record_a_finished_request_on_perf_counter(engine):
+    journey.LEGS.clear()
+    before = time.perf_counter()
+    generate(
+        engine, [[1, 2, 3], [4, 5, 6, 7, 8], list(range(1, 20))], 6,
+        session_id=None, trace_id="ring-test",
+    )
+    after = time.perf_counter()
+    legs = journey.finished_legs()
+    assert len(legs) == 3
+    for leg in legs:
+        stamps = [leg[key] for key in INSTANTS]
+        assert stamps == sorted(stamps)
+        assert before <= stamps[0] and stamps[-1] <= after
+        assert leg["trace_id"] == "ring-test"
+        assert leg["admit_class"] == "cold" and leg["finish_reason"] == "length"
+        assert leg["tokens"] == 6 and leg["batch"] is not None
+        assert leg["bucket"] in (16, 32)
+    assert sorted(leg["prompt_tokens"] for leg in legs) == [3, 5, 19]
+    # requests of one prefill dispatch share its batch number
+    by_bucket = {}
+    for leg in legs:
+        by_bucket.setdefault(leg["bucket"], set()).add(leg["batch"])
+    assert len(by_bucket[32]) == 1
+
+
+def test_the_ring_is_bounded_and_outlives_the_engine():
+    assert journey.LEGS.maxlen == 4096
+    journey.LEGS.clear()
+    small = make_engine(max_slots=2, decode_chunk=4)
+    small.start()
+    generate(small, [[1, 2, 3]], 4)
+    small.stop()
+    del small
+    assert len(journey.finished_legs()) == 1
+    for index in range(journey.LEGS.maxlen + 10):
+        journey.record_leg(submit=float(index))
+    legs = journey.finished_legs()
+    assert len(legs) == journey.LEGS.maxlen and legs[0]["submit"] == 10.0
+    journey.LEGS.clear()
+
+
+def test_cancelled_shed_and_resumed_requests_do_not_break_the_ring():
+    journey.LEGS.clear()
+    slow = make_engine(max_slots=1, decode_chunk=2, queue_timeout_s=0.05)
+    slow.start()
+
+    async def main():
+        handle = []
+        long_one = asyncio.ensure_future(slow.generate(
+            [1, 2, 3], SamplingParams(max_new_tokens=100), handle=handle,
+        ))
+        # behind the one slot: shed at its admission deadline, no leg
+        shed = asyncio.ensure_future(slow.generate(
+            [4, 5, 6], SamplingParams(max_new_tokens=4),
+        ))
+        with pytest.raises(Exception) as caught:
+            await shed
+        assert "queue" in str(caught.value).lower()
+        handle[0].cancel()
+        cancelled = await long_one
+        assert cancelled.finish_reason == "cancelled"
+        # a resurrected session: its prompt carries the replayed tokens
+        replay = [11, 12, 13]
+        resumed = await slow.generate(
+            [1, 2, 3] + replay[:-1], SamplingParams(max_new_tokens=6),
+            request_fields={"replay_tokens": replay, "prompt_len": 3},
+        )
+        assert resumed.tokens[:3] == replay and len(resumed.tokens) == 6
+
+    asyncio.run(main())
+    slow.stop()
+    legs = journey.finished_legs()
+    assert [leg["finish_reason"] for leg in legs] == ["cancelled", "length"]
+    for leg in legs:
+        stamps = [leg[key] for key in INSTANTS if leg[key] is not None]
+        assert stamps == sorted(stamps) and len(stamps) >= 4
+    journey.LEGS.clear()
+
+
+# ------------------------------------------------------------------ #
+# (4) every program lowers under its own stable name
+# ------------------------------------------------------------------ #
+@pytest.mark.parametrize("layout", ["dense", "paged"])
+def test_every_program_lowers_to_its_own_module_name(layout):
+    options = (
+        {"kv_layout": "paged", "kv_block_size": 16} if layout == "paged"
+        else {}
+    )
+    engine = make_engine(decode_chunk=4, **options)
+    assert engine.paged == (layout == "paged")
+    names = {}
+    for fn, avals in engine._variant_jobs():
+        with engine.mesh:
+            text = fn.lower(*engine._variant_args(avals)).as_text()
+        module = text.split("module @", 1)[1].split(" ", 1)[0]
+        assert module == "jit_" + fn.__name__
+        names.setdefault(fn.__name__, fn)
+    others = [engine._get_counts_restore()]
+    if layout == "paged":
+        others += [
+            engine._get_block_copy(), engine._get_handoff_export(4),
+            engine._get_handoff_import(4), engine._get_mixed(16),
+            engine._get_spec_decode(2),
+        ]
+    else:
+        others += [engine._get_copy_prefix(16), engine._get_spec_decode(2)]
+    for fn in others:
+        names.setdefault(fn.__name__, fn)
+    # distinct programs never share a name, and every name tells the
+    # layout it serves
+    assert len({id(fn) for fn in names.values()}) == len(names)
+    assert set(names) <= set(engine_lib.PROGRAM_KINDS)
+    for name in names:
+        assert name == "counts_restore" or name.endswith("_" + layout)
+    expected = {
+        kind for kind in engine_lib.PROGRAM_KINDS
+        if kind.endswith("_" + layout) and not kind.startswith("init_cache")
+    } | {"counts_restore"}
+    assert set(names) == expected
+    # a second engine gives the same names: they are stable
+    again = make_engine(decode_chunk=4, **options)
+    assert {fn.__name__ for fn, _ in again._variant_jobs()} <= set(names)
