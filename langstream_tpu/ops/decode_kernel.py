@@ -14,6 +14,16 @@ LIVE context instead of the allocated buffer:
 - grid = (slot, T/block_k); the kv-block axis is innermost/sequential,
   so VMEM scratch carries the online-softmax state across a slot's
   blocks (same recurrence as ``ops/flash_attention.py``).
+- the cache operands are the engine's STACKED leaves
+  ``[L, S, T, KVH, D]``, seen as ``[L*S, T, KVH, D]`` (a bitcast), and
+  the layer is a scalar-prefetch operand that the K/V (and scale)
+  index maps turn into the row ``layer*S + slot``: the kernel streams
+  its layer's slab where it lies. A custom call's operand is a
+  materialised buffer, so handing it ``stack[layer]`` made XLA copy
+  the slab out of the stack every layer of every step — and handing it
+  a leaf in another layout than the one it lies in makes XLA re-lay-out
+  the whole stack instead, which is why the scales go in position-last
+  and a single kv head goes in squeezed (see the operands below).
 - per-slot lengths ride as a scalar-prefetch operand: they are
   available to the BlockSpec index maps BEFORE the pipeline issues
   each block's DMA. Blocks past a slot's last live block clamp their
@@ -78,11 +88,13 @@ def _first_valid_block(length, window, block_k: int):
 def _decode_kernel_body(
     lens_ref,   # SMEM scalar-prefetch [S] int32
     win_ref,    # SMEM scalar-prefetch [1] int32 (0 = full attention)
+    layer_ref,  # SMEM scalar-prefetch [1] int32 — index maps only
     q_ref,      # VMEM [1, H, D]
-    k_ref,      # VMEM [1, block_k, KVH, D] (cache dtype, or int8)
+    k_ref,      # VMEM [1, block_k, KVH, D] (cache dtype, or int8);
+                # [1, block_k, D] when KVH is 1
     v_ref,      # VMEM [1, block_k, KVH, D]
-    ks_ref,     # VMEM [1, block_k, KVH] f32, or None (bf16 cache)
-    vs_ref,     # VMEM [1, block_k, KVH] f32, or None
+    ks_ref,     # VMEM [1, KVH, block_k] f32, or None (bf16 cache)
+    vs_ref,     # VMEM [1, KVH, block_k] f32, or None
     out_ref,    # VMEM [1, H, D]
     m_scratch,  # VMEM [H, 128] f32 — running row max
     l_scratch,  # VMEM [H, 128] f32 — running row sum
@@ -130,17 +142,17 @@ def _decode_kernel_body(
         # int8 values are exactly representable in bf16, so the MXU
         # sees the same numbers the XLA quant path computes
         k = k_ref[0].astype(q.dtype) if quantized else k_ref[0]
-        ks = ks_ref[0] if quantized else None  # [block_k, KVH] f32
+        ks = ks_ref[0] if quantized else None  # [KVH, block_k] f32
         parts = []
         for h in range(kv_heads):
             q_h = q[h * group:(h + 1) * group]  # [G, D]
-            k_h = k[:, h, :]                    # [block_k, D]
+            k_h = k if k.ndim == 2 else k[:, h, :]  # [block_k, D]
             s_h = jax.lax.dot_general(
                 q_h, k_h, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             ) * scale
             if quantized:
-                s_h = s_h * ks[:, h][None, :]
+                s_h = s_h * ks[h:h + 1, :]
             parts.append(s_h)
         s = jnp.concatenate(parts, axis=0)  # [H, block_k]
         if softcap is not None:
@@ -167,17 +179,17 @@ def _decode_kernel_body(
 
         if quantized:
             v = v_ref[0].astype(jnp.float32)  # f32 contraction, as XLA
-            vs = vs_ref[0]                    # [block_k, KVH] f32
+            vs = vs_ref[0]                    # [KVH, block_k] f32
         else:
             v = v_ref[0]
         pv_parts = []
         for h in range(kv_heads):
             p_h = p[h * group:(h + 1) * group]  # [G, block_k] f32
             if quantized:
-                p_h = p_h * vs[:, h][None, :]
+                p_h = p_h * vs[h:h + 1, :]
             else:
                 p_h = p_h.astype(v.dtype)
-            v_h = v[:, h, :]                    # [block_k, D]
+            v_h = v if v.ndim == 2 else v[:, h, :]  # [block_k, D]
             pv_parts.append(
                 jax.lax.dot_general(
                     p_h, v_h, (((1,), (0,)), ((), ())),
@@ -195,30 +207,31 @@ def _decode_kernel_body(
         out_ref[0] = (acc_scratch[:] / l_safe).astype(out_ref.dtype)
 
 
-def _decode_kernel(lens_ref, win_ref, q_ref, k_ref, v_ref, out_ref,
-                   m_scratch, l_scratch, acc_scratch, **kw):
+def _decode_kernel(lens_ref, win_ref, layer_ref, q_ref, k_ref, v_ref,
+                   out_ref, m_scratch, l_scratch, acc_scratch, **kw):
     _decode_kernel_body(
-        lens_ref, win_ref, q_ref, k_ref, v_ref, None, None, out_ref,
-        m_scratch, l_scratch, acc_scratch, **kw,
+        lens_ref, win_ref, layer_ref, q_ref, k_ref, v_ref, None, None,
+        out_ref, m_scratch, l_scratch, acc_scratch, **kw,
     )
 
 
-def _decode_kernel_quant(lens_ref, win_ref, q_ref, k_ref, v_ref, ks_ref,
-                         vs_ref, out_ref, m_scratch, l_scratch,
+def _decode_kernel_quant(lens_ref, win_ref, layer_ref, q_ref, k_ref, v_ref,
+                         ks_ref, vs_ref, out_ref, m_scratch, l_scratch,
                          acc_scratch, **kw):
     _decode_kernel_body(
-        lens_ref, win_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, out_ref,
-        m_scratch, l_scratch, acc_scratch, **kw,
+        lens_ref, win_ref, layer_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
+        out_ref, m_scratch, l_scratch, acc_scratch, **kw,
     )
 
 
 def flash_decode_attention(
     q: jnp.ndarray,        # [S, H, D] — one new token per slot
-    k_cache: jnp.ndarray,  # [S, T, KVH, D] (bf16; int8 with scales)
+    k_cache: jnp.ndarray,  # [L, S, T, KVH, D] stacked (bf16; int8 with scales)
     v_cache: jnp.ndarray,
     lengths: jnp.ndarray,  # [S] valid rows incl. the new token
+    layer: jnp.ndarray,    # scalar int32 — which slab of the stack
     *,
-    k_scale: Optional[jnp.ndarray] = None,  # [S, T, KVH] — int8 mode
+    k_scale: Optional[jnp.ndarray] = None,  # [L, S, T, KVH] — int8 mode
     v_scale: Optional[jnp.ndarray] = None,
     softcap: Optional[float] = None,
     window: Optional[jnp.ndarray] = None,  # scalar; None/0 = full attn
@@ -226,15 +239,19 @@ def flash_decode_attention(
     block_k: Optional[int] = None,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """Drop-in for :func:`langstream_tpu.ops.attention.decode_attention`
-    (or ``decode_attention_quant`` when scales are given) with HBM
-    traffic ∝ live context. Caller gates via :func:`use_flash_decode`;
-    shapes must satisfy D % 128 == 0, H % KVH == 0, and ``block_k`` must
-    divide T (``pick_block_k``). A sliding ``window`` (Gemma-2) bounds
-    the traffic by the window instead — blocks below it clamp-elide
-    their DMA just like dead blocks past the length."""
+    """:func:`langstream_tpu.ops.attention.decode_attention` (or
+    ``decode_attention_quant`` when scales are given) over slab
+    ``layer`` of the stacked cache, with HBM traffic ∝ live context and
+    no copy of the slab: the layer is one more scalar in the index maps.
+    Caller gates via :func:`use_flash_decode`; shapes must satisfy
+    D % 128 == 0, H % KVH == 0, and ``block_k`` must divide T
+    (``pick_block_k``). A sliding ``window`` (Gemma-2) bounds the
+    traffic by the window instead — blocks below it clamp-elide their
+    DMA just like dead blocks past the length."""
     slots, heads, dim = q.shape
-    max_len, kv_heads = k_cache.shape[1], k_cache.shape[2]
+    num_layers, max_len, kv_heads = (
+        k_cache.shape[0], k_cache.shape[2], k_cache.shape[3]
+    )
     group = heads // kv_heads
     scale = dim ** -0.5 if scale is None else scale
     block_k = block_k or pick_block_k(max_len)
@@ -246,6 +263,7 @@ def flash_decode_attention(
     window_arr = jnp.reshape(
         jnp.asarray(0 if window is None else window, dtype=jnp.int32), (1,)
     )
+    layer_arr = jnp.reshape(jnp.asarray(layer, dtype=jnp.int32), (1,))
 
     def block_index(s, j, lens, win):
         # clamp dead blocks (past the length OR below the sliding
@@ -255,43 +273,71 @@ def flash_decode_attention(
         last = _num_valid_blocks(lens[s], block_k) - 1
         return jnp.clip(j, first, last)
 
-    def kv_index(s, j, lens, win):
-        return (s, block_index(s, j, lens, win), 0, 0)
+    # layer and slot merge into one leading axis: slab ``layer``'s slot
+    # s is row ``layer * S + s``, and the pipeline moves the blocks it
+    # moved when it was handed a slab. With ONE kv head (a tp shard of
+    # a 4-kv-head model on four chips) the leaf lies with T and D as
+    # its tiled axes, which is [rows, T, D]; asked for [rows, T, 1, D]
+    # the operand would be the stack re-tiled over (1, D), a copy.
+    kv_tail = (kv_heads, dim) if kv_heads > 1 else (dim,)
 
-    def scale_index(s, j, lens, win):
-        return (s, block_index(s, j, lens, win), 0)
+    def kv_index(s, j, lens, win, lyr):
+        block = block_index(s, j, lens, win)
+        return (lyr[0] * slots + s, block) + (0,) * len(kv_tail)
+
+    def scale_index(s, j, lens, win, lyr):
+        return (lyr[0] * slots + s, 0, block_index(s, j, lens, win))
+
+    def q_index(s, j, lens, win, lyr):
+        return (s, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, heads, dim), lambda s, j, lens, win: (s, 0, 0)),
-        pl.BlockSpec((1, block_k, kv_heads, dim), kv_index),
-        pl.BlockSpec((1, block_k, kv_heads, dim), kv_index),
+        pl.BlockSpec((1, heads, dim), q_index),
+        pl.BlockSpec((1, block_k) + kv_tail, kv_index),
+        pl.BlockSpec((1, block_k) + kv_tail, kv_index),
     ]
-    operands = [q, k_cache, v_cache]
+    rows = num_layers * slots
+    operands = [
+        q,
+        k_cache.reshape((rows, max_len) + kv_tail),
+        v_cache.reshape((rows, max_len) + kv_tail),
+    ]
     if quantized:
         kernel = functools.partial(
             _decode_kernel_quant, scale=scale, block_k=block_k,
             kv_heads=kv_heads, group=group, softcap=softcap,
         )
         in_specs += [
-            pl.BlockSpec((1, block_k, kv_heads), scale_index),
-            pl.BlockSpec((1, block_k, kv_heads), scale_index),
+            pl.BlockSpec((1, kv_heads, block_k), scale_index),
+            pl.BlockSpec((1, kv_heads, block_k), scale_index),
         ]
-        operands += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
-        kv_bytes = k_cache.size + v_cache.size + (k_scale.size + v_scale.size) * 4
+        # the scales go in with the position axis LAST: that is how the
+        # f32[L, S, T, KVH] leaf lies on the chip (a kv-head axis 4 wide
+        # is no lane axis, so the device's layout has T minor-most) and
+        # the swap is a bitcast there. Handed [.., T, KVH] the kernel's
+        # operand is the whole stack re-laid-out with KVH padded to 128
+        # lanes, 32 times the leaf, copied every layer of every step.
+        operands += [
+            jnp.swapaxes(leaf.astype(jnp.float32), -1, -2).reshape(
+                rows, kv_heads, max_len
+            )
+            for leaf in (k_scale, v_scale)
+        ]
+        stack_bytes = (
+            k_cache.size + v_cache.size + (k_scale.size + v_scale.size) * 4
+        )
     else:
         kernel = functools.partial(
             _decode_kernel, scale=scale, block_k=block_k,
             kv_heads=kv_heads, group=group, softcap=softcap,
         )
-        kv_bytes = (k_cache.size + v_cache.size) * k_cache.dtype.itemsize
+        stack_bytes = (k_cache.size + v_cache.size) * k_cache.dtype.itemsize
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(slots, num_blocks),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, heads, dim), lambda s, j, lens, win: (s, 0, 0)
-        ),
+        out_specs=pl.BlockSpec((1, heads, dim), q_index),
         scratch_shapes=[
             pltpu.VMEM((heads, 128), jnp.float32),
             pltpu.VMEM((heads, 128), jnp.float32),
@@ -305,37 +351,42 @@ def flash_decode_attention(
         out_shape=jax.ShapeDtypeStruct((slots, heads, dim), q.dtype),
         cost_estimate=pl.CostEstimate(
             flops=4 * slots * heads * max_len * dim,
-            # the whole point: the scheduler should expect live-context
-            # traffic, not the full buffer (estimate at half occupancy)
-            bytes_accessed=q.size * q.dtype.itemsize * 2 + kv_bytes // 2,
+            # the operands are the whole stack but a call reads ONE
+            # layer's slab, and of that the live context: the scheduler
+            # should expect that traffic (estimate at half occupancy)
+            bytes_accessed=(
+                q.size * q.dtype.itemsize * 2 + stack_bytes // num_layers // 2
+            ),
             transcendentals=slots * heads * max_len,
         ),
         interpret=interpret,
-    )(lengths, window_arr, *operands)
+    )(lengths, window_arr, layer_arr, *operands)
 
 
 def flash_decode_attention_quant(
     q: jnp.ndarray,
-    k_cache: jnp.ndarray,   # int8
-    k_scale: jnp.ndarray,   # [S, T, KVH]
+    k_cache: jnp.ndarray,   # [L, S, T, KVH, D] int8
+    k_scale: jnp.ndarray,   # [L, S, T, KVH]
     v_cache: jnp.ndarray,
     v_scale: jnp.ndarray,
     lengths: jnp.ndarray,
+    layer: jnp.ndarray,
     **kwargs,
 ) -> jnp.ndarray:
     """Argument-ordering twin of
     :func:`langstream_tpu.ops.attention.decode_attention_quant`."""
     return flash_decode_attention(
-        q, k_cache, v_cache, lengths,
+        q, k_cache, v_cache, lengths, layer,
         k_scale=k_scale, v_scale=v_scale, **kwargs,
     )
 
 
 def flash_decode_attention_sharded(
     q: jnp.ndarray,        # [S, H, D] — H sharded over ``axis_name``
-    k_cache: jnp.ndarray,  # [S, T, KVH, D] — KVH sharded
+    k_cache: jnp.ndarray,  # [L, S, T, KVH, D] — KVH sharded
     v_cache: jnp.ndarray,
     lengths: jnp.ndarray,
+    layer: jnp.ndarray,
     mesh,
     *,
     k_scale: Optional[jnp.ndarray] = None,
@@ -350,21 +401,22 @@ def flash_decode_attention_sharded(
     head shard through ``shard_map`` (a Mosaic call has no SPMD
     partitioning rule). Attention never mixes heads, so no collective;
     query and kv heads shard by the same tp factor (``validate_mesh``
-    enforces divisibility). The (traced) ``window`` scalar rides as a
-    replicated operand."""
+    enforces divisibility). The (traced) ``window`` and ``layer``
+    scalars ride as replicated operands."""
     from jax.sharding import PartitionSpec as P
 
     head_spec = P(None, axis_name, None)
-    cache_spec = P(None, None, axis_name, None)
-    scale_spec = P(None, None, axis_name)
+    cache_spec = P(None, None, None, axis_name, None)
+    scale_spec = P(None, None, None, axis_name)
     quantized = k_scale is not None
     window_arr = jnp.asarray(
         0 if window is None else window, dtype=jnp.int32
     )
+    layer_arr = jnp.asarray(layer, dtype=jnp.int32)
 
-    def local(q_l, k_l, v_l, lengths_l, window_l, *scales):
+    def local(q_l, k_l, v_l, lengths_l, layer_l, window_l, *scales):
         return flash_decode_attention(
-            q_l, k_l, v_l, lengths_l, interpret=interpret,
+            q_l, k_l, v_l, lengths_l, layer_l, interpret=interpret,
             softcap=softcap, window=window_l, scale=scale,
             **(
                 {"k_scale": scales[0], "v_scale": scales[1]}
@@ -372,8 +424,8 @@ def flash_decode_attention_sharded(
             ),
         )
 
-    in_specs = [head_spec, cache_spec, cache_spec, P(None), P()]
-    operands = [q, k_cache, v_cache, lengths, window_arr]
+    in_specs = [head_spec, cache_spec, cache_spec, P(None), P(), P()]
+    operands = [q, k_cache, v_cache, lengths, layer_arr, window_arr]
     if quantized:
         in_specs += [scale_spec, scale_spec]
         operands += [k_scale, v_scale]

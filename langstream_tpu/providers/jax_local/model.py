@@ -672,10 +672,11 @@ def _prefill_attn(config, q, k, v, mask, mesh=None, window=None):
     return prefill_attention(q, k, v, mask=mask, **family)
 
 
-def _decode_flash_path(config, q, kc, mesh):
+def _decode_flash_path(config, kc, mesh):
     """Gate + dispatch mode for the flash-decode kernel — the decode
     twin of :func:`_flash_path`, same contract: returns (use the
-    kernel?, tp shard_map?). Shape requirements bind even under the
+    kernel?, tp shard_map?). ``kc`` is the stacked leaf
+    ``[L, S, T, KVH, D]``. Shape requirements bind even under the
     ``flash_interpret`` test hook; the backend/length policy (incl. the
     ``LS_DECODE_FLASH`` A/B override) only applies outside it."""
     from langstream_tpu.ops.decode_kernel import (
@@ -683,8 +684,8 @@ def _decode_flash_path(config, q, kc, mesh):
         use_flash_decode,
     )
 
-    heads, dim = q.shape[1], q.shape[2]
-    max_len, kv_heads = kc.shape[1], kc.shape[2]
+    heads, dim = config.num_heads, kc.shape[4]
+    max_len, kv_heads = kc.shape[2], kc.shape[3]
     flash_ok = config.use_flash and (
         use_flash_decode(max_len, dim, heads, kv_heads)
         or (
@@ -697,17 +698,22 @@ def _decode_flash_path(config, q, kc, mesh):
 
 
 @jax.named_scope("attention")
-def _decode_attn(config, q, kc, vc, lengths, mesh=None, window=None):
-    """Decode attention: length-aware Pallas kernel on TPU for long
+def _decode_attn(config, q, kc, vc, lengths, layer, mesh=None, window=None):
+    """Decode attention over slab ``layer`` of the STACKED cache leaves
+    ``[L, S, T, KVH, D]``: length-aware Pallas kernel on TPU for long
     allocated caches (HBM traffic ∝ live context — the XLA einsum
-    streams the full static buffer), XLA path otherwise. Under tp the
-    kernel runs per head shard through shard_map
+    streams the full static buffer), XLA path otherwise. The kernel
+    takes the stack and the layer (a custom call's operand is a
+    materialised buffer: a slab handed to it is a slab copied); the XLA
+    path reads ``kc[layer]``, which XLA fuses into the einsums. Under
+    tp the kernel runs per head shard through shard_map
     (``flash_decode_attention_sharded``). ``window`` is this layer's
     sliding-window size (Gemma-2) and rides into the flash-decode
     kernel as a traced scalar, like softcap and scale — the kernel
     handles windowed layers itself; only non-shape-compatible configs
-    gate off to XLA (see ``_decode_flash_path``)."""
-    flash_ok, tp_sharded = _decode_flash_path(config, q, kc, mesh)
+    gate off to XLA (see ``_decode_flash_path``, whose answer
+    ``decode_step``'s cache write follows too)."""
+    flash_ok, tp_sharded = _decode_flash_path(config, kc, mesh)
     family = dict(
         softcap=config.attn_logit_softcap, window=window,
         scale=_attn_scale(config),
@@ -720,20 +726,21 @@ def _decode_attn(config, q, kc, vc, lengths, mesh=None, window=None):
 
         if tp_sharded:
             return flash_decode_attention_sharded(
-                q, kc, vc, lengths, mesh, interpret=config.flash_interpret,
-                **family,
+                q, kc, vc, lengths, layer, mesh,
+                interpret=config.flash_interpret, **family,
             )
         return flash_decode_attention(
-            q, kc, vc, lengths, interpret=config.flash_interpret, **family
+            q, kc, vc, lengths, layer, interpret=config.flash_interpret,
+            **family,
         )
-    return decode_attention(q, kc, vc, lengths, **family)
+    return decode_attention(q, kc[layer], vc[layer], lengths, **family)
 
 
 @jax.named_scope("attention")
-def _decode_attn_quant(config, q, kc, ks, vc, vs, lengths, mesh=None,
+def _decode_attn_quant(config, q, kc, ks, vc, vs, lengths, layer, mesh=None,
                        window=None):
     """Int8-cache twin of :func:`_decode_attn`."""
-    flash_ok, tp_sharded = _decode_flash_path(config, q, kc, mesh)
+    flash_ok, tp_sharded = _decode_flash_path(config, kc, mesh)
     family = dict(
         softcap=config.attn_logit_softcap, window=window,
         scale=_attn_scale(config),
@@ -746,14 +753,16 @@ def _decode_attn_quant(config, q, kc, ks, vc, vs, lengths, mesh=None,
 
         if tp_sharded:
             return flash_decode_attention_sharded(
-                q, kc, vc, lengths, mesh, k_scale=ks, v_scale=vs,
+                q, kc, vc, lengths, layer, mesh, k_scale=ks, v_scale=vs,
                 interpret=config.flash_interpret, **family,
             )
         return flash_decode_attention_quant(
-            q, kc, ks, vc, vs, lengths, interpret=config.flash_interpret,
-            **family,
+            q, kc, ks, vc, vs, lengths, layer,
+            interpret=config.flash_interpret, **family,
         )
-    return decode_attention_quant(q, kc, ks, vc, vs, lengths, **family)
+    return decode_attention_quant(
+        q, kc[layer], ks[layer], vc[layer], vs[layer], lengths, **family
+    )
 
 
 @jax.named_scope("attention")
@@ -1515,10 +1524,18 @@ def decode_step(
                                                # flash-decode kernel
 ) -> Tuple[Dict[str, jnp.ndarray], jnp.ndarray]:
     """One decode step for every slot: write the new token's KV, attend
-    over the cache, return next-token logits [S, V]. Cache is donated by
-    the engine's jit wrapper (in-place on device). ``write_mask`` protects
-    slots that are merely riding along (inactive, or logits-only reruns)
-    from having their cache row clobbered."""
+    over the cache, return next-token logits [S, V]. ``write_mask``
+    protects slots that are merely riding along (inactive, or
+    logits-only reruns) from having their cache row clobbered.
+
+    No copy of a cache slab is ever materialised: the stacked leaves
+    ride the layer scan as CARRY (a while-loop carry is updated in
+    place, where a scanned output is a fresh stacked buffer), the new
+    row is written into the stack at ``[layer, slot, position]``, and
+    the attention reads its layer's slab where it lies
+    (:func:`_decode_attn`). The caller's jit donates the cache (the
+    engine's chunk does, and carries it across its steps), so the
+    returned leaves are the argument's buffers."""
     slots = tokens.shape[0]
     hd = config.dims_per_head
     positions = (lengths - 1).astype(jnp.int32)  # [S]
@@ -1529,17 +1546,47 @@ def decode_step(
     layer_inputs = _stack_layer_params(params, config)
     windows = layer_windows(config)
     quantized = "k_scale" in cache
+    names = ("k", "v", "k_scale", "v_scale") if quantized else ("k", "v")
+    max_len = cache["k"].shape[2]
+    rows = jnp.arange(slots)
+    # where the row goes: a negative position wraps as an index would, a
+    # masked slot (riding along with lengths 0, or a logits-only rerun)
+    # and a position past the end go out of bounds, where nothing is
+    # written — the slot's rows keep every bit and nothing is read back
+    write_pos = jnp.where(
+        write_mask,
+        jnp.where(positions < 0, positions + max_len, positions),
+        max_len,
+    )
+    flash = _decode_flash_path(config, cache["k"], mesh)
+    hit = jnp.arange(max_len)[None, :] == write_pos[:, None]  # [S, T]
 
     @jax.named_scope("cache_write")
-    def write(c, pos, new, enabled):
-        return c.at[pos].set(jnp.where(enabled, new, c[pos]))
+    def write(stacked, layer, new):
+        """stacked [L, S, max_len, ...], new [S, ...] (value leaves carry
+        kv-head and head_dim axes, scale leaves the kv-head axis), in
+        place on the carry. The write follows the attention's reader.
+        The kernel streams rows of a row-major stack, and there one
+        scatter of S rows is in place. XLA's einsums want the position
+        axis minor-most, which at head dim 64 is also how the leaf lies
+        on the chip; a scatter (or a row loop of dynamic_update_slice)
+        makes XLA carry the stack row-major instead: the whole cache
+        re-laid-out at the chunk's entry and exit behind two cache-sized
+        temps, and every layer's slab sliced out and re-laid-out for the
+        einsum. An elementwise select folded into the slab's
+        dynamic_update_slice leaves the layout alone and runs in place:
+        one pass over the slab the einsums read anyway."""
+        new = new.astype(stacked.dtype)
+        if flash[0]:
+            return stacked.at[layer, rows, write_pos].set(new, mode="drop")
+        slab = jax.lax.dynamic_index_in_dim(stacked, layer, 0, keepdims=False)
+        mask = hit.reshape(hit.shape + (1,) * (slab.ndim - 2))
+        slab = jnp.where(mask, new[:, None], slab)
+        return jax.lax.dynamic_update_index_in_dim(stacked, slab, layer, 0)
 
     def layer_fn(carry, inputs):
-        x = carry
-        if quantized:
-            layer, kc, vc, ks, vs, win = inputs
-        else:
-            layer, kc, vc, win = inputs
+        x, kv = carry
+        layer, win, index = inputs
         (attn_norm, wq, wk, wv, biases, wo, post_attn, mlp_norm, post_mlp,
          mlp_weights) = layer
         normed = _norm(config, x, attn_norm)
@@ -1550,23 +1597,23 @@ def decode_step(
         q = apply_rope(q[:, None], freqs, positions[:, None])[:, 0]
         k = apply_rope(k[:, None], freqs, positions[:, None])[:, 0]
         if quantized:
+            kc, vc, ks, vs = kv
             k_q, k_s = quantize_kv(k)
             v_q, v_s = quantize_kv(v)
-            kc = jax.vmap(write)(kc, positions, k_q, write_mask)
-            ks = jax.vmap(write)(ks, positions, k_s, write_mask)
-            vc = jax.vmap(write)(vc, positions, v_q, write_mask)
-            vs = jax.vmap(write)(vs, positions, v_s, write_mask)
+            kc, ks = write(kc, index, k_q), write(ks, index, k_s)
+            vc, vs = write(vc, index, v_q), write(vs, index, v_s)
             attn = _decode_attn_quant(
-                config, q, kc, ks, vc, vs, lengths, mesh=mesh, window=win
+                config, q, kc, ks, vc, vs, lengths, index, mesh=mesh,
+                window=win,
             )
-            kv_out = (kc, vc, ks, vs)
+            kv = (kc, vc, ks, vs)
         else:
-            kc = jax.vmap(write)(kc, positions, k, write_mask)
-            vc = jax.vmap(write)(vc, positions, v, write_mask)
+            kc, vc = kv
+            kc, vc = write(kc, index, k), write(vc, index, v)
             attn = _decode_attn(
-                config, q, kc, vc, lengths, mesh=mesh, window=win
+                config, q, kc, vc, lengths, index, mesh=mesh, window=win
             )
-            kv_out = (kc, vc)
+            kv = (kc, vc)
         attn = qeinsum(
             "sd,dh->sh", attn.reshape(slots, config.num_heads * hd), wo
         )
@@ -1580,22 +1627,18 @@ def decode_step(
         if post_mlp is not None:
             delta = _norm(config, delta, post_mlp)
         x = x + delta
-        return x, kv_out
+        return (x, kv), None
 
-    if quantized:
-        xs = (layer_inputs, cache["k"], cache["v"],
-              cache["k_scale"], cache["v_scale"], windows)
-    else:
-        xs = (layer_inputs, cache["k"], cache["v"], windows)
+    xs = (layer_inputs, windows, jnp.arange(config.num_layers))
     # unroll lets XLA software-pipeline the next layer's weight loads
     # against the current layer's compute on the weights-bound decode
     # path (measured via LS_DECODE_UNROLL; 1 = plain scan)
-    x, kv_caches = jax.lax.scan(layer_fn, x, xs, unroll=_decode_unroll())
+    (x, kv_caches), _ = jax.lax.scan(
+        layer_fn, (x, tuple(cache[name] for name in names)), xs,
+        unroll=_decode_unroll(),
+    )
     out = dict(cache)
-    if quantized:
-        out["k"], out["v"], out["k_scale"], out["v_scale"] = kv_caches
-    else:
-        out["k"], out["v"] = kv_caches
+    out.update(zip(names, kv_caches))
     x = _norm(config, x, params["final_norm"])
     logits = _logits(config, params, x)
     return out, logits

@@ -648,6 +648,30 @@ def test_repo_ast_passes_run_clean():
     assert all(f.reason for f in suppressed)
 
 
+def test_no_module_defines_a_top_level_name_twice():
+    """Python binds the later ``def`` silently: a shadowed copy is dead
+    code that grep still finds and an edit to it changes nothing."""
+    import ast as ast_mod
+
+    twice = []
+    for root, _, files in os.walk(PKG):
+        for name in files:
+            if not name.endswith(".py"):
+                continue
+            path = os.path.join(root, name)
+            seen = {}
+            for node in ast_mod.parse(open(path).read()).body:
+                if isinstance(node, (ast_mod.FunctionDef, ast_mod.ClassDef,
+                                     ast_mod.AsyncFunctionDef)):
+                    if node.name in seen:
+                        twice.append(
+                            f"{path}: {node.name} at lines "
+                            f"{seen[node.name]} and {node.lineno}"
+                        )
+                    seen[node.name] = node.lineno
+    assert not twice, "\n".join(twice)
+
+
 def test_annotations_cover_the_threaded_core():
     """The annotation work is load-bearing: the core threaded classes
     each declare at least one guarded/owned attribute, so the pass has

@@ -15,6 +15,7 @@ the library.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -99,16 +100,22 @@ def test_flash_prefill_compiles(one_chip, batch, seq, quant):
     assert "tpu_custom_call" in _compiled_text(fn, *shapes)
 
 
+STACK = 3         # layers in the stacked cache the decode kernel is handed
+
+
 def _decode_shapes(s, heads, kv_heads, quant, slots=32, max_len=2048):
+    """q, the STACKED k and v, lengths, the layer (and the stacked
+    scales): the kernel reads its layer's slab out of the stack."""
     kv_dtype = jnp.int8 if quant else jnp.bfloat16
     shapes = [
         s((slots, heads, D), jnp.bfloat16),
-        s((slots, max_len, kv_heads, D), kv_dtype),
-        s((slots, max_len, kv_heads, D), kv_dtype),
+        s((STACK, slots, max_len, kv_heads, D), kv_dtype),
+        s((STACK, slots, max_len, kv_heads, D), kv_dtype),
         s((slots,), jnp.int32),
+        s((), jnp.int32),
     ]
     if quant:
-        shapes += [s((slots, max_len, kv_heads), jnp.float32)] * 2
+        shapes += [s((STACK, slots, max_len, kv_heads), jnp.float32)] * 2
     return shapes
 
 
@@ -119,11 +126,14 @@ def test_flash_decode_compiles(one_chip, quant):
         functools.partial(_spec, sharding=one_chip), heads, kv_heads, quant
     )
 
-    def fn(q, k, v, lengths, *scales):
+    def fn(q, k, v, lengths, layer, *scales):
         kw = {"k_scale": scales[0], "v_scale": scales[1]} if scales else {}
-        return flash_decode_attention(q, k, v, lengths, **kw)
+        return flash_decode_attention(q, k, v, lengths, layer, **kw)
 
-    assert "tpu_custom_call" in _compiled_text(fn, *shapes)
+    text = _compiled_text(fn, *shapes)
+    assert "tpu_custom_call" in text
+    # the kernel's operand is the stack itself: nothing slices a slab out
+    assert "dynamic-slice" not in text
 
 
 def _paged_shapes(s, batch, tq, heads, kv_heads, quant):
@@ -199,16 +209,16 @@ def test_flash_decode_sharded_compiles(tp_mesh):
     heads, kv_heads = QWEN
 
     def s(shape, dtype):
-        # head axes shard over tp; lengths replicate
-        spec = {3: P(None, "tp", None), 4: P(None, None, "tp", None)}.get(
-            len(shape), P()
-        )
+        # head axes shard over tp; lengths and the layer replicate
+        spec = {
+            3: P(None, "tp", None), 5: P(None, None, None, "tp", None),
+        }.get(len(shape), P())
         return _spec(shape, dtype, NamedSharding(tp_mesh, spec))
 
     shapes = _decode_shapes(s, heads, kv_heads, quant=False)
 
-    def fn(q, k, v, lengths):
-        return flash_decode_attention_sharded(q, k, v, lengths, tp_mesh)
+    def fn(q, k, v, lengths, layer):
+        return flash_decode_attention_sharded(q, k, v, lengths, layer, tp_mesh)
 
     assert "tpu_custom_call" in _compiled_text(fn, *shapes)
 
@@ -263,7 +273,7 @@ def test_every_pallas_call_carries_its_name(one_chip, quant):
             prefill,
         ),
         "flash_decode": (
-            scaled(flash_decode_attention, 4),
+            scaled(flash_decode_attention, 5),
             _decode_shapes(s, heads, kv_heads, quant),
         ),
         "ragged_paged_attention": (
@@ -288,3 +298,224 @@ def test_every_pallas_call_carries_its_name(one_chip, quant):
     for name, (fn, shapes) in cases.items():
         text = jax.jit(fn).lower(*shapes).as_text()
         assert f'kernel_name = "{name}{suffix}"' in text, name
+
+
+# ---------------------------------------------------------------------- #
+# The dense decode chunk: no copy of a cache slab (ISSUE 27)
+# ---------------------------------------------------------------------- #
+_INSTRUCTION = re.compile(
+    r"^\s+(?:ROOT )?%(?P<name>[\w.\-]+) = (?P<type>\(.*?\)|\S+) "
+    r"(?P<op>[a-z][a-z0-9\-]*)\((?P<rest>.*)$"
+)
+_CALLED = re.compile(r"(?:body|condition|to_apply)=%([\w.\-]+)")
+# results that name or move a buffer without making one
+_NO_BUFFER = ("parameter", "get-tuple-element", "bitcast", "tuple")
+# control flow: its results are its body's, looked at there
+_CONTROL = ("while", "call")
+
+
+def _computations(text):
+    """{computation: [(name, result type, opcode, rest of the line)]}."""
+    found, current = {}, None
+    for line in text.splitlines():
+        if line and not line[0].isspace() and line.rstrip().endswith("{"):
+            current = line.split()[1 if line.startswith("ENTRY") else 0]
+            current = current.lstrip("%")
+            found[current] = []
+            continue
+        match = _INSTRUCTION.match(line)
+        if match and current is not None:
+            found[current].append(match.group("name", "type", "op", "rest"))
+    return found
+
+
+def _loop_instructions(computations):
+    """Every instruction that MATERIALISES a result inside the program's
+    loops: those of the while bodies and of what they call, nested loops
+    included — not the insides of a fusion, which live in registers and
+    VMEM and whose only buffer is the fusion's own result."""
+    todo = [
+        called
+        for body in computations.values()
+        for _, _, op, rest in body if op == "while"
+        for called in _CALLED.findall(rest)
+    ]
+    seen = set()
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in computations:
+            continue
+        seen.add(name)
+        for _, _, op, rest in computations[name]:
+            if op in _CONTROL:
+                todo += _CALLED.findall(rest)
+    return [ins for name in seen for ins in computations[name]]
+
+
+def _dims(result_type):
+    """The dims of every array in a result type (a tuple has several)."""
+    return [
+        tuple(int(d) for d in dims.split(",") if d)
+        for dims in re.findall(r"[a-z]\w*\[([\d,]*)\]", result_type)
+    ]
+
+
+def _compile_decode_chunk(
+    monkeypatch, preset, slots, kv_quant, place, mesh=None, int8_weights=False
+):
+    """The engine's dense chunk at a preset's published widths and full
+    depth, cut to what touches the cache (a 4-step scan of decode_step +
+    greedy pick, the cache donated: engine._get_decode), compiled for the
+    described chip. ``place(tree, axes)`` gives shapes their shardings.
+    Returns (compiled, the cache's shapes)."""
+    import langstream_tpu.ops.flash_attention as flash_attention
+    from langstream_tpu.ops.rope import rope_frequencies
+    from langstream_tpu.providers.jax_local import model as model_lib
+    from langstream_tpu.providers.jax_local.quant import init_quantized_params
+
+    # the kernel's gate asks jax.devices(), which is the CPU here: steer it
+    # in the test, to what the chip would answer
+    monkeypatch.setattr(flash_attention, "on_tpu", lambda: True)
+    monkeypatch.delenv("LS_DECODE_FLASH", raising=False)
+    max_len = 2048
+    config = getattr(model_lib.LlamaConfig, preset)(max_len)
+    freqs = rope_frequencies(
+        config.dims_per_head, config.max_seq_len, config.rope_theta
+    )
+    init = init_quantized_params if int8_weights else model_lib.init_params
+    params = place(
+        jax.eval_shape(lambda: init(config, seed=0)),
+        None if int8_weights else model_lib.logical_axes(config),
+    )
+    cache = place(
+        jax.eval_shape(
+            lambda: model_lib.init_cache(config, slots, max_len, kv_quant=kv_quant)
+        ),
+        model_lib.cache_logical_axes(kv_quant),
+    )
+
+    @functools.partial(jax.jit, donate_argnums=(1,))
+    def chunk(params, cache, tokens, lengths, active):
+        def body(carry, _):
+            cache, tokens, lengths = carry
+            cache, logits = model_lib.decode_step(
+                config, params, cache, tokens, lengths, freqs, active,
+                mesh=mesh,
+            )
+            picked = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            picked = jnp.where(active, picked, 0)
+            lengths = jnp.where(active, lengths + 1, lengths)
+            return (cache, picked, lengths), picked
+
+        (cache, _, _), out = jax.lax.scan(
+            body, (cache, tokens, lengths), None, length=4
+        )
+        return cache, out.T
+
+    per_slot = [
+        place(jax.ShapeDtypeStruct((slots,), dtype), None)
+        for dtype in (jnp.int32, jnp.int32, jnp.bool_)
+    ]
+    return chunk.lower(params, cache, *per_slot).compile(), cache
+
+
+@pytest.mark.parametrize(
+    "preset,slots,kv_quant",
+    [("qwen25_7b", 32, False), ("qwen25_7b", 32, True),
+     ("qwen25_0_5b", 128, False)],
+    ids=["7b-bf16kv", "7b-int8kv", "0.5b-xla"],
+)
+def test_dense_decode_chunk_copies_no_cache_slab(
+    one_chip, monkeypatch, preset, slots, kv_quant
+):
+    """The two cells' decode chunks, 32 x 2,048 and 128 x 2,048 (and the 7B
+    with an int8 cache). Inside the step's loops nothing may produce a
+    result of a slab's or the stack's shape but the in-place writes that
+    alias the carry: neither half of the copy a scanned xs -> ys cache makes
+    (a dynamic-slice of the stack into a slab, a dynamic-update-slice of a
+    slab into the stack), nor a re-layout of either. On the 7B the kernel
+    must be handed the stack (one Pallas call a layer) and the program's
+    temp stays under one slab."""
+
+    def place(tree, _axes):
+        return jax.tree_util.tree_map(
+            lambda leaf: _spec(leaf.shape, leaf.dtype, one_chip), tree
+        )
+
+    on_kernel = preset == "qwen25_7b"  # head dim 128; the 0.5B's is 64
+    compiled, cache = _compile_decode_chunk(
+        monkeypatch, preset, slots, kv_quant, place,
+        int8_weights=on_kernel,  # as the cell runs it
+    )
+    text = compiled.as_text()
+
+    stack = tuple(cache["k"].shape)           # [L, S, T, KVH, D]
+    guarded = {stack, stack[1:], (1,) + stack[1:]}
+    if kv_quant:                              # the scale leaves too
+        guarded |= {stack[:-1], stack[1:-1], (1,) + stack[1:-1]}
+    stacks = {stack, stack[:-1]}
+    computations = _computations(text)
+    loop = _loop_instructions(computations)
+    assert any(op == "fusion" for _, _, op, _ in loop), "no loop was read"
+    types = {
+        name: result_type
+        for body in computations.values()
+        for name, result_type, _, _ in body
+    }
+    offenders = []
+    for name, result_type, op, rest in loop:
+        if op in _NO_BUFFER + _CONTROL:
+            continue
+        made = [dims for dims in _dims(result_type) if dims in guarded]
+        if not made:
+            continue
+        in_place = all(dims in stacks for dims in made) and (
+            op in ("scatter", "dynamic-update-slice")
+            or (op == "fusion" and '"aliasing_operands":{"lists":[{' in rest)
+        )
+        # an in-place write takes rows, not a slab: the other half of the
+        # copy is a dynamic-update-slice of a slab into the stack
+        operands = re.findall(r"%([\w.\-]+)", rest.split("), ")[0])
+        fed_a_slab = any(
+            dims in guarded - stacks
+            for operand in operands for dims in _dims(types.get(operand, ""))
+        )
+        if not in_place or fed_a_slab:
+            offenders.append(f"{name} = {result_type} {op}")
+    assert not offenders, offenders
+
+    kernels = text.count('custom_call_target="tpu_custom_call"')
+    if on_kernel:
+        assert kernels == 1
+        slab_bytes = int(np.prod(stack[1:])) * cache["k"].dtype.itemsize
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < slab_bytes, (temp, slab_bytes)
+    else:
+        assert kernels == 0  # XLA attention
+
+
+def test_dense_decode_chunk_tp4_copies_no_cache_slab(tp_mesh, monkeypatch):
+    """The same chunk under tp=4 (chip_smoke.py --chips 4): a shard holds
+    ONE kv head, and the leaf then lies with T and D as its tiled axes. The
+    kernel must be handed that ([rows, T, D]); asked for [rows, T, 1, D] its
+    operand is the whole local stack re-tiled, every layer of every step.
+    Any materialised copy is a temp, so the program's temp (0.4 MiB) is held
+    under one local slab (16 MiB)."""
+    from langstream_tpu.parallel.mesh import param_shardings
+
+    def place(tree, axes):
+        if axes is None:  # per-slot vectors replicate
+            return _spec(tree.shape, tree.dtype, NamedSharding(tp_mesh, P()))
+        return jax.tree_util.tree_map(
+            lambda leaf, sharding: _spec(leaf.shape, leaf.dtype, sharding),
+            tree, param_shardings(axes, tp_mesh),
+        )
+
+    compiled, cache = _compile_decode_chunk(
+        monkeypatch, "qwen25_7b", 32, False, place, mesh=tp_mesh
+    )
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
+    local = cache["k"].sharding.shard_shape(cache["k"].shape)
+    local_slab = int(np.prod(local[1:])) * cache["k"].dtype.itemsize
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < local_slab, (temp, local_slab)

@@ -1,7 +1,10 @@
 """Flash-decode kernel (interpret mode / virtual CPU mesh) against the
 plain-XLA decode attention, incl. the int8-cache twin and the tp-sharded
 wrapper. Lengths cover full, partial-block, single-token, and empty
-slots — the block-skipping index map must stay numerically invisible."""
+slots — the block-skipping index map must stay numerically invisible.
+The kernel takes the STACKED cache and a layer index (it reads its slab
+where it lies); every case hands it a three-layer stack and asks for the
+middle slab, the reference gets that slab sliced out."""
 
 import jax
 import jax.numpy as jnp
@@ -23,24 +26,30 @@ from langstream_tpu.ops.decode_kernel import (
 )
 
 
+LAYERS = 3
+LAYER = jnp.asarray(1, dtype=jnp.int32)  # the slab every case reads
+
+
 def _make_inputs(slots, max_len, heads, kv_heads, dim, seed=0):
+    """q [S, H, D] and the stacked k, v [LAYERS, S, T, KVH, D]."""
     key = jax.random.PRNGKey(seed)
     kq, kk, kv = jax.random.split(key, 3)
+    stack = (LAYERS, slots, max_len, kv_heads, dim)
     q = jax.random.normal(kq, (slots, heads, dim), dtype=jnp.float32)
-    k = jax.random.normal(kk, (slots, max_len, kv_heads, dim), dtype=jnp.float32)
-    v = jax.random.normal(kv, (slots, max_len, kv_heads, dim), dtype=jnp.float32)
+    k = jax.random.normal(kk, stack, dtype=jnp.float32)
+    v = jax.random.normal(kv, stack, dtype=jnp.float32)
     return q, k, v
 
 
-@pytest.mark.parametrize("heads,kv_heads", [(8, 8), (8, 4), (8, 2)])
+@pytest.mark.parametrize("heads,kv_heads", [(8, 8), (8, 4), (8, 2), (8, 1)])
 def test_flash_decode_matches_reference(heads, kv_heads):
     slots, max_len, dim = 4, 256, 128
     q, k, v = _make_inputs(slots, max_len, heads, kv_heads, dim)
     lengths = jnp.array([256, 100, 1, 0], dtype=jnp.int32)
 
-    ref = decode_attention(q, k, v, lengths)
+    ref = decode_attention(q, k[LAYER], v[LAYER], lengths)
     out = flash_decode_attention(
-        q, k, v, lengths, block_k=64, interpret=True
+        q, k, v, lengths, LAYER, block_k=64, interpret=True
     )
     # empty slots are garbage in both paths; compare live rows only
     for s in range(slots):
@@ -58,25 +67,30 @@ def test_flash_decode_quant_matches_reference():
     v_q, v_s = quantize_kv(v)
     lengths = jnp.array([256, 130, 7], dtype=jnp.int32)
 
-    ref = decode_attention_quant(q, k_q, k_s, v_q, v_s, lengths)
+    ref = decode_attention_quant(
+        q, k_q[LAYER], k_s[LAYER], v_q[LAYER], v_s[LAYER], lengths
+    )
     out = flash_decode_attention_quant(
-        q, k_q, k_s, v_q, v_s, lengths, block_k=64, interpret=True
+        q, k_q, k_s, v_q, v_s, lengths, LAYER, block_k=64, interpret=True
     )
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref), rtol=2e-4, atol=2e-4
     )
 
 
-def test_flash_decode_sharded_matches_reference():
-    slots, max_len, heads, kv_heads, dim = 2, 128, 8, 4, 128
+@pytest.mark.parametrize("kv_heads", [4, 2], ids=["two-a-shard", "one-a-shard"])
+def test_flash_decode_sharded_matches_reference(kv_heads):
+    # one kv head a shard is what tp=4 leaves of a 4-kv-head model: the
+    # kernel is then handed the leaf with its head axis squeezed
+    slots, max_len, heads, dim = 2, 128, 8, 128
     q, k, v = _make_inputs(slots, max_len, heads, kv_heads, dim, seed=2)
     lengths = jnp.array([128, 60], dtype=jnp.int32)
 
     devices = np.asarray(jax.devices()[:2]).reshape(2)
     mesh = Mesh(devices, ("tp",))
-    ref = decode_attention(q, k, v, lengths)
+    ref = decode_attention(q, k[LAYER], v[LAYER], lengths)
     out = flash_decode_attention_sharded(
-        q, k, v, lengths, mesh, interpret=True
+        q, k, v, lengths, LAYER, mesh, interpret=True
     )
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5
@@ -160,10 +174,11 @@ def test_decode_step_flash_wiring(kv_quant):
         )
 
 
-def test_flash_decode_sharded_quant_matches_reference():
+@pytest.mark.parametrize("kv_heads", [4, 2], ids=["two-a-shard", "one-a-shard"])
+def test_flash_decode_sharded_quant_matches_reference(kv_heads):
     """The tp>1 + kv-quant branch of _decode_attn_quant: sharded kernel
     with int8 cache + scales must match the XLA quant path."""
-    slots, max_len, heads, kv_heads, dim = 2, 128, 8, 4, 128
+    slots, max_len, heads, dim = 2, 128, 8, 128
     q, k, v = _make_inputs(slots, max_len, heads, kv_heads, dim, seed=4)
     k_q, k_s = quantize_kv(k)
     v_q, v_s = quantize_kv(v)
@@ -171,9 +186,11 @@ def test_flash_decode_sharded_quant_matches_reference():
 
     devices = np.asarray(jax.devices()[:2]).reshape(2)
     mesh = Mesh(devices, ("tp",))
-    ref = decode_attention_quant(q, k_q, k_s, v_q, v_s, lengths)
+    ref = decode_attention_quant(
+        q, k_q[LAYER], k_s[LAYER], v_q[LAYER], v_s[LAYER], lengths
+    )
     out = flash_decode_attention_sharded(
-        q, k_q, v_q, lengths, mesh, k_scale=k_s, v_scale=v_s,
+        q, k_q, v_q, lengths, LAYER, mesh, k_scale=k_s, v_scale=v_s,
         interpret=True,
     )
     np.testing.assert_allclose(
@@ -193,9 +210,11 @@ def test_flash_decode_quant_bf16_matches_reference():
     v_q, v_s = quantize_kv(v)
     lengths = jnp.array([128, 77], dtype=jnp.int32)
 
-    ref = decode_attention_quant(q, k_q, k_s, v_q, v_s, lengths)
+    ref = decode_attention_quant(
+        q, k_q[LAYER], k_s[LAYER], v_q[LAYER], v_s[LAYER], lengths
+    )
     out = flash_decode_attention_quant(
-        q, k_q, k_s, v_q, v_s, lengths, block_k=64, interpret=True
+        q, k_q, k_s, v_q, v_s, lengths, LAYER, block_k=64, interpret=True
     )
     np.testing.assert_allclose(
         np.asarray(out, dtype=np.float32), np.asarray(ref, dtype=np.float32),
@@ -213,19 +232,19 @@ def test_flash_decode_window_softcap_matches_reference():
     window = jnp.asarray(40, dtype=jnp.int32)
 
     ref = decode_attention(
-        q, k, v, lengths, softcap=30.0, window=window, scale=0.17
+        q, k[LAYER], v[LAYER], lengths, softcap=30.0, window=window, scale=0.17
     )
     out = flash_decode_attention(
-        q, k, v, lengths, softcap=30.0, window=window, scale=0.17,
+        q, k, v, lengths, LAYER, softcap=30.0, window=window, scale=0.17,
         block_k=64, interpret=True,
     )
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5
     )
     # window wider than the context ≡ full attention
-    ref_full = decode_attention(q, k, v, lengths)
+    ref_full = decode_attention(q, k[LAYER], v[LAYER], lengths)
     out_wide = flash_decode_attention(
-        q, k, v, lengths, window=jnp.asarray(4096, dtype=jnp.int32),
+        q, k, v, lengths, LAYER, window=jnp.asarray(4096, dtype=jnp.int32),
         block_k=64, interpret=True,
     )
     np.testing.assert_allclose(
@@ -242,10 +261,11 @@ def test_flash_decode_window_quant_matches_reference():
     window = jnp.asarray(24, dtype=jnp.int32)
 
     ref = decode_attention_quant(
-        q, k_q, k_s, v_q, v_s, lengths, softcap=50.0, window=window
+        q, k_q[LAYER], k_s[LAYER], v_q[LAYER], v_s[LAYER], lengths,
+        softcap=50.0, window=window,
     )
     out = flash_decode_attention_quant(
-        q, k_q, k_s, v_q, v_s, lengths, softcap=50.0, window=window,
+        q, k_q, k_s, v_q, v_s, lengths, LAYER, softcap=50.0, window=window,
         block_k=32, interpret=True,
     )
     np.testing.assert_allclose(

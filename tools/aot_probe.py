@@ -6,7 +6,12 @@ This is the blind-perf-debugging tool for when the chip is unreachable:
 temp memory ≈ materialized intermediates (a dequantized bf16 weight copy
 would show up as ~14 GB of temp for an 8B model); bytes-accessed versus
 the int8 weight footprint shows whether decode is at its weights-bound
-roofline.
+roofline. For the decode chunk the cache is no part of temp: decode_step
+carries the stacked cache through its layer loop and writes it in place,
+so the chunk's temp is a few MiB of activations (3.6 MiB at Qwen-2.5-7B,
+32 x 2,048). A temp of one layer's slab or more there means a copy of the
+cache is back (tests/test_chip_compile.py holds the two benchmark shapes
+to that).
 
 Usage: python tools/aot_probe.py [preset] [slots] [chunk] [seq]
 """
@@ -119,7 +124,8 @@ def main() -> None:
     print(f"temp:    {mem.temp_size_in_bytes / gb:.3f} GB")
     print(f"args:    {mem.argument_size_in_bytes / gb:.2f} GB  "
           f"output: {mem.output_size_in_bytes / gb:.2f} GB  "
-          f"(donation aliases the cache)")
+          f"(donation aliases the cache; it is carried in place, so a "
+          f"temp of a slab or more is a cache copy)")
     if cost:
         bytes_accessed = cost.get("bytes accessed", 0.0)
         flops = cost.get("flops", 0.0)
