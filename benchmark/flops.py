@@ -1,8 +1,12 @@
-"""Operations and bytes the algorithm needs, from shapes and lengths.
+"""Operations and bytes a GQA decoder needs (grouped-query attention, a
+gated MLP, one output head), from shapes and lengths.
 
 Kept with the benchmark so that no PR that claims a gain can change how
-the work is counted. ``sizes`` is ``reference.qwen2.Sizes`` (the published
-sizes from the configuration's file).
+the work is counted. A family whose block has this shape points its work
+counts here (README, "A family"); ``sizes`` is that family's ``Sizes``
+(the published sizes from the configuration's file: ``hidden``, ``inter``,
+``layers``, ``heads``, ``kv_heads``, ``head_dim``, ``vocab``). A block of
+another shape brings its own counts in its family's file.
 """
 
 from __future__ import annotations

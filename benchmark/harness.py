@@ -5,7 +5,10 @@ compare what the window served with the plain reference, print the line.
 Nothing here names a cell, a configuration, a traffic mix or a metric:
 ``BENCHMARK.json`` names them, and each resolves to a file of its own
 (``configs/<config>.json``, ``traffic/<traffic>.json``,
-``generators/<kind>.py``, ``metrics/<metric>.py``, ``apps/<app>/``).
+``generators/<kind>.py``, ``metrics/<metric>.py``, ``apps/<app>/``). Nor
+does it name a model family: the configuration's file gives its
+``model_type``, and ``reference/<model_type>.py`` holds the family's plain
+reference, its size check and its work counts (README, "A family").
 """
 
 from __future__ import annotations
@@ -48,8 +51,9 @@ def load_module(folder: str, name: str):
 
 
 def load_cell(workload: str, benchmark: Optional[Dict[str, Any]] = None) -> Dict[str, Any]:
-    """The cell named ``workload`` with its configuration, traffic and
-    the metrics it reports, each from the file its name resolves to."""
+    """The cell named ``workload`` with its configuration, traffic, the
+    configuration's family (``reference/<model_type>.py``) and the metrics
+    it reports, each from the file its name resolves to."""
     if benchmark is None:
         with open(os.path.join(ROOT, "BENCHMARK.json")) as handle:
             benchmark = json.load(handle)
@@ -62,6 +66,7 @@ def load_cell(workload: str, benchmark: Optional[Dict[str, Any]] = None) -> Dict
     cell = dict(cells[workload])
     cell["config_file"] = load_json("configs", cell["config"] + ".json")
     cell["traffic_file"] = load_json("traffic", cell["traffic"] + ".json")
+    cell["family"] = load_module("reference", cell["config_file"]["model_type"])
 
     def mine(metric: Dict[str, Any]) -> bool:
         return "workloads" not in metric or workload in metric["workloads"]
@@ -150,23 +155,14 @@ def say(device: Dict[str, Any], message: str) -> None:
 # --------------------------------------------------------------------- #
 # the program's sizes against the configuration's file
 # --------------------------------------------------------------------- #
-def check_sizes(engine_config, config_file: Dict[str, Any]) -> None:
-    pairs = {
-        "vocab_size": engine_config.vocab_size,
-        "hidden_size": engine_config.hidden_size,
-        "intermediate_size": engine_config.intermediate_size,
-        "num_hidden_layers": engine_config.num_layers,
-        "num_attention_heads": engine_config.num_heads,
-        "num_key_value_heads": engine_config.num_kv_heads,
-        "head_dim": engine_config.dims_per_head,
-        "rope_theta": engine_config.rope_theta,
-        "rms_norm_eps": engine_config.norm_eps,
-        "tie_word_embeddings": engine_config.tie_embeddings,
-        "attention_bias": engine_config.qkv_bias,
-    }
+def check_sizes(family, engine_config, config_file: Dict[str, Any]) -> None:
+    """The family says which keys of the configuration's file are held
+    against which values of the program's config (``size_check``); any
+    difference, or a key the file lacks, ends the run."""
     wrong = {
-        key: (config_file[key], value) for key, value in pairs.items()
-        if config_file[key] != value
+        key: (config_file.get(key), value)
+        for key, value in family.size_check(engine_config).items()
+        if key not in config_file or config_file[key] != value
     }
     if wrong:
         raise SystemExit(
@@ -352,7 +348,7 @@ async def serving(cell: Dict[str, Any], seed: int, device, cache_dir: str):
     try:
         completions = runner._service_provider_registry.completions()  # noqa: SLF001
         engine = completions.engine
-        check_sizes(engine.config, config_file)
+        check_sizes(cell["family"], engine.config, config_file)
         completions.tokenizer = VisibleTokenizer()
         probed: Dict[tuple, Dict[str, Any]] = {}
         probes.wrap_engine(engine, probed)

@@ -8,8 +8,6 @@ from __future__ import annotations
 
 from typing import Any, Dict, List, Optional, Sequence
 
-from . import flops
-
 
 def percentile(values: Sequence[float], q: float) -> Optional[float]:
     """Linear interpolation between order statistics, q in [0, 100]."""
@@ -162,8 +160,9 @@ def _decoded_between(record, begin: float, end: float):
 
 def work_flops(ctx, begin: float, end: float) -> float:
     """Model flops of every prompt whose prefill the engine harvested in
-    [begin, end) and of every output token it emitted there."""
-    sizes, total = ctx["sizes"], 0.0
+    [begin, end) and of every output token it emitted there, by the
+    counts of the configuration's family (``ctx["family"]``)."""
+    flops, sizes, total = ctx["family"], ctx["sizes"], 0.0
     for r in ctx["requests"]:
         first, prompt = r.get("engine_first"), r.get("prompt_ids")
         if first is not None and prompt is not None and begin <= first < end:
@@ -204,52 +203,46 @@ def device_idle_share(ctx) -> Optional[float]:
     return 100.0 * (1.0 - trace["busy_s"] / trace["window_s"])
 
 
-def _decode_programs(ctx):
-    trace = ctx.get("trace")
-    if not trace:
-        return []
-    return [p for p in trace["programs"] if p["decode"] and p["whole"]]
-
-
-def decode_step_ms(ctx) -> Optional[float]:
-    """Device time of the decode programs that ran whole inside the traced
-    window over the steps they ran: a program's kernel calls over the
-    model's layers is its number of steps."""
-    programs = _decode_programs(ctx)
-    steps = sum(p["kernel_calls"] for p in programs) / ctx["sizes"].layers
-    if steps <= 0:
-        return None
-    return 1e3 * sum(p["seconds"] for p in programs) / steps
-
-
-def _decoded_in(ctx, begin: float, end: float):
-    """(queries, keys) of the decode steps in [begin, end): one query for
-    every output token after a request's first, seeing prompt + j keys."""
-    queries = keys = 0
+def served_between(ctx, begin: float, end: float) -> Dict[str, Any]:
+    """What the engine served in [begin, end), as a family's kernel counts
+    take it: ``prompts``, the lengths of the prompts whose prefill it
+    harvested there; ``decode_queries``, one for every output token after
+    a request's first, and ``decode_keys``, the cached keys those queries
+    saw between them (the j-th token of a request sees prompt + j)."""
+    prompts, queries, keys = [], 0, 0
     for r in ctx["requests"]:
+        first, prompt = r.get("engine_first"), r.get("prompt_ids")
+        if first is not None and prompt is not None and begin <= first < end:
+            prompts.append(len(prompt))
         decoded = _decoded_between(r, begin, end)
         if decoded:
             prompt_tokens, lo, hi = decoded
             queries += hi - lo + 1
             keys += (hi - lo + 1) * prompt_tokens + (lo + hi) * (hi - lo + 1) // 2
-    return queries, keys
+    return {"prompts": prompts, "decode_queries": queries, "decode_keys": keys}
 
 
-def decode_attn_roofline(ctx) -> Optional[float]:
-    """The decode-attention kernel against its roofline: the larger of its
-    flops over the bf16 peak and its bytes over the HBM peak (the bytes
-    bound it: 7 flops a byte), for the keys the traced window's decode
-    steps really served, over the kernel's device time inside the decode
-    programs. It says how near the kernel comes to streaming just the
-    cache rows that hold tokens."""
+def kernel_roofline(ctx, kernel: str, within: str) -> Optional[float]:
+    """The kernel named ``kernel`` against its roofline: the larger of its
+    flops over the bf16 peak and its bytes over the HBM peak, for the work
+    the traced window really served (the family's ``kernel_work``, by the
+    kernel's name), over the kernel's device time inside the programs
+    whose kind starts with ``within``. None where the trace holds no such
+    kernel or the family counts nothing for it."""
     trace = ctx.get("trace")
     if not trace:
         return None
-    kernel_s = sum(p["kernel_seconds"] for p in trace["programs"] if p["decode"])
-    queries, keys = _decoded_in(ctx, trace["begin"]["at"], trace["end"]["at"])
-    if kernel_s <= 0 or not queries:
+    kernel_s = sum(
+        p["kernels"][kernel]["seconds"] for p in trace["programs"]
+        if p["kind"].startswith(within) and kernel in p["kernels"]
+    )
+    if kernel_s <= 0:
         return None
-    work, moved = flops.decode_attention(ctx["sizes"], keys, queries)
+    served = served_between(ctx, trace["begin"]["at"], trace["end"]["at"])
+    counted = ctx["family"].kernel_work(ctx["sizes"], kernel, served)
+    if not counted:
+        return None
+    work, moved = counted
     peaks = ctx["peaks"]
     least = max(work / peaks["bf16_flops_per_s"], moved / peaks["hbm_bytes_per_s"])
     return 100.0 * least / (kernel_s * ctx["chips"])

@@ -1,7 +1,7 @@
-"""Device time of decode programs in the traced window over the decode steps run there."""
+"""Device time of programs named as decode chunks over the steps their dispatch spans give them."""
 
-from benchmark import measure
+from benchmark import spans
 
 
 def read(ctx):
-    return measure.decode_step_ms(ctx)
+    return spans.decode_step(ctx)

@@ -1,4 +1,5 @@
-"""The comparison that decides ``correct`` for a served model.
+"""The comparison that decides ``correct`` for a served model, whatever
+its family: the family's file (``reference/<model_type>.py``) is handed in.
 
 Once the window has closed, a sample of the requests it finished (drawn
 from the seed, the longest and the shortest prompt always in it) is run
@@ -25,8 +26,6 @@ from typing import Any, Dict, Optional, Sequence
 
 import numpy as np
 
-from . import qwen2
-
 
 def draw_sample(records: Sequence[Dict[str, Any]], seed: int, count: int):
     """``count`` finished requests: the longest prompt and the shortest
@@ -49,7 +48,8 @@ def draw_sample(records: Sequence[Dict[str, Any]], seed: int, count: int):
 
 
 def gaps(
-    sizes: "qwen2.Sizes",
+    family,
+    sizes,
     weights: Dict[str, Any],
     sample: Sequence[Dict[str, Any]],
     pad_to: int,
@@ -64,11 +64,11 @@ def gaps(
         (len(r["prompt_ids"]) - 1, len(r["prompt_ids"]) - 1 + len(r["output_ids"]))
         for r in sample
     ]
-    reference = qwen2.logits_at(sizes, weights, rows, spans, pad_to)
+    reference = family.logits_at(sizes, weights, rows, spans, pad_to)
     if lower is None:
         chosen = [np.asarray(r["output_ids"]) for r in sample]
     else:
-        lowered = qwen2.logits_at(sizes, weights, rows, spans, pad_to, lower)
+        lowered = family.logits_at(sizes, weights, rows, spans, pad_to, lower)
         chosen = [np.argmax(l, axis=-1) for l in lowered]
     each, worst = [], None
     for record, logits, tokens in zip(sample, reference, chosen):

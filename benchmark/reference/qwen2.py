@@ -24,6 +24,13 @@ scale or a bias that the program drops (PERF.md, Open questions).
 ``lower`` re-states the weights in the nearest precision below the
 configuration's (int4 for int8, int8 or fp8 for bf16): the control that
 the comparison has to fail.
+
+This file is the whole family as the harness sees it (README, "A
+family"): ``Sizes``, ``make_weights`` and ``logits_at`` for the
+comparison, ``size_check`` for the program's sizes against the file, and
+the work counts (``prompt_flops``, ``output_token_flops``, ``kernel_work``)
+for ``mfu.*`` and the kernels' rooflines. The counts are ``flops.py``'s,
+the count of a GQA decoder, which nothing else reaches.
 """
 
 from __future__ import annotations
@@ -35,6 +42,8 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from benchmark import flops
 
 MATMULS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
 
@@ -57,6 +66,39 @@ class Sizes:
         self.tied = bool(config["tie_word_embeddings"])
         self.qkv_bias = bool(config["attention_bias"])
         self.recipe = str(config["weights"])
+
+
+def size_check(engine_config) -> Dict[str, Any]:
+    """Key of the configuration's file -> what the program's config holds
+    for it; the harness refuses a run where any pair differs."""
+    return {
+        "vocab_size": engine_config.vocab_size,
+        "hidden_size": engine_config.hidden_size,
+        "intermediate_size": engine_config.intermediate_size,
+        "num_hidden_layers": engine_config.num_layers,
+        "num_attention_heads": engine_config.num_heads,
+        "num_key_value_heads": engine_config.num_kv_heads,
+        "head_dim": engine_config.dims_per_head,
+        "rope_theta": engine_config.rope_theta,
+        "rms_norm_eps": engine_config.norm_eps,
+        "tie_word_embeddings": engine_config.tie_embeddings,
+        "attention_bias": engine_config.qkv_bias,
+    }
+
+
+prompt_flops = flops.prompt_flops
+output_token_flops = flops.output_token_flops
+
+
+def kernel_work(sizes: Sizes, kernel: str, served: Dict[str, Any]):
+    """(flops, bytes) of the work of the kernel named ``kernel`` for what
+    the traced window served (``measure.served_between``); None for a
+    kernel this family's models do not run or that had nothing to do."""
+    if kernel == "flash_decode" and served["decode_queries"]:
+        return flops.decode_attention(
+            sizes, served["decode_keys"], served["decode_queries"]
+        )
+    return None
 
 
 def make_weights(sizes: Sizes, seed: int) -> Dict[str, Any]:

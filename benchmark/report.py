@@ -6,8 +6,8 @@ from __future__ import annotations
 import time
 from typing import Any, Dict, Optional
 
-from . import harness, measure, trace_reduce
-from .reference import compare, qwen2
+from . import harness, measure, spans, trace_reduce
+from .reference import compare
 
 
 def context(cell, raw, device) -> Dict[str, Any]:
@@ -23,7 +23,8 @@ def context(cell, raw, device) -> Dict[str, Any]:
         "decode_chunk": raw["decode_chunk"],
         "chips": cell["chips"],
         "limit_s": float(traffic["request_limit_seconds"]),
-        "sizes": qwen2.Sizes(cell["config_file"]),
+        "family": cell["family"],
+        "sizes": cell["family"].Sizes(cell["config_file"]),
         "peaks": harness.peaks_for(device["kind"]) if device["platform"] == "tpu" else None,
         "trace": None,
     }
@@ -37,35 +38,11 @@ def add_trace(ctx, raw) -> None:
     if path is None:
         return
     span = info["end"]["at"] - info["begin"]["at"]
-    reduced = trace_reduce.reduce_trace(path, span, ctx["sizes"].layers, ctx["chips"])
+    reduced = trace_reduce.reduce_trace(path, span, ctx["chips"])
     if reduced is None:
         return
     reduced["begin"], reduced["end"] = info["begin"], info["end"]
     ctx["trace"] = reduced
-
-
-def host_label(ctx):
-    """What the host was doing in an idle gap of the device, from the
-    requests' own instants (seconds after the marker -> host clock)."""
-    origin = ctx["trace"]["begin"]["at"]
-    stamps = [
-        (r.get("engine_submit"), r.get("engine_first"), r["frames"][-1][0] if r.get("frames") else None)
-        for r in ctx["requests"] if r.get("engine_submit") is not None
-    ]
-
-    def label(start: float, end: float) -> str:
-        a, b = origin + start, origin + end
-        if end - start < 50e-6:
-            return "device:inside_a_program"
-        waiting = sum(1 for s, f, _ in stamps if s <= a and (f is None or f >= b))
-        decoding = sum(1 for s, f, l in stamps if f is not None and f <= a and l is not None and l >= b)
-        if waiting and not decoding:
-            return "host:requests_awaiting_first_token"
-        if waiting or decoding:
-            return "host:between_dispatches_with_work"
-        return "host:no_request_in_the_engine"
-
-    return label
 
 
 # the numbers of the comparison with the reference; one is compared where
@@ -79,8 +56,8 @@ def compare_with_reference(cell, raw, seed: int, lowers=()) -> Dict[str, Any]:
     program served (``program``) and, for a tool, for each lower precision
     of ``lowers`` put in the program's place on the same prompts and
     tokens (``control_<lower>``); ``None`` where nothing finished."""
-    config_file = cell["config_file"]
-    sizes = qwen2.Sizes(config_file)
+    config_file, family = cell["config_file"], cell["family"]
+    sizes = family.Sizes(config_file)
     finished = [
         r for r in raw["records"]
         if r.get("output_ids") and "done" in r and "error" not in r
@@ -93,12 +70,12 @@ def compare_with_reference(cell, raw, seed: int, lowers=()) -> Dict[str, Any]:
         return {"program": None}
     began = time.perf_counter()
     harness.release()
-    weights = qwen2.make_weights(sizes, raw["weights_seed"])
+    weights = family.make_weights(sizes, raw["weights_seed"])
     pad_to = int(config_file["globals"]["max-seq-len"])
-    out = {"program": compare.gaps(sizes, weights, sample, pad_to)}
+    out = {"program": compare.gaps(family, sizes, weights, sample, pad_to)}
     out["reference_s"] = time.perf_counter() - began
     for lower in lowers:
-        out["control_" + lower] = compare.gaps(sizes, weights, sample, pad_to, lower)
+        out["control_" + lower] = compare.gaps(family, sizes, weights, sample, pad_to, lower)
     return out
 
 
@@ -159,7 +136,10 @@ def result_line(cell, raw, seed: int, trace: bool, device) -> Dict[str, Any]:
     if trace and ctx["trace"]:
         device_record["busy_s"] = ctx["trace"]["busy_s"]
         device_record["window_s"] = ctx["trace"]["window_s"]
-        line["breakdown"] = trace_reduce.breakdown(ctx["trace"], host_label(ctx))
+        found = spans.of(ctx)
+        line["breakdown"] = trace_reduce.breakdown(
+            ctx["trace"], spans.idle_by_phase(found["read"]) if found else None
+        )
     line["built_in_window"] = {
         "programs": len(raw["built_in_window"]), "seconds": sum(raw["built_in_window"]),
     }

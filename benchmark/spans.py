@@ -1,13 +1,14 @@
 """The engine's own phase spans and finished legs, laid on the device's trace.
 
-The same ``.xplane.pb`` ``trace_reduce`` reads, anchored on the same
-marker and cut to the same window, but keeping what the reduction drops:
+``trace_reduce.reduce_trace`` walks the ``.xplane.pb`` once, anchors it on
+the marker and cuts it to the window; this module takes what that walk
+kept beside the device's ops:
 
 - the ``engine.*`` host events (``langstream_tpu/runtime/tracing.py``'s
   ``phase``) with their attributes, on the profiler's clock;
 - the device's program executions (``XLA Modules``) with start, end and
-  name (``jit_<kind>``: the engine names its programs);
-- the device's idle gaps, each cut by the phase span that covers it.
+  kind (``jit_<kind>``: the engine names its programs);
+- the device's idle gaps, each cut here by the phase span that covers it.
 
 A program joins the dispatch span it was launched under by the runtime's
 own ids: the device's module event carries a ``run_id``, and so does the
@@ -21,24 +22,20 @@ number both carry, and the two clocks meet at the marker
 their own, a millisecond or so early: no program starts before its launch,
 so the largest lead of a start over its launch is taken as the two clocks'
 distance (``skew``), and the host's instants are moved onto the device's
-clock by it. The window and the gaps stay as ``trace_reduce`` has them.
+clock by it.
 
-Returns None where there is no trace, no device plane or no ``engine.*``
-annotation (a program from before the spans), so a reader leaves its
-metric out and never reports 0. Parts repeat ``trace_reduce.py`` (the
-planes' walk, the window): a ``benchmark`` issue may fold them.
+:func:`lay` returns None where there is no trace, no marker or no
+``engine.*`` annotation (a program from before the spans), so a reader
+leaves its metric out and never reports 0.
 """
 
 from __future__ import annotations
 
-import os
-import re
 import statistics
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional
 
 from . import trace_reduce
 
-PREFIX = "engine."
 # the spans that tile the engine thread; ``engine.prefill_dispatch`` is a
 # child of ``engine.admit`` and would count its time twice
 CHILDREN = ("engine.prefill_dispatch",)
@@ -47,107 +44,27 @@ CHILDREN = ("engine.prefill_dispatch",)
 # scheduling (admission, batch and dispatch building, linger, the host's
 # part of a harvest or of the wait for a chunk)
 EMIT, WAIT = "engine.emit", "engine.wait_for_work"
-# shorter gaps lie inside a program (``report.host_label``'s floor)
+# shorter gaps lie inside a program
 FLOOR_NS = 50e3
-_MODULE = re.compile(r"^jit_([A-Za-z0-9_]+)")
 
 
-def trace_path() -> Optional[str]:
-    """``run.py`` puts the cache dir's ``jax`` folder in
-    ``JAX_COMPILATION_CACHE_DIR``; the harness writes the trace beside it."""
-    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    if not placed:
+def lay(reduced: Optional[Dict[str, Any]]) -> Optional[Dict[str, Any]]:
+    """Lay the reduced trace's phase spans on its programs, once: every
+    program gains ``launched`` (the host instant of its launch) and
+    ``phase`` (its dispatch span), the spans move onto the device's clock
+    (``skew_ns``) and gain their ``programs``. Returns ``reduced``; times
+    in ns on the device's clock."""
+    if not reduced or not reduced["marked"] or not reduced["phases"]:
         return None
-    return trace_reduce.find_trace(os.path.join(os.path.dirname(placed), "trace"))
-
-
-def _stats(event) -> Dict[str, Any]:
-    try:
-        return {str(key): value for key, value in event.stats}
-    except Exception:  # noqa: BLE001 - a stat that does not decode is skipped
-        return {}
-
-
-def kind_of(module_name: str) -> str:
-    """``jit_prefill_dense(1234)`` -> ``prefill_dense``; '' for another's."""
-    found = _MODULE.match(module_name)
-    return found.group(1) if found else ""
-
-
-def read_trace(path: str, span_s: float, chips: int = 1) -> Optional[Dict[str, Any]]:
-    """Phases, programs and gaps of the window that starts at the marker
-    and lasts ``span_s`` seconds; times in ns on the device's clock."""
-    import jax
-
-    data = jax.profiler.ProfileData.from_file(path)
-    mark_ns = None
-    phases: List[Dict[str, Any]] = []
-    produced: Dict[Tuple[str, str], float] = {}   # flow -> its producer's start
-    consumers: Dict[str, List[Tuple[float, float, Tuple[str, str]]]] = {}
-    enqueues: Dict[str, Tuple[float, str]] = {}   # run_id -> (start, line)
-    devices = []
-    for plane in data.planes:
-        if plane.name.startswith("/device:TPU:"):
-            devices.append(plane)
-            continue
-        if not plane.name.startswith("/host:"):
-            continue
-        for number, line in enumerate(plane.lines):
-            key = f"{plane.name}/{number}"
-            for event in line.events:
-                name = event.name
-                if name == trace_reduce.MARK:
-                    mark_ns = event.start_ns if mark_ns is None else mark_ns
-                    continue
-                stats = _stats(event)
-                if name.startswith(PREFIX):
-                    phases.append({
-                        "name": name, "start": event.start_ns,
-                        "end": event.start_ns + event.duration_ns, "attrs": stats,
-                    })
-                    continue
-                if "_p" in stats:
-                    produced.setdefault(
-                        (str(stats.get("_pt")), str(stats["_p"])), event.start_ns
-                    )
-                if "_c" in stats:
-                    consumers.setdefault(key, []).append((
-                        event.start_ns, event.start_ns + event.duration_ns,
-                        (str(stats.get("_ct")), str(stats["_c"])),
-                    ))
-                if "run_id" in stats and "_p" in stats:
-                    # a run's enqueue produces the flow its module event
-                    # consumes (its completion callback, which carries the
-                    # id too, consumes one)
-                    enqueues.setdefault(str(stats["run_id"]), (event.start_ns, key))
-    devices = devices[:chips]
-    if not devices or not phases or mark_ns is None:
-        return None
-    lo, hi = mark_ns, mark_ns + span_s * 1e9
-    programs: List[Dict[str, Any]] = []
-    gaps: List[Tuple[float, float]] = []
-    for plane in devices:
-        for line in plane.lines:
-            if line.name == "XLA Ops":
-                intervals = [
-                    (max(e.start_ns, lo), min(e.start_ns + e.duration_ns, hi))
-                    for e in line.events
-                    if e.start_ns + e.duration_ns > lo and e.start_ns < hi
-                ]
-                gaps += trace_reduce.gaps_of(intervals, lo, hi)
-            elif line.name == "XLA Modules":
-                for event in line.events:
-                    run_id = _stats(event).get("run_id")
-                    programs.append({
-                        "name": event.name, "kind": kind_of(event.name),
-                        "start": event.start_ns,
-                        "end": event.start_ns + event.duration_ns,
-                        "launched": _launched(
-                            enqueues.get(str(run_id)), consumers, produced
-                        ),
-                    })
+    if "skew_ns" in reduced:
+        return reduced
+    phases, programs, flows = reduced["phases"], reduced["programs"], reduced["flows"]
+    for program in programs:
+        program["launched"] = _launched(
+            flows["enqueues"].get(str(program["run_id"])),
+            flows["consumers"], flows["produced"],
+        )
     phases.sort(key=lambda p: (p["start"], -p["end"]))
-    programs.sort(key=lambda p: p["start"])
     skew = max(
         [p["launched"] - p["start"] for p in programs if p["launched"] is not None]
         + [0.0]
@@ -156,10 +73,15 @@ def read_trace(path: str, span_s: float, chips: int = 1) -> Optional[Dict[str, A
         span["start"] -= skew
         span["end"] -= skew
     _join(phases, programs, skew)
-    return {
-        "lo": lo, "hi": hi, "chips": len(devices), "phases": phases,
-        "programs": programs, "gaps": gaps, "skew_ns": skew,
-    }
+    reduced["skew_ns"] = skew
+    return reduced
+
+
+def read_trace(path: str, span_s: float, chips: int = 1) -> Optional[Dict[str, Any]]:
+    """Phases, programs and gaps of the window that starts at the marker
+    and lasts ``span_s`` seconds, from a trace's file (a test's or a
+    tool's way in; a run reduces its trace once, in ``report.add_trace``)."""
+    return lay(trace_reduce.reduce_trace(path, span_s, chips))
 
 
 def _launched(enqueue, consumers, produced) -> Optional[float]:
@@ -293,7 +215,7 @@ def decode_step_ms(read: Dict[str, Any]) -> Optional[float]:
     for program in read["programs"]:
         if not program["kind"].startswith("decode_chunk") or program["phase"] is None:
             continue
-        if program["start"] < read["lo"] or program["end"] > read["hi"]:
+        if not program["whole"]:
             continue
         span = read["phases"][program["phase"]]
         seconds += (program["end"] - program["start"]) / 1e9
@@ -304,23 +226,20 @@ def decode_step_ms(read: Dict[str, Any]) -> Optional[float]:
 def prefill_seconds(read: Dict[str, Any]) -> float:
     """Device seconds of programs named as prefills, cut to the window."""
     return sum(
-        max(0.0, min(p["end"], read["hi"]) - max(p["start"], read["lo"])) / 1e9
-        for p in read["programs"] if p["kind"].startswith("prefill")
+        p["seconds"] for p in read["programs"] if p["kind"].startswith("prefill")
     ) / read["chips"]
 
 
 def of(ctx) -> Optional[Dict[str, Any]]:
-    """What a metric's reader asks for, read once a run and kept on
-    ``ctx``: ``read`` (:func:`read_trace`), ``legs`` (the ring), ``idle``
-    (:func:`idle_shares`) and ``parts`` (:func:`first_token_parts`). None
-    where there is nothing."""
+    """What a metric's reader asks for, laid once a run and kept on
+    ``ctx``: ``read`` (the run's reduced trace, :func:`lay`), ``legs``
+    (the ring), ``idle`` (:func:`idle_shares`) and ``parts``
+    (:func:`first_token_parts`). None where there is nothing."""
     if "spans" in ctx:
         return ctx["spans"]
     ctx["spans"] = None
-    trace, path = ctx.get("trace"), trace_path()
-    if not trace or path is None:
-        return None
-    read = read_trace(path, trace["window_s"], ctx.get("chips", 1))
+    trace = ctx.get("trace")
+    read = lay(trace)
     if read is None:
         return None
     try:

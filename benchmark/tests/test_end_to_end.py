@@ -1,12 +1,15 @@
-"""The harness end to end on a tiny configuration that no cell of
-BENCHMARK.json references and no harness file names: up to the point
-where it would demand a TPU through the command line (which it must
-refuse on a CPU), and past that point with the look for a chip skipped,
-sound and with the timed path broken underneath."""
+"""The harness end to end on two tiny configurations, of two families,
+that no cell of BENCHMARK.json references and no harness file names: up
+to the point where it would demand a TPU through the command line (which
+it must refuse on a CPU), and past that point with the look for a chip
+skipped, sound and with the timed path broken underneath. The second
+(``tiny-moe-selftest``: a sparse mixture of experts) enters as files
+alone: ``reference/mixtral.py`` and its configuration's file."""
 
 import asyncio
 import json
 import os
+import re
 import subprocess
 import sys
 import time
@@ -16,14 +19,16 @@ import pytest
 from benchmark import harness, report
 
 CPU = {"platform": "cpu", "kind": "cpu", "count": 1}
+# one configuration a family: the file's ``model_type`` finds the family
+FAMILIES = ["tiny-selftest", "tiny-moe-selftest"]
 
 
-def selftest_cell(traffic):
+def selftest_cell(config, traffic):
     with open(os.path.join(harness.ROOT, "BENCHMARK.json")) as handle:
         benchmark = json.load(handle)
-    name = "tiny-selftest." + traffic
+    name = config + "." + traffic
     benchmark["workloads"].append({
-        "name": name, "config": "tiny-selftest", "traffic": traffic,
+        "name": name, "config": config, "traffic": traffic,
         "chips": 1, "why": "self-test",
     })
     for metric in benchmark["end_to_end"] + benchmark["per_layer"]:
@@ -32,8 +37,8 @@ def selftest_cell(traffic):
     return harness.load_cell(name, benchmark)
 
 
-def run(traffic, seed, tmp_path, trace=False, seconds=3.0):
-    cell = selftest_cell(traffic)
+def run(traffic, seed, tmp_path, trace=False, seconds=3.0, config="tiny-selftest"):
+    cell = selftest_cell(config, traffic)
     raw = asyncio.run(harness.run_cell(
         cell, seed, seconds, trace, time.perf_counter(), CPU, str(tmp_path),
     ))
@@ -63,9 +68,13 @@ def test_the_command_refuses_an_unknown_cell():
     assert done.returncode != 0 and done.stdout.strip() == ""
 
 
-@pytest.mark.parametrize("traffic", ["selftest-open", "selftest-closed"])
-def test_a_sound_run_is_correct_and_reports_its_metrics(traffic, tmp_path):
-    cell, raw, line = run(traffic, 3_000_000_019, tmp_path)
+@pytest.mark.parametrize("config,traffic", [
+    ("tiny-selftest", "selftest-open"), ("tiny-selftest", "selftest-closed"),
+    ("tiny-moe-selftest", "selftest-closed"),
+])
+def test_a_sound_run_is_correct_and_reports_its_metrics(config, traffic, tmp_path):
+    cell, raw, line = run(traffic, 3_000_000_019, tmp_path, config=config)
+    assert cell["family"].__name__.endswith(cell["config_file"]["model_type"])
     assert line["correct"] is True, line["compared"]
     assert line["attempted"] > 0 and line["failed"] == 0
     assert list(line)[-1] == "compared"
@@ -128,7 +137,8 @@ def test_a_traced_run_reports_per_layer_metrics_and_leaves_out_what_it_cannot_re
         assert name not in line["metrics"]
 
 
-def test_a_token_altered_where_it_is_produced_is_not_correct(tmp_path, monkeypatch):
+@pytest.mark.parametrize("config", FAMILIES)
+def test_a_token_altered_where_it_is_produced_is_not_correct(config, tmp_path, monkeypatch):
     """The timed path broken underneath: the model's output head hands the
     sampler logits shifted by one id, so every served token is its
     neighbour's."""
@@ -141,7 +151,7 @@ def test_a_token_altered_where_it_is_produced_is_not_correct(tmp_path, monkeypat
         model, "_logits",
         lambda config, params, x: jnp.roll(sound(config, params, x), 1, axis=-1),
     )
-    _, _, line = run("selftest-closed", 7, tmp_path)
+    _, _, line = run("selftest-closed", 7, tmp_path, config=config)
     assert line["correct"] is False
     for name in ("max_logit_gap", "mean_logit_gap"):
         assert line["compared"][name]["value"] > 100 * line["compared"][name]["limit"]
@@ -166,12 +176,13 @@ def test_an_answer_that_never_comes_is_not_correct(tmp_path, monkeypatch):
     assert line["failed"] > 0 and line["correct"] is False
 
 
-def test_the_control_in_a_lower_precision_fails_the_comparison(tmp_path):
+@pytest.mark.parametrize("config", FAMILIES)
+def test_the_control_in_a_lower_precision_fails_the_comparison(config, tmp_path):
     """The reference put in the program's place, in the nearest precision
     below the configuration's, through the judgement a run's ``correct``
     comes from: not correct, by each number at three times its limit or
     more, and the program's own correct, on three seeds."""
-    cell = selftest_cell("selftest-closed")
+    cell = selftest_cell(config, "selftest-closed")
     lower = cell["config_file"]["lower_precision"]
     for seed in (21, 22, 3_000_000_023):
         raw = asyncio.run(harness.run_cell(
@@ -187,6 +198,37 @@ def test_the_control_in_a_lower_precision_fails_the_comparison(tmp_path):
 
 
 def test_nothing_to_compare_is_not_correct():
-    cell = selftest_cell("selftest-closed")
+    cell = selftest_cell("tiny-selftest", "selftest-closed")
     checks, correct = report.judge(cell, None, 0, 0, 0)
     assert correct is False and checks["requests_compared"]["value"] == 0
+
+
+def test_no_harness_file_names_a_family():
+    """A family is ``reference/<model_type>.py`` and is found through the
+    configuration's file: no module of the harness, no metric's reader, no
+    generator and no tool says its name, so a new one needs no edit."""
+    import glob
+
+    here = os.path.join(harness.ROOT, "benchmark")
+    families = [
+        os.path.basename(path)[:-3]
+        for path in glob.glob(os.path.join(here, "reference", "*.py"))
+        if os.path.basename(path) not in ("__init__.py", "compare.py")
+    ]
+    assert {"qwen2", "mixtral"} <= set(families)
+    files = [os.path.join(here, "reference", "compare.py")]
+    for folder in ("", "metrics", "generators", "tools"):
+        files += glob.glob(os.path.join(here, folder, "*.py"))
+    assert len(files) > 40
+    for path in files:
+        with open(path) as handle:
+            text = handle.read()
+        for family in families:
+            assert family not in text, (path, family)
+    with open(os.path.join(harness.ROOT, "BENCHMARK.json")) as handle:
+        named = json.load(handle)
+    assert "tiny-moe-selftest" not in json.dumps(named)
+    # and the count of a GQA decoder is reached through its family alone
+    for path in files:
+        with open(path) as handle:
+            assert not re.search(r"import .*\bflops\b|flops import", handle.read()), path

@@ -1,8 +1,16 @@
+import types
+
 import pytest
 
-from benchmark import flops, measure
-from benchmark.reference import qwen2
-from benchmark import harness
+from benchmark import harness, measure
+
+
+def family_of(config):
+    """A configuration's family and its sizes, found as a cell's are: by
+    the file's ``model_type``."""
+    config_file = harness.load_json("configs", config + ".json")
+    family = harness.load_module("reference", config_file["model_type"])
+    return family, family.Sizes(config_file), config_file
 
 
 def request(due, first, done, tokens=128, frames=16, error=None):
@@ -105,33 +113,142 @@ def test_slot_occupancy_and_padding_share():
     assert measure.prefill_useful_share(ctx) == pytest.approx(25.0)
 
 
-def test_flops_against_a_hand_count_for_the_tiny_preset():
-    sizes = qwen2.Sizes(harness.load_json("configs", "tiny-selftest.json"))
-    # by hand: q 64x64, k and v 64x32 each, o 64x64, three 64x128 mlp
-    layer = 64 * 64 + 2 * 64 * 32 + 64 * 64 + 3 * 64 * 128
-    assert flops.layer_matmul_params(sizes) == layer == 36864
+# by hand, a layer of each tiny preset: q 64x64, k and v 64x32 each, o
+# 64x64; then three 64x128 MLP matrices (qwen2), or a 64x4 router and the
+# three matrices of the 2 experts of 4 that a token is routed to (mixtral)
+ATTENTION = 64 * 64 + 2 * 64 * 32 + 64 * 64
+LAYER = {
+    "tiny-selftest": ATTENTION + 3 * 64 * 128,
+    "tiny-moe-selftest": ATTENTION + 64 * 4 + 2 * 3 * 64 * 128,
+}
+
+
+@pytest.mark.parametrize("config", sorted(LAYER))
+def test_flops_against_a_hand_count_for_the_tiny_presets(config):
+    family, sizes, _ = family_of(config)
+    layer = LAYER[config]
+    if config == "tiny-selftest":
+        # the count of a GQA decoder, reached through its family's file
+        from benchmark import flops
+
+        assert flops.layer_matmul_params(sizes) == layer == 36864
+        assert family.prompt_flops is flops.prompt_flops
+    else:
+        assert family.layer_matmul_params(sizes) == layer == 61696
     head = 64 * 512
     # ten prompt tokens: matmuls, causal attention over 1+2+...+10 keys
     # (4 heads x 16 x 2 matmuls x 2 flops, 2 layers), the head once
     attention = 4 * 4 * 16 * 2 * 55
-    assert flops.prompt_flops(sizes, 10) == 2 * layer * 2 * 10 + attention + 2 * head
-    assert flops.output_token_flops(sizes, 11) == 2 * (layer * 2 + head) + 4 * 4 * 16 * 2 * 11
+    assert family.prompt_flops(sizes, 10) == 2 * layer * 2 * 10 + attention + 2 * head
+    assert family.output_token_flops(sizes, 11) == 2 * (layer * 2 + head) + 4 * 4 * 16 * 2 * 11
 
 
-def test_mfu_is_work_over_wall_time_times_peak():
-    sizes = qwen2.Sizes(harness.load_json("configs", "tiny-selftest.json"))
+@pytest.mark.parametrize("config", sorted(LAYER))
+def test_mfu_is_work_over_wall_time_times_peak(config):
+    family, sizes, _ = family_of(config)
     record = {
         "prompt_ids": [0] * 10, "engine_first": 1.0,
         "frames": [(1.0, 1), (2.0, 1), (3.0, 1)],
     }
     ctx = {
-        "sizes": sizes, "chips": 1, "requests": [record],
+        "family": family, "sizes": sizes, "chips": 1, "requests": [record],
         "peaks": {"bf16_flops_per_s": 1e6},
         "trace": {"begin": {"at": 0.0}, "end": {"at": 4.0}},
     }
-    work = flops.prompt_flops(sizes, 10) + sum(
-        flops.output_token_flops(sizes, 10 + j) for j in (1, 2)
+    work = family.prompt_flops(sizes, 10) + sum(
+        family.output_token_flops(sizes, 10 + j) for j in (1, 2)
     )
     assert measure.mfu(ctx) == pytest.approx(100.0 * work / 4e6)
     ctx["trace"] = None
     assert measure.mfu(ctx) is None
+
+
+@pytest.mark.parametrize("config", sorted(LAYER))
+def test_a_kernels_roofline_is_found_by_the_kernels_name(config):
+    """The seconds are those of the kernel NAMED, inside the programs whose
+    kind is named, and the work is the family's count for that name: a
+    second kernel in the same program, the same kernel in a prefill, and a
+    kernel the family does not count change nothing or read nothing."""
+    family, sizes, _ = family_of(config)
+    record = {
+        "prompt_ids": [0] * 10, "engine_first": 1.0,
+        "frames": [(1.0, 1), (2.0, 1), (3.0, 1)],
+    }
+    programs = [
+        {"kind": "decode_chunk_dense", "kernels": {
+            "flash_decode": {"calls": 8, "seconds": 2e-3},
+            "expert_matmul": {"calls": 8, "seconds": 5e-3}}},
+        {"kind": "decode_chunk_paged", "kernels": {
+            "flash_decode": {"calls": 8, "seconds": 2e-3}}},
+        {"kind": "prefill_dense", "kernels": {
+            "flash_decode": {"calls": 2, "seconds": 9e-3},
+            "flash_prefill": {"calls": 2, "seconds": 1e-3}}},
+    ]
+    ctx = {
+        "family": family, "sizes": sizes, "chips": 1, "requests": [record],
+        "peaks": {"bf16_flops_per_s": 1e9, "hbm_bytes_per_s": 1e6},
+        "trace": {"begin": {"at": 0.0}, "end": {"at": 4.0}, "programs": programs},
+    }
+    served = measure.served_between(ctx, 0.0, 4.0)
+    # two decoded tokens, which saw 11 and 12 keys
+    assert served == {"prompts": [10], "decode_queries": 2, "decode_keys": 23}
+    work, moved = family.kernel_work(sizes, "flash_decode", served)
+    # by hand, 2 layers: QK^T and PV (4 heads x 16) over 23 keys; K and V
+    # rows of 2 kv heads x 16 in bf16 once each, two queries in and out
+    assert work == 4 * 4 * 16 * 23 * 2
+    assert moved == (2 * 2 * 16 * 2 * 23 + 2 * 2 * 4 * 16 * 2) * 2
+    least = max(work / 1e9, moved / 1e6)
+    assert measure.kernel_roofline(ctx, "flash_decode", within="decode_chunk") == (
+        pytest.approx(100.0 * least / 4e-3)
+    )
+    assert measure.kernel_roofline(ctx, "flash_decode", within="prefill") == (
+        pytest.approx(100.0 * least / 9e-3)
+    )
+    # a kernel the trace holds and the family does not count: left out
+    assert family.kernel_work(sizes, "expert_matmul", served) is None
+    assert measure.kernel_roofline(ctx, "expert_matmul", within="decode_chunk") is None
+    # a kernel the family counts and the trace does not hold: left out
+    assert measure.kernel_roofline(ctx, "flash_decode", within="verify") is None
+    ctx["requests"] = []  # nothing decoded in the window: nothing to count
+    assert measure.kernel_roofline(ctx, "flash_decode", within="decode_chunk") is None
+
+
+def engine_config_of(preset):
+    from langstream_tpu.providers.jax_local.model import LlamaConfig
+
+    return LlamaConfig.from_dict({"preset": preset, "vocab-size": 512, "head-dim": 16})
+
+
+@pytest.mark.parametrize("config,preset", [
+    ("tiny-selftest", "tiny-qwen2"), ("tiny-moe-selftest", "tiny-moe"),
+])
+def test_the_size_check_is_the_familys_and_the_exit_is_the_harnesss(config, preset):
+    family, _, config_file = family_of(config)
+    program = engine_config_of(preset)
+    harness.check_sizes(family, program, config_file)  # as the app builds it
+    held = family.size_check(program)
+    assert set(held) <= set(config_file)
+    assert ("num_local_experts" in held) == (config == "tiny-moe-selftest")
+    for key in held:
+        # every key the family holds ends the run when the file differs ...
+        wrong = dict(config_file, **{key: "another"})
+        with pytest.raises(SystemExit, match=key):
+            harness.check_sizes(family, program, wrong)
+        # ... or lacks it
+        with pytest.raises(SystemExit, match=key):
+            harness.check_sizes(
+                family, program, {k: v for k, v in config_file.items() if k != key}
+            )
+
+
+def test_a_family_checks_what_the_other_has_not():
+    """The mixture's file against the dense program, and the other way
+    round: the keys that only one family has are held too."""
+    moe_family, _, moe_file = family_of("tiny-moe-selftest")
+    with pytest.raises(SystemExit, match="num_local_experts"):
+        harness.check_sizes(moe_family, engine_config_of("tiny"), moe_file)
+    fewer = types.SimpleNamespace(**{
+        **vars(engine_config_of("tiny-moe")), "dims_per_head": 16, "num_experts_per_tok": 1,
+    })
+    with pytest.raises(SystemExit, match="num_experts_per_tok"):
+        harness.check_sizes(moe_family, fewer, moe_file)

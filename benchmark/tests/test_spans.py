@@ -28,7 +28,7 @@ def read(meta):
 
 def test_gaps_cut_by_phase_sum_to_the_idle_total(read, meta):
     span = meta["end"] - meta["begin"]
-    reduced = trace_reduce.reduce_trace(TRACE, span, layers=2)
+    reduced = read  # one walk, one window: the reduction and the spans are one record
     by_phase = spans.idle_by_phase(read)
     assert sum(by_phase.values()) == pytest.approx(reduced["gap_total_s"], rel=1e-9)
     shares = spans.idle_shares(read)
@@ -108,19 +108,62 @@ def test_decode_steps_and_prefill_seconds_by_name(read):
     )
 
 
+def test_the_recorded_trace_reads_what_the_parent_read(read, meta):
+    """The parent's readings of this file (PR 28, before the walk was
+    folded into ``trace_reduce``), to the last digit."""
+    assert spans.idle_by_phase(read) == {
+        "engine.linger": 0.010772649, "engine.admit": 0.006257896999999999,
+        "": 0.024040079000001772, "inside_a_program": 9.32299999999996e-06,
+        "engine.harvest_prefills": 0.0036791489999999996,
+        "engine.dispatch_decode": 0.023078396,
+        "engine.wait_chunk": 0.009316030999999999, "engine.emit": 0.00119117,
+    }
+    assert spans.idle_shares(read) == {
+        "emit": 1.4992285612750829, "schedule": 66.83782870945078,
+        "unspanned": 30.26902151665065,
+    }
+    assert spans.decode_step_ms(read) == 0.03599857142857143
+    assert spans.prefill_seconds(read) == 0.0001159
+    assert read["busy_s"] == 0.001107501 and read["kernel_s"] == 0.0
+    assert read["gap_total_s"] == 0.07834469400000177
+    assert [p["kind"] for p in read["programs"]].count("decode_chunk_dense") == 7
+    assert all(p["kernels"] == {} for p in read["programs"])  # head dim 16: no kernel
+
+
+def test_the_breakdown_names_the_phases_and_sums_to_the_idle_seconds(read):
+    out = trace_reduce.breakdown(read, spans.idle_by_phase(read))
+    names = [name for name, _ in out["idle_gaps"]]
+    assert len(names) <= 10 and len(set(names)) == len(names)
+    assert {"engine.emit", "engine.admit", "engine.dispatch_decode", "no_span"} <= set(names)
+    assert sum(seconds for _, seconds in out["idle_gaps"]) == pytest.approx(
+        read["gap_total_s"], rel=1e-9
+    )
+    assert out["idle_gaps"][0] == ["no_span", 0.024040079000001772]
+
+
+def test_a_runs_readers_share_the_runs_one_reduction(read, meta):
+    """``report.add_trace`` reduces a run's trace once; every reader of
+    spans lays that record and none walks the file again."""
+    trace = dict(read, begin={"at": meta["begin"]}, end={"at": meta["end"]})
+    ctx = {"trace": trace, "chips": 1, "window": {"opens": meta["begin"], "closes": meta["end"]}}
+    assert spans.of(ctx)["read"] is trace
+    assert spans.decode_step(ctx) == 0.03599857142857143
+    assert spans.prefill_busy_share(ctx) == pytest.approx(100.0 * 0.0001159 / 0.001107501)
+    assert spans.idle_share(ctx, "emit") == 1.4992285612750829
+
+
 def test_a_trace_without_annotations_reads_none():
     # the reduction's own fixture: a marker and a device, no engine span
     assert spans.read_trace(os.path.join(HERE, "fixture.xplane.pb"), 0.0133) is None
+    reduced = trace_reduce.reduce_trace(os.path.join(HERE, "fixture.xplane.pb"), 0.0133)
+    assert reduced["marked"] and reduced["phases"] == [] and spans.lay(reduced) is None
     ctx = {"trace": None, "window": {"opens": 0.0, "closes": 1.0}}
     assert spans.of(ctx) is None and spans.idle_share(ctx, "emit") is None
     assert spans.part_p50(ctx, "wait") is None and spans.queue_wait_p50(ctx) is None
     assert spans.decode_step(ctx) is None and spans.prefill_busy_share(ctx) is None
 
 
-def test_names_and_launches():
-    assert spans.kind_of("jit_prefill_dense(12503282535071236251)") == "prefill_dense"
-    assert spans.kind_of("jit_decode_chunk_paged") == "decode_chunk_paged"
-    assert spans.kind_of("pjit_something") == ""
+def test_launches():
     # enqueued on the launching thread: the enqueue is the launch
     assert spans._launched((5.0, "a"), {}, {}) == 5.0
     # enqueued from the runtime's own thread, inside the consumer of a flow

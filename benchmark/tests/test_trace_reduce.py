@@ -32,27 +32,39 @@ WHILE = (
 )
 
 
-def test_a_kernel_is_told_by_its_target_not_its_name():
+def test_a_kernel_is_a_kernel_by_its_target_and_which_by_its_name():
     assert trace_reduce.is_kernel(KERNEL)
     assert not trace_reduce.is_kernel(CONCAT)
     assert trace_reduce.opcode(KERNEL) == "custom-call"
     assert trace_reduce.opcode(WHILE) == "while"
     assert trace_reduce.short(KERNEL) == "closed_call.11 pallas-kernel bf16[32,28,128]"
+    assert trace_reduce.kernel_name(KERNEL) == "closed_call"
+    assert trace_reduce.kernel_name(KERNEL.replace("closed_call.11", "flash_decode.6")) == "flash_decode"
+    assert trace_reduce.kernel_name(KERNEL.replace("closed_call.11", "flash_decode_int8kv")) == "flash_decode_int8kv"
 
 
-def test_breakdown_sums_gaps_by_label():
-    reduced = {
-        "op_seconds": {"a": 2.0, "b": 1.0},
-        "gaps": [(0.0, 0.5), (1.0, 1.25), (2.0, 2.00001)],
-        "gap_total_s": 1.0,
-    }
-    out = trace_reduce.breakdown(
-        reduced, lambda s, e: "long" if e - s > 0.1 else "short"
-    )
+def test_a_program_is_told_by_its_name():
+    assert trace_reduce.kind_of("jit_prefill_dense(12503282535071236251)") == "prefill_dense"
+    assert trace_reduce.kind_of("jit_decode_chunk_paged") == "decode_chunk_paged"
+    assert trace_reduce.kind_of("pjit_something") == ""
+
+
+def test_breakdown_names_idle_by_the_phase_that_covers_it():
+    reduced = {"op_seconds": {"a": 2.0, "b": 1.0}, "gap_total_s": 1.0}
+    idle = {"engine.emit": 0.75, "": 0.2, "engine.admit": 0.04, "inside_a_program": 0.01}
+    out = trace_reduce.breakdown(reduced, idle)
     assert out["device_ops"][0] == ["a", 2.0]
-    labels = dict((k, v) for k, v in out["idle_gaps"])
-    assert labels["long"] == pytest.approx(0.75)
-    assert "gaps_shorter_than_the_200_longest" in labels
+    assert out["idle_gaps"] == [
+        ["engine.emit", 0.75], ["no_span", 0.2], ["engine.admit", 0.04],
+        ["inside_a_program", 0.01],
+    ]
+    # a trace without spans: all of the idle time under the one name
+    assert trace_reduce.breakdown(reduced, None)["idle_gaps"] == [["no_span", 1.0]]
+    # at most ten entries, and they still sum to the idle seconds
+    many = {f"engine.phase_{i}": float(i + 1) for i in range(14)}
+    listed = trace_reduce.breakdown(reduced, many)["idle_gaps"]
+    assert len(listed) == 10 and listed[-1][0] == "other_spans"
+    assert sum(seconds for _, seconds in listed) == pytest.approx(sum(many.values()))
 
 
 def test_the_recorded_trace_reduces_to_what_was_on_the_chip():
@@ -61,9 +73,7 @@ def test_the_recorded_trace_reduces_to_what_was_on_the_chip():
     passes, each a Pallas kernel and two matmuls) with 2 ms sleeps between
     them, 13.3 ms from the marker to the end by the host's clock."""
     span = 0.013322397999900204
-    reduced = trace_reduce.reduce_trace(
-        os.path.join(HERE, "fixture.xplane.pb"), span, layers=1
-    )
+    reduced = trace_reduce.reduce_trace(os.path.join(HERE, "fixture.xplane.pb"), span)
     assert reduced["marked"] and reduced["window_s"] == span
     # busy is the union of the op intervals: four programs of about 150 us
     assert reduced["busy_s"] == pytest.approx(584.8e-6, rel=1e-3)
@@ -72,13 +82,27 @@ def test_the_recorded_trace_reduces_to_what_was_on_the_chip():
     # the kernel is found by its target, 23 calls of it after the marker
     assert reduced["kernel_s"] == pytest.approx(15.78e-6, rel=1e-3)
     assert 100 * reduced["kernel_s"] / reduced["busy_s"] == pytest.approx(2.7, abs=0.1)
+    # the programs and their kernels by name, never by a count of calls:
+    # the fixture's program is ``jit_program`` and its kernel ``closed_call``
     programs = reduced["programs"]
-    assert [p["kernel_calls"] for p in programs] == [5, 6, 6, 6]
+    assert [p["kind"] for p in programs] == ["program"] * 4
+    assert all(set(p["kernels"]) == {"closed_call"} for p in programs)
+    assert [p["kernels"]["closed_call"]["calls"] for p in programs] == [5, 6, 6, 6]
+    assert [p["kernels"]["closed_call"]["seconds"] for p in programs] == [
+        3.43e-06, 4.118e-06, 4.116e-06, 4.115e-06,
+    ]
+    assert [p["seconds"] for p in programs] == [
+        0.000129214, 0.000151892, 0.000151799, 0.000151957,
+    ]
     assert [p["whole"] for p in programs] == [False, True, True, True]
-    assert all(p["decode"] for p in programs)  # more kernel calls than layers
     # the loop itself holds the other ops and is not summed beside them
     assert not any(" while " in name for name in reduced["op_seconds"])
     assert sum(reduced["op_seconds"].values()) == pytest.approx(reduced["busy_s"], rel=0.05)
     # the three sleeps between the programs are the longest gaps
     longest = sorted(e - s for s, e in reduced["gaps"])[-3:]
-    assert all(2e-3 < gap < 4e-3 for gap in longest)
+    assert all(2e6 < gap < 4e6 for gap in longest)
+    assert all(reduced["lo"] <= s < e <= reduced["hi"] for s, e in reduced["gaps"])
+    # the parent's readings of this file, to the last digit (PR 28)
+    assert reduced["busy_s"] == 0.000584828
+    assert reduced["kernel_s"] == 1.5779e-05
+    assert reduced["gap_total_s"] == 0.012737569999900207

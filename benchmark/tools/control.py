@@ -23,7 +23,6 @@ from __future__ import annotations
 
 import argparse
 import asyncio
-import copy
 import json
 import os
 import sys
@@ -78,7 +77,7 @@ def main() -> int:
     from benchmark import harness, report
     from benchmark.reference import compare
 
-    cell = copy.deepcopy(harness.load_cell(args.workload))
+    cell = harness.load_cell(args.workload)  # a fresh dict, its files fresh from disk
     cell["config_file"]["globals"].update(overrides)
     device = harness.require_tpu(cell["chips"])
     lowers = [x for x in (args.lower or cell["config_file"]["lower_precision"]).split(",") if x]

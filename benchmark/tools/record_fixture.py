@@ -59,8 +59,8 @@ def main() -> int:
     jax.profiler.stop_trace()
     path = glob.glob(os.path.join(scratch, "**", "*.xplane.pb"), recursive=True)[0]
     shutil.copy(path, os.path.join(out, "fixture.xplane.pb"))
-    reduced = trace_reduce.reduce_trace(path, span, layers=1)
-    print({k: v for k, v in reduced.items() if k not in ("op_seconds", "gaps")}, span)
+    reduced = trace_reduce.reduce_trace(path, span)
+    print({k: v for k, v in reduced.items() if k not in ("op_seconds", "gaps", "flows")}, span)
     with open(os.path.join(out, "fixture_span.txt"), "w") as handle:
         handle.write(repr(span))
     return 0

@@ -80,9 +80,8 @@ def main() -> int:
     if read is None:
         print("no device plane, marker or engine span in the trace")
         return 1
-    reduced = trace_reduce.reduce_trace(path, end - begin, config.num_layers)
     parts = spans.first_token_parts(read, legs, begin)
-    print("window_s", end - begin, "busy_s", reduced["busy_s"], "gap_total_s", reduced["gap_total_s"])
+    print("window_s", end - begin, "busy_s", read["busy_s"], "gap_total_s", read["gap_total_s"])
     print("idle_by_phase", spans.idle_by_phase(read))
     print("idle_shares", spans.idle_shares(read))
     print("programs", [(p["kind"], p["phase"] is not None) for p in read["programs"]])
