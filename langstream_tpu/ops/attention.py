@@ -73,7 +73,8 @@ def prefill_attention(
     scores = jnp.where(allowed, scores, -1e30)
     weights = _softmax(scores)
     out = jnp.einsum("bkgqs,bskd->bqkgd", weights.astype(v.dtype), v)
-    return out.reshape(batch, seq, heads, dim)
+    # as wide as the values (latent attention's are narrower than its keys)
+    return out.reshape(batch, seq, heads, v.shape[-1])
 
 
 def _decode_valid(

@@ -55,11 +55,12 @@ def _flash_kernel(
     window_ref,   # SMEM [1] — sliding window (0 = full attention)
     q_ref,        # VMEM [1, 1, block_q, d]
     k_ref,        # VMEM [1, 1, block_k, d]
-    v_ref,        # VMEM [1, 1, block_k, d]
-    out_ref,      # VMEM [1, 1, block_q, d]
+    v_ref,        # VMEM [1, 1, block_k, dv] (dv = d but for latent
+                  # attention's expanded heads: keys 192, values 128)
+    out_ref,      # VMEM [1, 1, block_q, dv]
     m_scratch,    # VMEM [block_q, 128] f32 — running row max
     l_scratch,    # VMEM [block_q, 128] f32 — running row sum
-    acc_scratch,  # VMEM [block_q, d] f32 — unnormalized output
+    acc_scratch,  # VMEM [block_q, dv] f32 — unnormalized output
     *,
     scale: float,
     block_q: int,
@@ -258,6 +259,7 @@ def _pallas_flash(
 ) -> jnp.ndarray:
     batch, heads, seq, dim = q.shape
     kv_heads = k.shape[1]
+    v_dim = v.shape[3]  # the values' width; the keys' is the queries'
     group = heads // kv_heads
     scale = dim ** -0.5 if scale is None else scale
     grid = (batch, heads, seq // block_q, seq // block_k)
@@ -280,7 +282,7 @@ def _pallas_flash(
     in_specs = [
         pl.BlockSpec((1, 1, block_q, dim), q_index),
         pl.BlockSpec((1, 1, block_k, dim), kv_index),
-        pl.BlockSpec((1, 1, block_k, dim), kv_index),
+        pl.BlockSpec((1, 1, block_k, v_dim), kv_index),
     ]
     operands = [q, k, v]
     if quantized:
@@ -309,22 +311,23 @@ def _pallas_flash(
         num_scalar_prefetch=2,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, block_q, dim), q_index),
+        out_specs=pl.BlockSpec((1, 1, block_q, v_dim), q_index),
         scratch_shapes=[
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, dim), jnp.float32),
+            pltpu.VMEM((block_q, v_dim), jnp.float32),
         ],
     )
     return pl.pallas_call(
         kernel,
         name="flash_prefill_int8kv" if quantized else "flash_prefill",
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((batch, heads, seq, dim), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((batch, heads, seq, v_dim), q.dtype),
         cost_estimate=pl.CostEstimate(
-            flops=4 * batch * heads * seq * seq * dim,
+            flops=2 * batch * heads * seq * seq * (dim + v_dim),
             bytes_accessed=(
-                (q.size + q.size) * q.dtype.itemsize + kv_bytes
+                (q.size + batch * heads * seq * v_dim) * q.dtype.itemsize
+                + kv_bytes
             ),
             transcendentals=batch * heads * seq * seq,
         ),
@@ -349,7 +352,8 @@ def flash_prefill_attention(
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Causal flash attention over right-padded prompts ([B, T, H, D] in
-    and out). ``mask`` must be CONTIGUOUS right-padding (True for the
+    and out; ``v`` may be narrower than ``q`` and ``k``, and the output
+    is as wide as ``v``: latent attention's expanded heads). ``mask`` must be CONTIGUOUS right-padding (True for the
     first ``lengths[b]`` positions, False after) — it is collapsed to
     per-row lengths for the kernel's SMEM masking, so a non-contiguous
     (packed / loss-style) mask would be silently misapplied; use

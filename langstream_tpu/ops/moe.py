@@ -43,6 +43,8 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def moe_capacity(
@@ -242,3 +244,241 @@ def _moe_mlp_dense(
         (first_choice.sum(0) / denom) * (probs_masked.sum(0) / denom)
     )
     return y, aux_loss
+
+
+# --------------------------------------------------------------------- #
+# serving over a chip's share of the experts: computed only where routed
+# --------------------------------------------------------------------- #
+def group_limited_routing(
+    logits: jnp.ndarray,  # [T, E] float32 router logits over ALL experts
+    *,
+    groups: int,
+    groups_kept: int,
+    num_selected: int,
+    scaling_factor: float,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Group-limited greedy routing: softmax over all ``E`` experts, a
+    group's score the largest probability among its ``E / groups``
+    experts, the ``groups_kept`` best groups stay and the others'
+    probabilities count as 0, the ``num_selected`` largest remaining are
+    the token's experts with weights ``scaling_factor * p_e``, NOT
+    renormalised. Returns (weights [T, k] float32, experts [T, k])."""
+    tokens, num_experts = logits.shape
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    by_group = probs.reshape(tokens, groups, num_experts // groups)
+    _, best = jax.lax.top_k(by_group.max(axis=-1), groups_kept)
+    keep = jnp.zeros((tokens, groups), dtype=bool).at[
+        jnp.arange(tokens)[:, None], best
+    ].set(True)
+    masked = jnp.where(keep[:, :, None], by_group, 0.0).reshape(
+        tokens, num_experts
+    )
+    weights, chosen = jax.lax.top_k(masked, num_selected)
+    return weights * scaling_factor, chosen
+
+
+def _grouped_kernel(group_ref, active_ref, layer_ref, x_ref, w_ref, out_ref):
+    """One row tile of one expert against one column tile of its weight
+    (the whole contraction at once); tiles past the last active one are
+    skipped (their blocks are not moved either: the index maps clamp)."""
+    del group_ref, layer_ref
+
+    @pl.when(pl.program_id(1) < active_ref[0])
+    def _compute():
+        out_ref[...] = jnp.dot(
+            x_ref[...], w_ref[0], preferred_element_type=jnp.float32
+        ).astype(out_ref.dtype)
+
+
+def _column_tile(k: int, n: int) -> int:
+    """Widest multiple of 128 dividing ``n`` whose [k, tile] bf16 weight
+    block stays near 5 MB (two are in flight)."""
+    best = n if n % 128 else 128
+    for tile in range(128, n + 1, 128):
+        if n % tile == 0 and k * tile * 2 <= 5 * 2 ** 20 + 2 ** 18:
+            best = tile
+    return best
+
+
+def grouped_matmul(
+    x: jnp.ndarray,           # [M, K] rows sorted by expert, every
+                              # expert's rows starting on a tile boundary
+    w: jnp.ndarray,           # [L, G, K, N]: every layer's stack
+    layer: jnp.ndarray,       # [] int32: which layer's experts
+    tile_group: jnp.ndarray,  # [M / tile] int32: the expert of each tile
+    num_active: jnp.ndarray,  # [] int32: tiles that hold routed rows
+    group_sizes: jnp.ndarray,  # [G] int32, multiples of ``tile``
+    *,
+    tile: int,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """``out[r] = x[r] @ w[layer, expert of r's tile]`` for the first
+    ``num_active`` tiles of ``tile`` rows; rows past them are not
+    computed and hold nothing meaningful. On TPU a Pallas kernel
+    (``moe_grouped_matmul``): the computed rows are exactly ``num_active
+    * tile``, and the weights are read where they lie in the layers'
+    STACK (the layer is one more prefetched scalar of the index maps, as
+    in ``ops/decode_kernel.py``: a slab sliced out of the stack for a
+    custom call is a copy of it, 629 MB a leaf a layer at the
+    DeepSeek-V2 sizes). Elsewhere ``jax.lax.ragged_dot`` over the same
+    layout."""
+    from langstream_tpu.ops.flash_attention import on_tpu
+
+    rows, k = x.shape
+    layers, groups, _, n = w.shape
+    if not (on_tpu() or interpret):
+        return jax.lax.ragged_dot(x, w[layer], group_sizes)
+    tiles = rows // tile
+    tn = _column_tile(k, n)
+
+    def clamp(t, active):
+        return jnp.minimum(t, jnp.maximum(active[0] - 1, 0))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(n // tn, tiles),
+        in_specs=[
+            pl.BlockSpec(
+                (tile, k), lambda j, t, group, active, lyr: (clamp(t, active), 0)
+            ),
+            pl.BlockSpec(
+                (1, k, tn),
+                lambda j, t, group, active, lyr: (
+                    lyr[0] * groups + group[clamp(t, active)], 0, j
+                ),
+            ),
+        ],
+        out_specs=pl.BlockSpec(
+            (tile, tn), lambda j, t, group, active, lyr: (clamp(t, active), j)
+        ),
+    )
+    return pl.pallas_call(
+        _grouped_kernel,
+        name="moe_grouped_matmul",
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((rows, n), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=48 * 2 ** 20,
+        ),
+        interpret=interpret,
+    )(
+        tile_group.astype(jnp.int32),
+        jnp.reshape(num_active, (1,)).astype(jnp.int32),
+        jnp.reshape(jnp.asarray(layer, jnp.int32), (1,)),
+        x,
+        # layer and expert merge into one leading axis (a bitcast)
+        w.reshape(layers * groups, k, n),
+    )
+
+
+def routed_tile(tokens: int, num_selected: int, num_experts: int) -> int:
+    """Row tile of the grouped path, from shapes: the power of two at or
+    above the rows an expert expects (``tokens * k / E``), between 16 (a
+    bf16 tile's sublanes) and 128 (the MXU's rows). A decode step of 64
+    tokens takes 16, a 4,096-token prefill 128."""
+    expected = max(1, -(-tokens * num_selected // num_experts))
+    return int(min(128, max(16, 1 << (expected - 1).bit_length())))
+
+
+def moe_mlp_held(
+    x2: jnp.ndarray,        # [T, H]
+    router_w: jnp.ndarray,  # [H, E]: the router over ALL experts
+    w_gate: jnp.ndarray,    # [L, held, H, F]: every layer's experts
+    w_up: jnp.ndarray,      # [held_first, held_first + held)
+    w_down: jnp.ndarray,    # [L, held, F, H]
+    *,
+    layer=0,                # [] int32: which layer of the stacks
+    held_first: int,
+    groups: int,
+    groups_kept: int,
+    num_selected: int,
+    scaling_factor: float,
+    valid: Optional[jnp.ndarray] = None,  # [T] bool; False = padding
+    interpret: bool = False,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """A chip's share of a routed mixture: the router scores all ``E``
+    experts, this chip computes ``sum_e w_e SwiGLU_e(x)`` over the experts
+    it holds, for the tokens routed to them and for no others. No token is
+    dropped and nothing stands in for the absent experts.
+
+    Static shapes: the (token, expert) assignments that meet a held
+    expert are sorted by expert, each expert's rows start on a tile
+    boundary, and the grouped matmul computes the tiles that hold rows.
+    The layout has room for the worst case (every assignment held:
+    ``T * k + held * tile`` rows); what is computed is ``ceil(rows_e /
+    tile) * tile`` summed over the held experts.
+
+    Returns (y [T, H], counters int32 [3 + held]: assignments routed (valid
+    tokens x k), assignments that met a held expert, rows the expert
+    matmuls computed (padding included), tokens by held expert)."""
+    tokens, hidden = x2.shape
+    held = w_gate.shape[1]
+    num_experts = router_w.shape[-1]
+    logits = jnp.einsum(
+        "th,he->te", x2.astype(jnp.float32), router_w.astype(jnp.float32)
+    )
+    weights, chosen = group_limited_routing(
+        logits, groups=groups, groups_kept=groups_kept,
+        num_selected=num_selected, scaling_factor=scaling_factor,
+    )
+    local = chosen - held_first                       # [T, k]
+    met = (local >= 0) & (local < held)
+    if valid is not None:
+        met = met & valid[:, None]
+    tile = routed_tile(tokens, num_selected, num_experts)
+    pairs = tokens * num_selected
+    rows = -(-pairs // tile) * tile + held * tile
+    # sort the assignments by expert; those that meet no held expert go
+    # last (group ``held``) and get no row
+    flat_group = jnp.where(met, local, held).reshape(pairs)
+    order = jnp.argsort(flat_group, stable=True)
+    sorted_group = flat_group[order]
+    counts = jnp.zeros((held + 1,), jnp.int32).at[flat_group].add(1)[:held]
+    padded = -(-counts // tile) * tile                # rows an expert takes
+    starts = jnp.cumsum(counts) - counts              # in the sorted order
+    padded_starts = jnp.cumsum(padded) - padded       # in the layout
+    safe_group = jnp.minimum(sorted_group, held - 1)
+    rank = jnp.arange(pairs) - starts[safe_group]
+    dest_sorted = jnp.where(
+        sorted_group < held, padded_starts[safe_group] + rank, rows - 1
+    )
+    # the row of every (token, choice); ``rows - 1`` is never an active
+    # row's (the layout has a tile to spare past the worst case)
+    dest = jnp.zeros((pairs,), jnp.int32).at[order].set(dest_sorted)
+    row_token = jnp.zeros((rows,), jnp.int32).at[dest_sorted].set(
+        (order // num_selected).astype(jnp.int32)
+    )
+    num_active = padded.sum() // tile
+    tile_group = jnp.minimum(
+        jnp.searchsorted(
+            jnp.cumsum(padded), jnp.arange(rows // tile) * tile, side="right"
+        ),
+        held - 1,
+    ).astype(jnp.int32)
+
+    def matmul(lhs, w):
+        return grouped_matmul(
+            lhs, w, layer, tile_group, num_active, padded, tile=tile,
+            interpret=interpret,
+        )
+
+    x_rows = x2[row_token]                            # [rows, H]
+    hidden_rows = jax.nn.silu(matmul(x_rows, w_gate)) * matmul(x_rows, w_up)
+    out_rows = matmul(hidden_rows, w_down)            # [rows, H]
+    picked = out_rows[dest].reshape(tokens, num_selected, hidden)
+    gate = jnp.where(met, weights, 0.0)
+    # rows past the active tiles hold nothing meaningful: masked, not
+    # multiplied by a zero weight (0 * nan is nan)
+    picked = jnp.where(met[:, :, None], picked, 0)
+    y = jnp.einsum(
+        "tk,tkh->th", gate.astype(jnp.float32), picked.astype(jnp.float32)
+    ).astype(x2.dtype)
+    routed = (
+        jnp.int32(pairs) if valid is None
+        else valid.sum().astype(jnp.int32) * num_selected
+    )
+    counters = jnp.concatenate([
+        jnp.stack([routed, met.sum().astype(jnp.int32), num_active * tile]),
+        counts,
+    ]).astype(jnp.int32)
+    return y, counters

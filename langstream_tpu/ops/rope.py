@@ -2,6 +2,7 @@
 
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax.numpy as jnp
@@ -18,8 +19,6 @@ def _llama3_scale_inv_freq(
     ``_compute_llama3_parameters``): high-frequency components keep
     their wavelength, low-frequency ones stretch by ``factor``, and the
     band between interpolates smoothly."""
-    import math
-
     low_wavelen = original_max_positions / low_freq_factor
     high_wavelen = original_max_positions / high_freq_factor
     wavelen = 2.0 * math.pi / inv_freq
@@ -35,6 +34,55 @@ def _llama3_scale_inv_freq(
     )
 
 
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature term: ``0.1 * mscale * ln(factor) + 1``
+    for a factor above 1, else 1."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_softmax_scale(scaling: Optional[tuple]) -> float:
+    """What YaRN multiplies the softmax scale by: ``mscale(factor,
+    mscale_all_dim) ** 2`` (the DeepSeek-V2 recipe); 1 without YaRN."""
+    if scaling is None or scaling[0] != "yarn":
+        return 1.0
+    return yarn_mscale(scaling[1], scaling[5]) ** 2
+
+
+def _yarn_scale_inv_freq(
+    head_dim: int,
+    theta: float,
+    factor: float,
+    beta_fast: float,
+    beta_slow: float,
+    original_max_positions: float,
+) -> jnp.ndarray:
+    """YaRN (HF ``DeepseekV2YarnRotaryEmbedding``): dimensions that turn
+    more than ``beta_fast`` times over the original context keep their
+    frequency, those that turn fewer than ``beta_slow`` times are
+    interpolated (divided by ``factor``), and a linear ramp over the
+    dimension index blends the two between the correction dims."""
+    exponents = jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim
+    extrapolated = 1.0 / (theta ** exponents)
+    interpolated = 1.0 / (factor * theta ** exponents)
+
+    def correction_dim(rotations: float) -> float:
+        return (
+            head_dim
+            * math.log(original_max_positions / (rotations * 2 * math.pi))
+            / (2 * math.log(theta))
+        )
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001  # the published guard against a zero-width ramp
+    ramp = jnp.clip(
+        (jnp.arange(head_dim // 2, dtype=jnp.float32) - low) / (high - low),
+        0.0, 1.0,
+    )
+    return interpolated * ramp + extrapolated * (1.0 - ramp)
+
+
 def rope_frequencies(
     head_dim: int,
     max_positions: int,
@@ -48,18 +96,34 @@ def rope_frequencies(
     ``scaling`` is the config's hashable rope-scaling tuple
     ``("llama3", factor, low_freq_factor, high_freq_factor,
     original_max_position_embeddings)`` — the Llama-3.1/3.2 long-context
-    recipe. None = plain RoPE."""
+    recipe — or ``("yarn", factor, beta_fast, beta_slow, mscale,
+    mscale_all_dim, original_max_position_embeddings)``, which also
+    scales cos and sin by ``mscale(factor, mscale) / mscale(factor,
+    mscale_all_dim)``. None = plain RoPE."""
     inv_freq = 1.0 / (
         theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
     )
+    on_cos_sin = 1.0
     if scaling is not None:
         kind = scaling[0]
-        if kind != "llama3":
+        if kind == "yarn":
+            factor, beta_fast, beta_slow, mscale, all_dim, original = scaling[1:]
+            inv_freq = _yarn_scale_inv_freq(
+                head_dim, theta, factor, beta_fast, beta_slow, original
+            )
+            on_cos_sin = yarn_mscale(factor, mscale) / yarn_mscale(
+                factor, all_dim
+            )
+        elif kind == "llama3":
+            inv_freq = _llama3_scale_inv_freq(inv_freq, *scaling[1:])
+        else:
             raise ValueError(f"unsupported rope scaling type: {kind!r}")
-        inv_freq = _llama3_scale_inv_freq(inv_freq, *scaling[1:])
     positions = jnp.arange(max_positions, dtype=jnp.float32)
     angles = jnp.outer(positions, inv_freq)
-    return jnp.stack([jnp.cos(angles), jnp.sin(angles)]).astype(dtype)
+    table = jnp.stack([jnp.cos(angles), jnp.sin(angles)])
+    if on_cos_sin != 1.0:
+        table = table * on_cos_sin
+    return table.astype(dtype)
 
 
 def apply_rope(

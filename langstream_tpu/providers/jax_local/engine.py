@@ -252,6 +252,29 @@ def engines_snapshot() -> Dict[str, float]:
             # SLO targets + multi-window burn rates: visible from the
             # first scrape (targets are config, not traffic)
             out.update(engine.slo.gauges())
+        experts = getattr(getattr(engine, "config", None), "experts", None)
+        if experts is not None:
+            # routed experts held here: what the router assigned, what
+            # met a held expert, what the expert matmuls computed
+            first = experts.held_first
+            counted = [
+                (f"jax_engine_{name}_total", stats[name])
+                for name in (
+                    "moe_assignments", "moe_assignments_held",
+                    "moe_rows_computed",
+                )
+            ] + [
+                (
+                    "jax_engine_moe_tokens_by_expert_total"
+                    f'{{expert="{first + offset}"}}',
+                    count,
+                )
+                for offset, count in enumerate(
+                    list(stats["moe_tokens_by_expert"])
+                )
+            ]
+            for key, count in counted:
+                out[key] = out.get(key, 0.0) + float(count)
         if getattr(engine, "spec", False):
             spec_engines += 1
             spec_drafted += stats["tokens_drafted"]
@@ -665,6 +688,15 @@ class DecodeEngine:
                                           # QueueTimeoutError (None=off)
     ) -> None:
         self.config = config
+        if config.mla is not None:
+            # what the latent/routed family cannot take yet is refused
+            # here, by the switch's name: nothing falls through to a
+            # GQA path
+            self._refuse_for_latent(
+                params, mesh_config, quantize=quantize, kv_quant=kv_quant,
+                kv_layout=kv_layout, kv_host_blocks=kv_host_blocks,
+                spec_decode=spec_decode, prefill_mode=prefill_mode,
+            )
         self.max_slots = max_slots
         self.decode_chunk = max(1, decode_chunk)
         # TTFT lever: when admissions are waiting at dispatch time, cap
@@ -898,7 +930,8 @@ class DecodeEngine:
             self._cache_sharding = cache_sharding
         else:
             cache_sharding = param_shardings(
-                model_lib.cache_logical_axes(self.kv_quant), self.mesh
+                model_lib.cache_logical_axes(self.kv_quant, config),
+                self.mesh,
             )
             with self.mesh:
                 # owned-by: _run_loop
@@ -1057,6 +1090,44 @@ class DecodeEngine:
         _LIVE_ENGINES.add(self)
 
     @staticmethod
+    def _refuse_for_latent(
+        params, mesh_config, *, quantize, kv_quant, kv_layout,
+        kv_host_blocks, spec_decode, prefill_mode,
+    ) -> None:
+        """The latent-attention, routed-experts family runs the dense
+        layout's three programs in the weights' own precision on one
+        chip; every other switch is refused by name when the engine is
+        built (ROADMAP R-M1 / R-M3 say what each needs)."""
+        from langstream_tpu.providers.jax_local.quant import QTensor
+
+        refused = {
+            "kv-layout: paged (the block pool holds GQA rows)":
+                kv_layout != "dense",
+            "prefill-mode: mixed (a paged dispatch)":
+                prefill_mode != "split",
+            "kv-host-blocks (the host tier holds paged GQA rows)":
+                bool(kv_host_blocks),
+            "kv-quant (the latent cache has no int8 form)":
+                bool(kv_quant),
+            "quantization (quant.py has no int8 form of the expert "
+            "stacks or the low-rank projections)":
+                bool(quantize) or any(
+                    isinstance(v, QTensor) for v in params.values()
+                ),
+            "spec-decode (no verify step over latents)":
+                spec_decode != "off",
+            "mesh (tp / ep / any axis > 1: the latent cache has one head "
+            "and the expert stacks hold this chip's share)":
+                mesh_config is not None and mesh_config.size > 1,
+        }
+        named = [switch for switch, on in refused.items() if on]
+        if named:
+            raise ValueError(
+                "the latent-attention, routed-experts family does not "
+                "support: " + "; ".join(named)
+            )
+
+    @staticmethod
     def _fresh_stats() -> Dict[str, Any]:
         return {
             "tokens_generated": 0,
@@ -1128,7 +1199,38 @@ class DecodeEngine:
             "host_promote_bytes": 0,
             "kv_host_hit_tokens": 0,
             "host_promote_aborts": 0,
+            # routed experts held here (model.RoutedExperts), from the
+            # counters every prefill and decode chunk returns: (token,
+            # expert) assignments the router made, those that met a held
+            # expert, rows the expert matmuls computed (tile padding
+            # included), and tokens by held expert
+            "moe_assignments": 0,
+            "moe_assignments_held": 0,
+            "moe_rows_computed": 0,
+            "moe_tokens_by_expert": [],
         }
+
+    def _note_moe(self, span, counters) -> None:
+        """A harvested dispatch's expert counters (None for a family
+        without routed experts): into ``stats`` and onto the phase span
+        the host harvests them under."""
+        if counters is None:
+            return
+        counters = np.asarray(counters)
+        routed, held, rows = (int(n) for n in counters[:3])
+        by_expert = [int(n) for n in counters[3:]]
+        stats = self.stats
+        stats["moe_assignments"] += routed
+        stats["moe_assignments_held"] += held
+        stats["moe_rows_computed"] += rows
+        kept = stats["moe_tokens_by_expert"]
+        stats["moe_tokens_by_expert"] = [
+            a + b for a, b in zip(kept or [0] * len(by_expert), by_expert)
+        ]
+        span.set(
+            moe_routed=routed, moe_held=held, moe_rows=rows,
+            moe_by_expert=":".join(map(str, by_expert)),
+        )
 
     # lint: allow(owned-by-violation) -- bench/warmup contract: callers
     #   reset counters only while the engine is idle (no dispatch in
@@ -1215,7 +1317,7 @@ class DecodeEngine:
                         logits, slot_ids, counts, temperature, top_k,
                         top_p, seeds, lengths, bias_ids, bias_vals,
                     )
-                    return cache, counts, sampled, lp, tops
+                    return cache, counts, sampled, lp, tops, None
 
             else:
 
@@ -1223,15 +1325,17 @@ class DecodeEngine:
                 def run(params, cache, tokens, lengths, slot_ids, counts,
                         temperature, top_k, top_p, seeds,
                         bias_ids, bias_vals):
-                    cache, logits = model_lib.prefill(
-                        config, params, cache, tokens, lengths, slot_ids,
-                        freqs, mesh=mesh,
+                    cache, logits, moe = model_lib.step_results(
+                        model_lib.prefill(
+                            config, params, cache, tokens, lengths,
+                            slot_ids, freqs, mesh=mesh,
+                        )
                     )
                     counts, sampled, lp, tops = sample_first(
                         logits, slot_ids, counts, temperature, top_k,
                         top_p, seeds, lengths, bias_ids, bias_vals,
                     )
-                    return cache, counts, sampled, lp, tops
+                    return cache, counts, sampled, lp, tops, moe
 
             fn = run
             self._compiled_prefill[bucket] = fn
@@ -1275,7 +1379,7 @@ class DecodeEngine:
                         logits, slot_ids, counts, temperature, top_k,
                         top_p, seeds, offsets, lengths, bias_ids, bias_vals,
                     )
-                    return cache, counts, sampled, lp, tops
+                    return cache, counts, sampled, lp, tops, None
 
             else:
 
@@ -1283,15 +1387,17 @@ class DecodeEngine:
                 def run(params, cache, tokens, lengths, offsets, slot_ids,
                         counts, temperature, top_k, top_p, seeds,
                         bias_ids, bias_vals):
-                    cache, logits = model_lib.prefill_at_offset(
-                        config, params, cache, tokens, lengths, offsets,
-                        slot_ids, freqs,
+                    cache, logits, moe = model_lib.step_results(
+                        model_lib.prefill_at_offset(
+                            config, params, cache, tokens, lengths, offsets,
+                            slot_ids, freqs,
+                        )
                     )
                     counts, sampled, lp, tops = sample_first(
                         logits, slot_ids, counts, temperature, top_k,
                         top_p, seeds, offsets, lengths, bias_ids, bias_vals,
                     )
-                    return cache, counts, sampled, lp, tops
+                    return cache, counts, sampled, lp, tops, moe
 
             fn = run
             self._prefill_offset_fns[bucket] = fn
@@ -1325,7 +1431,7 @@ class DecodeEngine:
                 slots = tokens.shape[0]
 
                 def body(carry, _):
-                    cache, tokens, lengths, counts = carry
+                    cache, tokens, lengths, counts, moe = carry
                     if paged:
                         cache, logits = model_lib.paged_decode_step(
                             config, params, cache, tokens, lengths,
@@ -1333,10 +1439,17 @@ class DecodeEngine:
                             kernel=paged_kernel,
                         )
                     else:
-                        cache, logits = model_lib.decode_step(
-                            config, params, cache, tokens, lengths, freqs,
-                            write_mask, mesh=mesh,
+                        # the step's expert counters, summed over the
+                        # chunk (None, an empty pytree, for a family
+                        # without routed experts)
+                        cache, logits, step_moe = model_lib.step_results(
+                            model_lib.decode_step(
+                                config, params, cache, tokens, lengths,
+                                freqs, write_mask, mesh=mesh,
+                            )
                         )
+                        if step_moe is not None:
+                            moe = moe + step_moe
                     # presence/frequency penalties over generated tokens
                     # (identity when both are 0 — exact float math)
                     adjusted = (
@@ -1362,13 +1475,15 @@ class DecodeEngine:
                     ys = (sampled, lp)
                     if topk:
                         ys = ys + _top_logprobs(logits, topk)
-                    return (cache, sampled, lengths, counts), ys
+                    return (cache, sampled, lengths, counts, moe), ys
 
+                moe = model_lib.zero_counters(config)
                 (
-                    (cache, final_tokens, final_lengths, counts),
+                    (cache, final_tokens, final_lengths, counts, moe),
                     ys,
                 ) = jax.lax.scan(
-                    body, (cache, tokens, lengths, counts), None, length=steps
+                    body, (cache, tokens, lengths, counts, moe), None,
+                    length=steps,
                 )
                 out, lps = ys[0], ys[1]
                 # [steps, S, K] -> [S, steps, K] to match out.T's layout
@@ -1380,7 +1495,7 @@ class DecodeEngine:
                 # chunk can chain without a host round trip
                 return (
                     cache, self._pin_counts(counts), out.T, lps.T, tops,
-                    final_tokens, final_lengths,
+                    final_tokens, final_lengths, moe,
                 )
 
             if paged:
@@ -2425,6 +2540,24 @@ class DecodeEngine:
                 f"logit_bias has {len(bias)} entries; this engine supports "
                 f"at most {self.MAX_LOGIT_BIAS}"
             )
+        if self.config.mla is not None:
+            # what a REQUEST can ask of the latent family that it cannot
+            # take (the engine's switches were refused when it was built)
+            if request.export_handoff or request.kv_import is not None:
+                raise ValueError(
+                    "the latent-attention family has no handoff rows "
+                    "(export_handoff / kv_import: the payload holds paged "
+                    "GQA rows)"
+                )
+            largest = self.prefill_buckets[-1]
+            if len(request.prompt_tokens) > largest:
+                raise ValueError(
+                    f"prompt of {len(request.prompt_tokens)} tokens exceeds "
+                    f"the largest prefill bucket ({largest}): the latent "
+                    "family has no chunked prefill (a window over a latent "
+                    "prefix needs a flash kernel with a key offset); raise "
+                    "prefill-buckets"
+                )
         # prompts longer than the largest bucket prefill in bucket-sized
         # windows (chunked prefill), so context length is the only limit
         limit = self.max_seq_len - 1
@@ -3631,7 +3764,7 @@ class DecodeEngine:
                 self._stamp_dispatch(
                     [request for _, request in group], batch_id, bucket
                 )
-                self.cache, self._counts, sampled, lps, tops = run(
+                self.cache, self._counts, sampled, lps, tops, moe = run(
                     self.params, self.cache, *host_args[:3], *paged_args,
                     self._counts, *host_args[3:],
                 )
@@ -3669,6 +3802,7 @@ class DecodeEngine:
                     "sampled": sampled,
                     "lps": lps,
                     "tops": tops,
+                    "moe": moe,
                     "reused": {},
                     "started": started,
                     "batch": batch_id,
@@ -3727,7 +3861,7 @@ class DecodeEngine:
                 self._stamp_dispatch(
                     [request for _, request, _ in group], batch_id, bucket
                 )
-                self.cache, self._counts, sampled, lps, tops = run(
+                self.cache, self._counts, sampled, lps, tops, moe = run(
                     self.params, self.cache, *host_args[:4], *paged_args,
                     self._counts, *host_args[4:],
                 )
@@ -3763,6 +3897,7 @@ class DecodeEngine:
                     "sampled": sampled,
                     "lps": lps,
                     "tops": tops,
+                    "moe": moe,
                     "reused": {index: reused for index, _, reused in group},
                     "started": started,
                     "batch": batch_id,
@@ -3837,7 +3972,7 @@ class DecodeEngine:
                     "prefill_offset", {"bucket": bucket},
                     [*host_args[:4], *paged_args, *host_args[4:]],
                 )
-            self.cache, self._counts, sampled, lps, tops = run(
+            self.cache, self._counts, sampled, lps, tops, moe = run(
                 self.params, self.cache, *host_args[:4], *paged_args,
                 self._counts, *host_args[4:],
             )
@@ -3849,6 +3984,7 @@ class DecodeEngine:
                     "sampled": sampled,
                     "lps": lps,
                     "tops": tops,
+                    "moe": moe,
                     "reused": {index: reused} if reused else {},
                     "started": started,
                     "batch": batch_id,
@@ -3934,8 +4070,9 @@ class DecodeEngine:
             with self._phase(
                 "engine.harvest_prefills",
                 rows=len(record["group"]), batch=record["batch"],
-            ):
+            ) as span:
                 self._harvest_record(record)
+                self._note_moe(span, record.get("moe"))
             self._prefill_inflight.pop(0)
             block = False  # only the oldest is worth waiting for
 
@@ -4300,7 +4437,7 @@ class DecodeEngine:
                 )
         run = self._get_decode(steps)
         paged_args = (tables_arg,) if self.paged else ()
-        out_valid = out_drafted = final_history = None
+        out_valid = out_drafted = final_history = out_moe = None
         if self.spec:
             (
                 self.cache, self._counts, out_tokens, out_lps, out_valid,
@@ -4315,7 +4452,7 @@ class DecodeEngine:
         else:
             (
                 self.cache, self._counts, out_tokens, out_lps, out_tops,
-                final_tokens, final_lengths,
+                final_tokens, final_lengths, out_moe,
             ) = run(
                 self.params, self.cache, tokens_arg, lengths_arg,
                 active_arg, active_arg, *paged_args, self._counts,
@@ -4328,6 +4465,7 @@ class DecodeEngine:
             "out_tops": out_tops,
             "out_valid": out_valid,
             "out_drafted": out_drafted,
+            "out_moe": out_moe,
             "final_tokens": final_tokens,
             "final_lengths": final_lengths,
             "final_history": final_history,
@@ -4691,7 +4829,7 @@ class DecodeEngine:
         token), timed into ``emit_time`` at the same boundaries."""
         with self._phase("engine.emit", "emit_time") as span:
             before = self.stats["tokens_generated"]
-            yield
+            yield span
             span.set(tokens=self.stats["tokens_generated"] - before)
 
     def _account_mixed(
@@ -4876,7 +5014,8 @@ class DecodeEngine:
             tops = inflight.get("out_tops")
             if tops is not None:  # ([S, steps, K] ids, [S, steps, K] lps)
                 tops = (np.asarray(tops[0]), np.asarray(tops[1]))
-        with self._emit_span():
+        with self._emit_span() as span:
+            self._note_moe(span, inflight.get("out_moe"))
             self._account_decode(
                 inflight, out_host, lps_host, tops, time.perf_counter()
             )

@@ -45,6 +45,53 @@ from langstream_tpu.providers.jax_local.quant import qeinsum
 
 
 @dataclasses.dataclass(frozen=True)
+class LatentAttention:
+    """Latent (MLA) attention: low-rank query and key/value projections
+    with their own RMSNorms, a rotary part shared by all heads; the cache
+    holds ``kv_lora_rank + qk_rope_head_dim`` values a token a layer."""
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+
+@dataclasses.dataclass(frozen=True)
+class RoutedExperts:
+    """A group-limited router over ``routed`` experts of which THIS chip
+    holds ``[held_first, held_first + held)`` and computes only where
+    routed, ``shared`` experts every token meets, after ``leading_dense``
+    layers with a plain SwiGLU of the config's ``intermediate_size``."""
+    routed: int
+    held_first: int
+    held: int
+    intermediate_size: int
+    per_token: int
+    shared: int
+    leading_dense: int
+    groups: int
+    groups_kept: int
+    scaling_factor: float
+
+
+def zero_counters(config: "LlamaConfig"):
+    """The expert counters a scan over steps or layers starts from (int32
+    ``[3 + held]``); None, an empty pytree, without routed experts."""
+    if config.experts is None:
+        return None
+    return jnp.zeros((3 + config.experts.held,), jnp.int32)
+
+
+def step_results(out):
+    """(cache, logits, expert counters or None) of what ``prefill``,
+    ``prefill_at_offset`` or ``decode_step`` returned: the latent/routed
+    family's steps (latent_moe.py) return their counters as a third
+    result, every other family's return two and their programs are what
+    they were (None is an empty pytree)."""
+    return out if len(out) == 3 else (*out, None)
+
+
+@dataclasses.dataclass(frozen=True)
 class LlamaConfig:
     vocab_size: int = 32000
     hidden_size: int = 2048
@@ -77,6 +124,11 @@ class LlamaConfig:
     # low_freq_factor, high_freq_factor, original_max_positions) — the
     # Llama-3.1/3.2 long-context recipe (ops/rope.py). None = plain.
     rope_scaling: Optional[Tuple] = None
+    # The latent-attention, routed-experts family (latent_moe.py): both
+    # set, or both None (every other preset: the program it compiles to
+    # is what it was).
+    mla: Optional[LatentAttention] = None
+    experts: Optional[RoutedExperts] = None
     dtype: Any = jnp.bfloat16
     # Pallas flash prefill (TPU only; tp-sharded meshes route it through
     # shard_map over the head axis — see _prefill_attn).
@@ -192,6 +244,52 @@ class LlamaConfig:
         )
 
     @classmethod
+    def deepseek_v2(cls, max_seq_len: int = 8192) -> "LlamaConfig":
+        """DeepSeek-V2 (HF deepseek-ai/DeepSeek-V2): latent attention,
+        160 routed experts in 8 groups (3 groups and 6 experts a token,
+        weights x16, not renormalised), 2 shared experts, one leading
+        dense layer, YaRN x40 over 4,096. All 160 experts held: a chip's
+        share is set beside the preset (``experts-held-first``,
+        ``experts-held``, ``num-layers``, ``vocab-size``)."""
+        return cls(
+            vocab_size=102400, hidden_size=5120, intermediate_size=12288,
+            num_layers=60, num_heads=128, num_kv_heads=128, head_dim=192,
+            rope_theta=10000.0, max_seq_len=max_seq_len, norm_eps=1e-6,
+            rope_scaling=("yarn", 40.0, 32.0, 1.0, 0.707, 0.707, 4096.0),
+            mla=LatentAttention(
+                q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
+                qk_rope_head_dim=64, v_head_dim=128,
+            ),
+            experts=RoutedExperts(
+                routed=160, held_first=0, held=160, intermediate_size=1536,
+                per_token=6, shared=2, leading_dense=1, groups=8,
+                groups_kept=3, scaling_factor=16.0,
+            ),
+        )
+
+    @classmethod
+    def tiny_deepseek_v2(cls, max_seq_len: int = 256) -> "LlamaConfig":
+        """Test-size shape of the latent/routed family: 4 heads of 16 + 8
+        / 16, latent 32, 8 experts in 4 groups (2 groups and 3 experts a
+        token), 1 dense + 2 expert layers."""
+        return cls(
+            vocab_size=512, hidden_size=64, intermediate_size=128,
+            num_layers=3, num_heads=4, num_kv_heads=4, head_dim=24,
+            rope_theta=10000.0, max_seq_len=max_seq_len, norm_eps=1e-6,
+            rope_scaling=("yarn", 40.0, 32.0, 1.0, 0.707, 0.707, 64.0),
+            mla=LatentAttention(
+                q_lora_rank=24, kv_lora_rank=32, qk_nope_head_dim=16,
+                qk_rope_head_dim=8, v_head_dim=16,
+            ),
+            experts=RoutedExperts(
+                routed=8, held_first=0, held=8, intermediate_size=32,
+                per_token=3, shared=2, leading_dense=1, groups=4,
+                groups_kept=2, scaling_factor=4.0,
+            ),
+            dtype=jnp.float32,
+        )
+
+    @classmethod
     def tiny_qwen2(cls, max_seq_len: int = 256) -> "LlamaConfig":
         """Test-size Qwen-2 shape (qkv biases on)."""
         return dataclasses.replace(cls.tiny(max_seq_len), qkv_bias=True)
@@ -232,16 +330,62 @@ class LlamaConfig:
             "tiny-gemma2": cls.tiny_gemma2,
             "qwen-2.5-7b": cls.qwen25_7b, "qwen-2.5-0.5b": cls.qwen25_0_5b,
             "tiny-qwen2": cls.tiny_qwen2,
+            "deepseek-v2": cls.deepseek_v2,
+            "tiny-deepseek-v2": cls.tiny_deepseek_v2,
         }
         preset = clean.pop("preset", None)
+        # a chip's share of the routed experts, beside the preset
+        share = {
+            name: int(clean.pop("experts_" + name))
+            for name in ("held_first", "held")
+            if clean.get("experts_" + name) not in (None, "")
+        }
+        for name in ("num_layers", "vocab_size"):
+            # placeholders (``${globals.num-layers}``) arrive as strings
+            if isinstance(clean.get(name), str):
+                clean[name] = int(clean[name])
+        for name, record in (("mla", LatentAttention), ("experts", RoutedExperts)):
+            if isinstance(clean.get(name), dict):
+                clean[name] = record(**{
+                    k.replace("-", "_"): v for k, v in clean[name].items()
+                })
         if preset:
-            base = presets[preset]()
-            return dataclasses.replace(
-                base, **{k: v for k, v in clean.items() if k in known}
+            config = dataclasses.replace(
+                presets[preset](),
+                **{k: v for k, v in clean.items() if k in known},
             )
-        return cls(**{k: v for k, v in clean.items() if k in known})
+        else:
+            config = cls(**{k: v for k, v in clean.items() if k in known})
+        if share:
+            if config.experts is None:
+                raise ValueError(
+                    "experts-held-first / experts-held need a model with "
+                    "routed experts"
+                )
+            config = dataclasses.replace(
+                config, experts=dataclasses.replace(config.experts, **share)
+            )
+        if (config.mla is None) != (config.experts is None):
+            raise ValueError(
+                "latent attention and routed experts come together "
+                "(latent_moe.py): set both `mla` and `experts`, or neither"
+            )
+        experts = config.experts
+        if experts is not None and not (
+            0 <= experts.held_first
+            and 0 < experts.held
+            and experts.held_first + experts.held <= experts.routed
+            and experts.routed % experts.groups == 0
+            and 0 < experts.leading_dense < config.num_layers
+        ):
+            raise ValueError(f"inconsistent routed experts: {experts}")
+        return config
 
     def num_params(self) -> int:
+        if self.mla is not None:
+            from langstream_tpu.providers.jax_local import latent_moe
+
+            return latent_moe.num_params(self)
         head_dim = self.dims_per_head
         attn = self.hidden_size * head_dim * (2 * self.num_heads + 2 * self.num_kv_heads)
         mlp = 3 * self.hidden_size * self.intermediate_size
@@ -254,6 +398,10 @@ class LlamaConfig:
 
 def init_params(config: LlamaConfig, seed: int = 0) -> Dict[str, jnp.ndarray]:
     """Random-init (scaled normal) parameter pytree with stacked layers."""
+    if config.mla is not None:
+        from langstream_tpu.providers.jax_local import latent_moe
+
+        return latent_moe.init_params(config, seed)
     key = jax.random.PRNGKey(seed)
     keys = jax.random.split(key, 10)
     h, f, v = config.hidden_size, config.intermediate_size, config.vocab_size
@@ -310,6 +458,10 @@ def init_params(config: LlamaConfig, seed: int = 0) -> Dict[str, jnp.ndarray]:
 
 def logical_axes(config: LlamaConfig) -> Dict[str, Any]:
     """Logical sharding axes per parameter (fed to parallel.mesh rules)."""
+    if config.mla is not None:
+        from langstream_tpu.providers.jax_local import latent_moe
+
+        return latent_moe.logical_axes(config)
     if config.num_experts:
         mlp_axes = {
             "w_gate": L("layers", "expert", "embed", "mlp"),
@@ -357,8 +509,17 @@ def init_cache(
     ``kv_quant`` stores int8 values plus per-(position, kv-head) f32
     scales — halves the cache's HBM bytes on the weights+cache-bound
     decode path (scales are 1/32 of the int8 bytes at head_dim 128).
-    The forward paths detect quantization by the ``k_scale`` key."""
+    The forward paths detect quantization by the ``k_scale`` key.
+
+    The latent family's cache is one leaf of latents instead
+    (``latent_moe.init_cache``)."""
     max_len = max_len or config.max_seq_len
+    if config.mla is not None:
+        from langstream_tpu.providers.jax_local import latent_moe
+
+        if kv_quant:
+            raise ValueError("the latent cache has no int8 form")
+        return latent_moe.init_cache(config, batch, max_len)
     shape = (config.num_layers, batch, max_len, config.num_kv_heads, config.dims_per_head)
     if kv_quant:
         return {
@@ -373,7 +534,13 @@ def init_cache(
     }
 
 
-def cache_logical_axes(kv_quant: bool = False) -> Dict[str, Any]:
+def cache_logical_axes(
+    kv_quant: bool = False, config: Optional[LlamaConfig] = None
+) -> Dict[str, Any]:
+    if config is not None and config.mla is not None:
+        from langstream_tpu.providers.jax_local import latent_moe
+
+        return latent_moe.cache_logical_axes()
     axes: Dict[str, Any] = {
         "k": L("layers", "cache_batch", "cache_sequence", "kv_heads", None),
         "v": L("layers", "cache_batch", "cache_sequence", "kv_heads", None),
@@ -437,9 +604,10 @@ def paged_cache_logical_axes(kv_quant: bool = False) -> Dict[str, Any]:
 
 def normalize_rope_scaling(value: Any) -> Optional[Tuple]:
     """HF configs carry rope scaling as a dict; the config field is a
-    hashable tuple ("llama3", factor, low, high, original_max). Accepts
-    either spelling; only the llama3 (3.1/3.2 long-context) type is
-    supported — anything else raises rather than silently degrading."""
+    hashable tuple ("llama3", factor, low, high, original_max) or
+    ("yarn", factor, beta_fast, beta_slow, mscale, mscale_all_dim,
+    original_max). Accepts either spelling; any other type raises rather
+    than silently degrading."""
     if value is None or isinstance(value, tuple):
         return value
     if isinstance(value, (list,)):
@@ -449,6 +617,15 @@ def normalize_rope_scaling(value: Any) -> Optional[Tuple]:
     kind = value.get("rope_type") or value.get("type")
     if kind == "default":
         return None
+    if kind == "yarn":
+        wanted = (
+            "factor", "beta_fast", "beta_slow", "mscale", "mscale_all_dim",
+            "original_max_position_embeddings",
+        )
+        missing = [key for key in wanted if key not in value]
+        if missing:
+            raise ValueError(f"yarn rope_scaling missing {missing}")
+        return ("yarn",) + tuple(float(value[key]) for key in wanted)
     if kind != "llama3":
         raise ValueError(f"unsupported rope scaling type: {kind!r}")
     # all four parameters are REQUIRED (as in HF's validation): assumed
@@ -477,8 +654,13 @@ def model_freqs(config: LlamaConfig, dtype=jnp.float32) -> jnp.ndarray:
     rope-scaling recipe (engine, trainer, forward, and the graft entry
     all route through here so a scaled checkpoint can never silently
     get plain frequencies)."""
+    # latent attention rotates only the part of a head set aside for it
+    rotated = (
+        config.mla.qk_rope_head_dim if config.mla is not None
+        else config.dims_per_head
+    )
     return rope_frequencies(
-        config.dims_per_head, config.max_seq_len, config.rope_theta,
+        rotated, config.max_seq_len, config.rope_theta,
         dtype=dtype, scaling=config.rope_scaling,
     )
 
@@ -513,6 +695,15 @@ def _stack_layer_params(params: Dict[str, jnp.ndarray], config=None):
     without them — None is an empty pytree, so scan passes it through
     untouched. With ``config`` given, validates the family tensors are
     actually present first (see :func:`validate_family_params`)."""
+    if config is not None and config.mla is not None:
+        # every loop over the uniform stack comes through here: the
+        # latent family's layers are not alike and none of these loops
+        # computes them
+        raise NotImplementedError(
+            "this program has no latent-attention form: the family runs "
+            "the dense layout's prefill, prefill_at_offset and decode_step "
+            "only (latent_moe.py)"
+        )
     if config is not None:
         validate_family_params(config, params)
     mlp = (params["w_gate"], params["w_up"], params["w_down"])
@@ -1089,6 +1280,12 @@ def prefill(
 ) -> Tuple[Dict[str, jnp.ndarray], jnp.ndarray]:
     """Run the prompt through the model, write the KV cache at the given
     slots, return logits of each prompt's last real token [B, V]."""
+    if config.mla is not None:
+        from langstream_tpu.providers.jax_local import latent_moe
+
+        return latent_moe.prefill(
+            config, params, cache, tokens, lengths, slot_ids, freqs
+        )
     seq = tokens.shape[1]
     quantized = "k_scale" in cache
     x, layer_kv = _prefill_scan(
@@ -1143,6 +1340,12 @@ def prefill_at_offset(
     warm check enforces it — a clamped dynamic_update_slice would
     silently overwrite live prefix rows otherwise).
     Returns (cache, logits of each row's last real suffix token [B, V])."""
+    if config.mla is not None:
+        from langstream_tpu.providers.jax_local import latent_moe
+
+        return latent_moe.prefill_at_offset(
+            config, params, cache, tokens, lengths, offsets, slot_ids, freqs
+        )
     batch, seq = tokens.shape
     hd = config.dims_per_head
     positions = offsets[:, None] + jnp.arange(seq)[None, :]  # [B, T] global
@@ -1536,6 +1739,12 @@ def decode_step(
     (:func:`_decode_attn`). The caller's jit donates the cache (the
     engine's chunk does, and carries it across its steps), so the
     returned leaves are the argument's buffers."""
+    if config.mla is not None:
+        from langstream_tpu.providers.jax_local import latent_moe
+
+        return latent_moe.decode_step(
+            config, params, cache, tokens, lengths, freqs, write_mask
+        )
     slots = tokens.shape[0]
     hd = config.dims_per_head
     positions = (lengths - 1).astype(jnp.int32)  # [S]

@@ -137,6 +137,30 @@ class CostModel:
         params = config.num_params()
         head_dim = config.dims_per_head
         tp = max(1, int(tp))
+        if getattr(config, "mla", None) is not None:
+            # latent attention: one row of latents a token a layer, and a
+            # token meets the routed experts it is sent to among the held
+            # ones (in expectation), not every expert the chip holds
+            experts = config.experts
+            expert = 3 * config.hidden_size * experts.intermediate_size
+            idle = experts.held - experts.per_token * experts.held / experts.routed
+            met = params - int(
+                (config.num_layers - experts.leading_dense) * idle * expert
+            )
+            return cls(
+                params=met,
+                num_layers=config.num_layers,
+                num_heads=config.num_heads,
+                num_kv_heads=1,
+                head_dim=config.mla.kv_lora_rank + config.mla.qk_rope_head_dim,
+                weight_bytes=params * 2,
+                kv_row_bytes=config.num_layers * 2 * (
+                    config.mla.kv_lora_rank + config.mla.qk_rope_head_dim
+                ),
+                kv_block_size=1,
+                paged_kernel=None,
+                tp_shards=1,
+            )
         if kv_quant:
             # int8 values + one f32 scale per (layer, pos, kv_head) for
             # each of k and v

@@ -352,16 +352,16 @@ class FollowerExecutor:
         with engine.mesh:
             if kind == "prefill":
                 run = engine._get_prefill(meta["bucket"])
-                engine.cache, engine._counts, _, _, _ = run(
+                engine.cache, engine._counts = run(
                     engine.params, engine.cache, *arrays[:3 + extra],
                     engine._counts, *arrays[3 + extra:],
-                )
+                )[:2]
             elif kind == "prefill_offset":
                 run = engine._get_prefill_offset(meta["bucket"])
-                engine.cache, engine._counts, _, _, _ = run(
+                engine.cache, engine._counts = run(
                     engine.params, engine.cache, *arrays[:4 + extra],
                     engine._counts, *arrays[4 + extra:],
-                )
+                )[:2]
             elif kind == "copy":
                 run = engine._get_copy_prefix(meta["bucket"])
                 (engine.cache,) = run(engine.params, engine.cache, *arrays)
@@ -420,7 +420,7 @@ class FollowerExecutor:
         paged_args = (tables,) if tables is not None else ()
         (
             engine.cache, engine._counts, _, _, _,
-            final_tokens, final_lengths,
+            final_tokens, final_lengths, _,
         ) = run(
             engine.params, engine.cache, tokens, lengths, active, active,
             *paged_args, engine._counts, *sampling,

@@ -519,3 +519,173 @@ def test_dense_decode_chunk_tp4_copies_no_cache_slab(tp_mesh, monkeypatch):
     local_slab = int(np.prod(local[1:])) * cache["k"].dtype.itemsize
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < local_slab, (temp, local_slab)
+
+
+# ---------------------------------------------------------------------- #
+# The latent-attention, routed-experts share at published widths (ISSUE 29)
+# ---------------------------------------------------------------------- #
+LATENT_SHARE = {
+    "preset": "deepseek-v2", "num-layers": 5, "experts-held-first": 0,
+    "experts-held": 40, "vocab-size": 25600, "max_seq_len": 4608,
+}
+LATENT_SLOTS = 64
+
+
+def _compile_latent(monkeypatch, one_chip, program):
+    """One chip's share of DeepSeek-V2 as ``deepseek-v2-ep4.docs`` runs it
+    (5 layers, 40 of 160 experts, a quarter of the vocabulary, 64 slots x
+    4,608): the decode chunk (a 4-step scan of decode_step + greedy pick)
+    or the cold 4,096-token prefill, the cache donated, compiled for the
+    described chip. Returns (compiled, the cache's shapes)."""
+    import langstream_tpu.ops.flash_attention as flash_attention
+    from langstream_tpu.providers.jax_local import model as model_lib
+
+    monkeypatch.setattr(flash_attention, "on_tpu", lambda: True)
+    config = model_lib.LlamaConfig.from_dict(dict(LATENT_SHARE))
+    freqs = model_lib.model_freqs(config)
+
+    def place(tree):
+        return jax.tree_util.tree_map(
+            lambda leaf: _spec(leaf.shape, leaf.dtype, one_chip), tree
+        )
+
+    params = place(jax.eval_shape(lambda: model_lib.init_params(config, 0)))
+    cache = place(jax.eval_shape(
+        lambda: model_lib.init_cache(config, LATENT_SLOTS, config.max_seq_len)
+    ))
+
+    if program == "decode_chunk":
+
+        @functools.partial(jax.jit, donate_argnums=(1,))
+        def run(params, cache, tokens, lengths, active):
+            def body(carry, _):
+                cache, tokens, lengths, moe = carry
+                cache, logits, step_moe = model_lib.decode_step(
+                    config, params, cache, tokens, lengths, freqs, active
+                )
+                picked = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                picked = jnp.where(active, picked, 0)
+                lengths = jnp.where(active, lengths + 1, lengths)
+                return (cache, picked, lengths, moe + step_moe), picked
+
+            moe = jnp.zeros((3 + config.experts.held,), jnp.int32)
+            (cache, _, _, moe), out = jax.lax.scan(
+                body, (cache, tokens, lengths, moe), None, length=4
+            )
+            return cache, out.T, moe
+
+        args = [
+            place(jax.ShapeDtypeStruct((LATENT_SLOTS,), dtype))
+            for dtype in (jnp.int32, jnp.int32, jnp.bool_)
+        ]
+    else:
+
+        @functools.partial(jax.jit, donate_argnums=(1,))
+        def run(params, cache, tokens, lengths, slot_ids):
+            return model_lib.prefill(
+                config, params, cache, tokens, lengths, slot_ids, freqs
+            )
+
+        args = [
+            place(jax.ShapeDtypeStruct(shape, jnp.int32))
+            for shape in ((1, 4096), (1,), (1,))
+        ]
+    return run.lower(params, cache, *args).compile(), cache
+
+
+@pytest.mark.parametrize("kernel", ["mla_decode", "moe_grouped_matmul"])
+def test_latent_family_kernels_carry_their_names(one_chip, monkeypatch, kernel):
+    """The two kernels the family adds reach the lowered text under the
+    names the trace reduction looks for, at the cell's shapes."""
+    import langstream_tpu.ops.flash_attention as flash_attention
+    from langstream_tpu.ops.mla_attention import mla_decode_attention
+    from langstream_tpu.ops.moe import grouped_matmul
+
+    monkeypatch.setattr(flash_attention, "on_tpu", lambda: True)
+    s = functools.partial(_spec, sharding=one_chip)
+    if kernel == "mla_decode":
+        def fn(q, stack, lengths, layer):
+            return mla_decode_attention(
+                q, stack, lengths, layer, latent=512, scale=0.1
+            )
+
+        shapes = [
+            s((LATENT_SLOTS, 128, 640), jnp.bfloat16),
+            s((5, LATENT_SLOTS, 4608, 640), jnp.bfloat16),
+            s((LATENT_SLOTS,), jnp.int32), s((), jnp.int32),
+        ]
+    else:
+        def fn(x, w, layer, tile_group, num_active, sizes):
+            return grouped_matmul(
+                x, w, layer, tile_group, num_active, sizes, tile=128
+            )
+
+        rows = 4096 * 6 + 40 * 128
+        shapes = [
+            s((rows, 5120), jnp.bfloat16),
+            s((4, 40, 5120, 1536), jnp.bfloat16), s((), jnp.int32),
+            s((rows // 128,), jnp.int32), s((), jnp.int32),
+            s((40,), jnp.int32),
+        ]
+    text = jax.jit(fn).lower(*shapes).as_text()
+    assert f'kernel_name = "{kernel}"' in text
+
+
+@pytest.mark.parametrize("program", ["decode_chunk", "prefill_4096"])
+def test_latent_share_compiles_at_published_widths(
+    one_chip, monkeypatch, program
+):
+    """``deepseek-v2-ep4.docs``' two hot programs fit the chip and move
+    what they should. Both: the program's temp stays under 2 GB beside
+    12.2 GB of weights and latents, and the latents' stack is an aliased
+    output (the cache is written where it lies). The decode chunk besides:
+    inside the step's loops nothing makes a result of the stack's or a
+    slab's shape but the row scatter that aliases the carry (no slab
+    sliced out for the kernel, no re-layout), no ``[slots, heads, keys]``
+    float32 scores exist anywhere (the kernel keeps them in VMEM), and the
+    kernels are the two this family names: ``mla_decode`` once for the
+    unrolled dense layer and once in the scan's body, the grouped matmul
+    three times (gate, up, down)."""
+    compiled, cache = _compile_latent(monkeypatch, one_chip, program)
+    text = compiled.as_text()
+    memory = compiled.memory_analysis()
+    stack = tuple(cache["latent"].shape)       # [L, S, T, row]
+    stack_bytes = int(np.prod(stack)) * 2
+    assert memory.temp_size_in_bytes < 2 * 2 ** 30, memory
+    assert memory.alias_size_in_bytes >= stack_bytes, memory
+    kernels = text.count('custom_call_target="tpu_custom_call"')
+    if program == "prefill_4096":
+        # flash_prefill for the dense layer and in the scan, gate/up/down
+        assert kernels == 5
+        return
+    assert kernels == 5
+    assert memory.temp_size_in_bytes < stack_bytes // stack[0], memory
+    guarded = {stack, stack[1:], (1,) + stack[1:]}
+    computations = _computations(text)
+    loop = _loop_instructions(computations)
+    assert any(op == "fusion" for _, _, op, _ in loop), "no loop was read"
+    offenders = []
+    for name, result_type, op, rest in loop:
+        if op in _NO_BUFFER + _CONTROL:
+            continue
+        made = [dims for dims in _dims(result_type) if dims in guarded]
+        if not made:
+            continue
+        in_place = all(dims == stack for dims in made) and (
+            op in ("scatter", "dynamic-update-slice")
+            or (op == "fusion" and '"aliasing_operands":{"lists":[{' in rest)
+        )
+        if not in_place:
+            offenders.append(f"{name} = {result_type} {op}")
+    assert not offenders, offenders
+    heads, keys = 128, stack[2]
+    scores = [
+        f"{name} = {result_type}"
+        for body in computations.values()
+        for name, result_type, _, _ in body
+        if result_type.startswith("f32") and any(
+            LATENT_SLOTS in dims and heads in dims and keys in dims
+            for dims in _dims(result_type)
+        )
+    ]
+    assert not scores, scores
