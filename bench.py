@@ -27,8 +27,8 @@ bf16 tensors are never materialized.
 
 Override via env: BENCH_MODEL=llama-3-1b BENCH_QUANT= (empty = bf16)
 BENCH_MODE=engine BENCH_CLIENTS=32 BENCH_ROUNDS=3 BENCH_KV_QUANT=int8
-BENCH_ADMISSION_CHUNK=8 BENCH_MAX_SEQ=2048 BENCH_RTT_BUDGET_MS=1500
-LS_DECODE_FLASH=0/1 LS_WEIGHTS_CACHE_DIR=<dir> (opt-in weights cache).
+BENCH_MAX_SEQ=2048 BENCH_RTT_BUDGET_MS=1500
+LS_WEIGHTS_CACHE_DIR=<dir> (opt-in weights cache).
 
 vs_baseline compares against the BASELINE.md north-star of 800 output
 tok/s/chip (defined for 8B end-to-end on v5e).
@@ -51,7 +51,6 @@ MAX_SLOTS = int(os.environ.get("BENCH_SLOTS", "32"))
 DECODE_CHUNK = int(os.environ.get("BENCH_DECODE_CHUNK", "32"))
 # TTFT/RTT A/B lever: cap the decode chunk while admissions wait
 # (0/empty = off). Costs one extra compiled decode variant.
-ADMISSION_CHUNK = int(os.environ.get("BENCH_ADMISSION_CHUNK", "0") or "0")
 PROMPT_LEN = int(os.environ.get("BENCH_PROMPT_LEN", "128"))
 NEW_TOKENS = int(os.environ.get("BENCH_NEW_TOKENS", "128"))
 REQUESTS = int(os.environ.get("BENCH_REQUESTS", "96"))
@@ -432,7 +431,7 @@ _EMITTED_SUCCESS = False
 
 def emit_failure(reason: str) -> bool:
     """Failure record with the same identifying fields as a success
-    (metric id, kv_cache, decode_kernel) so the heal script's A/B legs
+    (metric id, kv_cache) so the heal script's A/B legs
     stay distinguishable, plus the phase stamp."""
     flight = _flight()
     if flight is not None:
@@ -448,7 +447,6 @@ def emit_failure(reason: str) -> bool:
         mixed_carry=MIXED_CARRY,
         chaos=CHAOS,
         tp=TP,
-        decode_kernel=os.environ.get("LS_DECODE_FLASH", "") or "auto",
     )
 
 
@@ -474,7 +472,6 @@ def emit_provisional(metric: str, tok_s: float, **extra) -> None:
         "timings_s": timings(),
         # same identifying fields as emit_failure: a dead A/B leg whose
         # last line is a provisional must stay attributable to its leg
-        "decode_kernel": os.environ.get("LS_DECODE_FLASH", "") or "auto",
         "kv_layout": KV_LAYOUT,
         "kv_host_blocks": KV_HOST_BLOCKS,
         "paged_kernel": PAGED_KERNEL,
@@ -643,7 +640,6 @@ async def run_bench():
         max_seq_len=config.max_seq_len,
         prefill_buckets=[PROMPT_LEN],
         decode_chunk=DECODE_CHUNK,
-        admission_chunk=ADMISSION_CHUNK or None,
         quantize=QUANT,
         kv_quant=KV_QUANT,
         kv_layout=KV_LAYOUT,
@@ -698,7 +694,6 @@ async def run_bench():
             "prefill_mode": PREFILL_MODE,
             "tp": TP,
             "chaos": CHAOS,
-            "decode_kernel": os.environ.get("LS_DECODE_FLASH", "") or "auto",
             **mixed_carry_extras(stats),
             **host_tier_extras(stats),
         })
@@ -774,7 +769,6 @@ async def run_bench_e2e():
                 "max-tokens": NEW_TOKENS,
                 "quantization": QUANT or "",
                 "decode-chunk": DECODE_CHUNK,
-                "admission-chunk": ADMISSION_CHUNK or "",
                 "pipeline-decode": PIPELINE,
                 # deterministic compile coverage: admission group sizes
                 # are timing-dependent, so without this a (bucket, size)
@@ -1050,8 +1044,6 @@ async def _drive_e2e(runner, gateway, port, get_engine):
         "prefill_chunk": PREFILL_CHUNK if PREFILL_MODE == "mixed" else 0,
         "tp": TP,
         "chaos": CHAOS,
-        "admission_chunk": ADMISSION_CHUNK,
-        "decode_kernel": os.environ.get("LS_DECODE_FLASH", "") or "auto",
         "raw_engine_tok_s": round(raw_tok_s, 1),
         "p50_rtt_ms": round(p50_rtt * 1e3, 1),
         "p95_rtt_ms": round(p95_rtt * 1e3, 1),

@@ -973,12 +973,6 @@ def build_parser() -> argparse.ArgumentParser:
     serve.add_argument("--max-slots", type=int, default=8)
     serve.add_argument("--max-seq-len", type=int, default=2048)
     serve.add_argument("--decode-chunk", type=int, default=16)
-    serve.add_argument(
-        "--admission-chunk", type=int, default=0,
-        help="cap the decode chunk at this many steps while admissions "
-             "wait, so new requests join the batch sooner (TTFT lever; "
-             "0 = off)",
-    )
     serve.add_argument("--precompile", action="store_true")
     # pipelined dispatch hides the host gap between decode chunks;
     # token-identical by test

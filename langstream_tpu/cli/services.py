@@ -280,7 +280,6 @@ async def serve_main(args) -> None:
             "max-slots": args.max_slots,
             "max-seq-len": args.max_seq_len,
             "decode-chunk": args.decode_chunk,
-            "admission-chunk": getattr(args, "admission_chunk", 0) or "",
             "precompile": bool(args.precompile),
             "pipeline-decode": not getattr(args, "no_pipeline_decode", False),
             "prefix-cache": not getattr(args, "no_prefix_cache", False),

@@ -448,18 +448,9 @@ def decode_shapes_ok(max_len: int, dim: int, heads: int, kv_heads: int) -> bool:
 def use_flash_decode(max_len: int, dim: int, heads: int, kv_heads: int) -> bool:
     """The kernel pays once dead-block skipping can actually drop HBM
     traffic: a long allocated cache, MXU-aligned head_dim, a block size
-    that divides it, and a real TPU backend. ``LS_DECODE_FLASH=1/0``
-    overrides the auto policy (on-chip A/B knob) — shape requirements
-    still bind."""
-    import os
-
+    that divides it, and a real TPU backend."""
     from langstream_tpu.ops.flash_attention import on_tpu
 
     if not decode_shapes_ok(max_len, dim, heads, kv_heads):
-        return False
-    override = os.environ.get("LS_DECODE_FLASH", "")
-    if override == "1":
-        return on_tpu()
-    if override == "0":
         return False
     return on_tpu() and max_len >= 1024

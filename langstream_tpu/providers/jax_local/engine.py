@@ -654,7 +654,6 @@ class DecodeEngine:
         max_seq_len: Optional[int] = None,
         prefill_buckets: Optional[List[int]] = None,
         decode_chunk: int = 8,
-        admission_chunk: Optional[int] = None,
         seed: int = 0,
         quantize: Optional[str] = None,  # "int8" = weight-only int8
         kv_quant: Optional[str] = None,  # "int8" = int8 KV cache
@@ -711,20 +710,6 @@ class DecodeEngine:
             )
         self.max_slots = max_slots
         self.decode_chunk = max(1, decode_chunk)
-        # TTFT lever: when admissions are waiting at dispatch time, cap
-        # the chunk at this many steps so the freshly-prefilled request
-        # joins the batch sooner — a full 32-step chunk makes a new
-        # arrival wait ~chunk×ms_step before its first token. Costs one
-        # extra compiled decode variant and more host round trips while
-        # the queue is non-empty (chaining is already off then), so it
-        # is an A/B knob, default off until measured on-chip. Superseded
-        # by the loop's order: prefills are harvested before the chunk
-        # is built, so nothing prefilled waits to join, and only requests
-        # without a slot still shorten a chunk (ROADMAP D1: delete).
-        self.admission_chunk = (
-            min(int(admission_chunk), self.decode_chunk)
-            if admission_chunk and int(admission_chunk) > 0 else None
-        )
         # top-K alternative logprobs per generated token (OpenAI
         # `top_logprobs`). STATIC — it shapes the jit outputs, so 0
         # (off) keeps the serving graphs byte-identical to a build
@@ -1116,11 +1101,15 @@ class DecodeEngine:
         """The latent-attention, routed-experts family runs the dense
         layout's three programs in the weights' own precision on one
         chip; every other switch is refused by name when the engine is
-        built (ROADMAP R-M1 / R-M3 say what each needs)."""
+        built. The programs and the layer loop are every family's
+        (``model._run_layers``): what the paged layout, the mixed
+        dispatch and speculation lack is an ``attend`` over latents
+        (``latent_moe.py`` has three), the rest a cache or weight format
+        (ROADMAP R-M1 / R-M3 say what each needs)."""
         from langstream_tpu.providers.jax_local.quant import QTensor
 
         refused = {
-            "kv-layout: paged (the block pool holds GQA rows)":
+            "kv-layout: paged (no attend over a pool of latents yet)":
                 kv_layout != "dense",
             "prefill-mode: mixed (a paged dispatch)":
                 prefill_mode != "split",
@@ -1133,7 +1122,7 @@ class DecodeEngine:
                 bool(quantize) or any(
                     isinstance(v, QTensor) for v in params.values()
                 ),
-            "spec-decode (no verify step over latents)":
+            "spec-decode (no verify attend over latents yet)":
                 spec_decode != "off",
             "mesh (tp / ep / any axis > 1: the latent cache has one head "
             "and the expert stacks hold this chip's share)":
@@ -1336,7 +1325,7 @@ class DecodeEngine:
                 def run(params, cache, tokens, lengths, slot_ids, tables,
                         counts, temperature, top_k, top_p, seeds,
                         bias_ids, bias_vals):
-                    cache, logits = model_lib.paged_prefill(
+                    cache, logits, _ = model_lib.paged_prefill(
                         config, params, cache, tokens, lengths, tables,
                         freqs, mesh=mesh, kernel=paged_kernel,
                     )
@@ -1352,11 +1341,9 @@ class DecodeEngine:
                 def run(params, cache, tokens, lengths, slot_ids, counts,
                         temperature, top_k, top_p, seeds,
                         bias_ids, bias_vals):
-                    cache, logits, moe = model_lib.step_results(
-                        model_lib.prefill(
-                            config, params, cache, tokens, lengths,
-                            slot_ids, freqs, mesh=mesh,
-                        )
+                    cache, logits, moe = model_lib.prefill(
+                        config, params, cache, tokens, lengths, slot_ids,
+                        freqs, mesh=mesh,
                     )
                     counts, sampled, lp, tops = sample_first(
                         logits, slot_ids, counts, temperature, top_k,
@@ -1398,7 +1385,7 @@ class DecodeEngine:
                 def run(params, cache, tokens, lengths, offsets, slot_ids,
                         tables, counts, temperature, top_k, top_p, seeds,
                         bias_ids, bias_vals):
-                    cache, logits = model_lib.paged_prefill_at_offset(
+                    cache, logits, _ = model_lib.paged_prefill_at_offset(
                         config, params, cache, tokens, lengths, offsets,
                         tables, freqs, mesh=mesh, kernel=paged_kernel,
                     )
@@ -1414,11 +1401,9 @@ class DecodeEngine:
                 def run(params, cache, tokens, lengths, offsets, slot_ids,
                         counts, temperature, top_k, top_p, seeds,
                         bias_ids, bias_vals):
-                    cache, logits, moe = model_lib.step_results(
-                        model_lib.prefill_at_offset(
-                            config, params, cache, tokens, lengths, offsets,
-                            slot_ids, freqs,
-                        )
+                    cache, logits, moe = model_lib.prefill_at_offset(
+                        config, params, cache, tokens, lengths, offsets,
+                        slot_ids, freqs,
                     )
                     counts, sampled, lp, tops = sample_first(
                         logits, slot_ids, counts, temperature, top_k,
@@ -1460,7 +1445,7 @@ class DecodeEngine:
                 def body(carry, _):
                     cache, tokens, lengths, counts, moe = carry
                     if paged:
-                        cache, logits = model_lib.paged_decode_step(
+                        cache, logits, _ = model_lib.paged_decode_step(
                             config, params, cache, tokens, lengths,
                             tables, freqs, write_mask, mesh=mesh,
                             kernel=paged_kernel,
@@ -1469,11 +1454,9 @@ class DecodeEngine:
                         # the step's expert counters, summed over the
                         # chunk (None, an empty pytree, for a family
                         # without routed experts)
-                        cache, logits, step_moe = model_lib.step_results(
-                            model_lib.decode_step(
-                                config, params, cache, tokens, lengths,
-                                freqs, write_mask, mesh=mesh,
-                            )
+                        cache, logits, step_moe = model_lib.decode_step(
+                            config, params, cache, tokens, lengths, freqs,
+                            write_mask, mesh=mesh,
                         )
                         if step_moe is not None:
                             moe = moe + step_moe
@@ -1599,14 +1582,14 @@ class DecodeEngine:
                     )  # [S, 1+k]
                     valid_lens = jnp.where(active, 1 + num, 0)
                     if paged:
-                        cache, logits = model_lib.paged_verify_step(
+                        cache, logits, _ = model_lib.paged_verify_step(
                             config, params, cache, block, lengths,
                             valid_lens, tables, freqs,
                             write_mask=write_mask, mesh=mesh,
                             kernel=paged_kernel,
                         )
                     else:
-                        cache, logits = model_lib.verify_step(
+                        cache, logits, _ = model_lib.verify_step(
                             config, params, cache, block, lengths,
                             valid_lens, freqs, write_mask=write_mask,
                             mesh=mesh,
@@ -1733,7 +1716,7 @@ class DecodeEngine:
                 tokens = tokens.at[:, 0].set(
                     jnp.where(chain_mask, prev_sampled, tokens[:, 0])
                 )
-                cache, logits = model_lib.paged_mixed_step(
+                cache, logits, _ = model_lib.paged_mixed_step(
                     config, params, cache, tokens, offsets, num_tokens,
                     tables, freqs, write_mask=write_mask, mesh=mesh,
                     kernel=paged_kernel,
@@ -2349,8 +2332,6 @@ class DecodeEngine:
                 )))
         slots = self.max_slots
         step_variants = {self.decode_chunk, 1}
-        if self.admission_chunk:
-            step_variants.add(self.admission_chunk)
         # spec decode threads the per-slot token history (drafting
         # source) through the scan carry as one extra [S, max_seq] array
         history = (
@@ -4371,10 +4352,6 @@ class DecodeEngine:
             seeds_host = np.zeros((self.max_slots,), dtype=np.uint32)
             epochs = [0] * self.max_slots
             steps = self.decode_chunk
-            if self.admission_chunk and (self._pending or self._prefill_inflight):
-                # someone is waiting to join: run a short chunk so the
-                # next dispatch picks them up (see admission_chunk)
-                steps = self.admission_chunk
             history = (
                 np.zeros((self.max_slots, self.max_seq_len), dtype=np.int32)
                 if self.spec else None

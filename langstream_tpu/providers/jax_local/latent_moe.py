@@ -9,18 +9,16 @@ feed-forward is a group-limited router over ``routed`` experts of which
 this chip holds ``[held_first, held_first + held)`` and computes only
 where routed (``ops/moe.py::moe_mlp_held``), plus shared experts.
 
-One attention block and one expert block, called by the three
-dense-layout programs the engine compiles (``model.prefill`` /
-``prefill_at_offset`` / ``decode_step`` hand over to the functions of the
-same names here):
+This file holds the family's attention kind, its parameters and its
+cache; ``model.py`` holds the programs, the layer loop and the block body,
+and takes from here the ``attend`` of each dense-layout program:
 
-- :func:`prefill`: cold, over expanded heads (keys ``nope + rope`` wide,
-  values ``v_head_dim`` wide) through the flash prefill kernel;
-- :func:`prefill_at_offset`: a suffix over cached latents, in the
-  absorbed form (``ops/mla_attention.py::absorbed_attention``);
-- :func:`decode_step`: absorbed, the stacked cache carried through the
-  layer loop and written in place, the dense layers first and a scan
-  over the expert layers after them.
+- :func:`prefill_attend`: cold, over expanded heads (keys ``nope + rope``
+  wide, values ``v_head_dim`` wide) through the flash prefill kernel;
+- :func:`offset_attend`: a suffix over cached latents, in the absorbed
+  form (``ops/mla_attention.py::absorbed_attention``);
+- :func:`decode_attend`: absorbed, the stacked cache carried through the
+  layer loop and written in place.
 
 The layer stack is not uniform, so the parameters are two stacks:
 ``dense.*`` leaves ``[leading_dense, ...]`` and ``moe.*`` leaves
@@ -30,12 +28,6 @@ layers, ``latent: [L, S, T, row_width]`` (``kv_lora_rank +
 qk_rope_head_dim`` values, then zeros up to whole lanes): the decode
 kernel reads a row as key and value at once, so the row is written as
 the reader wants it, where it lies.
-
-Every program returns the step's expert counters as a third result beside
-its cache and logits (int32 ``[3 + held]``: assignments routed,
-assignments that met a held expert, rows the expert matmuls computed,
-tokens by held expert; summed over the expert layers); the engine's
-programs read it through ``model.step_results``.
 
 Random initialisation follows the recipe of
 ``benchmark/reference/deepseek_v2.py`` (norm scales away from 1); the
@@ -47,11 +39,13 @@ from __future__ import annotations
 
 import math
 from functools import partial
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
 
+from langstream_tpu.ops.attention import prefill_attention
+from langstream_tpu.ops.flash_attention import flash_prefill_attention, use_flash
 from langstream_tpu.ops.mla_attention import (
     absorbed_attention,
     latent_query,
@@ -59,7 +53,7 @@ from langstream_tpu.ops.mla_attention import (
     mla_decode_shapes_ok,
     use_mla_decode,
 )
-from langstream_tpu.ops.moe import moe_mlp_held
+from langstream_tpu.ops.norms import rms_norm
 from langstream_tpu.ops.rope import apply_rope, yarn_softmax_scale
 from langstream_tpu.parallel.mesh import L
 
@@ -325,33 +319,48 @@ def validate_params(config, params: Dict[str, Any]) -> None:
         )
 
 
-def _stacks(config, params):
-    """(dense layers as a list of per-layer tuples, the expert layers'
-    stacked tuple for the scan, the routed experts' stacks)."""
+def layer_stacks(config, params):
+    """The family's layers as ``model._run_layers`` takes them: (the
+    leading dense layers, one tuple each; the expert layers' stacked tuple
+    for the scan; the routed experts' stacks). A layer is ``(attn_norm,
+    attention weights, wo, None, mlp_norm, None, feed-forward weights)``:
+    the family has no post norms."""
     validate_params(config, params)
-    dense = [
-        tuple(params[f"dense.{n}"][i] for n in ATTENTION + DENSE_MLP)
+
+    def layers(group, mlp, at=None):
+        def leaf(name):
+            stack = params[f"{group}.{name}"]
+            return stack if at is None else stack[at]
+
+        return (
+            leaf("attn_norm"), tuple(leaf(n) for n in ATTENTION[1:-1]),
+            leaf("wo"), None, leaf(mlp[0]), None,
+            tuple(leaf(n) for n in mlp[1:]),
+        )
+
+    lead = [
+        layers("dense", DENSE_MLP, at=i)
         for i in range(config.experts.leading_dense)
     ]
-    moe = tuple(params[f"moe.{n}"] for n in ATTENTION + EXPERT_MLP)
     stacks = tuple(params[f"moe.{n}"] for n in EXPERT_STACKS)
-    return dense, moe, stacks
+    return lead, layers("moe", EXPERT_MLP), stacks
 
 
 # --------------------------------------------------------------------- #
-# the two blocks
+# the attention kind
 # --------------------------------------------------------------------- #
-def _project(config, x, weights, freqs, positions):
-    """The attention's input side on x [B, T, h]: (x^ normed,
-    q_nope [B, T, H, nope], q_pe [B, T, H, rope] rotated, the token's
+def _norm(config, x, w):
+    return rms_norm(x, w, config.norm_eps, plus_one=config.norm_plus_one)
+
+
+def _project(config, normed, weights, freqs, positions):
+    """The attention's input side on normed x [B, T, h]:
+    (q_nope [B, T, H, nope], q_pe [B, T, H, rope] rotated, the token's
     cache row [B, T, row_width] = RMS(c_kv) | RoPE(k_pe) | zeros)."""
-    from langstream_tpu.providers.jax_local.model import _norm
-
     mla = config.mla
-    attn_norm, wq_a, q_norm, wq_nope, wq_pe, wkv_a, kv_norm = weights[:7]
-    batch, seq = x.shape[:2]
+    wq_a, q_norm, wq_nope, wq_pe, wkv_a, kv_norm = weights[:6]
+    batch, seq = normed.shape[:2]
     heads = config.num_heads
-    normed = _norm(config, x, attn_norm)
     c_q = _norm(config, jnp.einsum("bth,hr->btr", normed, wq_a), q_norm)
     q_nope = jnp.einsum("btr,dr->btd", c_q, wq_nope).reshape(
         batch, seq, heads, mla.qk_nope_head_dim
@@ -373,24 +382,12 @@ def _project(config, x, weights, freqs, positions):
     return q_nope, q_pe, jnp.concatenate([c_kv, k_pe], axis=-1)
 
 
-def _output(x, attn, wo):
-    """attn [B, T, H, v] -> residual added."""
-    batch, seq = attn.shape[:2]
-    return x + jnp.einsum("btd,dh->bth", attn.reshape(batch, seq, -1), wo)
-
-
 @jax.named_scope("attention")
 def _expanded_attention(config, q_nope, q_pe, rows, wk_b, wv_b, mask):
     """Cold prefill's self-attention over expanded heads: keys ``nope +
     rope`` wide (the rotary part one for all heads), values ``v`` wide.
     The flash kernel on TPU for long prompts (it takes the two widths
     apart), XLA otherwise."""
-    from langstream_tpu.ops.attention import prefill_attention
-    from langstream_tpu.ops.flash_attention import (
-        flash_prefill_attention,
-        use_flash,
-    )
-
     latent = config.mla.kv_lora_rank
     c_kv, k_pe = rows[..., :latent], rows[..., latent:width(config)]
     k_nope = jnp.einsum("btc,hcd->bthd", c_kv, wk_b)
@@ -423,139 +420,46 @@ def _expand(o_lat, wv_b):
     return jnp.einsum("bthc,hcd->bthd", o_lat, wv_b)
 
 
-@jax.named_scope("mlp")
-def _expert_block(config, normed, weights, stacks, layer, valid):
-    """The routed experts held here (``stacks[..][layer]``), where
-    routed, plus the shared experts, on normed [B, T, h]; returns (delta,
-    counters)."""
-    from langstream_tpu.providers.jax_local.model import _mlp_block
-
-    experts = config.experts
-    router, s_gate, s_up, s_down = weights
-    shape = normed.shape
-    routed, counters = moe_mlp_held(
-        normed.reshape(-1, shape[-1]), router, *stacks, layer=layer,
-        held_first=experts.held_first, groups=experts.groups,
-        groups_kept=experts.groups_kept, num_selected=experts.per_token,
-        scaling_factor=experts.scaling_factor,
-        valid=None if valid is None else valid.reshape(-1),
-        interpret=config.flash_interpret,
+def _decode_kernel_ok(config, stack) -> bool:
+    """The Pallas ``mla_decode`` kernel on TPU (and under the interpret
+    test hook) where its shapes hold; the XLA absorbed form otherwise."""
+    mla = config.mla
+    shape = (stack.shape[2], mla.kv_lora_rank, stack.shape[3])
+    return config.use_flash and (
+        use_mla_decode(*shape)
+        or (config.flash_interpret and mla_decode_shapes_ok(*shape))
     )
-    shared, _ = _mlp_block(config, normed, (s_gate, s_up, s_down))
-    return routed.reshape(shape) + shared, counters
-
-
-def _feed_forward(config, x, weights, valid, stacks=None, layer=None):
-    """(x + the layer's feed-forward, its counters or None): a dense
-    SwiGLU without ``stacks``, else the expert block over expert layer
-    ``layer`` of them."""
-    from langstream_tpu.providers.jax_local.model import _mlp_block, _norm
-
-    normed = _norm(config, x, weights[0])
-    if stacks is None:
-        delta, _ = _mlp_block(config, normed, weights[1:])
-        return x + delta, None
-    delta, counters = _expert_block(
-        config, normed, weights[1:], stacks, layer, valid
-    )
-    return x + delta, counters
-
-
-def _run_layers(config, params, x, attend, state, valid):
-    """The layer loop every program shares: the leading dense layers
-    unrolled, then one scan over the expert layers. ``attend(x, weights,
-    index, state) -> (x, state, out)`` is the program's attention (it
-    owns the cache: ``state``); ``out`` is stacked over the layers.
-    Returns (x, state, outs [L, ...] or None, counters)."""
-    from langstream_tpu.providers.jax_local.model import zero_counters
-
-    dense, moe, stacks = _stacks(config, params)
-    lead = len(dense)
-    outs = []
-    for index, weights in enumerate(dense):
-        x, state, out = attend(
-            x, weights[:len(ATTENTION)], jnp.int32(index), state
-        )
-        x, _ = _feed_forward(config, x, weights[len(ATTENTION):], valid)
-        outs.append(out)
-
-    def layer(carry, inputs):
-        x, state, counters = carry
-        weights, index = inputs
-        x, state, out = attend(x, weights[:len(ATTENTION)], index, state)
-        x, step = _feed_forward(
-            config, x, weights[len(ATTENTION):], valid, stacks, index - lead
-        )
-        return (x, state, counters + step), out
-
-    (x, state, counters), scanned = jax.lax.scan(
-        layer, (x, state, zero_counters(config)),
-        (moe, jnp.arange(lead, config.num_layers)),
-    )
-    if scanned is None:
-        return x, state, None, counters
-    stacked = jnp.concatenate([jnp.stack(outs), scanned]) if outs else scanned
-    return x, state, stacked, counters
 
 
 # --------------------------------------------------------------------- #
-# the three programs
+# the three attends: ``attend(normed [B, T, h], a layer's attention
+# weights, the layer's index, None, the stacked latents or None) ->
+# (attn [B, T, H, v], the latents, the layer's rows or None)``
 # --------------------------------------------------------------------- #
-def _with_latent(cache, latent):
-    out = dict(cache)
-    out["latent"] = latent
-    return out
-
-
-def prefill(config, params, cache, tokens, lengths, slot_ids, freqs):
-    """Cold prefill: the prompt through the model over expanded heads,
-    its latents written at the given slots; logits of each prompt's last
-    real token [B, V]; the expert counters."""
-    from langstream_tpu.providers.jax_local.model import (
-        _embed,
-        _last_token_logits,
-    )
-
-    batch, seq = tokens.shape
-    positions = jnp.arange(seq)[None, :].repeat(batch, 0)
-    mask = positions < lengths[:, None]
-    x = _embed(config, params, tokens)
-
-    def attend(x, weights, index, state):
-        *_, wk_b, wv_b, wo = weights
-        q_nope, q_pe, rows = _project(config, x, weights, freqs, positions)
+def prefill_attend(config, freqs, positions, mask):
+    """Cold prefill over expanded heads; the prompt's rows come back
+    stacked over the layers (one stack a cache leaf) and the program
+    writes them at its slots."""
+    def attend(normed, weights, index, inputs, state):
+        *_, wk_b, wv_b = weights
+        q_nope, q_pe, rows = _project(config, normed, weights, freqs, positions)
         attn = _expanded_attention(
             config, q_nope, q_pe, rows, wk_b, wv_b, mask
         )
-        return _output(x, attn, wo), state, rows
+        return attn, state, (rows,)
 
-    x, _, rows, counters = _run_layers(config, params, x, attend, None, mask)
-    stack = cache["latent"]
-    pad = stack.shape[2] - seq
-    if pad > 0:
-        rows = jnp.pad(rows, ((0, 0), (0, 0), (0, pad), (0, 0)))
-    with jax.named_scope("cache_write"):
-        stack = stack.at[:, slot_ids].set(rows.astype(stack.dtype))
-    logits = _last_token_logits(config, params, x, lengths)
-    return _with_latent(cache, stack), logits, counters
+    return attend
 
 
-def prefill_at_offset(
-    config, params, cache, tokens, lengths, offsets, slot_ids, freqs
-):
+def offset_attend(config, freqs, seq, lengths, offsets, slot_ids):
     """A suffix into warm slots: its latents written at ``offset..``, its
     queries over prefix + suffix in the absorbed form (the cached latents
-    are keys and values as they lie). Caller guarantees ``offset + T <=
-    max_len``. Returns (cache, logits of each row's last real token, the
-    expert counters)."""
-    from langstream_tpu.providers.jax_local.model import _embed, _logits, _norm
-
-    batch, seq = tokens.shape
+    are keys and values as they lie). Returns (attend, the suffix's valid
+    mask [B, T])."""
     steps = jnp.arange(seq)[None, :]
     positions = offsets[:, None] + steps
     mask = steps < lengths[:, None]
     visible = positions + 1  # query i sees keys [0, offset + i]
-    x = _embed(config, params, tokens)
     scale = softmax_scale(config)
 
     @jax.named_scope("cache_write")
@@ -570,65 +474,43 @@ def prefill_at_offset(
             rows.astype(stack.dtype), mode="drop"
         )
 
-    def attend(x, weights, index, stack):
-        *_, wk_b, wv_b, wo = weights
-        q_nope, q_pe, rows = _project(config, x, weights, freqs, positions)
+    def attend(normed, weights, index, inputs, state):
+        (stack,) = state
+        *_, wk_b, wv_b = weights
+        q_nope, q_pe, rows = _project(config, normed, weights, freqs, positions)
         stack = write_rows(stack, index, rows)
         with jax.named_scope("attention"):
             attn = absorbed_attention(
                 q_nope, q_pe, stack[index, slot_ids], visible, wk_b, wv_b,
                 scale=scale,
             )
-        return _output(x, attn, wo), stack, None
+        return attn, (stack,), None
 
-    x, stack, _, counters = _run_layers(
-        config, params, x, attend, cache["latent"], mask
-    )
-    x = _norm(config, x, params["final_norm"])
-    last = x[jnp.arange(batch), (lengths - 1).astype(jnp.int32)]
-    return _with_latent(cache, stack), _logits(config, params, last), counters
+    return attend, mask
 
 
-def _decode_kernel_ok(config, stack) -> bool:
-    """The Pallas ``mla_decode`` kernel on TPU (and under the interpret
-    test hook) where its shapes hold; the XLA absorbed form otherwise."""
-    mla = config.mla
-    shape = (stack.shape[2], mla.kv_lora_rank, stack.shape[3])
-    return config.use_flash and (
-        use_mla_decode(*shape)
-        or (config.flash_interpret and mla_decode_shapes_ok(*shape))
-    )
-
-
-def decode_step(config, params, cache, tokens, lengths, freqs, write_mask=None):
-    """One decode step for every slot, absorbed: the new token's latent
-    written into the stack at ``[layer, slot, position]`` in place, the
-    layer's slab read once where it lies. Returns (cache, logits [S, V],
-    the expert counters)."""
-    from langstream_tpu.providers.jax_local.model import _embed, _logits, _norm
-
-    slots = tokens.shape[0]
-    positions = (lengths - 1).astype(jnp.int32)
-    if write_mask is None:
-        write_mask = jnp.ones((slots,), dtype=bool)
-    max_len = cache["latent"].shape[2]
-    # as model.decode_step: a masked slot and a position past the end go
-    # out of bounds, where nothing is written
+def decode_attend(config, freqs, stack, lengths, positions, write_mask):
+    """One decode step for every slot, absorbed, on normed [S, 1, h]: the
+    new token's latent written into the stack at ``[layer, slot,
+    position]`` in place, the layer's slab read once where it lies."""
+    slots, max_len = stack.shape[1:3]
+    # as the GQA step's: a masked slot and a position past the end go out
+    # of bounds, where nothing is written
     write_pos = jnp.where(
         write_mask,
         jnp.where(positions < 0, positions + max_len, positions),
         max_len,
     )
     rows_at = jnp.arange(slots)
-    kernel = _decode_kernel_ok(config, cache["latent"])
+    kernel = _decode_kernel_ok(config, stack)
     scale = softmax_scale(config)
     latent = config.mla.kv_lora_rank
-    x = _embed(config, params, tokens)[:, None]  # [S, 1, h]
 
-    def attend(x, weights, index, stack):
-        *_, wk_b, wv_b, wo = weights
+    def attend(normed, weights, index, inputs, state):
+        (stack,) = state
+        *_, wk_b, wv_b = weights
         q_nope, q_pe, rows = _project(
-            config, x, weights, freqs, positions[:, None]
+            config, normed, weights, freqs, positions[:, None]
         )
         with jax.named_scope("cache_write"):
             stack = stack.at[index, rows_at, write_pos].set(
@@ -648,11 +530,6 @@ def decode_step(config, params, cache, tokens, lengths, freqs, write_mask=None):
                     q_nope, q_pe, stack[index], lengths[:, None], wk_b,
                     wv_b, scale=scale,
                 )
-        return _output(x, attn, wo), stack, None
+        return attn, (stack,), None
 
-    # slots that ride along (inactive, lengths 0) are routed nowhere
-    x, stack, _, counters = _run_layers(
-        config, params, x, attend, cache["latent"], write_mask[:, None]
-    )
-    x = _norm(config, x[:, 0], params["final_norm"])
-    return _with_latent(cache, stack), _logits(config, params, x), counters
+    return attend

@@ -10,6 +10,16 @@ SwiGLU MLP — Llama 2/3 family (config covers TinyLlama through 70B).
 Weights import from a local HuggingFace checkpoint (torch state dict →
 stacked jax arrays), or random-init for benchmarks.
 
+Every program of every family runs the same layer loop
+(:func:`_run_layers`, the only scan over layers) around the same block
+body (:func:`_block`). What a program brings is its prelude (positions
+and masks), its head, and its ``attend``: the attention, which owns the
+cache (projects, ropes, writes, attends). An attention kind is a set of
+attends and a cache layout: GQA's are in this file, the latent kind's in
+``latent_moe.py``; :func:`_kinds` says which a config has. Every serving
+program returns ``(cache, logits, expert counters)``, the counters None
+(an empty pytree) without routed experts.
+
 Logical sharding axes per parameter feed the mesh rules in
 ``langstream_tpu.parallel.mesh`` (tp shards heads/mlp, fsdp shards embed).
 """
@@ -18,7 +28,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -38,9 +48,11 @@ from langstream_tpu.ops.attention import (
     quantize_kv,
 )
 from langstream_tpu.ops.flash_attention import flash_prefill_attention, use_flash
+from langstream_tpu.ops.moe import moe_mlp, moe_mlp_held
 from langstream_tpu.ops.norms import rms_norm
 from langstream_tpu.ops.rope import apply_rope, rope_frequencies
 from langstream_tpu.parallel.mesh import L
+from langstream_tpu.providers.jax_local import latent_moe
 from langstream_tpu.providers.jax_local.quant import qeinsum
 
 
@@ -82,15 +94,6 @@ def zero_counters(config: "LlamaConfig"):
     return jnp.zeros((3 + config.experts.held,), jnp.int32)
 
 
-def step_results(out):
-    """(cache, logits, expert counters or None) of what ``prefill``,
-    ``prefill_at_offset`` or ``decode_step`` returned: the latent/routed
-    family's steps (latent_moe.py) return their counters as a third
-    result, every other family's return two and their programs are what
-    they were (None is an empty pytree)."""
-    return out if len(out) == 3 else (*out, None)
-
-
 @dataclasses.dataclass(frozen=True)
 class LlamaConfig:
     vocab_size: int = 32000
@@ -125,8 +128,7 @@ class LlamaConfig:
     # Llama-3.1/3.2 long-context recipe (ops/rope.py). None = plain.
     rope_scaling: Optional[Tuple] = None
     # The latent-attention, routed-experts family (latent_moe.py): both
-    # set, or both None (every other preset: the program it compiles to
-    # is what it was).
+    # set, or both None.
     mla: Optional[LatentAttention] = None
     experts: Optional[RoutedExperts] = None
     dtype: Any = jnp.bfloat16
@@ -382,9 +384,7 @@ class LlamaConfig:
         return config
 
     def num_params(self) -> int:
-        if self.mla is not None:
-            from langstream_tpu.providers.jax_local import latent_moe
-
+        if _kinds(self).attention == "latent":
             return latent_moe.num_params(self)
         head_dim = self.dims_per_head
         attn = self.hidden_size * head_dim * (2 * self.num_heads + 2 * self.num_kv_heads)
@@ -398,9 +398,7 @@ class LlamaConfig:
 
 def init_params(config: LlamaConfig, seed: int = 0) -> Dict[str, jnp.ndarray]:
     """Random-init (scaled normal) parameter pytree with stacked layers."""
-    if config.mla is not None:
-        from langstream_tpu.providers.jax_local import latent_moe
-
+    if _kinds(config).attention == "latent":
         return latent_moe.init_params(config, seed)
     key = jax.random.PRNGKey(seed)
     keys = jax.random.split(key, 10)
@@ -458,9 +456,7 @@ def init_params(config: LlamaConfig, seed: int = 0) -> Dict[str, jnp.ndarray]:
 
 def logical_axes(config: LlamaConfig) -> Dict[str, Any]:
     """Logical sharding axes per parameter (fed to parallel.mesh rules)."""
-    if config.mla is not None:
-        from langstream_tpu.providers.jax_local import latent_moe
-
+    if _kinds(config).attention == "latent":
         return latent_moe.logical_axes(config)
     if config.num_experts:
         mlp_axes = {
@@ -514,9 +510,7 @@ def init_cache(
     The latent family's cache is one leaf of latents instead
     (``latent_moe.init_cache``)."""
     max_len = max_len or config.max_seq_len
-    if config.mla is not None:
-        from langstream_tpu.providers.jax_local import latent_moe
-
+    if _kinds(config).attention == "latent":
         if kv_quant:
             raise ValueError("the latent cache has no int8 form")
         return latent_moe.init_cache(config, batch, max_len)
@@ -537,9 +531,7 @@ def init_cache(
 def cache_logical_axes(
     kv_quant: bool = False, config: Optional[LlamaConfig] = None
 ) -> Dict[str, Any]:
-    if config is not None and config.mla is not None:
-        from langstream_tpu.providers.jax_local import latent_moe
-
+    if config is not None and _kinds(config).attention == "latent":
         return latent_moe.cache_logical_axes()
     axes: Dict[str, Any] = {
         "k": L("layers", "cache_batch", "cache_sequence", "kv_heads", None),
@@ -656,7 +648,7 @@ def model_freqs(config: LlamaConfig, dtype=jnp.float32) -> jnp.ndarray:
     get plain frequencies)."""
     # latent attention rotates only the part of a head set aside for it
     rotated = (
-        config.mla.qk_rope_head_dim if config.mla is not None
+        config.mla.qk_rope_head_dim if _kinds(config).attention == "latent"
         else config.dims_per_head
     )
     return rope_frequencies(
@@ -689,20 +681,42 @@ def validate_family_params(
         )
 
 
+class Kinds(NamedTuple):
+    attention: str           # "gqa" | "latent" (latent_moe.py)
+    cache: Tuple[str, ...]   # the cache's leaves, as every program carries them
+
+
+def _kinds(
+    config: LlamaConfig, cache: Optional[Dict[str, jnp.ndarray]] = None
+) -> Kinds:
+    """What a config's layers are made of, asked here and nowhere else:
+    the attention kind and, with it, the cache's leaves (an int8 GQA
+    cache is told by its ``k_scale`` leaf). The feed-forward kinds follow
+    the stacks of :func:`_layers_of`. ROADMAP D3 (per-layer block kinds
+    on the config) takes this function's place."""
+    if config.mla is not None:
+        return Kinds("latent", ("latent",))
+    if cache is not None and "k_scale" in cache:
+        return Kinds("gqa", ("k", "v", "k_scale", "v_scale"))
+    return Kinds("gqa", ("k", "v"))
+
+
 def _stack_layer_params(params: Dict[str, jnp.ndarray], config=None):
-    """Stacked per-layer tuple for the lax.scan layer loop. Post norms
+    """The uniform (GQA) stack's layers for the layer loop: ``(attn_norm,
+    (wq, wk, wv, biases), wo, post_attn_norm, mlp_norm, post_mlp_norm,
+    feed-forward weights)``, every leaf ``[layers, ...]``. Post norms
     (Gemma-2 sandwich) and qkv biases (Qwen-2) are None for families
     without them — None is an empty pytree, so scan passes it through
     untouched. With ``config`` given, validates the family tensors are
     actually present first (see :func:`validate_family_params`)."""
-    if config is not None and config.mla is not None:
-        # every loop over the uniform stack comes through here: the
-        # latent family's layers are not alike and none of these loops
-        # computes them
+    if config is not None and _kinds(config).attention == "latent":
+        # the programs that come through here have no attend for the
+        # latent family's cache yet (the dense layout's three do, through
+        # :func:`_layers_of`)
         raise NotImplementedError(
             "this program has no latent-attention form: the family runs "
             "the dense layout's prefill, prefill_at_offset and decode_step "
-            "only (latent_moe.py)"
+            "only (latent_moe.py holds their attends)"
         )
     if config is not None:
         validate_family_params(config, params)
@@ -714,10 +728,21 @@ def _stack_layer_params(params: Dict[str, jnp.ndarray], config=None):
         if "bq" in params else None
     )
     return (
-        params["attn_norm"], params["wq"], params["wk"], params["wv"],
-        biases, params["wo"], params.get("post_attn_norm"),
+        params["attn_norm"],
+        (params["wq"], params["wk"], params["wv"], biases),
+        params["wo"], params.get("post_attn_norm"),
         params["mlp_norm"], params.get("post_mlp_norm"), mlp,
     )
+
+
+def _layers_of(config: LlamaConfig, params):
+    """A config's layers as :func:`_run_layers` takes them: (leading
+    layers of another kind than the scanned run, one tuple each; the
+    stacked layers; the routed experts' stacks, or None where the
+    feed-forward is :func:`_mlp_block`'s)."""
+    if _kinds(config).attention == "latent":
+        return latent_moe.layer_stacks(config, params)
+    return (), _stack_layer_params(params, config), None
 
 
 def _project_qkv(normed, wq, wk, wv, biases):
@@ -782,8 +807,6 @@ def _mlp_block(
     serving capacity regime (no token ever dropped — required for
     checkpoints trained dropless, e.g. Mixtral)."""
     if config.num_experts:
-        from langstream_tpu.ops.moe import moe_mlp
-
         w_gate, w_up, w_down, router = mlp_weights
         return moe_mlp(
             normed, router, w_gate, w_up, w_down,
@@ -868,8 +891,8 @@ def _decode_flash_path(config, kc, mesh):
     twin of :func:`_flash_path`, same contract: returns (use the
     kernel?, tp shard_map?). ``kc`` is the stacked leaf
     ``[L, S, T, KVH, D]``. Shape requirements bind even under the
-    ``flash_interpret`` test hook; the backend/length policy (incl. the
-    ``LS_DECODE_FLASH`` A/B override) only applies outside it."""
+    ``flash_interpret`` test hook; the backend/length policy only
+    applies outside it."""
     from langstream_tpu.ops.decode_kernel import (
         decode_shapes_ok,
         use_flash_decode,
@@ -903,7 +926,7 @@ def _decode_attn(config, q, kc, vc, lengths, layer, mesh=None, window=None):
     kernel as a traced scalar, like softcap and scale — the kernel
     handles windowed layers itself; only non-shape-compatible configs
     gate off to XLA (see ``_decode_flash_path``, whose answer
-    ``decode_step``'s cache write follows too)."""
+    ``_decode_attend``'s cache write follows too)."""
     flash_ok, tp_sharded = _decode_flash_path(config, kc, mesh)
     family = dict(
         softcap=config.attn_logit_softcap, window=window,
@@ -1184,75 +1207,200 @@ def _paged_attn_quant(config, q, k_pool, k_scale, v_pool, v_scale, tables,
     )
 
 
+@jax.named_scope("mlp")
+def _expert_block(config, normed, weights, stacks, layer, valid):
+    """The feed-forward of the latent/routed family's expert layers on
+    normed [B, T, h]: the routed experts held here (``stacks[..][layer]``),
+    where routed, plus the shared experts. Returns (delta, counters)."""
+    experts = config.experts
+    router, s_gate, s_up, s_down = weights
+    shape = normed.shape
+    routed, counters = moe_mlp_held(
+        normed.reshape(-1, shape[-1]), router, *stacks, layer=layer,
+        held_first=experts.held_first, groups=experts.groups,
+        groups_kept=experts.groups_kept, num_selected=experts.per_token,
+        scaling_factor=experts.scaling_factor,
+        valid=None if valid is None else valid.reshape(-1),
+        interpret=config.flash_interpret,
+    )
+    shared, _ = _mlp_block(config, normed, (s_gate, s_up, s_down))
+    return routed.reshape(shape) + shared, counters
+
+
+def _block(config, x, layer, attend, index, inputs, state, *, valid,
+           dropless, experts=None):
+    """The decoder block, written once for every program of every family,
+    on x ``[..., H]`` (a GQA decode step's ``[S, H]``, a prefill's
+    ``[B, T, H]``): pre-norm, the program's ``attend``, ``wo``, residual,
+    pre-norm, the feed-forward by the layer's kind (the routed experts of
+    ``experts`` where given, else :func:`_mlp_block`), residual; Gemma-2's
+    post norms where the layer has them. Returns (x, state, the attend's
+    per-layer output, what the feed-forward counted: expert counters or
+    the MoE aux loss)."""
+    attn_norm, attention, wo, post_attn, mlp_norm, post_mlp, mlp = layer
+    attn, state, out = attend(
+        _norm(config, x, attn_norm), attention, index, inputs, state
+    )
+    attn = qeinsum(
+        "sd,dh->sh" if x.ndim == 2 else "btd,dh->bth",
+        attn.reshape(x.shape[:-1] + (-1,)), wo,
+    )
+    if post_attn is not None:
+        attn = _norm(config, attn, post_attn)
+    x = x + attn
+    if experts is not None:
+        # the routed experts' stacks hold the expert layers alone
+        held_layer = index - config.experts.leading_dense
+    normed = _norm(config, x, mlp_norm)
+    if experts is None:
+        delta, counted = _mlp_block(
+            config, normed, mlp, valid=valid, dropless=dropless
+        )
+    else:
+        delta, counted = _expert_block(
+            config, normed, mlp, experts, held_layer, valid
+        )
+    if post_mlp is not None:
+        delta = _norm(config, delta, post_mlp)
+    return x + delta, state, out, counted
+
+
+def _run_layers(config, layers, x, attend, *, state=None, per_layer=None,
+                valid=None, dropless=True, total=None, lead=(),
+                experts=None):
+    """The layer loop of every program: the only ``lax.scan`` over layers.
+
+    ``attend(normed, attention weights, index, inputs, state) -> (attn,
+    state, out)`` is the program's attention and owns the cache: it
+    projects, ropes, writes and attends. ``state`` rides the scan as
+    carry (a cache written in place), ``per_layer`` is scanned (each
+    layer's ``inputs``: its window, a cache slab handed over as xs), and
+    ``out`` comes back stacked over the layers (a cold prefill's rows, a
+    slab handed back as ys). ``lead`` are layers of another kind than the
+    scanned run (``_layers_of``), unrolled in front of it with
+    ``inputs=None`` and a dense feed-forward. ``valid`` and ``dropless``
+    are the feed-forward's. ``total`` starts the sum of what the scanned
+    layers' feed-forwards count: the expert counters by default (None, an
+    empty pytree, without routed experts), a scalar for the MoE aux loss.
+    Returns (x, state, outs, total)."""
+    outs = []
+    for index, layer in enumerate(lead):
+        x, state, out, _ = _block(
+            config, x, layer, attend, jnp.int32(index), None, state,
+            valid=valid, dropless=dropless,
+        )
+        outs.append(out)
+
+    def layer_fn(carry, scanned):
+        x, state, total = carry
+        layer, inputs, index = scanned
+        x, state, out, counted = _block(
+            config, x, layer, attend, index, inputs, state, valid=valid,
+            dropless=dropless, experts=experts,
+        )
+        if total is not None:
+            total = total + counted
+        return (x, state, total), out
+
+    if total is None:
+        total = zero_counters(config)
+    count = jax.tree_util.tree_leaves(layers)[0].shape[0]
+    (x, state, total), scanned = jax.lax.scan(
+        layer_fn, (x, state, total),
+        (layers, per_layer, jnp.arange(len(lead), len(lead) + count)),
+    )
+    if outs:
+        scanned = jax.tree_util.tree_map(
+            lambda *parts: jnp.concatenate(
+                [jnp.stack(parts[:-1]), parts[-1]]
+            ),
+            *outs, scanned,
+        )
+    return x, state, scanned, total
+
+
+def _rotated_heads(config, normed, weights, freqs, positions):
+    """GQA's input side on normed ``[..., H]``: q ``[..., heads, D]`` and
+    k, v ``[..., kv_heads, D]``, q and k rotated at ``positions`` (a
+    decode step's ``[S]``, one a slot; else ``[B, T]``)."""
+    hd = config.dims_per_head
+    q, k, v = _project_qkv(normed, *weights)
+    q = q.reshape(normed.shape[:-1] + (config.num_heads, hd))
+    k = k.reshape(normed.shape[:-1] + (config.num_kv_heads, hd))
+    v = v.reshape(normed.shape[:-1] + (config.num_kv_heads, hd))
+    if normed.ndim == 2:
+        q = apply_rope(q[:, None], freqs, positions[:, None])[:, 0]
+        k = apply_rope(k[:, None], freqs, positions[:, None])[:, 0]
+    else:
+        q = apply_rope(q, freqs, positions)
+        k = apply_rope(k, freqs, positions)
+    return q, k, v
+
+
+def _prefill_attend(config, freqs, positions, mask, lengths, mesh,
+                    quantized: bool):
+    """GQA's cold-prefill attend: self-attention that never reads the
+    cache; the prompt's K and V (quantized for an int8 cache) come back
+    stacked over the layers and the program writes them."""
+    def attend(normed, weights, index, win, state):
+        q, k, v = _rotated_heads(config, normed, weights, freqs, positions)
+        if not quantized:
+            attn = _prefill_attn(config, q, k, v, mask, mesh=mesh, window=win)
+            return attn, state, (k, v)
+        # quantize ONCE and run the prompt's self-attention through
+        # the SAME f32 scale-folded math the warm/decode dispatches
+        # use (the just-written rows as the "cache", starts=0):
+        # identical formulas over identical row contents keep
+        # cold/warm/prefix-copy paths token-identical. Long
+        # MXU-aligned prompts take the int8 flash kernel — identical
+        # scale-folded algebra, int8 HBM tile loads — so kv-quant
+        # keeps the flash HBM profile on cold prefill; block
+        # boundaries reassociate f32 sums exactly like the bf16
+        # flash path does.
+        k_q, k_s = quantize_kv(k)
+        v_q, v_s = quantize_kv(v)
+        attn = _prefill_attn_quant(
+            config, q, k_q, k_s, v_q, v_s, lengths, mesh=mesh, window=win,
+        )
+        # grouped (k, v, k_scale, v_scale) — the ordering every
+        # quantized program in this module uses
+        return attn, state, (k_q, v_q, k_s, v_s)
+
+    return attend
+
+
 def _prefill_scan(
     config: LlamaConfig,
     params: Dict[str, jnp.ndarray],
+    cache: Dict[str, jnp.ndarray],
     tokens: jnp.ndarray,     # [B, T] int32 (right-padded)
     lengths: jnp.ndarray,    # [B] true prompt lengths
     freqs: jnp.ndarray,
     mesh,
-    quantized: bool,
-) -> Tuple[jnp.ndarray, Tuple]:
-    """The cold-prefill layer scan, shared by the dense and paged cache
-    layouts (cold prefill's self-attention never reads the cache, so
-    only the KV WRITE differs between them). Returns (activations
-    [B, T, H] after the final layer, stacked per-layer KV outputs)."""
+):
+    """The cold prefill's pass over the layers, shared by the dense and
+    paged cache layouts (cold prefill's self-attention never reads the
+    cache, so only the WRITE differs between them). Returns (activations
+    [B, T, H] after the final layer, the rows to write by cache leaf,
+    each ``[L, B, T, ...]``, the expert counters)."""
+    kinds = _kinds(config, cache)
     batch, seq = tokens.shape
-    hd = config.dims_per_head
     positions = jnp.arange(seq)[None, :].repeat(batch, 0)
     mask = positions < lengths[:, None]
     x = _embed(config, params, tokens)  # [B, T, H]
-
-    layer_inputs = _stack_layer_params(params, config)
-    windows = layer_windows(config)
-
-    def layer_fn(x, inputs):
-        layer, win = inputs
-        (attn_norm, wq, wk, wv, biases, wo, post_attn, mlp_norm, post_mlp,
-         mlp_weights) = layer
-        normed = _norm(config, x, attn_norm)
-        q, k, v = _project_qkv(normed, wq, wk, wv, biases)
-        q = q.reshape(batch, seq, config.num_heads, hd)
-        k = k.reshape(batch, seq, config.num_kv_heads, hd)
-        v = v.reshape(batch, seq, config.num_kv_heads, hd)
-        q = apply_rope(q, freqs, positions)
-        k = apply_rope(k, freqs, positions)
-        if quantized:
-            # quantize ONCE and run the prompt's self-attention through
-            # the SAME f32 scale-folded math the warm/decode dispatches
-            # use (the just-written rows as the "cache", starts=0):
-            # identical formulas over identical row contents keep
-            # cold/warm/prefix-copy paths token-identical. Long
-            # MXU-aligned prompts take the int8 flash kernel — identical
-            # scale-folded algebra, int8 HBM tile loads — so kv-quant
-            # keeps the flash HBM profile on cold prefill; block
-            # boundaries reassociate f32 sums exactly like the bf16
-            # flash path does.
-            k_q, k_s = quantize_kv(k)
-            v_q, v_s = quantize_kv(v)
-            attn = _prefill_attn_quant(
-                config, q, k_q, k_s, v_q, v_s, lengths, mesh=mesh,
-                window=win,
-            )
-            layer_kv_out = (k_q, v_q, k_s, v_s)
-        else:
-            layer_kv_out = (k, v)
-            attn = _prefill_attn(config, q, k, v, mask, mesh=mesh,
-                                 window=win)
-        attn = qeinsum(
-            "btd,dh->bth", attn.reshape(batch, seq, config.num_heads * hd), wo
+    if kinds.attention == "latent":
+        attend = latent_moe.prefill_attend(config, freqs, positions, mask)
+    else:
+        attend = _prefill_attend(
+            config, freqs, positions, mask, lengths, mesh,
+            "k_scale" in kinds.cache,
         )
-        if post_attn is not None:
-            attn = _norm(config, attn, post_attn)
-        x = x + attn
-        normed = _norm(config, x, mlp_norm)
-        delta, _ = _mlp_block(config, normed, mlp_weights, valid=mask, dropless=True)
-        if post_mlp is not None:
-            delta = _norm(config, delta, post_mlp)
-        x = x + delta
-        return x, layer_kv_out
-
-    return jax.lax.scan(layer_fn, x, (layer_inputs, windows))
+    lead, layers, experts = _layers_of(config, params)
+    x, _, rows, counters = _run_layers(
+        config, layers, x, attend, per_layer=layer_windows(config),
+        valid=mask, lead=lead, experts=experts,
+    )
+    return x, dict(zip(kinds.cache, rows)), counters
 
 
 def _last_token_logits(
@@ -1267,6 +1415,12 @@ def _last_token_logits(
     return _logits(config, params, last)
 
 
+def _scales_first(new):
+    """The order the cold prefills write an int8 cache's leaves in."""
+    names = tuple(new)
+    return names[2:] + names[:2]
+
+
 # jit: device-context — runs inside the engine's jitted dispatches
 def prefill(
     config: LlamaConfig,
@@ -1277,85 +1431,39 @@ def prefill(
     slot_ids: jnp.ndarray,   # [B] cache slots to write
     freqs: jnp.ndarray,
     mesh=None,               # tp mesh for the sharded flash path
-) -> Tuple[Dict[str, jnp.ndarray], jnp.ndarray]:
+):
     """Run the prompt through the model, write the KV cache at the given
     slots, return logits of each prompt's last real token [B, V]."""
-    if config.mla is not None:
-        from langstream_tpu.providers.jax_local import latent_moe
-
-        return latent_moe.prefill(
-            config, params, cache, tokens, lengths, slot_ids, freqs
-        )
-    seq = tokens.shape[1]
-    quantized = "k_scale" in cache
-    x, layer_kv = _prefill_scan(
-        config, params, tokens, lengths, freqs, mesh, quantized
+    x, new, counters = _prefill_scan(
+        config, params, cache, tokens, lengths, freqs, mesh
     )
-    max_len = cache["k"].shape[2]
-    pad = max_len - seq
-
-    def pad_rows(array):
-        if pad <= 0:
-            return array
-        widths = [(0, 0), (0, 0), (0, pad)] + [(0, 0)] * (array.ndim - 3)
-        return jnp.pad(array, widths)
 
     @jax.named_scope("cache_write")
-    def write(name, new):
-        return cache[name].at[:, slot_ids].set(
-            pad_rows(new).astype(cache[name].dtype)
-        )
+    def write(name):
+        leaf, rows = cache[name], new[name]
+        pad = leaf.shape[2] - rows.shape[2]
+        if pad > 0:
+            rows = jnp.pad(
+                rows, [(0, 0), (0, 0), (0, pad)] + [(0, 0)] * (rows.ndim - 3)
+            )
+        return leaf.at[:, slot_ids].set(rows.astype(leaf.dtype))
 
     out = dict(cache)
-    if quantized:
-        # grouped (k, v, k_scale, v_scale) — the ordering every
-        # quantized scan in this module uses
-        new_k, new_v, k_scale, v_scale = layer_kv
-        out["k_scale"] = write("k_scale", k_scale)
-        out["v_scale"] = write("v_scale", v_scale)
-    else:
-        new_k, new_v = layer_kv
-    out["k"] = write("k", new_k)
-    out["v"] = write("v", new_v)
-    return out, _last_token_logits(config, params, x, lengths)
+    for name in _scales_first(new):
+        out[name] = write(name)
+    return out, _last_token_logits(config, params, x, lengths), counters
 
 
-# jit: device-context — runs inside the engine's jitted dispatches
-def prefill_at_offset(
-    config: LlamaConfig,
-    params: Dict[str, jnp.ndarray],
-    cache: Dict[str, jnp.ndarray],
-    tokens: jnp.ndarray,     # [B, T] int32 suffix tokens (right-padded)
-    lengths: jnp.ndarray,    # [B] true suffix lengths
-    offsets: jnp.ndarray,    # [B] existing valid cache length per row
-    slot_ids: jnp.ndarray,   # [B] cache slots to extend
-    freqs: jnp.ndarray,
-) -> Tuple[Dict[str, jnp.ndarray], jnp.ndarray]:
-    """Chunked prefill of a *suffix* into warm cache slots: positions are
-    offset by the already-cached prefix, new KV is written at
-    ``offset..offset+len-1``, and attention runs over prefix + suffix.
-    One dispatch replaces the old per-token teacher-forcing path for
-    warm-session follow-ups (KV session reuse, BASELINE config #5).
-    Caller must guarantee ``offset + T <= cache max_len`` (the engine's
-    warm check enforces it — a clamped dynamic_update_slice would
-    silently overwrite live prefix rows otherwise).
-    Returns (cache, logits of each row's last real suffix token [B, V])."""
-    if config.mla is not None:
-        from langstream_tpu.providers.jax_local import latent_moe
-
-        return latent_moe.prefill_at_offset(
-            config, params, cache, tokens, lengths, offsets, slot_ids, freqs
-        )
-    batch, seq = tokens.shape
-    hd = config.dims_per_head
+def _offset_attend(config, freqs, seq, lengths, offsets, slot_ids):
+    """GQA's attend for a suffix into warm dense slots: new KV written at
+    ``offset..offset+len-1``, attention over prefix + suffix. Returns
+    (attend, the suffix's valid mask [B, T])."""
     positions = offsets[:, None] + jnp.arange(seq)[None, :]  # [B, T] global
     mask = jnp.arange(seq)[None, :] < lengths[:, None]       # [B, T] valid
     totals = offsets + lengths                               # [B]
-    x = _embed(config, params, tokens)                       # [B, T, H]
-
-    layer_inputs = _stack_layer_params(params, config)
-    windows = layer_windows(config)
-    quantized = "k_scale" in cache
+    family = dict(
+        softcap=config.attn_logit_softcap, scale=_attn_scale(config)
+    )
 
     # The stacked cache rides the layer scan as CARRY, indexed by layer —
     # not as scanned xs → ys. A while-loop carry is updated in place;
@@ -1380,21 +1488,9 @@ def prefill_at_offset(
         stacked, _ = jax.lax.scan(body, stacked, (new, offsets, slot_ids))
         return stacked
 
-    def layer_fn(carry, inputs):
-        x, kv = carry
-        layer, win, index = inputs
-        (attn_norm, wq, wk, wv, biases, wo, post_attn, mlp_norm, post_mlp,
-         mlp_weights) = layer
-        normed = _norm(config, x, attn_norm)
-        q, k, v = _project_qkv(normed, wq, wk, wv, biases)
-        q = q.reshape(batch, seq, config.num_heads, hd)
-        k = k.reshape(batch, seq, config.num_kv_heads, hd)
-        v = v.reshape(batch, seq, config.num_kv_heads, hd)
-        q = apply_rope(q, freqs, positions)
-        k = apply_rope(k, freqs, positions)
-        softcap = config.attn_logit_softcap
-        scale = _attn_scale(config)
-        if quantized:
+    def attend(normed, weights, index, win, kv):
+        q, k, v = _rotated_heads(config, normed, weights, freqs, positions)
+        if len(kv) == 4:
             kc, vc, ks, vs = kv
             k_q, k_s = quantize_kv(k)
             v_q, v_s = quantize_kv(v)
@@ -1406,43 +1502,62 @@ def prefill_at_offset(
                 attn = chunk_attention_quant(
                     q, kc[index, slot_ids], ks[index, slot_ids],
                     vc[index, slot_ids], vs[index, slot_ids], offsets, totals,
-                    softcap=softcap, window=win, scale=scale,
+                    window=win, **family,
                 )
-            kv = (kc, vc, ks, vs)
-        else:
-            kc, vc = kv
-            kc = write_rows(kc, index, k)
-            vc = write_rows(vc, index, v)
-            with jax.named_scope("attention"):
-                attn = chunk_attention(
-                    q, kc[index, slot_ids], vc[index, slot_ids], offsets,
-                    totals, softcap=softcap, window=win, scale=scale,
-                )
-            kv = (kc, vc)
-        attn = qeinsum(
-            "btd,dh->bth", attn.reshape(batch, seq, config.num_heads * hd), wo
-        )
-        if post_attn is not None:
-            attn = _norm(config, attn, post_attn)
-        x = x + attn
-        normed = _norm(config, x, mlp_norm)
-        delta, _ = _mlp_block(config, normed, mlp_weights, valid=mask, dropless=True)
-        if post_mlp is not None:
-            delta = _norm(config, delta, post_mlp)
-        x = x + delta
-        return (x, kv), None
+            return attn, (kc, vc, ks, vs), None
+        kc, vc = kv
+        kc = write_rows(kc, index, k)
+        vc = write_rows(vc, index, v)
+        with jax.named_scope("attention"):
+            attn = chunk_attention(
+                q, kc[index, slot_ids], vc[index, slot_ids], offsets,
+                totals, window=win, **family,
+            )
+        return attn, (kc, vc), None
 
-    names = ("k", "v", "k_scale", "v_scale") if quantized else ("k", "v")
-    xs = (layer_inputs, windows, jnp.arange(config.num_layers))
-    (x, kv_caches), _ = jax.lax.scan(
-        layer_fn, (x, tuple(cache[name] for name in names)), xs
+    return attend, mask
+
+
+# jit: device-context — runs inside the engine's jitted dispatches
+def prefill_at_offset(
+    config: LlamaConfig,
+    params: Dict[str, jnp.ndarray],
+    cache: Dict[str, jnp.ndarray],
+    tokens: jnp.ndarray,     # [B, T] int32 suffix tokens (right-padded)
+    lengths: jnp.ndarray,    # [B] true suffix lengths
+    offsets: jnp.ndarray,    # [B] existing valid cache length per row
+    slot_ids: jnp.ndarray,   # [B] cache slots to extend
+    freqs: jnp.ndarray,
+):
+    """Chunked prefill of a *suffix* into warm cache slots: positions are
+    offset by the already-cached prefix, new KV is written at
+    ``offset..offset+len-1``, and attention runs over prefix + suffix.
+    One dispatch replaces the old per-token teacher-forcing path for
+    warm-session follow-ups (KV session reuse, BASELINE config #5).
+    Caller must guarantee ``offset + T <= cache max_len`` (the engine's
+    warm check enforces it — a clamped dynamic_update_slice would
+    silently overwrite live prefix rows otherwise).
+    Returns (cache, logits of each row's last real suffix token [B, V],
+    the expert counters)."""
+    kinds = _kinds(config, cache)
+    make = (
+        latent_moe.offset_attend if kinds.attention == "latent"
+        else _offset_attend
+    )
+    attend, mask = make(
+        config, freqs, tokens.shape[1], lengths, offsets, slot_ids
+    )
+    x = _embed(config, params, tokens)                       # [B, T, H]
+    lead, layers, experts = _layers_of(config, params)
+    x, stacked, _, counters = _run_layers(
+        config, layers, x, attend,
+        state=tuple(cache[name] for name in kinds.cache),
+        per_layer=layer_windows(config), valid=mask, lead=lead,
+        experts=experts,
     )
     out = dict(cache)
-    out.update(zip(names, kv_caches))
-    x = _norm(config, x, params["final_norm"])
-    last = x[jnp.arange(batch), (lengths - 1).astype(jnp.int32)]  # [B, H]
-    logits = _logits(config, params, last)
-    return out, logits
+    out.update(zip(kinds.cache, stacked))
+    return out, _last_token_logits(config, params, x, lengths), counters
 
 
 # jit: device-context — runs inside the engine's jitted dispatches
@@ -1456,7 +1571,7 @@ def paged_prefill(
     freqs: jnp.ndarray,
     mesh=None,                       # tp mesh for the sharded flash path
     kernel: str = "fused",           # paged attention: fused | reference
-) -> Tuple[Dict[str, jnp.ndarray], jnp.ndarray]:
+):
     """Cold prefill into the paged block pool.
 
     Fused path (``kernel="fused"`` and the gate passes): cold prefill is
@@ -1464,45 +1579,103 @@ def paged_prefill(
     the warm and decode paths use, reading the just-written blocks
     through the tables (identical formulas over identical row contents,
     the same trick the quantized cold path has always used). Reference
-    path: the dense layer scan (and flash kernel gating) of
+    path: the dense layout's cold pass (and flash kernel gating) of
     :func:`prefill` — cold self-attention never reads the cache — with
     the KV write scattered through the block tables."""
     batch, seq = tokens.shape
-    quantized = "k_scale" in cache
-    hd = config.dims_per_head
     if kernel == "fused" and _use_fused_paged(
-        config, hd, config.num_heads, config.num_kv_heads, mesh
+        config, config.dims_per_head, config.num_heads, config.num_kv_heads,
+        mesh,
     ):
         return paged_prefill_at_offset(
             config, params, cache, tokens, lengths,
             jnp.zeros_like(lengths), block_tables, freqs,
             mesh=mesh, kernel=kernel,
         )
-    x, layer_kv = _prefill_scan(
-        config, params, tokens, lengths, freqs, mesh, quantized
+    x, new, counters = _prefill_scan(
+        config, params, cache, tokens, lengths, freqs, mesh
     )
     valid = jnp.arange(seq)[None, :] < lengths[:, None]
     zeros = jnp.zeros((batch,), jnp.int32)
 
     @jax.named_scope("cache_write")
-    def write(pool, new, scale=False):
+    def write(name):
         return _constrain_kv_shard(
             jax.vmap(
                 lambda p, n: paged_write_rows(p, n, block_tables, zeros, valid)
-            )(pool, new),
-            mesh, scale=scale,
+            )(cache[name], new[name]),
+            mesh, scale=name.endswith("_scale"),
         )
 
     out = dict(cache)
-    if quantized:
-        new_k, new_v, k_scale, v_scale = layer_kv
-        out["k_scale"] = write(cache["k_scale"], k_scale, scale=True)
-        out["v_scale"] = write(cache["v_scale"], v_scale, scale=True)
-    else:
-        new_k, new_v = layer_kv
-    out["k"] = write(cache["k"], new_k)
-    out["v"] = write(cache["v"], new_v)
-    return out, _last_token_logits(config, params, x, lengths)
+    for name in _scales_first(new):
+        out[name] = write(name)
+    return out, _last_token_logits(config, params, x, lengths), counters
+
+
+def _paged_attend(config, freqs, positions, block_tables, starts, totals,
+                  write_mask, mesh, kernel, q_lens=None):
+    """GQA's attend over the paged block pool, for every paged program:
+    the layer's pool slabs arrive scanned (``inputs = (slabs, window)``:
+    (k, v) or, int8, (k, v, k_scale, v_scale)) and go back as the layer's
+    ``out``. New KV scatters through the block tables from ``starts``
+    (masked positions route to the null block), attention reads the live
+    context through the SAME tables (:func:`_paged_attn`)."""
+    decode = positions.ndim == 1
+
+    @jax.named_scope("cache_write")
+    def write(pool, new, scale=False):
+        mask = write_mask
+        if decode:  # one position a slot
+            new, mask = new[:, None], mask[:, None]
+        return _constrain_kv_shard(
+            paged_write_rows(pool, new, block_tables, starts, mask),
+            mesh, scale=scale,
+        )
+
+    def attend(normed, weights, index, inputs, state):
+        slabs, win = inputs
+        q, k, v = _rotated_heads(config, normed, weights, freqs, positions)
+        mode = dict(window=win, kernel=kernel, mesh=mesh, q_lens=q_lens)
+        if len(slabs) == 4:
+            kp, vp, ks, vs = slabs
+            k_q, k_s = quantize_kv(k)
+            v_q, v_s = quantize_kv(v)
+            kp = write(kp, k_q)
+            ks = write(ks, k_s, scale=True)
+            vp = write(vp, v_q)
+            vs = write(vs, v_s, scale=True)
+            attn = _paged_attn_quant(
+                config, q, kp, ks, vp, vs, block_tables, starts, totals,
+                **mode,
+            )
+            return attn, state, (kp, vp, ks, vs)
+        kp, vp = slabs
+        kp = write(kp, k)
+        vp = write(vp, v)
+        attn = _paged_attn(
+            config, q, kp, vp, block_tables, starts, totals, **mode
+        )
+        return attn, state, (kp, vp)
+
+    return attend
+
+
+def _run_over_slabs(config, params, cache, x, attend, valid):
+    """The layer loop for the programs that hand the cache to the scan as
+    xs and take it back as ys (the paged pool's, and the dense verify
+    step's). Returns (cache, x)."""
+    names = _kinds(config, cache).cache
+    x, _, slabs, _ = _run_layers(
+        config, _stack_layer_params(params, config), x, attend,
+        per_layer=(
+            tuple(cache[name] for name in names), layer_windows(config)
+        ),
+        valid=valid,
+    )
+    out = dict(cache)
+    out.update(zip(names, slabs))
+    return out, x
 
 
 # jit: device-context — runs inside the engine's jitted dispatches
@@ -1518,7 +1691,7 @@ def paged_prefill_at_offset(
     mesh=None,                       # tp mesh (fused kernel runs per
                                      # kv-head shard via shard_map)
     kernel: str = "fused",           # paged attention: fused | reference
-) -> Tuple[Dict[str, jnp.ndarray], jnp.ndarray]:
+):
     """Paged twin of :func:`prefill_at_offset`: suffix KV scatters into
     table-addressed blocks, attention reads prefix + suffix through
     the SAME tables — which is how a request admitted onto a cached
@@ -1529,84 +1702,17 @@ def paged_prefill_at_offset(
     dispatch). Attention dispatches through :func:`_paged_attn` — one
     fused table-addressed launch by default, gather/scatter reference
     otherwise."""
-    batch, seq = tokens.shape
-    hd = config.dims_per_head
+    seq = tokens.shape[1]
     positions = offsets[:, None] + jnp.arange(seq)[None, :]  # [B, T] global
     mask = jnp.arange(seq)[None, :] < lengths[:, None]       # [B, T] valid
     totals = offsets + lengths                               # [B]
     x = _embed(config, params, tokens)                       # [B, T, H]
-
-    layer_inputs = _stack_layer_params(params, config)
-    windows = layer_windows(config)
-    quantized = "k_scale" in cache
-
-    @jax.named_scope("cache_write")
-    def write(pool, new, scale=False):
-        return _constrain_kv_shard(
-            paged_write_rows(pool, new, block_tables, offsets, mask),
-            mesh, scale=scale,
-        )
-
-    def layer_fn(carry, inputs):
-        x = carry
-        if quantized:
-            layer, kp, vp, ks, vs, win = inputs
-        else:
-            layer, kp, vp, win = inputs
-        (attn_norm, wq, wk, wv, biases, wo, post_attn, mlp_norm, post_mlp,
-         mlp_weights) = layer
-        normed = _norm(config, x, attn_norm)
-        q, k, v = _project_qkv(normed, wq, wk, wv, biases)
-        q = q.reshape(batch, seq, config.num_heads, hd)
-        k = k.reshape(batch, seq, config.num_kv_heads, hd)
-        v = v.reshape(batch, seq, config.num_kv_heads, hd)
-        q = apply_rope(q, freqs, positions)
-        k = apply_rope(k, freqs, positions)
-        if quantized:
-            k_q, k_s = quantize_kv(k)
-            v_q, v_s = quantize_kv(v)
-            kp = write(kp, k_q)
-            ks = write(ks, k_s, scale=True)
-            vp = write(vp, v_q)
-            vs = write(vs, v_s, scale=True)
-            attn = _paged_attn_quant(
-                config, q, kp, ks, vp, vs, block_tables, offsets, totals,
-                window=win, kernel=kernel, mesh=mesh,
-            )
-            kv_out = (kp, vp, ks, vs)
-        else:
-            kp = write(kp, k)
-            vp = write(vp, v)
-            attn = _paged_attn(
-                config, q, kp, vp, block_tables, offsets, totals,
-                window=win, kernel=kernel, mesh=mesh,
-            )
-            kv_out = (kp, vp)
-        attn = qeinsum(
-            "btd,dh->bth", attn.reshape(batch, seq, config.num_heads * hd), wo
-        )
-        if post_attn is not None:
-            attn = _norm(config, attn, post_attn)
-        x = x + attn
-        normed = _norm(config, x, mlp_norm)
-        delta, _ = _mlp_block(config, normed, mlp_weights, valid=mask, dropless=True)
-        if post_mlp is not None:
-            delta = _norm(config, delta, post_mlp)
-        x = x + delta
-        return x, kv_out
-
-    if quantized:
-        xs = (layer_inputs, cache["k"], cache["v"],
-              cache["k_scale"], cache["v_scale"], windows)
-    else:
-        xs = (layer_inputs, cache["k"], cache["v"], windows)
-    x, kv_caches = jax.lax.scan(layer_fn, x, xs)
-    out = dict(cache)
-    if quantized:
-        out["k"], out["v"], out["k_scale"], out["v_scale"] = kv_caches
-    else:
-        out["k"], out["v"] = kv_caches
-    return out, _last_token_logits(config, params, x, lengths)
+    attend = _paged_attend(
+        config, freqs, positions, block_tables, offsets, totals, mask, mesh,
+        kernel,
+    )
+    out, x = _run_over_slabs(config, params, cache, x, attend, mask)
+    return out, _last_token_logits(config, params, x, lengths), None
 
 
 # jit: device-context — runs inside the engine's jitted dispatches
@@ -1622,7 +1728,7 @@ def paged_decode_step(
     mesh=None,                       # tp mesh (fused kernel runs per
                                      # kv-head shard via shard_map)
     kernel: str = "fused",           # paged attention: fused | reference
-) -> Tuple[Dict[str, jnp.ndarray], jnp.ndarray]:
+):
     """Paged twin of :func:`decode_step`: the new token's KV scatters
     into its slot's current block (masked slots route to the null
     block), attention reads the live context through the tables — the
@@ -1632,131 +1738,26 @@ def paged_decode_step(
     each request's worst case (prompt + max_new_tokens) at admission, so
     this path cannot fail on pool pressure mid-flight."""
     slots = tokens.shape[0]
-    hd = config.dims_per_head
     positions = (lengths - 1).astype(jnp.int32)  # [S]
     if write_mask is None:
         write_mask = jnp.ones((slots,), dtype=bool)
     x = _embed(config, params, tokens)  # [S, H]
-
-    layer_inputs = _stack_layer_params(params, config)
-    windows = layer_windows(config)
-    quantized = "k_scale" in cache
-
-    @jax.named_scope("cache_write")
-    def write(pool, new, scale=False):
-        return _constrain_kv_shard(
-            paged_write_rows(
-                pool, new[:, None], block_tables, positions,
-                write_mask[:, None],
-            ),
-            mesh, scale=scale,
-        )
-
-    def layer_fn(carry, inputs):
-        x = carry
-        if quantized:
-            layer, kp, vp, ks, vs, win = inputs
-        else:
-            layer, kp, vp, win = inputs
-        (attn_norm, wq, wk, wv, biases, wo, post_attn, mlp_norm, post_mlp,
-         mlp_weights) = layer
-        normed = _norm(config, x, attn_norm)
-        q, k, v = _project_qkv(normed, wq, wk, wv, biases)
-        q = q.reshape(slots, config.num_heads, hd)
-        k = k.reshape(slots, config.num_kv_heads, hd)
-        v = v.reshape(slots, config.num_kv_heads, hd)
-        q = apply_rope(q[:, None], freqs, positions[:, None])[:, 0]
-        k = apply_rope(k[:, None], freqs, positions[:, None])[:, 0]
-        if quantized:
-            k_q, k_s = quantize_kv(k)
-            v_q, v_s = quantize_kv(v)
-            kp, ks = write(kp, k_q), write(ks, k_s, scale=True)
-            vp, vs = write(vp, v_q), write(vs, v_s, scale=True)
-            attn = _paged_attn_quant(
-                config, q, kp, ks, vp, vs, block_tables, positions,
-                lengths, window=win, kernel=kernel, mesh=mesh,
-            )
-            kv_out = (kp, vp, ks, vs)
-        else:
-            kp, vp = write(kp, k), write(vp, v)
-            attn = _paged_attn(
-                config, q, kp, vp, block_tables, positions, lengths,
-                window=win, kernel=kernel, mesh=mesh,
-            )
-            kv_out = (kp, vp)
-        attn = qeinsum(
-            "sd,dh->sh", attn.reshape(slots, config.num_heads * hd), wo
-        )
-        if post_attn is not None:
-            attn = _norm(config, attn, post_attn)
-        x = x + attn
-        normed = _norm(config, x, mlp_norm)
-        delta, _ = _mlp_block(config, normed, mlp_weights, dropless=True)
-        if post_mlp is not None:
-            delta = _norm(config, delta, post_mlp)
-        x = x + delta
-        return x, kv_out
-
-    if quantized:
-        xs = (layer_inputs, cache["k"], cache["v"],
-              cache["k_scale"], cache["v_scale"], windows)
-    else:
-        xs = (layer_inputs, cache["k"], cache["v"], windows)
-    x, kv_caches = jax.lax.scan(layer_fn, x, xs, unroll=_decode_unroll())
-    out = dict(cache)
-    if quantized:
-        out["k"], out["v"], out["k_scale"], out["v_scale"] = kv_caches
-    else:
-        out["k"], out["v"] = kv_caches
+    attend = _paged_attend(
+        config, freqs, positions, block_tables, positions, lengths,
+        write_mask, mesh, kernel,
+    )
+    out, x = _run_over_slabs(config, params, cache, x, attend, None)
     x = _norm(config, x, params["final_norm"])
-    logits = _logits(config, params, x)
-    return out, logits
+    return out, _logits(config, params, x), None
 
 
-# jit: device-context — runs inside the engine's jitted dispatches
-def decode_step(
-    config: LlamaConfig,
-    params: Dict[str, jnp.ndarray],
-    cache: Dict[str, jnp.ndarray],
-    tokens: jnp.ndarray,     # [S] int32 — one new token per slot
-    lengths: jnp.ndarray,    # [S] current length INCLUDING the new token
-    freqs: jnp.ndarray,
-    write_mask: Optional[jnp.ndarray] = None,  # [S] bool; False = don't
-                                               # touch this slot's cache
-    mesh=None,                                 # tp mesh for the sharded
-                                               # flash-decode kernel
-) -> Tuple[Dict[str, jnp.ndarray], jnp.ndarray]:
-    """One decode step for every slot: write the new token's KV, attend
-    over the cache, return next-token logits [S, V]. ``write_mask``
-    protects slots that are merely riding along (inactive, or
-    logits-only reruns) from having their cache row clobbered.
-
-    No copy of a cache slab is ever materialised: the stacked leaves
-    ride the layer scan as CARRY (a while-loop carry is updated in
-    place, where a scanned output is a fresh stacked buffer), the new
-    row is written into the stack at ``[layer, slot, position]``, and
-    the attention reads its layer's slab where it lies
-    (:func:`_decode_attn`). The caller's jit donates the cache (the
-    engine's chunk does, and carries it across its steps), so the
-    returned leaves are the argument's buffers."""
-    if config.mla is not None:
-        from langstream_tpu.providers.jax_local import latent_moe
-
-        return latent_moe.decode_step(
-            config, params, cache, tokens, lengths, freqs, write_mask
-        )
-    slots = tokens.shape[0]
-    hd = config.dims_per_head
-    positions = (lengths - 1).astype(jnp.int32)  # [S]
-    if write_mask is None:
-        write_mask = jnp.ones((slots,), dtype=bool)
-    x = _embed(config, params, tokens)  # [S, H]
-
-    layer_inputs = _stack_layer_params(params, config)
-    windows = layer_windows(config)
-    quantized = "k_scale" in cache
-    names = ("k", "v", "k_scale", "v_scale") if quantized else ("k", "v")
-    max_len = cache["k"].shape[2]
+def _decode_attend(config, freqs, stacked, lengths, positions, write_mask,
+                   mesh):
+    """GQA's attend for one decode step over the dense layout, on normed
+    [S, H]: the new row written into the stacked leaves at ``[layer, slot,
+    position]`` in place, the layer's slab read where it lies
+    (:func:`_decode_attn`)."""
+    slots, max_len = stacked[0].shape[1:3]
     rows = jnp.arange(slots)
     # where the row goes: a negative position wraps as an index would, a
     # masked slot (riding along with lengths 0, or a logits-only rerun)
@@ -1767,7 +1768,7 @@ def decode_step(
         jnp.where(positions < 0, positions + max_len, positions),
         max_len,
     )
-    flash = _decode_flash_path(config, cache["k"], mesh)
+    flash = _decode_flash_path(config, stacked[0], mesh)
     hit = jnp.arange(max_len)[None, :] == write_pos[:, None]  # [S, T]
 
     @jax.named_scope("cache_write")
@@ -1793,19 +1794,9 @@ def decode_step(
         slab = jnp.where(mask, new[:, None], slab)
         return jax.lax.dynamic_update_index_in_dim(stacked, slab, layer, 0)
 
-    def layer_fn(carry, inputs):
-        x, kv = carry
-        layer, win, index = inputs
-        (attn_norm, wq, wk, wv, biases, wo, post_attn, mlp_norm, post_mlp,
-         mlp_weights) = layer
-        normed = _norm(config, x, attn_norm)
-        q, k, v = _project_qkv(normed, wq, wk, wv, biases)
-        q = q.reshape(slots, config.num_heads, hd)
-        k = k.reshape(slots, config.num_kv_heads, hd)
-        v = v.reshape(slots, config.num_kv_heads, hd)
-        q = apply_rope(q[:, None], freqs, positions[:, None])[:, 0]
-        k = apply_rope(k[:, None], freqs, positions[:, None])[:, 0]
-        if quantized:
+    def attend(normed, weights, index, win, kv):
+        q, k, v = _rotated_heads(config, normed, weights, freqs, positions)
+        if len(kv) == 4:
             kc, vc, ks, vs = kv
             k_q, k_s = quantize_kv(k)
             v_q, v_s = quantize_kv(v)
@@ -1815,48 +1806,73 @@ def decode_step(
                 config, q, kc, ks, vc, vs, lengths, index, mesh=mesh,
                 window=win,
             )
-            kv = (kc, vc, ks, vs)
-        else:
-            kc, vc = kv
-            kc, vc = write(kc, index, k), write(vc, index, v)
-            attn = _decode_attn(
-                config, q, kc, vc, lengths, index, mesh=mesh, window=win
-            )
-            kv = (kc, vc)
-        attn = qeinsum(
-            "sd,dh->sh", attn.reshape(slots, config.num_heads * hd), wo
+            return attn, (kc, vc, ks, vs), None
+        kc, vc = kv
+        kc, vc = write(kc, index, k), write(vc, index, v)
+        attn = _decode_attn(
+            config, q, kc, vc, lengths, index, mesh=mesh, window=win
         )
-        if post_attn is not None:
-            attn = _norm(config, attn, post_attn)
-        x = x + attn
-        normed = _norm(config, x, mlp_norm)
+        return attn, (kc, vc), None
+
+    return attend
+
+
+# jit: device-context — runs inside the engine's jitted dispatches
+def decode_step(
+    config: LlamaConfig,
+    params: Dict[str, jnp.ndarray],
+    cache: Dict[str, jnp.ndarray],
+    tokens: jnp.ndarray,     # [S] int32 — one new token per slot
+    lengths: jnp.ndarray,    # [S] current length INCLUDING the new token
+    freqs: jnp.ndarray,
+    write_mask: Optional[jnp.ndarray] = None,  # [S] bool; False = don't
+                                               # touch this slot's cache
+    mesh=None,                                 # tp mesh for the sharded
+                                               # flash-decode kernel
+):
+    """One decode step for every slot: write the new token's KV, attend
+    over the cache, return next-token logits [S, V]. ``write_mask``
+    protects slots that are merely riding along (inactive, or
+    logits-only reruns) from having their cache row clobbered.
+
+    No copy of a cache slab is ever materialised: the stacked leaves
+    ride the layer scan as CARRY (a while-loop carry is updated in
+    place, where a scanned output is a fresh stacked buffer), the new
+    row is written into the stack at ``[layer, slot, position]``, and
+    the attention reads its layer's slab where it lies. The caller's jit
+    donates the cache (the engine's chunk does, and carries it across its
+    steps), so the returned leaves are the argument's buffers."""
+    slots = tokens.shape[0]
+    positions = (lengths - 1).astype(jnp.int32)  # [S]
+    if write_mask is None:
+        write_mask = jnp.ones((slots,), dtype=bool)
+    kinds = _kinds(config, cache)
+    stacked = tuple(cache[name] for name in kinds.cache)
+    x = _embed(config, params, tokens)  # [S, H]
+    windows = layer_windows(config)
+    if kinds.attention == "latent":
+        attend = latent_moe.decode_attend(
+            config, freqs, stacked[0], lengths, positions, write_mask
+        )
+        # the family's attends are written over [B, T, h]: a step is T = 1;
+        # slots that ride along (inactive, lengths 0) are routed nowhere
+        x, valid = x[:, None], write_mask[:, None]
+    else:
+        attend = _decode_attend(
+            config, freqs, stacked, lengths, positions, write_mask, mesh
+        )
         # decode groups are tiny (S = slots) so dropless capacity is cheap;
         # inactive slots can't evict anyone, so no valid mask is needed
-        delta, _ = _mlp_block(config, normed, mlp_weights, dropless=True)
-        if post_mlp is not None:
-            delta = _norm(config, delta, post_mlp)
-        x = x + delta
-        return (x, kv), None
-
-    xs = (layer_inputs, windows, jnp.arange(config.num_layers))
-    # unroll lets XLA software-pipeline the next layer's weight loads
-    # against the current layer's compute on the weights-bound decode
-    # path (measured via LS_DECODE_UNROLL; 1 = plain scan)
-    (x, kv_caches), _ = jax.lax.scan(
-        layer_fn, (x, tuple(cache[name] for name in names)), xs,
-        unroll=_decode_unroll(),
+        valid = None
+    lead, layers, experts = _layers_of(config, params)
+    x, stacked, _, counters = _run_layers(
+        config, layers, x, attend, state=stacked, per_layer=windows,
+        valid=valid, lead=lead, experts=experts,
     )
     out = dict(cache)
-    out.update(zip(names, kv_caches))
-    x = _norm(config, x, params["final_norm"])
-    logits = _logits(config, params, x)
-    return out, logits
-
-
-def _decode_unroll() -> int:
-    import os
-
-    return max(1, int(os.environ.get("LS_DECODE_UNROLL", "1")))
+    out.update(zip(kinds.cache, stacked))
+    x = _norm(config, x.reshape(slots, -1), params["final_norm"])
+    return out, _logits(config, params, x), counters
 
 
 # jit: device-context — runs inside the engine's jitted dispatches
@@ -1871,7 +1887,7 @@ def verify_step(
     freqs: jnp.ndarray,
     write_mask: Optional[jnp.ndarray] = None,  # [S] bool
     mesh=None,
-) -> Tuple[Dict[str, jnp.ndarray], jnp.ndarray]:
+):
     """Speculative verify: :func:`decode_step` generalized to a [S, B]
     token block per slot. Teacher-forces the block at each slot's
     current position (tokens[:, 0] is the pending token whose KV row a
@@ -1885,7 +1901,6 @@ def verify_step(
     positions past the accepted length hold garbage that is causally
     invisible until a later step overwrites them in order."""
     slots, seq = tokens.shape
-    hd = config.dims_per_head
     offsets = (lengths - 1).astype(jnp.int32)                # [S]
     positions = offsets[:, None] + jnp.arange(seq)[None, :]  # [S, B] global
     mask = jnp.arange(seq)[None, :] < valid_lens[:, None]    # [S, B] valid
@@ -1894,14 +1909,11 @@ def verify_step(
         write_mask = jnp.ones((slots,), dtype=bool)
     wmask = mask & write_mask[:, None]
     x = _embed(config, params, tokens)                       # [S, B, H]
-
-    layer_inputs = _stack_layer_params(params, config)
-    windows = layer_windows(config)
-    quantized = "k_scale" in cache
     max_len = cache["k"].shape[2]
     rows = jnp.arange(slots)[:, None]
-    softcap = config.attn_logit_softcap
-    scale = _attn_scale(config)
+    family = dict(
+        softcap=config.attn_logit_softcap, scale=_attn_scale(config)
+    )
     # masked rows (inactive slot, padding beyond the drafted count, or a
     # carry that ran past max_seq_len) route out of bounds and drop —
     # a clamped dynamic_update_slice would silently overwrite live rows
@@ -1913,22 +1925,11 @@ def verify_step(
             new.astype(kc.dtype), mode="drop"
         )
 
-    def layer_fn(carry, inputs):
-        x = carry
-        if quantized:
-            layer, kc, vc, ks, vs, win = inputs
-        else:
-            layer, kc, vc, win = inputs
-        (attn_norm, wq, wk, wv, biases, wo, post_attn, mlp_norm, post_mlp,
-         mlp_weights) = layer
-        normed = _norm(config, x, attn_norm)
-        q, k, v = _project_qkv(normed, wq, wk, wv, biases)
-        q = q.reshape(slots, seq, config.num_heads, hd)
-        k = k.reshape(slots, seq, config.num_kv_heads, hd)
-        v = v.reshape(slots, seq, config.num_kv_heads, hd)
-        q = apply_rope(q, freqs, positions)
-        k = apply_rope(k, freqs, positions)
-        if quantized:
+    def attend(normed, weights, index, inputs, state):
+        slabs, win = inputs
+        q, k, v = _rotated_heads(config, normed, weights, freqs, positions)
+        if len(slabs) == 4:
+            kc, vc, ks, vs = slabs
             k_q, k_s = quantize_kv(k)
             v_q, v_s = quantize_kv(v)
             kc = write_rows(kc, k_q)
@@ -1937,46 +1938,21 @@ def verify_step(
             vs = write_rows(vs, v_s)
             with jax.named_scope("attention"):
                 attn = chunk_attention_quant(
-                    q, kc, ks, vc, vs, offsets, totals,
-                    softcap=softcap, window=win, scale=scale,
+                    q, kc, ks, vc, vs, offsets, totals, window=win, **family
                 )
-            kv_out = (kc, vc, ks, vs)
-        else:
-            kc = write_rows(kc, k)
-            vc = write_rows(vc, v)
-            with jax.named_scope("attention"):
-                attn = chunk_attention(
-                    q, kc, vc, offsets, totals,
-                    softcap=softcap, window=win, scale=scale,
-                )
-            kv_out = (kc, vc)
-        attn = qeinsum(
-            "sbd,dh->sbh", attn.reshape(slots, seq, config.num_heads * hd), wo
-        )
-        if post_attn is not None:
-            attn = _norm(config, attn, post_attn)
-        x = x + attn
-        normed = _norm(config, x, mlp_norm)
-        delta, _ = _mlp_block(config, normed, mlp_weights, valid=mask,
-                              dropless=True)
-        if post_mlp is not None:
-            delta = _norm(config, delta, post_mlp)
-        x = x + delta
-        return x, kv_out
+            return attn, state, (kc, vc, ks, vs)
+        kc, vc = slabs
+        kc = write_rows(kc, k)
+        vc = write_rows(vc, v)
+        with jax.named_scope("attention"):
+            attn = chunk_attention(
+                q, kc, vc, offsets, totals, window=win, **family
+            )
+        return attn, state, (kc, vc)
 
-    if quantized:
-        xs = (layer_inputs, cache["k"], cache["v"],
-              cache["k_scale"], cache["v_scale"], windows)
-    else:
-        xs = (layer_inputs, cache["k"], cache["v"], windows)
-    x, kv_caches = jax.lax.scan(layer_fn, x, xs, unroll=_decode_unroll())
-    out = dict(cache)
-    if quantized:
-        out["k"], out["v"], out["k_scale"], out["v_scale"] = kv_caches
-    else:
-        out["k"], out["v"] = kv_caches
+    out, x = _run_over_slabs(config, params, cache, x, attend, mask)
     x = _norm(config, x, params["final_norm"])
-    return out, _logits(config, params, x)  # [S, B, V]
+    return out, _logits(config, params, x), None  # [S, B, V]
 
 
 # jit: device-context — runs inside the engine's jitted dispatches
@@ -1992,7 +1968,7 @@ def paged_verify_step(
     write_mask: Optional[jnp.ndarray] = None,  # [S] bool
     mesh=None,
     kernel: str = "fused",
-) -> Tuple[Dict[str, jnp.ndarray], jnp.ndarray]:
+):
     """Paged twin of :func:`verify_step`: the candidate block's KV
     scatters into table-addressed blocks (masked/overflow rows route to
     the null block) and attention is the fused kernel's existing Tq>1
@@ -2000,7 +1976,6 @@ def paged_verify_step(
     worst-case at admission, so verify never allocates and rollback is
     a length-pointer rewind only."""
     slots, seq = tokens.shape
-    hd = config.dims_per_head
     offsets = (lengths - 1).astype(jnp.int32)
     positions = offsets[:, None] + jnp.arange(seq)[None, :]  # [S, B] global
     mask = jnp.arange(seq)[None, :] < valid_lens[:, None]
@@ -2009,80 +1984,13 @@ def paged_verify_step(
         write_mask = jnp.ones((slots,), dtype=bool)
     wmask = mask & write_mask[:, None]
     x = _embed(config, params, tokens)
-
-    layer_inputs = _stack_layer_params(params, config)
-    windows = layer_windows(config)
-    quantized = "k_scale" in cache
-
-    @jax.named_scope("cache_write")
-    def write(pool, new, scale=False):
-        return _constrain_kv_shard(
-            paged_write_rows(pool, new, block_tables, offsets, wmask),
-            mesh, scale=scale,
-        )
-
-    def layer_fn(carry, inputs):
-        x = carry
-        if quantized:
-            layer, kp, vp, ks, vs, win = inputs
-        else:
-            layer, kp, vp, win = inputs
-        (attn_norm, wq, wk, wv, biases, wo, post_attn, mlp_norm, post_mlp,
-         mlp_weights) = layer
-        normed = _norm(config, x, attn_norm)
-        q, k, v = _project_qkv(normed, wq, wk, wv, biases)
-        q = q.reshape(slots, seq, config.num_heads, hd)
-        k = k.reshape(slots, seq, config.num_kv_heads, hd)
-        v = v.reshape(slots, seq, config.num_kv_heads, hd)
-        q = apply_rope(q, freqs, positions)
-        k = apply_rope(k, freqs, positions)
-        if quantized:
-            k_q, k_s = quantize_kv(k)
-            v_q, v_s = quantize_kv(v)
-            kp = write(kp, k_q)
-            ks = write(ks, k_s, scale=True)
-            vp = write(vp, v_q)
-            vs = write(vs, v_s, scale=True)
-            attn = _paged_attn_quant(
-                config, q, kp, ks, vp, vs, block_tables, offsets, totals,
-                window=win, kernel=kernel, mesh=mesh,
-            )
-            kv_out = (kp, vp, ks, vs)
-        else:
-            kp = write(kp, k)
-            vp = write(vp, v)
-            attn = _paged_attn(
-                config, q, kp, vp, block_tables, offsets, totals,
-                window=win, kernel=kernel, mesh=mesh,
-            )
-            kv_out = (kp, vp)
-        attn = qeinsum(
-            "sbd,dh->sbh", attn.reshape(slots, seq, config.num_heads * hd), wo
-        )
-        if post_attn is not None:
-            attn = _norm(config, attn, post_attn)
-        x = x + attn
-        normed = _norm(config, x, mlp_norm)
-        delta, _ = _mlp_block(config, normed, mlp_weights, valid=mask,
-                              dropless=True)
-        if post_mlp is not None:
-            delta = _norm(config, delta, post_mlp)
-        x = x + delta
-        return x, kv_out
-
-    if quantized:
-        xs = (layer_inputs, cache["k"], cache["v"],
-              cache["k_scale"], cache["v_scale"], windows)
-    else:
-        xs = (layer_inputs, cache["k"], cache["v"], windows)
-    x, kv_caches = jax.lax.scan(layer_fn, x, xs, unroll=_decode_unroll())
-    out = dict(cache)
-    if quantized:
-        out["k"], out["v"], out["k_scale"], out["v_scale"] = kv_caches
-    else:
-        out["k"], out["v"] = kv_caches
+    attend = _paged_attend(
+        config, freqs, positions, block_tables, offsets, totals, wmask, mesh,
+        kernel,
+    )
+    out, x = _run_over_slabs(config, params, cache, x, attend, mask)
     x = _norm(config, x, params["final_norm"])
-    return out, _logits(config, params, x)  # [S, B, V]
+    return out, _logits(config, params, x), None  # [S, B, V]
 
 
 # jit: device-context — runs inside the engine's jitted dispatches
@@ -2098,7 +2006,7 @@ def paged_mixed_step(
     write_mask: Optional[jnp.ndarray] = None,  # [S] bool
     mesh=None,
     kernel: str = "fused",
-) -> Tuple[Dict[str, jnp.ndarray], jnp.ndarray]:
+):
     """Unified mixed prefill+decode dispatch — ``decode_step`` and
     ``prefill_at_offset`` as ONE seam over per-row token counts
     (Sarathi-style chunked-prefill batching): a decode row carries its
@@ -2114,12 +2022,11 @@ def paged_mixed_step(
     whole point: admitting a prompt costs decode riders a bounded
     mixed step, never a monolithic bucket-sized prefill dispatch.
 
-    Returns (cache, logits [S, V]) of each row's LAST live token — the
-    only position the engine samples (decode rows sample their next
+    Returns (cache, logits [S, V], None) of each row's LAST live token —
+    the only position the engine samples (decode rows sample their next
     token; an admitting row's sample is meaningful only on the window
     that completes its prompt; idle/mid-prefill rows are discarded)."""
     slots, width = tokens.shape
-    hd = config.dims_per_head
     positions = offsets[:, None] + jnp.arange(width)[None, :]  # [S, W]
     mask = jnp.arange(width)[None, :] < num_tokens[:, None]    # [S, W]
     totals = offsets + num_tokens                              # [S]
@@ -2127,85 +2034,17 @@ def paged_mixed_step(
         write_mask = jnp.ones((slots,), dtype=bool)
     wmask = mask & write_mask[:, None]
     x = _embed(config, params, tokens)                         # [S, W, H]
-
-    layer_inputs = _stack_layer_params(params, config)
-    windows = layer_windows(config)
-    quantized = "k_scale" in cache
-
-    @jax.named_scope("cache_write")
-    def write(pool, new, scale=False):
-        return _constrain_kv_shard(
-            paged_write_rows(pool, new, block_tables, offsets, wmask),
-            mesh, scale=scale,
-        )
-
-    def layer_fn(carry, inputs):
-        x = carry
-        if quantized:
-            layer, kp, vp, ks, vs, win = inputs
-        else:
-            layer, kp, vp, win = inputs
-        (attn_norm, wq, wk, wv, biases, wo, post_attn, mlp_norm, post_mlp,
-         mlp_weights) = layer
-        normed = _norm(config, x, attn_norm)
-        q, k, v = _project_qkv(normed, wq, wk, wv, biases)
-        q = q.reshape(slots, width, config.num_heads, hd)
-        k = k.reshape(slots, width, config.num_kv_heads, hd)
-        v = v.reshape(slots, width, config.num_kv_heads, hd)
-        q = apply_rope(q, freqs, positions)
-        k = apply_rope(k, freqs, positions)
-        if quantized:
-            k_q, k_s = quantize_kv(k)
-            v_q, v_s = quantize_kv(v)
-            kp = write(kp, k_q)
-            ks = write(ks, k_s, scale=True)
-            vp = write(vp, v_q)
-            vs = write(vs, v_s, scale=True)
-            attn = _paged_attn_quant(
-                config, q, kp, ks, vp, vs, block_tables, offsets, totals,
-                window=win, kernel=kernel, mesh=mesh, q_lens=num_tokens,
-            )
-            kv_out = (kp, vp, ks, vs)
-        else:
-            kp = write(kp, k)
-            vp = write(vp, v)
-            attn = _paged_attn(
-                config, q, kp, vp, block_tables, offsets, totals,
-                window=win, kernel=kernel, mesh=mesh, q_lens=num_tokens,
-            )
-            kv_out = (kp, vp)
-        attn = qeinsum(
-            "sbd,dh->sbh",
-            attn.reshape(slots, width, config.num_heads * hd), wo,
-        )
-        if post_attn is not None:
-            attn = _norm(config, attn, post_attn)
-        x = x + attn
-        normed = _norm(config, x, mlp_norm)
-        delta, _ = _mlp_block(config, normed, mlp_weights, valid=mask,
-                              dropless=True)
-        if post_mlp is not None:
-            delta = _norm(config, delta, post_mlp)
-        x = x + delta
-        return x, kv_out
-
-    if quantized:
-        xs = (layer_inputs, cache["k"], cache["v"],
-              cache["k_scale"], cache["v_scale"], windows)
-    else:
-        xs = (layer_inputs, cache["k"], cache["v"], windows)
-    x, kv_caches = jax.lax.scan(layer_fn, x, xs, unroll=_decode_unroll())
-    out = dict(cache)
-    if quantized:
-        out["k"], out["v"], out["k_scale"], out["v_scale"] = kv_caches
-    else:
-        out["k"], out["v"] = kv_caches
+    attend = _paged_attend(
+        config, freqs, positions, block_tables, offsets, totals, wmask, mesh,
+        kernel, q_lens=num_tokens,
+    )
+    out, x = _run_over_slabs(config, params, cache, x, attend, mask)
     x = _norm(config, x, params["final_norm"])
     last = x[
         jnp.arange(slots),
         jnp.clip(num_tokens - 1, 0, width - 1).astype(jnp.int32),
     ]  # [S, H] — each row's last live token
-    return out, _logits(config, params, last)  # [S, V]
+    return out, _logits(config, params, last), None  # [S, V]
 
 
 # jit: device-context — runs inside the engine's jitted dispatches
@@ -2226,13 +2065,13 @@ def apply_layers(
                             # of layer_windows(), since a static offset
                             # cannot vary across SPMD stages)
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Scan the transformer layers over activations → (x, moe aux sum).
+    """The layer loop with the cache-free attend, over activations →
+    (x, moe aux sum).
 
     Factored out of :func:`forward` so pipeline parallelism
     (``parallel.pipeline``) can run a *slice* of the layer stack as one
     pipeline stage."""
     batch, seq = x.shape[:2]
-    hd = config.dims_per_head
     positions = jnp.arange(seq)[None, :].repeat(batch, 0)
     if windows is None:
         windows = layer_windows(config)
@@ -2240,41 +2079,18 @@ def apply_layers(
             n = jax.tree_util.tree_leaves(layer_inputs)[0].shape[0]
             windows = windows[layer_offset:layer_offset + n]
 
-    def layer_fn(carry, inputs):
-        (x, aux) = carry
-        layer, win = inputs
-        (attn_norm, wq, wk, wv, biases, wo, post_attn, mlp_norm, post_mlp,
-         mlp_weights) = layer
-        normed = _norm(config, x, attn_norm)
-        q, k, v = _project_qkv(normed, wq, wk, wv, biases)
-        q = q.reshape(batch, seq, config.num_heads, hd)
-        k = k.reshape(batch, seq, config.num_kv_heads, hd)
-        v = v.reshape(batch, seq, config.num_kv_heads, hd)
-        q = apply_rope(q, freqs, positions)
-        k = apply_rope(k, freqs, positions)
+    def attend(normed, weights, index, win, state):
+        q, k, v = _rotated_heads(config, normed, weights, freqs, positions)
         attn = prefill_attention(
             q, k, v, mask=mask,
             softcap=config.attn_logit_softcap, window=win,
             scale=_attn_scale(config),
         )
-        attn = qeinsum(
-            "btd,dh->bth", attn.reshape(batch, seq, config.num_heads * hd), wo
-        )
-        if post_attn is not None:
-            attn = _norm(config, attn, post_attn)
-        x = x + attn
-        normed = _norm(config, x, mlp_norm)
-        delta, layer_aux = _mlp_block(
-            config, normed, mlp_weights, valid=mask, dropless=dropless
-        )
-        if post_mlp is not None:
-            delta = _norm(config, delta, post_mlp)
-        x = x + delta
-        return (x, aux + layer_aux), None
+        return attn, state, None
 
-    (x, aux), _ = jax.lax.scan(
-        layer_fn, (x, jnp.zeros((), dtype=jnp.float32)),
-        (layer_inputs, windows),
+    x, _, _, aux = _run_layers(
+        config, layer_inputs, x, attend, per_layer=windows, valid=mask,
+        dropless=dropless, total=jnp.zeros((), dtype=jnp.float32),
     )
     return x, aux
 

@@ -150,11 +150,6 @@ class JaxCompletionsService(CompletionsService):
             ),
             prefill_buckets=buckets,
             decode_chunk=int(engine_config.get("decode-chunk", 8)),
-            admission_chunk=(
-                int(engine_config["admission-chunk"])
-                if engine_config.get("admission-chunk")
-                else None
-            ),
             seed=sampling_seed,
             quantize=config.get("quantization"),
             kv_quant=engine_config.get("kv-quant") or None,

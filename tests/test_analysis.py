@@ -672,6 +672,51 @@ def test_no_module_defines_a_top_level_name_twice():
     assert not twice, "\n".join(twice)
 
 
+def test_the_model_step_and_the_latent_family_import_one_way():
+    """``model.py`` (programs, the layer loop, the block body) imports
+    ``latent_moe.py`` (an attention kind) at module level and never the
+    other way round, and neither imports a provider module from inside a
+    function: nothing is held apart by import order."""
+    import ast as ast_mod
+
+    package = "langstream_tpu.providers.jax_local"
+
+    def provider_imports(tree):
+        for node in ast_mod.walk(tree):
+            if isinstance(node, ast_mod.ImportFrom) and node.module:
+                names = [f"{node.module}.{alias.name}" for alias in node.names]
+            elif isinstance(node, ast_mod.Import):
+                names = [alias.name for alias in node.names]
+            else:
+                continue
+            for name in names:
+                if name.startswith(package):
+                    yield node, name
+
+    trees = {
+        name: ast_mod.parse(open(os.path.join(
+            PKG, "providers", "jax_local", name + ".py"
+        )).read())
+        for name in ("model", "latent_moe")
+    }
+    for name, tree in trees.items():
+        top_level = set(map(id, tree.body))
+        inside = [
+            f"{name}.py:{node.lineno} imports {what} inside a function"
+            for node, what in provider_imports(tree)
+            if id(node) not in top_level
+        ]
+        assert not inside, "\n".join(inside)
+    assert not [
+        what for _, what in provider_imports(trees["latent_moe"])
+        if what.startswith(package + ".model")
+    ]
+    assert [
+        what for _, what in provider_imports(trees["model"])
+        if what == package + ".latent_moe"
+    ]
+
+
 def test_annotations_cover_the_threaded_core():
     """The annotation work is load-bearing: the core threaded classes
     each declare at least one guarded/owned attribute, so the pass has

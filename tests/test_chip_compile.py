@@ -376,7 +376,6 @@ def _compile_decode_chunk(
     # the kernel's gate asks jax.devices(), which is the CPU here: steer it
     # in the test, to what the chip would answer
     monkeypatch.setattr(flash_attention, "on_tpu", lambda: True)
-    monkeypatch.delenv("LS_DECODE_FLASH", raising=False)
     max_len = 2048
     config = getattr(model_lib.LlamaConfig, preset)(max_len)
     freqs = rope_frequencies(
@@ -398,7 +397,7 @@ def _compile_decode_chunk(
     def chunk(params, cache, tokens, lengths, active):
         def body(carry, _):
             cache, tokens, lengths = carry
-            cache, logits = model_lib.decode_step(
+            cache, logits, _ = model_lib.decode_step(
                 config, params, cache, tokens, lengths, freqs, active,
                 mesh=mesh,
             )

@@ -77,7 +77,7 @@ def test_write_lands_on_its_rows_and_nowhere_else(path, kv_quant):
     before = _random_cache(config, slots, kv_quant)
     kept = {name: np.array(leaf) for name, leaf in before.items()}
 
-    after, logits = jax.jit(
+    after, logits, _ = jax.jit(
         lambda cache: model_lib.decode_step(
             config, params, cache, tokens, lengths, freqs, write_mask
         )
@@ -109,7 +109,7 @@ def test_masked_step_changes_no_bit(path, kv_quant):
     tokens = jnp.array([5, 9, 0], dtype=jnp.int32)
     before = _random_cache(config, 3, kv_quant)
     kept = {name: np.array(leaf) for name, leaf in before.items()}
-    after, logits = model_lib.decode_step(
+    after, logits, _ = model_lib.decode_step(
         config, params, before, tokens, lengths, freqs,
         jnp.zeros((3,), dtype=bool),
     )
@@ -149,7 +149,7 @@ def test_decode_logits_match_forward(path, kv_quant):
     for t in range(total):
         active = live > t
         lengths = np.where(active, t + 1, np.minimum(live, t + 1))
-        cache, logits = step(
+        cache, logits, _ = step(
             cache, jnp.asarray(seq[:, t]), jnp.asarray(lengths, dtype=jnp.int32),
             jnp.asarray(active),
         )

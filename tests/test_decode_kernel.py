@@ -160,10 +160,10 @@ def test_decode_step_flash_wiring(kv_quant):
             cfg, params, cache, tokens, lengths, freqs
         )
 
-    cache_ref, logits_ref = run(
+    cache_ref, logits_ref, _ = run(
         dataclasses.replace(config, use_flash=False, flash_interpret=False)
     )
-    cache_out, logits_out = run(config)
+    cache_out, logits_out, _ = run(config)
     np.testing.assert_allclose(
         np.asarray(logits_out), np.asarray(logits_ref), rtol=2e-4, atol=2e-4
     )

@@ -463,10 +463,11 @@ def test_the_four_shares_and_one_shared_expert_are_the_uncut_layer():
                 params[f"moe.{name}"][layer - 1] for name in latent_moe.EXPERT_MLP
             )
             stacks = tuple(params[f"moe.{name}"] for name in latent_moe.EXPERT_STACKS)
-            out, counters = latent_moe._feed_forward(
-                config, x, weights, None, stacks, layer - 1
-            )
             normed = model_lib._norm(config, x, weights[0])
+            delta, counters = model_lib._expert_block(
+                config, normed, weights[1:], stacks, layer - 1, None
+            )
+            out = x + delta
             shared, _ = model_lib._mlp_block(config, normed, weights[2:])
             parts.append(out - x - shared)  # this chip's routed experts' part
             assert int(counters[3:].sum()) == int(counters[1]) < int(counters[0])

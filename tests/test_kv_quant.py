@@ -63,7 +63,7 @@ def test_model_level_logits_close_to_bf16():
     outs = {}
     for name, quant in (("bf16", False), ("int8", True)):
         cache = init_cache(config, 1, 64, kv_quant=quant)
-        cache, logits = prefill(
+        cache, logits, _ = prefill(
             config, params, cache, tokens, lengths, slots, freqs
         )
         steps = [logits]
@@ -71,7 +71,7 @@ def test_model_level_logits_close_to_bf16():
         token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         for _ in range(4):
             step_lengths = step_lengths + 1
-            cache, logits = decode_step(
+            cache, logits, _ = decode_step(
                 config, params, cache, token, step_lengths, freqs
             )
             steps.append(logits)
@@ -182,8 +182,8 @@ def test_quantized_prefill_flash_kernel_matches_xla():
         cache = init_cache(cfg, 1, 64, kv_quant=True)
         return prefill(cfg, params, cache, tokens, lengths, slots, freqs)
 
-    cache_xla, logits_xla = run(False)
-    cache_flash, logits_flash = run(True)
+    cache_xla, logits_xla, _ = run(False)
+    cache_flash, logits_flash, _ = run(True)
     # cache rows come from quantize_kv on the SAME k/v activations of
     # each layer; layer>0 activations pass through the attention impl,
     # so int8 rows may differ by ±1 quantum at most

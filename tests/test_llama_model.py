@@ -24,14 +24,70 @@ def test_prefill_and_decode_shapes():
     tokens = jnp.array([[1, 2, 3, 0], [4, 5, 0, 0]], dtype=jnp.int32)
     lengths = jnp.array([3, 2], dtype=jnp.int32)
     slots = jnp.array([0, 2], dtype=jnp.int32)
-    cache, logits = prefill(config, params, cache, tokens, lengths, slots, freqs)
+    cache, logits, _ = prefill(config, params, cache, tokens, lengths, slots, freqs)
     assert logits.shape == (2, config.vocab_size)
     # decode one token for every slot
     new_tokens = jnp.zeros((4,), dtype=jnp.int32)
     slot_lengths = jnp.array([4, 1, 3, 1], dtype=jnp.int32)
-    cache2, logits2 = decode_step(config, params, cache, new_tokens, slot_lengths, freqs)
+    cache2, logits2, _ = decode_step(config, params, cache, new_tokens, slot_lengths, freqs)
     assert logits2.shape == (4, config.vocab_size)
     assert cache2["k"].shape == cache["k"].shape
+
+
+@pytest.mark.parametrize(
+    "program", ["prefill", "prefill_at_offset", "decode_step"]
+)
+@pytest.mark.parametrize("preset", ["tiny", "tiny-deepseek-v2"])
+def test_every_dense_program_returns_cache_logits_and_counters(preset, program):
+    """One set of programs serves both families: each returns (cache,
+    logits, expert counters), the counters None exactly where the config
+    has no routed experts, and the engine's program of that kind hands
+    them on as its last result with nothing in between."""
+    from langstream_tpu.providers.jax_local import model as model_lib
+    from langstream_tpu.providers.jax_local.engine import DecodeEngine
+
+    config = LlamaConfig.from_dict({"preset": preset, "max_seq_len": 32})
+    params = init_params(config)
+    freqs = model_lib.model_freqs(config)
+    cache = init_cache(config, batch=2, max_len=32)
+    tokens = jnp.array([[1, 2, 3, 0], [4, 5, 0, 0]], dtype=jnp.int32)
+    two = jnp.array([3, 2], dtype=jnp.int32)
+    slots = jnp.array([0, 1], dtype=jnp.int32)
+    run = {
+        "prefill": lambda: prefill(
+            config, params, cache, tokens, two, slots, freqs
+        ),
+        "prefill_at_offset": lambda: model_lib.prefill_at_offset(
+            config, params, cache, tokens, two, two, slots, freqs
+        ),
+        "decode_step": lambda: decode_step(
+            config, params, cache, tokens[:, 0], two, freqs
+        ),
+    }[program]
+    new_cache, logits, counters = jax.eval_shape(run)
+    assert set(new_cache) == set(cache)
+    assert logits.shape == (2, config.vocab_size)
+    routed = config.experts is not None
+    if routed:
+        assert counters.shape == (3 + config.experts.held,)
+        assert counters.dtype == jnp.int32
+    else:
+        assert counters is None
+    engine = DecodeEngine(
+        config, params, max_slots=2, max_seq_len=32, prefill_buckets=[16],
+        decode_chunk=2,
+    )
+    name = {
+        "prefill": "prefill_dense", "prefill_at_offset": "prefill_offset_dense",
+        "decode_step": "decode_chunk_dense",
+    }[program]
+    fn, avals = next(
+        job for job in engine._variant_jobs() if job[0].__name__ == name
+    )
+    with engine.mesh:
+        results = jax.eval_shape(fn, *engine._variant_args(avals))
+    assert (results[-1] is not None) == routed
+    assert not hasattr(model_lib, "step_results")
 
 
 def test_prefill_padding_invariance():
@@ -43,7 +99,7 @@ def test_prefill_padding_invariance():
     for pad in (0, 3, 9):
         cache = init_cache(config, batch=1, max_len=32)
         tokens = jnp.array([prompt + [0] * pad], dtype=jnp.int32)
-        _, logits = prefill(
+        _, logits, _ = prefill(
             config, params, cache, tokens,
             jnp.array([3], dtype=jnp.int32), jnp.array([0], dtype=jnp.int32),
             freqs,
@@ -64,7 +120,7 @@ def test_decode_matches_prefill():
     prompt = [3, 7, 11, 19]
 
     cache = init_cache(config, batch=1, max_len=32)
-    cache, logits_prefill = prefill(
+    cache, logits_prefill, _ = prefill(
         config, params, cache, jnp.array([prompt], dtype=jnp.int32),
         jnp.array([len(prompt)], dtype=jnp.int32),
         jnp.array([0], dtype=jnp.int32), freqs,
@@ -72,12 +128,12 @@ def test_decode_matches_prefill():
 
     # now: prefill only the first token, decode the rest one by one
     cache2 = init_cache(config, batch=1, max_len=32)
-    cache2, logits_step = prefill(
+    cache2, logits_step, _ = prefill(
         config, params, cache2, jnp.array([prompt[:1]], dtype=jnp.int32),
         jnp.array([1], dtype=jnp.int32), jnp.array([0], dtype=jnp.int32), freqs,
     )
     for i, token in enumerate(prompt[1:], start=2):
-        cache2, logits_step = decode_step(
+        cache2, logits_step, _ = decode_step(
             config, params, cache2,
             jnp.array([token], dtype=jnp.int32),
             jnp.array([i], dtype=jnp.int32), freqs,
@@ -110,7 +166,7 @@ def test_parity_with_huggingface_llama():
         hf_logits = hf_model(torch.tensor([prompt])).logits[0, -1].numpy()
 
     cache = init_cache(config, batch=1, max_len=32)
-    _, logits = prefill(
+    _, logits, _ = prefill(
         config, params, cache, jnp.array([prompt], dtype=jnp.int32),
         jnp.array([len(prompt)], dtype=jnp.int32),
         jnp.array([0], dtype=jnp.int32), freqs,
@@ -134,7 +190,7 @@ def test_sharded_params_on_mesh():
     freqs = rope_frequencies(config.dims_per_head, config.max_seq_len, config.rope_theta)
     cache = init_cache(config, batch=2, max_len=32)
     tokens = jnp.array([[1, 2], [3, 4]], dtype=jnp.int32)
-    cache, logits = jax.jit(
+    cache, logits, _ = jax.jit(
         lambda p, c, t: prefill(
             config, p, c, t,
             jnp.array([2, 2], dtype=jnp.int32),

@@ -84,7 +84,7 @@ def test_moe_prefill_padding_invariance():
     for pad in (0, 5, 13):
         cache = init_cache(config, batch=1, max_len=32)
         tokens = jnp.array([prompt + [0] * pad], dtype=jnp.int32)
-        _, logits = prefill(
+        _, logits, _ = prefill(
             config, params, cache, tokens,
             jnp.array([3], dtype=jnp.int32), jnp.array([0], dtype=jnp.int32),
             freqs,
@@ -164,7 +164,7 @@ def test_moe_decode_matches_prefill():
     )
     prompt = [3, 7, 11, 19]
     cache = init_cache(config, batch=1, max_len=32)
-    cache, logits_pre = prefill(
+    cache, logits_pre, _ = prefill(
         config, params, cache,
         jnp.array([prompt], dtype=jnp.int32),
         jnp.array([len(prompt)], dtype=jnp.int32),
@@ -173,7 +173,7 @@ def test_moe_decode_matches_prefill():
     cache2 = init_cache(config, batch=1, max_len=32)
     logits_dec = None
     for i, token in enumerate(prompt):
-        cache2, logits_dec = decode_step(
+        cache2, logits_dec, _ = decode_step(
             config, params, cache2,
             jnp.array([token], dtype=jnp.int32),
             jnp.array([i + 1], dtype=jnp.int32), freqs,

@@ -69,20 +69,20 @@ def test_qwen2_decode_matches_prefill():
     prompt = [5, 9, 13, 2, 7, 30]
 
     cache = init_cache(config, batch=1, max_len=32)
-    cache, logits_full = prefill(
+    cache, logits_full, _ = prefill(
         config, params, cache, jnp.array([prompt], dtype=jnp.int32),
         jnp.array([len(prompt)], dtype=jnp.int32),
         jnp.array([0], dtype=jnp.int32), freqs,
     )
 
     cache2 = init_cache(config, batch=1, max_len=32)
-    cache2, logits_step = prefill(
+    cache2, logits_step, _ = prefill(
         config, params, cache2, jnp.array([prompt[:1]], dtype=jnp.int32),
         jnp.array([1], dtype=jnp.int32),
         jnp.array([0], dtype=jnp.int32), freqs,
     )
     for position, token in enumerate(prompt[1:], start=2):
-        cache2, logits_step = decode_step(
+        cache2, logits_step, _ = decode_step(
             config, params, cache2,
             jnp.array([token], dtype=jnp.int32),
             jnp.array([position], dtype=jnp.int32), freqs,

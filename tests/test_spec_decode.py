@@ -357,10 +357,10 @@ def test_paged_length_rewind_at_block_boundary():
         return model_lib.init_paged_cache(config, 8, block_size)
 
     prompt = jnp.asarray([[3, 1, 4, 1, 5, 9, 2]], jnp.int32)  # 7 tokens
-    spec_cache, _ = model_lib.paged_prefill(
+    spec_cache, _, _ = model_lib.paged_prefill(
         config, params, fresh(), prompt, jnp.asarray([7]), tables, freqs,
     )
-    control_cache, _ = model_lib.paged_prefill(
+    control_cache, _, _ = model_lib.paged_prefill(
         config, params, fresh(), prompt, jnp.asarray([7]), tables, freqs,
     )
 
@@ -368,12 +368,12 @@ def test_paged_length_rewind_at_block_boundary():
     # d1..d3 land at positions 8..10 — the first rows of block 2
     lengths = jnp.asarray([8], jnp.int32)
     block = jnp.asarray([[6, 11, 12, 13]], jnp.int32)
-    spec_cache, spec_logits = model_lib.paged_verify_step(
+    spec_cache, spec_logits, _ = model_lib.paged_verify_step(
         config, params, spec_cache, block, lengths,
         jnp.asarray([4], jnp.int32), tables, freqs,
     )
     # control: the same step WITHOUT drafts (plain decode of t0)
-    control_cache, control_logits = model_lib.paged_decode_step(
+    control_cache, control_logits, _ = model_lib.paged_decode_step(
         config, params, control_cache, jnp.asarray([6], jnp.int32),
         lengths, tables, freqs,
     )
@@ -388,11 +388,11 @@ def test_paged_length_rewind_at_block_boundary():
     # up to its own block
     lengths = jnp.asarray([9], jnp.int32)
     next_block = jnp.asarray([[7, 21, 22, 23]], jnp.int32)
-    _, spec_next = model_lib.paged_verify_step(
+    _, spec_next, _ = model_lib.paged_verify_step(
         config, params, spec_cache, next_block, lengths,
         jnp.asarray([4], jnp.int32), tables, freqs,
     )
-    _, control_next = model_lib.paged_verify_step(
+    _, control_next, _ = model_lib.paged_verify_step(
         config, params, control_cache, next_block, lengths,
         jnp.asarray([4], jnp.int32), tables, freqs,
     )

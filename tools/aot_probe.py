@@ -80,7 +80,7 @@ def main() -> None:
                    temperature, top_k, top_p, rng):
         def body(carry, key):
             cache, tokens, lengths = carry
-            cache, logits = model_lib.decode_step(
+            cache, logits, _ = model_lib.decode_step(
                 config, params, cache, tokens, lengths, freqs, write_mask
             )
             sampled, lp = _sample_with_logprob(
@@ -256,7 +256,7 @@ def probe_tp8_70b(slots=8, chunk=16, seq=512) -> None:
                    temperature, top_k, top_p, rng):
         def body(carry, key):
             cache, tokens, lengths = carry
-            cache, logits = model_lib.decode_step(
+            cache, logits, _ = model_lib.decode_step(
                 config, params, cache, tokens, lengths, freqs, write_mask
             )
             sampled, lp = _sample_with_logprob(
