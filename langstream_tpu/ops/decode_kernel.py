@@ -33,6 +33,16 @@ LIVE context instead of the allocated buffer:
 - GQA runs as one small MXU matmul per kv head against the block's
   ``[block_k, D]`` slab (a static python loop — KVH is a config
   constant); q is tiny ([H, D]) and loaded once per slot.
+- heads narrower than a lane row (D = 64, 32, 16) are read PACKED: the
+  cache then lies as ``[L, S, T, KVH / pack, 128]`` with ``pack = 128 //
+  D`` kv heads side by side in one 128-lane row (``kv_pack`` has the
+  rule; ``model.init_cache`` lays the leaf out so). The wrapper hands
+  the kernel a query padded to 128 lanes, head ``h``'s D values in the
+  lanes of its kv head and zeros elsewhere, so ``q_pad . k_row`` is
+  head ``h``'s score exactly and ``p . v_row`` holds its output in the
+  same lanes: the body above runs unchanged with ``KVH / pack`` kv
+  heads and ``pack`` times the group, at ``pack`` times the MXU flops
+  of a step that is bound by bytes.
 - the int8-cache twin streams int8 k/v tiles (half the bytes — the
   kv-quant win compounds with block skipping) and folds the
   per-(position, head) scales exactly like the XLA quant path:
@@ -51,10 +61,12 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+LANES = 128  # a vector register's minor axis: what a cache row must fill
 
 # candidate kv-block sizes, largest first; the allocated cache length
 # must divide evenly (no padding — padding would copy the cache)
@@ -244,16 +256,29 @@ def flash_decode_attention(
     ``layer`` of the stacked cache, with HBM traffic ∝ live context and
     no copy of the slab: the layer is one more scalar in the index maps.
     Caller gates via :func:`use_flash_decode`; shapes must satisfy
-    D % 128 == 0, H % KVH == 0, and ``block_k`` must divide T
+    :func:`decode_shapes_ok`, and ``block_k`` must divide T
     (``pick_block_k``). A sliding ``window`` (Gemma-2) bounds the
     traffic by the window instead — blocks below it clamp-elide their
-    DMA just like dead blocks past the length."""
-    slots, heads, dim = q.shape
-    num_layers, max_len, kv_heads = (
-        k_cache.shape[0], k_cache.shape[2], k_cache.shape[3]
+    DMA just like dead blocks past the length.
+
+    A PACKED stack ``[L, S, T, KVH / pack, pack * D]`` (heads narrower
+    than 128 lanes, :func:`kv_pack`) is told by its row being wider than
+    q's heads: the kernel then runs on 128-lane rows with q zero-padded
+    into its kv head's lanes, and each head's own D lanes come back."""
+    slots, heads, head_dim = q.shape
+    num_layers, max_len, kv_heads, dim = (
+        k_cache.shape[0], k_cache.shape[2], k_cache.shape[3],
+        k_cache.shape[4],
     )
+    scale = head_dim ** -0.5 if scale is None else scale
+    pack = dim // head_dim
+    if pack > 1:
+        # head h reads kv head h // (H / KVH), which lies in lanes
+        # [lane * D, (lane + 1) * D) of packed row (h // group)
+        lane = (np.arange(heads) // (heads // (kv_heads * pack))) % pack
+        own = jnp.asarray(lane[:, None] == np.arange(pack))[:, :, None]
+        q = jnp.where(own, q[:, :, None, :], 0).reshape(slots, heads, dim)
     group = heads // kv_heads
-    scale = dim ** -0.5 if scale is None else scale
     block_k = block_k or pick_block_k(max_len)
     if block_k is None:
         raise ValueError(f"no kv block size divides max_len={max_len}")
@@ -344,7 +369,7 @@ def flash_decode_attention(
             pltpu.VMEM((heads, dim), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         name="flash_decode_int8kv" if quantized else "flash_decode",
         grid_spec=grid_spec,
@@ -361,6 +386,11 @@ def flash_decode_attention(
         ),
         interpret=interpret,
     )(lengths, window_arr, layer_arr, *operands)
+    if pack > 1:
+        out = jnp.where(
+            own, out.reshape(slots, heads, pack, head_dim), 0
+        ).sum(axis=2)
+    return out
 
 
 def flash_decode_attention_quant(
@@ -401,8 +431,9 @@ def flash_decode_attention_sharded(
     head shard through ``shard_map`` (a Mosaic call has no SPMD
     partitioning rule). Attention never mixes heads, so no collective;
     query and kv heads shard by the same tp factor (``validate_mesh``
-    enforces divisibility). The (traced) ``window`` and ``layer``
-    scalars ride as replicated operands."""
+    enforces divisibility; a packed stack shards whole packed rows,
+    which :func:`kv_pack` asks of ``tp``). The (traced) ``window`` and
+    ``layer`` scalars ride as replicated operands."""
     from jax.sharding import PartitionSpec as P
 
     head_spec = P(None, axis_name, None)
@@ -436,21 +467,45 @@ def flash_decode_attention_sharded(
     )(*operands)
 
 
-def decode_shapes_ok(max_len: int, dim: int, heads: int, kv_heads: int) -> bool:
+def kv_pack(
+    dim: int, kv_heads: int, quantized: bool = False, tp: int = 1
+) -> Optional[int]:
+    """How many kv heads one row of the cache holds for the kernel: 1
+    where a head is whole lanes wide (D % 128 == 0), ``128 // D`` where
+    narrower heads fill a 128-lane row exactly, None where the kernel
+    cannot read the cache. Packing wants a bf16 cache (an int8 row would
+    hold ``pack`` heads with ``pack`` scales, which the per-head scale
+    fold cannot express) and whole packed rows a tp shard."""
+    if dim % LANES == 0:
+        return 1
+    pack = LANES // dim
+    if LANES % dim == 0 and not quantized and kv_heads % (pack * tp) == 0:
+        return pack
+    return None
+
+
+def decode_shapes_ok(
+    max_len: int, dim: int, heads: int, kv_heads: int,
+    quantized: bool = False, tp: int = 1,
+) -> bool:
     """Hard shape requirements of the kernel (hold on ANY backend)."""
     return (
-        dim % 128 == 0
+        kv_pack(dim, kv_heads, quantized, tp) is not None
         and heads % kv_heads == 0
         and pick_block_k(max_len) is not None
     )
 
 
-def use_flash_decode(max_len: int, dim: int, heads: int, kv_heads: int) -> bool:
+def use_flash_decode(
+    max_len: int, dim: int, heads: int, kv_heads: int,
+    quantized: bool = False, tp: int = 1,
+) -> bool:
     """The kernel pays once dead-block skipping can actually drop HBM
-    traffic: a long allocated cache, MXU-aligned head_dim, a block size
-    that divides it, and a real TPU backend."""
+    traffic: a long allocated cache, a head dim that fills whole lanes
+    (alone or packed), a block size that divides it, and a real TPU
+    backend."""
     from langstream_tpu.ops.flash_attention import on_tpu
 
-    if not decode_shapes_ok(max_len, dim, heads, kv_heads):
+    if not decode_shapes_ok(max_len, dim, heads, kv_heads, quantized, tp):
         return False
     return on_tpu() and max_len >= 1024

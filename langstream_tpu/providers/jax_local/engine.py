@@ -257,6 +257,15 @@ def engines_snapshot() -> Dict[str, float]:
             # SLO targets + multi-window burn rates: visible from the
             # first scrape (targets are config, not traffic)
             out.update(engine.slo.gauges())
+        reader = getattr(engine, "decode_reader", None)
+        if reader is not None:
+            # engines by what reads the cache in their decode step and how
+            # a cache leaf lies (settled at construction)
+            key = (
+                f'jax_engine_decode_reader{{reader="{reader}",cache="'
+                + "x".join(map(str, engine.cache_leaf_shape)) + '"}'
+            )
+            out[key] = out.get(key, 0.0) + 1.0
         experts = getattr(getattr(engine, "config", None), "experts", None)
         if experts is not None:
             # routed experts held here: what the router assigned, what
@@ -942,8 +951,25 @@ class DecodeEngine:
                     lambda: model_lib.init_cache(
                         config, max_slots, self.max_seq_len,
                         kv_quant=self.kv_quant,
+                        tp=dict(self.mesh.shape).get("tp", 1),
                     )
                 )()
+        # what reads the cache in the decode step, and how a leaf lies:
+        # settled here, by the model's own gates, and said once (stats,
+        # the jax_engine_decode_reader gauge, the start-up log line)
+        if not self.paged:
+            self.decode_reader = model_lib.decode_reader(
+                config, self.cache, self.mesh
+            )
+        elif self.paged_kernel == "fused":
+            self.decode_reader = "ragged_paged_attention" + (
+                "_int8kv" if self.kv_quant else ""
+            )
+        else:
+            self.decode_reader = "xla"
+        self.cache_leaf_shape = tuple(
+            jax.tree_util.tree_leaves(self.cache)[0].shape
+        )
         self.slots = [_Slot() for _ in range(max_slots)]
         # efficiency accounting: analytical FLOPs/bytes per dispatch from
         # the model shape + quantization widths + KV layout, divided by
@@ -1050,7 +1076,7 @@ class DecodeEngine:
         # counters mutated only on the device thread; cross-thread
         # readers (engines_snapshot, build_heartbeat, the watchdog)
         # take snapshot-tolerant reads — see _stable_items there
-        self.stats = self._fresh_stats()  # owned-by: _run_loop
+        self.stats = self._new_stats()  # owned-by: _run_loop
         # per-chunk dispatch log: (steps, active_slots, wall_seconds) —
         # the occupancy/step-time evidence the bench prints (bounded)
         self.chunk_log: List[Tuple[int, int, float]] = []  # owned-by: _run_loop
@@ -1134,6 +1160,14 @@ class DecodeEngine:
                 "the latent-attention, routed-experts family does not "
                 "support: " + "; ".join(named)
             )
+
+    def _new_stats(self) -> Dict[str, Any]:
+        """Zeroed counters, beside what construction settled."""
+        return dict(
+            self._fresh_stats(),
+            decode_reader=self.decode_reader,
+            cache_leaf_shape=self.cache_leaf_shape,
+        )
 
     @staticmethod
     def _fresh_stats() -> Dict[str, Any]:
@@ -1254,7 +1288,7 @@ class DecodeEngine:
     #   the replacement dicts/lists are fully formed before publication
     def reset_stats(self) -> None:
         """Zero the counters (e.g. after warmup, before measurement)."""
-        self.stats = self._fresh_stats()
+        self.stats = self._new_stats()
         self.chunk_log = []
         self.dispatch_log = []
 
@@ -2647,8 +2681,10 @@ class DecodeEngine:
     # ------------------------------------------------------------------ #
     def _run_loop(self) -> None:
         logger.info(
-            "engine started: %d slots × %d ctx, mesh %s",
+            "engine started: %d slots × %d ctx, mesh %s, decode reads the "
+            "cache %s through %s",
             self.max_slots, self.max_seq_len, dict(self.mesh.shape),
+            list(self.cache_leaf_shape), self.decode_reader,
         )
         try:
             with self.mesh:

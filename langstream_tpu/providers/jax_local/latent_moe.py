@@ -420,7 +420,7 @@ def _expand(o_lat, wv_b):
     return jnp.einsum("bthc,hcd->bthd", o_lat, wv_b)
 
 
-def _decode_kernel_ok(config, stack) -> bool:
+def decode_kernel_ok(config, stack) -> bool:
     """The Pallas ``mla_decode`` kernel on TPU (and under the interpret
     test hook) where its shapes hold; the XLA absorbed form otherwise."""
     mla = config.mla
@@ -502,7 +502,7 @@ def decode_attend(config, freqs, stack, lengths, positions, write_mask):
         max_len,
     )
     rows_at = jnp.arange(slots)
-    kernel = _decode_kernel_ok(config, stack)
+    kernel = decode_kernel_ok(config, stack)
     scale = softmax_scale(config)
     latent = config.mla.kv_lora_rank
 

@@ -32,6 +32,7 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 import numpy as np
 
 from langstream_tpu.ops.attention import (
@@ -494,13 +495,47 @@ def logical_axes(config: LlamaConfig) -> Dict[str, Any]:
     return axes
 
 
+def flash_decode_pack(
+    config: LlamaConfig, max_len: int, kv_quant: bool = False, tp: int = 1
+) -> Optional[int]:
+    """Does the ``flash_decode`` kernel read this config's dense cache,
+    and how: the kv heads one cache row holds (1: a row is a head; ``128
+    // head_dim`` where narrower heads are PACKED into 128-lane rows), or
+    None where XLA's attention reads it. The one answer ``init_cache``
+    lays the value leaves out by and the decode step's write and read
+    follow (:func:`_decode_flash_path`): a leaf that lies otherwise than
+    its reader wants is re-laid-out whole at every chunk's entry. Shape
+    requirements bind even under the ``flash_interpret`` test hook; the
+    backend/length policy only applies outside it."""
+    from langstream_tpu.ops.decode_kernel import (
+        decode_shapes_ok,
+        kv_pack,
+        use_flash_decode,
+    )
+
+    dim, kv_heads = config.dims_per_head, config.num_kv_heads
+    shape = (max_len, dim, config.num_heads, kv_heads, kv_quant, tp)
+    if config.use_flash and (
+        use_flash_decode(*shape)
+        or (config.flash_interpret and decode_shapes_ok(*shape))
+    ):
+        return kv_pack(dim, kv_heads, kv_quant, tp)
+    return None
+
+
 def init_cache(
     config: LlamaConfig,
     batch: int,
     max_len: Optional[int] = None,
     kv_quant: bool = False,
+    tp: int = 1,
 ) -> Dict[str, jnp.ndarray]:
-    """KV cache: [layers, batch, max_len, kv_heads, head_dim].
+    """KV cache: [layers, batch, max_len, kv_heads, head_dim]; where the
+    decode kernel reads narrow heads packed (:func:`flash_decode_pack`,
+    which ``tp``, the mesh's, is for) the value leaves are ``[layers,
+    batch, max_len, kv_heads / pack, pack * head_dim]``: the same bytes,
+    lying as 128-lane rows. Programs move rows in and out of such a leaf
+    through :func:`_pack_kv` and :func:`_unpack_kv`.
 
     ``kv_quant`` stores int8 values plus per-(position, kv-head) f32
     scales — halves the cache's HBM bytes on the weights+cache-bound
@@ -514,7 +549,11 @@ def init_cache(
         if kv_quant:
             raise ValueError("the latent cache has no int8 form")
         return latent_moe.init_cache(config, batch, max_len)
-    shape = (config.num_layers, batch, max_len, config.num_kv_heads, config.dims_per_head)
+    pack = flash_decode_pack(config, max_len, kv_quant, tp) or 1
+    shape = (
+        config.num_layers, batch, max_len, config.num_kv_heads // pack,
+        config.dims_per_head * pack,
+    )
     if kv_quant:
         return {
             "k": jnp.zeros(shape, dtype=jnp.int8),
@@ -528,11 +567,42 @@ def init_cache(
     }
 
 
+def _pack_kv(rows: jnp.ndarray, leaf: jnp.ndarray) -> jnp.ndarray:
+    """K or V rows ``[..., kv_heads, head_dim]`` in the row form of the
+    dense cache leaf (or slab) they go into: ``[..., kv_heads / pack,
+    pack * head_dim]`` where :func:`init_cache` packed it, a reshape of
+    the last two axes (row-major: kv head g is lanes ``[g % pack *
+    head_dim, ...)`` of packed row ``g // pack``); else as they are."""
+    if rows.shape[-2:] == leaf.shape[-2:]:
+        return rows
+    return rows.reshape(rows.shape[:-2] + leaf.shape[-2:])
+
+
+def _unpack_kv(config: LlamaConfig, rows: jnp.ndarray) -> jnp.ndarray:
+    """Rows read out of a dense value leaf (a slab, a slot's rows) as
+    heads ``[..., kv_heads, head_dim]``: :func:`_pack_kv` the other way,
+    for XLA's attention. Packed rows are pinned row-major first, as the
+    leaf lies: XLA's einsums want the position axis minor-most, and
+    unpinned that wish runs back through the slice to the stack, which
+    is then copied whole at the program's entry and exit (1.5 GB of temp
+    in the 0.5B's warm prefill); pinned, the rows that were read are
+    transposed and the stack stays put."""
+    heads = (config.num_kv_heads, config.dims_per_head)
+    if rows.shape[-2:] == heads:
+        return rows
+    rows = with_layout_constraint(
+        rows, Layout(major_to_minor=tuple(range(rows.ndim)))
+    )
+    return rows.reshape(rows.shape[:-2] + heads)
+
+
 def cache_logical_axes(
     kv_quant: bool = False, config: Optional[LlamaConfig] = None
 ) -> Dict[str, Any]:
     if config is not None and _kinds(config).attention == "latent":
         return latent_moe.cache_logical_axes()
+    # a packed leaf's fourth axis is still kv heads, ``pack`` to a row:
+    # ``flash_decode_pack`` packs only where whole rows fall to a shard
     axes: Dict[str, Any] = {
         "k": L("layers", "cache_batch", "cache_sequence", "kv_heads", None),
         "v": L("layers", "cache_batch", "cache_sequence", "kv_heads", None),
@@ -889,37 +959,46 @@ def _prefill_attn(config, q, k, v, mask, mesh=None, window=None):
 def _decode_flash_path(config, kc, mesh):
     """Gate + dispatch mode for the flash-decode kernel — the decode
     twin of :func:`_flash_path`, same contract: returns (use the
-    kernel?, tp shard_map?). ``kc`` is the stacked leaf
-    ``[L, S, T, KVH, D]``. Shape requirements bind even under the
-    ``flash_interpret`` test hook; the backend/length policy only
-    applies outside it."""
-    from langstream_tpu.ops.decode_kernel import (
-        decode_shapes_ok,
-        use_flash_decode,
-    )
-
-    heads, dim = config.num_heads, kc.shape[4]
-    max_len, kv_heads = kc.shape[2], kc.shape[3]
-    flash_ok = config.use_flash and (
-        use_flash_decode(max_len, dim, heads, kv_heads)
-        or (
-            config.flash_interpret
-            and decode_shapes_ok(max_len, dim, heads, kv_heads)
+    kernel?, tp shard_map?). ``kc`` is the stacked leaf ``[L, S, T,
+    KVH / pack, pack * D]``: the kernel reads it where
+    :func:`flash_decode_pack` says so AND the leaf lies as that answer
+    lays it (``init_cache`` asked the same question; a leaf made for
+    another mesh falls to the XLA side, through ``_unpack_kv``)."""
+    tp = 1 if mesh is None else dict(mesh.shape).get("tp", 1)
+    pack = flash_decode_pack(config, kc.shape[2], kc.dtype == jnp.int8, tp)
+    flash_ok = (
+        pack is not None
+        and kc.shape[3:] == (
+            config.num_kv_heads // pack, config.dims_per_head * pack
         )
     )
-    tp_sharded = mesh is not None and dict(mesh.shape).get("tp", 1) > 1
-    return flash_ok, tp_sharded
+    return flash_ok, tp > 1
+
+
+def decode_reader(config, cache, mesh=None) -> str:
+    """The attention that reads the DENSE cache in this engine's decode
+    step, by the kernel's name as a device trace shows it
+    (``flash_decode``, ``flash_decode_int8kv``, ``mla_decode``), or
+    ``xla``: what the gates above answer for the cache as it lies."""
+    if _kinds(config).attention == "latent":
+        on_kernel = latent_moe.decode_kernel_ok(config, cache["latent"])
+        return "mla_decode" if on_kernel else "xla"
+    if not _decode_flash_path(config, cache["k"], mesh)[0]:
+        return "xla"
+    return "flash_decode_int8kv" if "k_scale" in cache else "flash_decode"
 
 
 @jax.named_scope("attention")
 def _decode_attn(config, q, kc, vc, lengths, layer, mesh=None, window=None):
     """Decode attention over slab ``layer`` of the STACKED cache leaves
-    ``[L, S, T, KVH, D]``: length-aware Pallas kernel on TPU for long
-    allocated caches (HBM traffic ∝ live context — the XLA einsum
-    streams the full static buffer), XLA path otherwise. The kernel
-    takes the stack and the layer (a custom call's operand is a
-    materialised buffer: a slab handed to it is a slab copied); the XLA
-    path reads ``kc[layer]``, which XLA fuses into the einsums. Under
+    ``[L, S, T, KVH, D]`` (narrow heads packed ``pack`` to a 128-lane
+    row where the kernel reads them: ``init_cache``): length-aware Pallas
+    kernel on TPU for long allocated caches (HBM traffic ∝ live context
+    — the XLA einsum streams the full static buffer), XLA path
+    otherwise. The kernel takes the stack and the layer (a custom call's
+    operand is a materialised buffer: a slab handed to it is a slab
+    copied); the XLA path reads ``kc[layer]``, which XLA fuses into the
+    einsums. Under
     tp the kernel runs per head shard through shard_map
     (``flash_decode_attention_sharded``). ``window`` is this layer's
     sliding-window size (Gemma-2) and rides into the flash-decode
@@ -947,7 +1026,10 @@ def _decode_attn(config, q, kc, vc, lengths, layer, mesh=None, window=None):
             q, kc, vc, lengths, layer, interpret=config.flash_interpret,
             **family,
         )
-    return decode_attention(q, kc[layer], vc[layer], lengths, **family)
+    return decode_attention(
+        q, _unpack_kv(config, kc[layer]), _unpack_kv(config, vc[layer]),
+        lengths, **family,
+    )
 
 
 @jax.named_scope("attention")
@@ -1441,6 +1523,8 @@ def prefill(
     @jax.named_scope("cache_write")
     def write(name):
         leaf, rows = cache[name], new[name]
+        if rows.shape[3:] != leaf.shape[3:]:  # a packed value leaf
+            rows = _pack_kv(rows, leaf)
         pad = leaf.shape[2] - rows.shape[2]
         if pad > 0:
             rows = jnp.pad(
@@ -1506,12 +1590,13 @@ def _offset_attend(config, freqs, seq, lengths, offsets, slot_ids):
                 )
             return attn, (kc, vc, ks, vs), None
         kc, vc = kv
-        kc = write_rows(kc, index, k)
-        vc = write_rows(vc, index, v)
+        kc = write_rows(kc, index, _pack_kv(k, kc))
+        vc = write_rows(vc, index, _pack_kv(v, vc))
         with jax.named_scope("attention"):
             attn = chunk_attention(
-                q, kc[index, slot_ids], vc[index, slot_ids], offsets,
-                totals, window=win, **family,
+                q, _unpack_kv(config, kc[index, slot_ids]),
+                _unpack_kv(config, vc[index, slot_ids]), offsets, totals,
+                window=win, **family,
             )
         return attn, (kc, vc), None
 
@@ -1774,12 +1859,15 @@ def _decode_attend(config, freqs, stacked, lengths, positions, write_mask,
     @jax.named_scope("cache_write")
     def write(stacked, layer, new):
         """stacked [L, S, max_len, ...], new [S, ...] (value leaves carry
-        kv-head and head_dim axes, scale leaves the kv-head axis), in
-        place on the carry. The write follows the attention's reader.
-        The kernel streams rows of a row-major stack, and there one
-        scatter of S rows is in place. XLA's einsums want the position
-        axis minor-most, which at head dim 64 is also how the leaf lies
-        on the chip; a scatter (or a row loop of dynamic_update_slice)
+        kv-head and head_dim axes, as the leaf packs them; scale leaves
+        the kv-head axis), in place on the carry. The write follows the
+        attention's reader. The kernel streams rows of a row-major
+        stack, and there one scatter of S rows is in place: every head
+        dim that fills 128-lane rows, alone or packed, on the chip. What
+        is left to XLA's einsums (an int8 cache of narrow heads, a head
+        dim that divides no lane row, a short cache, the CPU) wants the
+        position axis minor-most, which is also how such a leaf lies on
+        the chip; a scatter (or a row loop of dynamic_update_slice)
         makes XLA carry the stack row-major instead: the whole cache
         re-laid-out at the chunk's entry and exit behind two cache-sized
         temps, and every layer's slab sliced out and re-laid-out for the
@@ -1808,7 +1896,8 @@ def _decode_attend(config, freqs, stacked, lengths, positions, write_mask,
             )
             return attn, (kc, vc, ks, vs), None
         kc, vc = kv
-        kc, vc = write(kc, index, k), write(vc, index, v)
+        kc = write(kc, index, _pack_kv(k, kc))
+        vc = write(vc, index, _pack_kv(v, vc))
         attn = _decode_attn(
             config, q, kc, vc, lengths, index, mesh=mesh, window=win
         )
@@ -1942,11 +2031,12 @@ def verify_step(
                 )
             return attn, state, (kc, vc, ks, vs)
         kc, vc = slabs
-        kc = write_rows(kc, k)
-        vc = write_rows(vc, v)
+        kc = write_rows(kc, _pack_kv(k, kc))
+        vc = write_rows(vc, _pack_kv(v, vc))
         with jax.named_scope("attention"):
             attn = chunk_attention(
-                q, kc, vc, offsets, totals, window=win, **family
+                q, _unpack_kv(config, kc), _unpack_kv(config, vc), offsets,
+                totals, window=win, **family,
             )
         return attn, state, (kc, vc)
 

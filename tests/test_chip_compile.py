@@ -119,12 +119,22 @@ def _decode_shapes(s, heads, kv_heads, quant, slots=32, max_len=2048):
     return shapes
 
 
-@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8kv"])
-def test_flash_decode_compiles(one_chip, quant):
+@pytest.mark.parametrize(
+    "quant,packed", [(False, False), (True, False), (False, True)],
+    ids=["bf16", "int8kv", "0.5b-packed"],
+)
+def test_flash_decode_compiles(one_chip, quant, packed):
     heads, kv_heads = QWEN
-    shapes = _decode_shapes(
-        functools.partial(_spec, sharding=one_chip), heads, kv_heads, quant
-    )
+    s = functools.partial(_spec, sharding=one_chip)
+    shapes = _decode_shapes(s, heads, kv_heads, quant)
+    if packed:
+        # Qwen-2.5-0.5B at the chat cell's size: 14 heads of 64 over a
+        # stack whose row is both kv heads, [24, 128, 2048, 1, 128]
+        stack = s((24, 128, 2048, 1, D), jnp.bfloat16)
+        shapes = [
+            s((128, 14, D // 2), jnp.bfloat16), stack, stack,
+            s((128,), jnp.int32), s((), jnp.int32),
+        ]
 
     def fn(q, k, v, lengths, layer, *scales):
         kw = {"k_scale": scales[0], "v_scale": scales[1]} if scales else {}
@@ -422,7 +432,7 @@ def _compile_decode_chunk(
     "preset,slots,kv_quant",
     [("qwen25_7b", 32, False), ("qwen25_7b", 32, True),
      ("qwen25_0_5b", 128, False)],
-    ids=["7b-bf16kv", "7b-int8kv", "0.5b-xla"],
+    ids=["7b-bf16kv", "7b-int8kv", "0.5b-packed"],
 )
 def test_dense_decode_chunk_copies_no_cache_slab(
     one_chip, monkeypatch, preset, slots, kv_quant
@@ -432,23 +442,26 @@ def test_dense_decode_chunk_copies_no_cache_slab(
     result of a slab's or the stack's shape but the in-place writes that
     alias the carry: neither half of the copy a scanned xs -> ys cache makes
     (a dynamic-slice of the stack into a slab, a dynamic-update-slice of a
-    slab into the stack), nor a re-layout of either. On the 7B the kernel
-    must be handed the stack (one Pallas call a layer) and the program's
-    temp stays under one slab."""
+    slab into the stack), nor a re-layout of either. The kernel must be
+    handed the stack (one Pallas call a layer) and the program's temp stays
+    under one slab: on the 7B, and on the 0.5B, whose two 64-wide kv heads
+    lie packed in one 128-lane row (``[24, 128, 2048, 1, 128]``) so that
+    the same kernel reads them."""
 
     def place(tree, _axes):
         return jax.tree_util.tree_map(
             lambda leaf: _spec(leaf.shape, leaf.dtype, one_chip), tree
         )
 
-    on_kernel = preset == "qwen25_7b"  # head dim 128; the 0.5B's is 64
     compiled, cache = _compile_decode_chunk(
         monkeypatch, preset, slots, kv_quant, place,
-        int8_weights=on_kernel,  # as the cell runs it
+        int8_weights=preset == "qwen25_7b",  # as the cells run them
     )
     text = compiled.as_text()
 
     stack = tuple(cache["k"].shape)           # [L, S, T, KVH, D]
+    if preset == "qwen25_0_5b":
+        assert stack == (24, 128, 2048, 1, 128)
     guarded = {stack, stack[1:], (1,) + stack[1:]}
     if kv_quant:                              # the scale leaves too
         guarded |= {stack[:-1], stack[1:-1], (1,) + stack[1:-1]}
@@ -483,14 +496,10 @@ def test_dense_decode_chunk_copies_no_cache_slab(
             offenders.append(f"{name} = {result_type} {op}")
     assert not offenders, offenders
 
-    kernels = text.count('custom_call_target="tpu_custom_call"')
-    if on_kernel:
-        assert kernels == 1
-        slab_bytes = int(np.prod(stack[1:])) * cache["k"].dtype.itemsize
-        temp = compiled.memory_analysis().temp_size_in_bytes
-        assert temp < slab_bytes, (temp, slab_bytes)
-    else:
-        assert kernels == 0  # XLA attention
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    slab_bytes = int(np.prod(stack[1:])) * cache["k"].dtype.itemsize
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < slab_bytes, (temp, slab_bytes)
 
 
 def test_dense_decode_chunk_tp4_copies_no_cache_slab(tp_mesh, monkeypatch):

@@ -18,9 +18,11 @@ from langstream_tpu.ops.attention import (
     quantize_kv,
 )
 from langstream_tpu.ops.decode_kernel import (
+    decode_shapes_ok,
     flash_decode_attention,
     flash_decode_attention_quant,
     flash_decode_attention_sharded,
+    kv_pack,
     pick_block_k,
     use_flash_decode,
 )
@@ -41,16 +43,43 @@ def _make_inputs(slots, max_len, heads, kv_heads, dim, seed=0):
     return q, k, v
 
 
-@pytest.mark.parametrize("heads,kv_heads", [(8, 8), (8, 4), (8, 2), (8, 1)])
-def test_flash_decode_matches_reference(heads, kv_heads):
-    slots, max_len, dim = 4, 256, 128
-    q, k, v = _make_inputs(slots, max_len, heads, kv_heads, dim)
-    lengths = jnp.array([256, 100, 1, 0], dtype=jnp.int32)
-
-    ref = decode_attention(q, k[LAYER], v[LAYER], lengths)
-    out = flash_decode_attention(
-        q, k, v, lengths, LAYER, block_k=64, interpret=True
+def _packed(stack, pack):
+    """[.., KVH, D] as the cache holds narrow heads: [.., KVH / pack,
+    pack * D], ``pack`` kv heads side by side in one 128-lane row."""
+    return stack.reshape(
+        stack.shape[:3] + (stack.shape[3] // pack, stack.shape[4] * pack)
     )
+
+
+# (heads, kv heads, head dim, Gemma-2's window + softcap + scale). Head
+# dims under 128 go in PACKED, the reference reads the heads apart.
+@pytest.mark.parametrize(
+    "heads,kv_heads,dim,family",
+    [
+        (8, 8, 128, False), (8, 4, 128, False), (8, 2, 128, False),
+        (8, 1, 128, False),
+        (14, 2, 64, False), (14, 2, 64, True),    # Qwen-2.5-0.5B: one row
+        (32, 8, 64, False), (32, 8, 64, True),    # Llama-3.2-1B: four rows
+        (8, 4, 32, False),                        # four heads to a row
+    ],
+)
+def test_flash_decode_matches_reference(heads, kv_heads, dim, family):
+    slots, max_len = 4, 256
+    q, k, v = _make_inputs(slots, max_len, heads, kv_heads, dim)
+    # a full slot, a ragged one, a single token, an empty slot
+    lengths = jnp.array([256, 100, 1, 0], dtype=jnp.int32)
+    kw = (
+        dict(softcap=30.0, window=jnp.asarray(40, jnp.int32), scale=0.17)
+        if family else {}
+    )
+
+    ref = decode_attention(q, k[LAYER], v[LAYER], lengths, **kw)
+    pack = kv_pack(dim, kv_heads)
+    out = flash_decode_attention(
+        q, _packed(k, pack), _packed(v, pack), lengths, LAYER, block_k=64,
+        interpret=True, **kw,
+    )
+    assert out.shape == q.shape
     # empty slots are garbage in both paths; compare live rows only
     for s in range(slots):
         if int(lengths[s]) == 0:
@@ -58,6 +87,47 @@ def test_flash_decode_matches_reference(heads, kv_heads):
         np.testing.assert_allclose(
             np.asarray(out[s]), np.asarray(ref[s]), rtol=2e-5, atol=2e-5
         )
+
+
+@pytest.mark.parametrize(
+    "dim,heads,kv_heads,quantized,tp,pack",
+    [
+        (128, 28, 4, False, 1, 1), (128, 28, 4, True, 4, 1),
+        (256, 8, 4, False, 1, 1),
+        (64, 14, 2, False, 1, 2),      # two heads fill a row
+        (64, 14, 1, False, 1, None),   # half a row
+        (64, 14, 2, True, 1, None),    # two heads, two scales a row
+        (64, 14, 2, False, 2, None),   # a shard would hold half a row
+        (64, 32, 8, False, 2, 2), (64, 32, 8, False, 4, 2),
+        (64, 32, 8, False, 8, None),
+        (96, 8, 4, False, 1, None), (32, 8, 4, False, 1, 4),
+        (16, 4, 2, False, 1, None),
+    ],
+)
+def test_gate_truth_table(dim, heads, kv_heads, quantized, tp, pack):
+    """What the kernel can read, and how many kv heads to a cache row."""
+    assert kv_pack(dim, kv_heads, quantized, tp) == pack
+    assert decode_shapes_ok(2048, dim, heads, kv_heads, quantized, tp) == (
+        pack is not None
+    )
+    # no block size divides a 7-row cache, whatever the heads
+    assert not decode_shapes_ok(7, dim, heads, kv_heads, quantized, tp)
+
+
+def test_flash_decode_sharded_packed_matches_reference():
+    """tp=2 over four 64-wide kv heads: a shard holds one packed row and
+    the four heads that read it; no head's lanes cross a shard."""
+    slots, max_len, heads, kv_heads, dim = 2, 128, 8, 4, 64
+    q, k, v = _make_inputs(slots, max_len, heads, kv_heads, dim, seed=8)
+    lengths = jnp.array([128, 60], dtype=jnp.int32)
+    mesh = Mesh(np.asarray(jax.devices()[:2]).reshape(2), ("tp",))
+    ref = decode_attention(q, k[LAYER], v[LAYER], lengths)
+    out = flash_decode_attention_sharded(
+        q, _packed(k, 2), _packed(v, 2), lengths, LAYER, mesh, interpret=True
+    )
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5
+    )
 
 
 def test_flash_decode_quant_matches_reference():
