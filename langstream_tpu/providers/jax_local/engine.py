@@ -164,6 +164,7 @@ def engines_snapshot() -> Dict[str, float]:
     decode_time = prefill_time = 0.0
     prefill_rows = prefill_join_rows = 0
     prefill_join_wait = 0.0
+    long_prompts_held = 0
     loop_seconds = {"idle": 0.0, "admit": 0.0, "dispatch": 0.0, "emit": 0.0}
     active_slot_steps = total_slot_steps = 0
     paged_engines = 0
@@ -235,6 +236,7 @@ def engines_snapshot() -> Dict[str, float]:
         prefill_rows += stats["prefill_rows"]
         prefill_join_rows += stats["prefill_join_rows"]
         prefill_join_wait += stats["prefill_join_wait"]
+        long_prompts_held += stats["long_prompts_held"]
         for phase_name in loop_seconds:
             loop_seconds[phase_name] += stats[phase_name + "_time"]
         active_slot_steps += stats["active_slot_steps"]
@@ -289,6 +291,14 @@ def engines_snapshot() -> Dict[str, float]:
             ]
             for key, count in counted:
                 out[key] = out.get(key, 0.0) + float(count)
+        if getattr(getattr(engine, "config", None), "mixers", None) is not None:
+            # block selection and recurrent state (the hybrid family)
+            for name in (
+                "sparse_kept", "sparse_visible", "sparse_queries",
+                "state_resets",
+            ):
+                key = f"jax_engine_{name}_total"
+                out[key] = out.get(key, 0.0) + float(stats[name])
         if getattr(engine, "spec", False):
             spec_engines += 1
             spec_drafted += stats["tokens_drafted"]
@@ -442,6 +452,9 @@ def engines_snapshot() -> Dict[str, float]:
     out["jax_engine_prefill_join_wait_seconds_total"] = round(
         prefill_join_wait, 6
     )
+    # cycles in which a prompt past the largest bucket waited for the
+    # decode chunk behind another's windows (_admit: one a cycle)
+    out["jax_engine_long_prompts_held_total"] = float(long_prompts_held)
     if steps:
         out["jax_engine_decode_ms_per_step"] = round(
             decode_time / steps * 1e3, 4
@@ -717,6 +730,23 @@ class DecodeEngine:
                 kv_layout=kv_layout, kv_host_blocks=kv_host_blocks,
                 spec_decode=spec_decode, prefill_mode=prefill_mode,
             )
+        # the hybrid family (a recurrent state beside its KV rows) cannot
+        # reuse rows across slots or turns: a state has no snapshot at a
+        # prefix's end
+        self.stateful = config.mixers is not None
+        if self.stateful:
+            self._refuse_for_hybrid(
+                mesh_config, kv_quant=kv_quant, kv_layout=kv_layout,
+                kv_host_blocks=kv_host_blocks, spec_decode=spec_decode,
+                prefill_mode=prefill_mode,
+            )
+            if prefix_cache:
+                logger.info(
+                    "prefix-cache and session warm reuse are off for the "
+                    "hybrid family: its recurrent state has no snapshot at "
+                    "a prefix's end (every prefill is cold)"
+                )
+            prefix_cache = False
         self.max_slots = max_slots
         self.decode_chunk = max(1, decode_chunk)
         # top-K alternative logprobs per generated token (OpenAI
@@ -1161,6 +1191,42 @@ class DecodeEngine:
                 "support: " + "; ".join(named)
             )
 
+    @staticmethod
+    def _refuse_for_hybrid(
+        mesh_config, *, kv_quant, kv_layout, kv_host_blocks, spec_decode,
+        prefill_mode,
+    ) -> None:
+        """The hybrid family (hybrid_sparse_linear.py) runs the dense
+        layout's three programs on one chip, in bf16 or int8 weights;
+        every other switch is refused by name when the engine is built
+        (ROADMAP R-M2 / R-M4 / R-M6 say what each needs). Prefix reuse is
+        not a switch to refuse but a path to leave: the constructor turns
+        it off and :meth:`_session_warm` answers cold."""
+        refused = {
+            "kv-layout: paged (the pool holds one kind of row; the family "
+            "has a recurrent state and compressed keys beside K and V)":
+                kv_layout != "dense",
+            "prefill-mode: mixed (a paged dispatch)":
+                prefill_mode != "split",
+            "kv-host-blocks (the host tier holds paged GQA rows)":
+                bool(kv_host_blocks),
+            "kv-quant (no int8 form of the state or of the selection's "
+            "compressed keys)":
+                bool(kv_quant),
+            "spec-decode (a rejected draft cannot be rolled out of a "
+            "recurrent state)":
+                spec_decode != "off",
+            "mesh (tp / any axis > 1: two kv heads, and a state that is "
+            "not sharded)":
+                mesh_config is not None and mesh_config.size > 1,
+        }
+        named = [switch for switch, on in refused.items() if on]
+        if named:
+            raise ValueError(
+                "the hybrid (linear + block-sparse attention) family does "
+                "not support: " + "; ".join(named)
+            )
+
     def _new_stats(self) -> Dict[str, Any]:
         """Zeroed counters, beside what construction settled."""
         return dict(
@@ -1191,6 +1257,10 @@ class DecodeEngine:
             "prefill_rows": 0,
             "prefill_join_rows": 0,
             "prefill_join_wait": 0.0,
+            # cycles in which a cold prompt past the largest bucket had a
+            # slot and waited all the same, for the decode chunk behind
+            # another such prompt's windows (_admit)
+            "long_prompts_held": 0,
             "active_slot_steps": 0,  # sum of active slots over decode steps
             # wall-clock breakdown of everything OUTSIDE device dispatches,
             # so "unaccounted" time has a name (VERDICT r2 weak #1)
@@ -1258,15 +1328,37 @@ class DecodeEngine:
             "moe_assignments_held": 0,
             "moe_rows_computed": 0,
             "moe_tokens_by_expert": [],
+            # the hybrid family: key blocks its sparse layers attended
+            # and had in context and the queries that chose (summed over
+            # sparse layers; every prefill and decode chunk returns them),
+            # and the slots whose recurrent state a prefill at position 0
+            # started from zeros
+            "sparse_kept": 0,
+            "sparse_visible": 0,
+            "sparse_queries": 0,
+            "state_resets": 0,
         }
 
-    def _note_moe(self, span, counters) -> None:
-        """A harvested dispatch's expert counters (None for a family
-        without routed experts): into ``stats`` and onto the phase span
-        the host harvests them under."""
+    def _note_counters(self, span, counters) -> None:
+        """A harvested dispatch's counters (None for a family that
+        returns none): the routed experts', or the block selection's,
+        into ``stats`` and onto the phase span the host harvests them
+        under."""
         if counters is None:
             return
+        if isinstance(counters, list):  # a chunked prompt's windows
+            counters = sum(np.asarray(each) for each in counters)
         counters = np.asarray(counters)
+        if self.stateful:
+            kept, visible, queries = (int(n) for n in counters)
+            self.stats["sparse_kept"] += kept
+            self.stats["sparse_visible"] += visible
+            self.stats["sparse_queries"] += queries
+            span.set(
+                sparse_kept=kept, sparse_visible=visible,
+                sparse_queries=queries,
+            )
+            return
         routed, held, rows = (int(n) for n in counters[:3])
         by_expert = [int(n) for n in counters[3:]]
         stats = self.stats
@@ -2582,6 +2674,13 @@ class DecodeEngine:
                 f"logit_bias has {len(bias)} entries; this engine supports "
                 f"at most {self.MAX_LOGIT_BIAS}"
             )
+        if self.stateful and (
+            request.export_handoff or request.kv_import is not None
+        ):
+            raise ValueError(
+                "the hybrid family has no handoff rows (export_handoff / "
+                "kv_import: the payload holds paged GQA rows and no state)"
+            )
         if self.config.mla is not None:
             # what a REQUEST can ask of the latent family that it cannot
             # take (the engine's switches were refused when it was built)
@@ -2907,11 +3006,13 @@ class DecodeEngine:
         divergence point and overwrites the stale rows beyond it."""
         slot = self.slots[index]
         prompt = request.prompt_tokens
-        if not (
+        if self.stateful or not (
             request.session_id is not None
             and slot.session_id == request.session_id
             and slot.history
         ):
+            # (a recurrent state has no snapshot at the shared prefix's
+            # end: the hybrid family's follow-ups prefill cold)
             return None
         lcp = self._lcp(prompt, slot.history)
         if lcp == len(prompt):
@@ -3065,14 +3166,19 @@ class DecodeEngine:
         bucket are prefilled in ONE batched device call, and warm-session
         follow-ups sharing a suffix bucket likewise batch into one
         prefill-at-offset dispatch (batches split into power-of-two group
-        sizes so compilations stay bounded)."""
+        sizes so compilations stay bounded). A cold prompt past the
+        largest bucket takes a slot alone, and while some slot decodes
+        only one of them a call."""
         if self.mixed:
             return self._admit_mixed()
         if self.paged:
             return self._admit_paged()
         self._shed_expired()
         self._drop_cancelled()
-        while self._pending:
+        # whether this call has admitted a cold prompt past the largest
+        # bucket, and whether the next one waits for the cycle's chunk
+        long_cold = held = False
+        while self._pending and not held:
             cold: List[Tuple[int, GenerationRequest]] = []
             cold_bucket: Optional[int] = None
             # suffix bucket -> [(slot index, request, reused prefix len)]
@@ -3204,9 +3310,22 @@ class DecodeEngine:
                     # needs_long with an in-round source: the source's
                     # prefill hasn't dispatched yet — fall through cold
                 if prompt_len > largest:
+                    if long_cold and self._any_ready():
+                        # a chunked prompt holds the device for all its
+                        # windows, and every decoding slot waits them
+                        # out: while slots decode, ONE such prompt a
+                        # cycle, so a stall is one prompt long however
+                        # many slots came free together (admitted all at
+                        # once, the slots that were freed together stay
+                        # together, and their answers come in waves: a
+                        # stall of several prompts, then nothing)
+                        held = True
+                        self.stats["long_prompts_held"] += 1
+                        break
                     self._pending.pop(position)
                     self.slots[index].request = request  # reserve the slot
                     self._prefill_long(index, request, 0)
+                    long_cold = True
                     progressed = True
                     continue
                 bucket = _bucket(prompt_len, self.prefill_buckets)
@@ -3687,6 +3806,10 @@ class DecodeEngine:
                     "evicted_recompute",
                     min(cached, len(request.prompt_tokens)) - reused,
                 )
+        if self.stateful and not reused:
+            # the prefill at position 0 starts the slot's recurrent state
+            # from zeros (hybrid_sparse_linear.window_attends)
+            self.stats["state_resets"] += 1
         slot.generated = []
         slot.logprobs = []
         slot.tops = [] if self.logprobs_topk else None
@@ -3963,9 +4086,17 @@ class DecodeEngine:
             windows.append((position, largest))
             position += largest
         tail_bucket = _bucket(total - position, self.prefill_buckets)
-        # shift the tail window left so offset + bucket == total
-        windows.append((max(0, total - tail_bucket), tail_bucket))
-        with self._prefill_phase("long", tail_bucket, [index]) as batch_id:
+        if self.stateful:
+            # a recurrent state cannot be taught a position twice: the
+            # tail window starts where the last one ended, right-padded
+            # (rows past max_seq_len are dropped by the write)
+            windows.append((position, tail_bucket))
+        else:
+            # shift the tail window left so offset + bucket == total
+            windows.append((max(0, total - tail_bucket), tail_bucket))
+        with self._prefill_phase(
+            "long", tail_bucket, [index], offset=reused, windows=len(windows),
+        ) as batch_id:
             self._dispatch_long(
                 index, request, reused, windows, batch_id
             )
@@ -3986,6 +4117,7 @@ class DecodeEngine:
         self._stamp_dispatch(
             [request], batch_id, windows[-1][1]
         )
+        counted = []  # every window's counters; the host sums them
         for step, (offset, bucket) in enumerate(windows):
             chunk = prompt[offset:offset + bucket]
             tokens = np.zeros((1, bucket), dtype=np.int32)
@@ -4011,11 +4143,13 @@ class DecodeEngine:
                 self.params, self.cache, *host_args[:4], *paged_args,
                 self._counts, *host_args[4:],
             )
+            if moe is not None:
+                counted.append(moe)
             if step == len(windows) - 1:
                 # only the final window's sampled token is the real first
                 # token; intermediate windows' samples are discarded
                 self._launched(
-                    [(index, request)], (sampled, lps, tops, moe),
+                    [(index, request)], (sampled, lps, tops, counted or None),
                     {index: reused} if reused else {}, started, batch_id,
                 )
         self.stats["warm_prefill_calls" if reused else "prefill_calls"] += 1
@@ -4066,16 +4200,19 @@ class DecodeEngine:
         })
 
     @contextlib.contextmanager
-    def _prefill_phase(self, kind: str, bucket: int, slot_ids: List[int]):
+    def _prefill_phase(self, kind: str, bucket: int, slot_ids: List[int],
+                       **chunked):
         """One prefill dispatch (batch build and jit call) as a child
         span of ``engine.admit``; yields the batch's number, which its
-        requests' ring records carry too (``runtime/journey.py``)."""
+        requests' ring records carry too (``runtime/journey.py``). A
+        chunked prompt's span also says where it starts (``offset``) and
+        in how many ``windows`` it is taught."""
         self._prefill_batches += 1
         with self._phase(
             "engine.prefill_dispatch",
             kind=kind, bucket=bucket, rows=len(slot_ids),
             batch=self._prefill_batches,
-            slots=":".join(map(str, slot_ids)),
+            slots=":".join(map(str, slot_ids)), **chunked,
         ):
             yield self._prefill_batches
 
@@ -4122,7 +4259,7 @@ class DecodeEngine:
                 rows=len(record["group"]), batch=record["batch"],
             ) as span:
                 self._harvest_record(record)
-                self._note_moe(span, record.get("moe"))
+                self._note_counters(span, record.get("moe"))
                 # rows still live after their first token, with no decode
                 # dispatch since their launch: they ride the next one
                 joined = sum(
@@ -5073,7 +5210,7 @@ class DecodeEngine:
             if tops is not None:  # ([S, steps, K] ids, [S, steps, K] lps)
                 tops = (np.asarray(tops[0]), np.asarray(tops[1]))
         with self._emit_span() as span:
-            self._note_moe(span, inflight.get("out_moe"))
+            self._note_counters(span, inflight.get("out_moe"))
             self._account_decode(
                 inflight, out_host, lps_host, tops, time.perf_counter()
             )

@@ -16,9 +16,12 @@ body (:func:`_block`). What a program brings is its prelude (positions
 and masks), its head, and its ``attend``: the attention, which owns the
 cache (projects, ropes, writes, attends). An attention kind is a set of
 attends and a cache layout: GQA's are in this file, the latent kind's in
-``latent_moe.py``; :func:`_kinds` says which a config has. Every serving
-program returns ``(cache, logits, expert counters)``, the counters None
-(an empty pytree) without routed experts.
+``latent_moe.py``, the two kinds a hybrid config mixes layer by layer
+(``config.mixers``: linear attention with a recurrent state, block-sparse
+GQA) in ``hybrid_sparse_linear.py``; :func:`_kinds` says which a config
+has and :func:`_layers_of` cuts its layers into runs of one kind. Every
+serving program returns ``(cache, logits, counters)``: the routed
+experts', the block selection's, or None (an empty pytree).
 
 Logical sharding axes per parameter feed the mesh rules in
 ``langstream_tpu.parallel.mesh`` (tp shards heads/mlp, fsdp shards embed).
@@ -53,6 +56,8 @@ from langstream_tpu.ops.moe import moe_mlp, moe_mlp_held
 from langstream_tpu.ops.norms import rms_norm
 from langstream_tpu.ops.rope import apply_rope, rope_frequencies
 from langstream_tpu.parallel.mesh import L
+from langstream_tpu.ops.block_sparse_attention import Selection
+from langstream_tpu.providers.jax_local import hybrid_sparse_linear as hybrid
 from langstream_tpu.providers.jax_local import latent_moe
 from langstream_tpu.providers.jax_local.quant import qeinsum
 
@@ -87,9 +92,24 @@ class RoutedExperts:
     scaling_factor: float
 
 
+@dataclasses.dataclass(frozen=True)
+class HybridMixers:
+    """What the mixers of a config with per-layer ``mixers`` need beside
+    the GQA sizes (which its sparse layers take): the linear-attention
+    layers' heads, and the block selection of the sparse ones
+    (hybrid_sparse_linear.py)."""
+    lightning_heads: int
+    lightning_head_dim: int
+    selection: Selection = Selection()
+
+
 def zero_counters(config: "LlamaConfig"):
-    """The expert counters a scan over steps or layers starts from (int32
-    ``[3 + held]``); None, an empty pytree, without routed experts."""
+    """The counters a program returns with its outputs, as a scan over a
+    chunk's steps starts them: the routed experts' (int32 ``[3 +
+    held]``), the block selection's (int32 ``[3]``), or None, an empty
+    pytree, for a family with neither."""
+    if config.mixers is not None:
+        return hybrid.zero_counters()
     if config.experts is None:
         return None
     return jnp.zeros((3 + config.experts.held,), jnp.int32)
@@ -132,6 +152,17 @@ class LlamaConfig:
     # set, or both None.
     mla: Optional[LatentAttention] = None
     experts: Optional[RoutedExperts] = None
+    # The hybrid family (hybrid_sparse_linear.py): the mixer of every
+    # layer by kind ("sparse" | "lightning") and what they need; both set,
+    # or both None (every layer GQA, or latent with ``mla``).
+    mixers: Optional[Tuple[str, ...]] = None
+    hybrid: Optional[HybridMixers] = None
+    # muP scalings (MiniCPM): x *= embedding_scale after the lookup, both
+    # residual branches times residual_scale, the final hidden state over
+    # logit_divisor before the head. None leaves the program as it is.
+    embedding_scale: Optional[float] = None
+    residual_scale: Optional[float] = None
+    logit_divisor: Optional[float] = None
     dtype: Any = jnp.bfloat16
     # Pallas flash prefill (TPU only; tp-sharded meshes route it through
     # shard_map over the head axis — see _prefill_attn).
@@ -293,6 +324,48 @@ class LlamaConfig:
         )
 
     @classmethod
+    def minicpm_sala(cls, max_seq_len: int = 16384) -> "LlamaConfig":
+        """MiniCPM-SALA (HF openbmb/MiniCPM-SALA): 24 lightning
+        linear-attention layers beside 8 block-sparse GQA layers (at 0,
+        9, 16, 17, 22, 29, 30, 31), q/k norms, output gates, no rotation
+        in the sparse layers, MiniCPM's muP scalings. The selection's
+        sizes are MiniCPM4's ``sparse_config`` (the config carries none)."""
+        sparse_at = (0, 9, 16, 17, 22, 29, 30, 31)
+        return cls(
+            vocab_size=73448, hidden_size=4096, intermediate_size=16384,
+            num_layers=32, num_heads=32, num_kv_heads=2, head_dim=128,
+            rope_theta=10000.0, max_seq_len=max_seq_len, norm_eps=1e-6,
+            mixers=tuple(
+                "sparse" if i in sparse_at else "lightning" for i in range(32)
+            ),
+            hybrid=HybridMixers(lightning_heads=32, lightning_head_dim=128),
+            embedding_scale=12.0, residual_scale=1.4 / math.sqrt(32),
+            logit_divisor=4096 / 256,
+        )
+
+    @classmethod
+    def tiny_hybrid(cls, max_seq_len: int = 256) -> "LlamaConfig":
+        """Test-size shape of the hybrid family: 5 layers (sparse at 0, 3
+        and 4), 4 heads of 16 over 2 kv heads, 4 lightning heads; the
+        selection at ``dense_len`` 32 with blocks of 8, the 2 best beside
+        a window of 16."""
+        return cls(
+            vocab_size=256, hidden_size=64, intermediate_size=128,
+            num_layers=5, num_heads=4, num_kv_heads=2, head_dim=16,
+            rope_theta=10000.0, max_seq_len=max_seq_len, norm_eps=1e-6,
+            mixers=("sparse", "lightning", "lightning", "sparse", "sparse"),
+            hybrid=HybridMixers(
+                lightning_heads=4, lightning_head_dim=16,
+                selection=Selection(
+                    kernel_size=4, kernel_stride=2, block_size=8, topk=2,
+                    init_blocks=1, window_size=16, dense_len=32,
+                ),
+            ),
+            embedding_scale=12.0, residual_scale=1.4 / math.sqrt(5),
+            logit_divisor=4.0, dtype=jnp.float32,
+        )
+
+    @classmethod
     def tiny_qwen2(cls, max_seq_len: int = 256) -> "LlamaConfig":
         """Test-size Qwen-2 shape (qkv biases on)."""
         return dataclasses.replace(cls.tiny(max_seq_len), qkv_bias=True)
@@ -335,6 +408,7 @@ class LlamaConfig:
             "tiny-qwen2": cls.tiny_qwen2,
             "deepseek-v2": cls.deepseek_v2,
             "tiny-deepseek-v2": cls.tiny_deepseek_v2,
+            "minicpm-sala": cls.minicpm_sala, "tiny-hybrid": cls.tiny_hybrid,
         }
         preset = clean.pop("preset", None)
         # a chip's share of the routed experts, beside the preset
@@ -352,6 +426,18 @@ class LlamaConfig:
                 clean[name] = record(**{
                     k.replace("-", "_"): v for k, v in clean[name].items()
                 })
+        if isinstance(clean.get("hybrid"), dict):
+            sizes = {
+                k.replace("-", "_"): v for k, v in clean["hybrid"].items()
+            }
+            if isinstance(sizes.get("selection"), dict):
+                sizes["selection"] = Selection(**{
+                    k.replace("-", "_"): v
+                    for k, v in sizes["selection"].items()
+                })
+            clean["hybrid"] = HybridMixers(**sizes)
+        if clean.get("mixers") is not None:
+            clean["mixers"] = tuple(clean["mixers"])
         if preset:
             config = dataclasses.replace(
                 presets[preset](),
@@ -373,6 +459,25 @@ class LlamaConfig:
                 "latent attention and routed experts come together "
                 "(latent_moe.py): set both `mla` and `experts`, or neither"
             )
+        if (config.mixers is None) != (config.hybrid is None):
+            raise ValueError(
+                "per-layer mixers come with their sizes "
+                "(hybrid_sparse_linear.py): set both `mixers` and `hybrid`, "
+                "or neither"
+            )
+        if config.mixers is not None:
+            if (
+                len(config.mixers) != config.num_layers
+                or set(config.mixers) - set(hybrid.KINDS)
+                or config.mla is not None
+                or config.hybrid.lightning_head_dim != config.dims_per_head
+            ):
+                raise ValueError(
+                    f"inconsistent mixers for {config.num_layers} layers: "
+                    f"{config.mixers} (kinds: {hybrid.KINDS}; no latent "
+                    "attention; one head dim for both kinds)"
+                )
+            config.hybrid.selection.check()
         experts = config.experts
         if experts is not None and not (
             0 <= experts.held_first
@@ -385,8 +490,8 @@ class LlamaConfig:
         return config
 
     def num_params(self) -> int:
-        if _kinds(self).attention == "latent":
-            return latent_moe.num_params(self)
+        if _family_of(self) is not None:
+            return _family_of(self).num_params(self)
         head_dim = self.dims_per_head
         attn = self.hidden_size * head_dim * (2 * self.num_heads + 2 * self.num_kv_heads)
         mlp = 3 * self.hidden_size * self.intermediate_size
@@ -399,8 +504,8 @@ class LlamaConfig:
 
 def init_params(config: LlamaConfig, seed: int = 0) -> Dict[str, jnp.ndarray]:
     """Random-init (scaled normal) parameter pytree with stacked layers."""
-    if _kinds(config).attention == "latent":
-        return latent_moe.init_params(config, seed)
+    if _family_of(config) is not None:
+        return _family_of(config).init_params(config, seed)
     key = jax.random.PRNGKey(seed)
     keys = jax.random.split(key, 10)
     h, f, v = config.hidden_size, config.intermediate_size, config.vocab_size
@@ -457,8 +562,8 @@ def init_params(config: LlamaConfig, seed: int = 0) -> Dict[str, jnp.ndarray]:
 
 def logical_axes(config: LlamaConfig) -> Dict[str, Any]:
     """Logical sharding axes per parameter (fed to parallel.mesh rules)."""
-    if _kinds(config).attention == "latent":
-        return latent_moe.logical_axes(config)
+    if _family_of(config) is not None:
+        return _family_of(config).logical_axes(config)
     if config.num_experts:
         mlp_axes = {
             "w_gate": L("layers", "expert", "embed", "mlp"),
@@ -543,12 +648,16 @@ def init_cache(
     The forward paths detect quantization by the ``k_scale`` key.
 
     The latent family's cache is one leaf of latents instead
-    (``latent_moe.init_cache``)."""
+    (``latent_moe.init_cache``), the hybrid family's a recurrent state
+    beside K, V and compressed keys (``hybrid_sparse_linear.init_cache``)."""
     max_len = max_len or config.max_seq_len
-    if _kinds(config).attention == "latent":
+    family = _family_of(config)
+    if family is not None:
         if kv_quant:
-            raise ValueError("the latent cache has no int8 form")
-        return latent_moe.init_cache(config, batch, max_len)
+            raise ValueError(
+                f"the {_kinds(config).attention} cache has no int8 form"
+            )
+        return family.init_cache(config, batch, max_len)
     pack = flash_decode_pack(config, max_len, kv_quant, tp) or 1
     shape = (
         config.num_layers, batch, max_len, config.num_kv_heads // pack,
@@ -599,8 +708,8 @@ def _unpack_kv(config: LlamaConfig, rows: jnp.ndarray) -> jnp.ndarray:
 def cache_logical_axes(
     kv_quant: bool = False, config: Optional[LlamaConfig] = None
 ) -> Dict[str, Any]:
-    if config is not None and _kinds(config).attention == "latent":
-        return latent_moe.cache_logical_axes()
+    if config is not None and _family_of(config) is not None:
+        return _family_of(config).cache_logical_axes()
     # a packed leaf's fourth axis is still kv heads, ``pack`` to a row:
     # ``flash_decode_pack`` packs only where whole rows fall to a shard
     axes: Dict[str, Any] = {
@@ -752,8 +861,22 @@ def validate_family_params(
 
 
 class Kinds(NamedTuple):
-    attention: str           # "gqa" | "latent" (latent_moe.py)
+    attention: str           # "gqa" | "latent" (latent_moe.py) | "hybrid"
+                             # (hybrid_sparse_linear.py: a kind a layer,
+                             # ``config.mixers``)
     cache: Tuple[str, ...]   # the cache's leaves, as every program carries them
+
+
+class Run(NamedTuple):
+    """Consecutive layers of one kind, as :func:`_run_layers` takes them."""
+    layers: Any           # scanned: one tuple of stacked leaves ``[count,
+                          # ...]``; unrolled: a list of per-layer tuples
+    first: int = 0        # the index ``attend`` is given for the first
+    kind: Optional[str] = None   # which of a program's attends, where it
+                                 # brings one a kind
+    unroll: bool = False
+    experts: Any = None   # the routed experts' stacks of the run's
+                          # feed-forward (None: :func:`_mlp_block`'s)
 
 
 def _kinds(
@@ -761,14 +884,24 @@ def _kinds(
 ) -> Kinds:
     """What a config's layers are made of, asked here and nowhere else:
     the attention kind and, with it, the cache's leaves (an int8 GQA
-    cache is told by its ``k_scale`` leaf). The feed-forward kinds follow
-    the stacks of :func:`_layers_of`. ROADMAP D3 (per-layer block kinds
-    on the config) takes this function's place."""
+    cache is told by its ``k_scale`` leaf). A config with per-layer
+    ``mixers`` answers ``hybrid``: its layers' kinds are that list, and
+    :func:`_layers_of` cuts it into runs. The feed-forward kinds follow
+    the runs of :func:`_layers_of`."""
+    if config.mixers is not None:
+        return Kinds("hybrid", hybrid.CACHE)
     if config.mla is not None:
         return Kinds("latent", ("latent",))
     if cache is not None and "k_scale" in cache:
         return Kinds("gqa", ("k", "v", "k_scale", "v_scale"))
     return Kinds("gqa", ("k", "v"))
+
+
+def _family_of(config: LlamaConfig):
+    """The file that holds a family's parameters, cache and attends where
+    it is not this one: ``latent_moe``, ``hybrid_sparse_linear``, or None
+    for the uniform GQA stack."""
+    return {"latent": latent_moe, "hybrid": hybrid}.get(_kinds(config).attention)
 
 
 def _stack_layer_params(params: Dict[str, jnp.ndarray], config=None):
@@ -779,14 +912,15 @@ def _stack_layer_params(params: Dict[str, jnp.ndarray], config=None):
     without them — None is an empty pytree, so scan passes it through
     untouched. With ``config`` given, validates the family tensors are
     actually present first (see :func:`validate_family_params`)."""
-    if config is not None and _kinds(config).attention == "latent":
+    if config is not None and _kinds(config).attention != "gqa":
         # the programs that come through here have no attend for the
-        # latent family's cache yet (the dense layout's three do, through
-        # :func:`_layers_of`)
+        # latent or the hybrid family's cache yet (the dense layout's
+        # three do, through :func:`_layers_of`)
         raise NotImplementedError(
-            "this program has no latent-attention form: the family runs "
-            "the dense layout's prefill, prefill_at_offset and decode_step "
-            "only (latent_moe.py holds their attends)"
+            f"this program has no {_kinds(config).attention}-attention "
+            "form: the family runs the dense layout's prefill, "
+            "prefill_at_offset and decode_step only (latent_moe.py and "
+            "hybrid_sparse_linear.py hold their attends)"
         )
     if config is not None:
         validate_family_params(config, params)
@@ -805,14 +939,29 @@ def _stack_layer_params(params: Dict[str, jnp.ndarray], config=None):
     )
 
 
-def _layers_of(config: LlamaConfig, params):
-    """A config's layers as :func:`_run_layers` takes them: (leading
-    layers of another kind than the scanned run, one tuple each; the
-    stacked layers; the routed experts' stacks, or None where the
-    feed-forward is :func:`_mlp_block`'s)."""
-    if _kinds(config).attention == "latent":
-        return latent_moe.layer_stacks(config, params)
-    return (), _stack_layer_params(params, config), None
+def _layers_of(config: LlamaConfig, params) -> Tuple[Run, ...]:
+    """A config's layers as :func:`_run_layers` takes them, a :class:`Run`
+    for every stretch of one kind: the uniform stack is one; the latent
+    family's leading dense layers (unrolled) and its expert layers are
+    two; the hybrid family's follow ``config.mixers``, each run a stack
+    of its own (a lone layer is unrolled)."""
+    kind = _kinds(config).attention
+    if kind == "latent":
+        lead, layers, experts = latent_moe.layer_stacks(config, params)
+        return (
+            Run(list(lead), unroll=True),
+            Run(layers, first=len(lead), experts=experts),
+        )
+    if kind == "hybrid":
+        runs = []
+        for mixer, layers, first in hybrid.layer_runs(config, params):
+            # a lone layer is its stack's one row (a bitcast), unrolled
+            lone = jax.tree_util.tree_leaves(layers)[0].shape[0] == 1
+            if lone:
+                layers = [jax.tree_util.tree_map(lambda x: x[0], layers)]
+            runs.append(Run(layers, first, mixer, unroll=lone))
+        return tuple(runs)
+    return (Run(_stack_layer_params(params, config)),)
 
 
 def _project_qkv(normed, wq, wk, wv, biases):
@@ -859,6 +1008,8 @@ def _embed(config: LlamaConfig, params, tokens: jnp.ndarray) -> jnp.ndarray:
     x = params["embedding"][tokens].astype(config.dtype)
     if config.scale_embedding:
         x = x * jnp.asarray(math.sqrt(config.hidden_size), dtype=x.dtype)
+    if config.embedding_scale is not None:
+        x = x * jnp.asarray(config.embedding_scale, dtype=x.dtype)
     return x
 
 
@@ -897,6 +1048,8 @@ def _mlp_block(
 
 @jax.named_scope("head")
 def _logits(config: LlamaConfig, params, x):
+    if config.logit_divisor is not None:
+        x = x * jnp.asarray(1.0 / config.logit_divisor, dtype=x.dtype)
     if config.tie_embeddings:
         head = params["embedding"].T.astype(x.dtype)
         logits = jnp.einsum("...h,hv->...v", x, head).astype(jnp.float32)
@@ -983,6 +1136,8 @@ def decode_reader(config, cache, mesh=None) -> str:
     if _kinds(config).attention == "latent":
         on_kernel = latent_moe.decode_kernel_ok(config, cache["latent"])
         return "mla_decode" if on_kernel else "xla"
+    if _kinds(config).attention == "hybrid":
+        return hybrid.decode_reader(config, cache)
     if not _decode_flash_path(config, cache["k"], mesh)[0]:
         return "xla"
     return "flash_decode_int8kv" if "k_scale" in cache else "flash_decode"
@@ -1329,6 +1484,8 @@ def _block(config, x, layer, attend, index, inputs, state, *, valid,
     )
     if post_attn is not None:
         attn = _norm(config, attn, post_attn)
+    if config.residual_scale is not None:
+        attn = attn * jnp.asarray(config.residual_scale, dtype=attn.dtype)
     x = x + attn
     if experts is not None:
         # the routed experts' stacks hold the expert layers alone
@@ -1344,61 +1501,72 @@ def _block(config, x, layer, attend, index, inputs, state, *, valid,
         )
     if post_mlp is not None:
         delta = _norm(config, delta, post_mlp)
+    if config.residual_scale is not None:
+        delta = delta * jnp.asarray(config.residual_scale, dtype=delta.dtype)
     return x + delta, state, out, counted
 
 
-def _run_layers(config, layers, x, attend, *, state=None, per_layer=None,
-                valid=None, dropless=True, total=None, lead=(),
-                experts=None):
-    """The layer loop of every program: the only ``lax.scan`` over layers.
+def _run_layers(config, runs, x, attend, *, state=None, per_layer=None,
+                valid=None, dropless=True, total=None):
+    """The layer loop of every program: the only ``lax.scan`` over layers,
+    one for every scanned :class:`Run` of ``runs`` (:func:`_layers_of`).
 
     ``attend(normed, attention weights, index, inputs, state) -> (attn,
     state, out)`` is the program's attention and owns the cache: it
-    projects, ropes, writes and attends. ``state`` rides the scan as
-    carry (a cache written in place), ``per_layer`` is scanned (each
-    layer's ``inputs``: its window, a cache slab handed over as xs), and
-    ``out`` comes back stacked over the layers (a cold prefill's rows, a
-    slab handed back as ys). ``lead`` are layers of another kind than the
-    scanned run (``_layers_of``), unrolled in front of it with
-    ``inputs=None`` and a dense feed-forward. ``valid`` and ``dropless``
-    are the feed-forward's. ``total`` starts the sum of what the scanned
-    layers' feed-forwards count: the expert counters by default (None, an
-    empty pytree, without routed experts), a scalar for the MoE aux loss.
+    projects, ropes, writes and attends; a program whose layers differ in
+    kind passes one a kind, ``{kind: attend}``, and a run names its own.
+    ``index`` counts from the run's ``first``. ``state`` rides the loop as
+    carry (a cache written in place), ``per_layer`` is scanned beside a
+    lone run's layers (each layer's ``inputs``: its window, a cache slab
+    handed over as xs; an unrolled layer gets None), and ``out`` comes
+    back stacked over the layers (a cold prefill's rows, a slab handed
+    back as ys). ``valid`` and ``dropless`` are the feed-forward's.
+    ``total`` starts the sum of what the scanned layers' feed-forwards
+    count: the expert counters by default (None, an empty pytree, without
+    routed experts), a scalar for the MoE aux loss.
     Returns (x, state, outs, total)."""
-    outs = []
-    for index, layer in enumerate(lead):
-        x, state, out, _ = _block(
-            config, x, layer, attend, jnp.int32(index), None, state,
-            valid=valid, dropless=dropless,
-        )
-        outs.append(out)
-
-    def layer_fn(carry, scanned):
-        x, state, total = carry
-        layer, inputs, index = scanned
-        x, state, out, counted = _block(
-            config, x, layer, attend, index, inputs, state, valid=valid,
-            dropless=dropless, experts=experts,
-        )
-        if total is not None:
-            total = total + counted
-        return (x, state, total), out
-
-    if total is None:
+    if per_layer is not None and len(runs) > 1:
+        raise ValueError("per-layer inputs are scanned beside ONE run's layers")
+    if total is None and config.experts is not None:
         total = zero_counters(config)
-    count = jax.tree_util.tree_leaves(layers)[0].shape[0]
-    (x, state, total), scanned = jax.lax.scan(
-        layer_fn, (x, state, total),
-        (layers, per_layer, jnp.arange(len(lead), len(lead) + count)),
-    )
-    if outs:
-        scanned = jax.tree_util.tree_map(
-            lambda *parts: jnp.concatenate(
-                [jnp.stack(parts[:-1]), parts[-1]]
-            ),
-            *outs, scanned,
+    outs = []
+    for run in runs:
+        attend_run = attend[run.kind] if run.kind is not None else attend
+        if run.unroll:
+            each = []
+            for index, layer in enumerate(run.layers, run.first):
+                x, state, out, _ = _block(
+                    config, x, layer, attend_run, jnp.int32(index), None,
+                    state, valid=valid, dropless=dropless,
+                )
+                each.append(out)
+            outs.append(
+                jax.tree_util.tree_map(lambda *parts: jnp.stack(parts), *each)
+            )
+            continue
+
+        def layer_fn(carry, scanned, run=run, attend_run=attend_run):
+            x, state, total = carry
+            layer, inputs, index = scanned
+            x, state, out, counted = _block(
+                config, x, layer, attend_run, index, inputs, state,
+                valid=valid, dropless=dropless, experts=run.experts,
+            )
+            if total is not None:
+                total = total + counted
+            return (x, state, total), out
+
+        count = jax.tree_util.tree_leaves(run.layers)[0].shape[0]
+        (x, state, total), scanned = jax.lax.scan(
+            layer_fn, (x, state, total),
+            (run.layers, per_layer, jnp.arange(run.first, run.first + count)),
         )
-    return x, state, scanned, total
+        outs.append(scanned)
+    if len(outs) > 1:
+        outs = [jax.tree_util.tree_map(
+            lambda *parts: jnp.concatenate(parts), *outs
+        )]
+    return x, state, outs[0], total
 
 
 def _rotated_heads(config, normed, weights, freqs, positions):
@@ -1477,10 +1645,9 @@ def _prefill_scan(
             config, freqs, positions, mask, lengths, mesh,
             "k_scale" in kinds.cache,
         )
-    lead, layers, experts = _layers_of(config, params)
     x, _, rows, counters = _run_layers(
-        config, layers, x, attend, per_layer=layer_windows(config),
-        valid=mask, lead=lead, experts=experts,
+        config, _layers_of(config, params), x, attend,
+        per_layer=layer_windows(config), valid=mask,
     )
     return x, dict(zip(kinds.cache, rows)), counters
 
@@ -1516,6 +1683,13 @@ def prefill(
 ):
     """Run the prompt through the model, write the KV cache at the given
     slots, return logits of each prompt's last real token [B, V]."""
+    if _kinds(config).attention == "hybrid":
+        # the family's cold prefill is its window at offset 0, where the
+        # recurrent state starts from zeros
+        return prefill_at_offset(
+            config, params, cache, tokens, lengths, jnp.zeros_like(lengths),
+            slot_ids, freqs,
+        )
     x, new, counters = _prefill_scan(
         config, params, cache, tokens, lengths, freqs, mesh
     )
@@ -1603,6 +1777,28 @@ def _offset_attend(config, freqs, seq, lengths, offsets, slot_ids):
     return attend, mask
 
 
+def _carried(config, cache):
+    """The cache's leaves as the layer loop carries them; the hybrid
+    family's attends carry the selection's counters behind them."""
+    kinds = _kinds(config, cache)
+    leaves = tuple(cache[name] for name in kinds.cache)
+    if kinds.attention == "hybrid":
+        leaves += (zero_counters(config),)
+    return leaves
+
+
+def _uncarried(config, cache, carried, counters):
+    """(the cache with the leaves the loop carried, the program's
+    counters: the loop's own, or what the hybrid family's attends
+    carried)."""
+    kinds = _kinds(config, cache)
+    if kinds.attention == "hybrid":
+        *carried, counters = carried
+    out = dict(cache)
+    out.update(zip(kinds.cache, carried))
+    return out, counters
+
+
 # jit: device-context — runs inside the engine's jitted dispatches
 def prefill_at_offset(
     config: LlamaConfig,
@@ -1625,23 +1821,20 @@ def prefill_at_offset(
     Returns (cache, logits of each row's last real suffix token [B, V],
     the expert counters)."""
     kinds = _kinds(config, cache)
-    make = (
-        latent_moe.offset_attend if kinds.attention == "latent"
-        else _offset_attend
-    )
-    attend, mask = make(
-        config, freqs, tokens.shape[1], lengths, offsets, slot_ids
-    )
+    window = (config, freqs, tokens.shape[1], lengths, offsets, slot_ids)
+    if kinds.attention == "hybrid":
+        attend, mask = hybrid.window_attends(*window, cache["k"].shape[3])
+    elif kinds.attention == "latent":
+        attend, mask = latent_moe.offset_attend(*window)
+    else:
+        attend, mask = _offset_attend(*window)
     x = _embed(config, params, tokens)                       # [B, T, H]
-    lead, layers, experts = _layers_of(config, params)
     x, stacked, _, counters = _run_layers(
-        config, layers, x, attend,
-        state=tuple(cache[name] for name in kinds.cache),
-        per_layer=layer_windows(config), valid=mask, lead=lead,
-        experts=experts,
+        config, _layers_of(config, params), x, attend,
+        state=_carried(config, cache),
+        per_layer=layer_windows(config), valid=mask,
     )
-    out = dict(cache)
-    out.update(zip(kinds.cache, stacked))
+    out, counters = _uncarried(config, cache, stacked, counters)
     return out, _last_token_logits(config, params, x, lengths), counters
 
 
@@ -1752,7 +1945,7 @@ def _run_over_slabs(config, params, cache, x, attend, valid):
     step's). Returns (cache, x)."""
     names = _kinds(config, cache).cache
     x, _, slabs, _ = _run_layers(
-        config, _stack_layer_params(params, config), x, attend,
+        config, (Run(_stack_layer_params(params, config)),), x, attend,
         per_layer=(
             tuple(cache[name] for name in names), layer_windows(config)
         ),
@@ -1936,10 +2129,15 @@ def decode_step(
     if write_mask is None:
         write_mask = jnp.ones((slots,), dtype=bool)
     kinds = _kinds(config, cache)
-    stacked = tuple(cache[name] for name in kinds.cache)
+    stacked = _carried(config, cache)
     x = _embed(config, params, tokens)  # [S, H]
     windows = layer_windows(config)
-    if kinds.attention == "latent":
+    if kinds.attention == "hybrid":
+        attend = hybrid.decode_attends(
+            config, freqs, lengths, positions, write_mask, cache["k"].shape[3]
+        )
+        x, valid = x[:, None], None
+    elif kinds.attention == "latent":
         attend = latent_moe.decode_attend(
             config, freqs, stacked[0], lengths, positions, write_mask
         )
@@ -1953,13 +2151,11 @@ def decode_step(
         # decode groups are tiny (S = slots) so dropless capacity is cheap;
         # inactive slots can't evict anyone, so no valid mask is needed
         valid = None
-    lead, layers, experts = _layers_of(config, params)
     x, stacked, _, counters = _run_layers(
-        config, layers, x, attend, state=stacked, per_layer=windows,
-        valid=valid, lead=lead, experts=experts,
+        config, _layers_of(config, params), x, attend, state=stacked,
+        per_layer=windows, valid=valid,
     )
-    out = dict(cache)
-    out.update(zip(kinds.cache, stacked))
+    out, counters = _uncarried(config, cache, stacked, counters)
     x = _norm(config, x.reshape(slots, -1), params["final_norm"])
     return out, _logits(config, params, x), counters
 
@@ -2179,8 +2375,8 @@ def apply_layers(
         return attn, state, None
 
     x, _, _, aux = _run_layers(
-        config, layer_inputs, x, attend, per_layer=windows, valid=mask,
-        dropless=dropless, total=jnp.zeros((), dtype=jnp.float32),
+        config, (Run(layer_inputs),), x, attend, per_layer=windows,
+        valid=mask, dropless=dropless, total=jnp.zeros((), dtype=jnp.float32),
     )
     return x, aux
 
@@ -2216,6 +2412,47 @@ def forward(
 # ---------------------------------------------------------------------- #
 # HuggingFace checkpoint import
 # ---------------------------------------------------------------------- #
+def _hybrid_from_hf(hf_config) -> Dict[str, Any]:
+    """The hybrid family's fields from a published ``minicpm_sala``
+    config: the layers' kinds from ``mixer_types``, the muP scalings, the
+    selection's sizes from ``sparse_config`` where the config carries one
+    (MiniCPM4's otherwise). A switch the family's mixers do not compute
+    is refused by its name."""
+    wanted = dict(
+        qk_norm=True, attn_use_rope=False, lightning_use_rope=True,
+        use_output_gate=True, use_output_norm=True, attn_use_output_gate=True,
+    )
+    wrong = [
+        f"{name}={getattr(hf_config, name, None)!r}"
+        for name, value in wanted.items()
+        if getattr(hf_config, name, value) != value
+    ]
+    if hf_config.lightning_nkv != hf_config.lightning_nh:
+        wrong.append(f"lightning_nkv={hf_config.lightning_nkv!r}")
+    if wrong:
+        raise ValueError(
+            "unsupported minicpm_sala switches: " + ", ".join(wrong)
+        )
+    kinds = {"minicpm4": "sparse", "lightning-attn": "lightning"}
+    selection = getattr(hf_config, "sparse_config", None) or {}
+    return dict(
+        mixers=tuple(kinds[name] for name in hf_config.mixer_types),
+        hybrid=HybridMixers(
+            lightning_heads=hf_config.lightning_nh,
+            lightning_head_dim=hf_config.lightning_head_dim,
+            selection=Selection(**{
+                key: int(selection[key])
+                for key in Selection.__dataclass_fields__ if key in selection
+            }),
+        ),
+        embedding_scale=float(hf_config.scale_emb),
+        residual_scale=hf_config.scale_depth / math.sqrt(
+            hf_config.num_hidden_layers
+        ),
+        logit_divisor=hf_config.hidden_size / hf_config.dim_model_base,
+    )
+
+
 def config_from_hf(hf_config) -> LlamaConfig:
     rope_scaling = normalize_rope_scaling(
         getattr(hf_config, "rope_scaling", None)
@@ -2235,6 +2472,8 @@ def config_from_hf(hf_config) -> LlamaConfig:
                     f"unsupported gemma2 layer_types pattern: {layer_types}"
                 )
     family = {}
+    if getattr(hf_config, "model_type", "") == "minicpm_sala":
+        family = _hybrid_from_hf(hf_config)
     if getattr(hf_config, "model_type", "") == "qwen2":
         family = dict(qkv_bias=True)
     if gemma2:

@@ -95,6 +95,11 @@ def quantize_params(
     """Quantize the large matmul weights of a stacked-params pytree.
     Embedding and norms stay full precision (lookups/elementwise).
     Idempotent: already-quantized leaves pass through."""
+    if "run0.wq" in params:
+        # the hybrid family's leaves lie in a stack a run of layers
+        from langstream_tpu.providers.jax_local import hybrid_sparse_linear
+
+        return hybrid_sparse_linear.quantize_params(params, quantize)
     out = dict(params)
     moe_names = {"w_gate", "w_up", "w_down"} if num_experts else set()
     for name in QUANTIZED_PARAMS:
@@ -119,6 +124,11 @@ def init_quantized_params(
 
     from langstream_tpu.providers.jax_local import model as model_lib
 
+    if config.mixers is not None:
+        # the hybrid family draws its int8 form itself, layer by layer
+        from langstream_tpu.providers.jax_local import hybrid_sparse_linear
+
+        return hybrid_sparse_linear.init_params(config, seed, quantized=True)
     key = jax.random.PRNGKey(seed)
     h = config.hidden_size
     scale = 1.0 / math.sqrt(h) / 127.0

@@ -161,19 +161,24 @@ class CostModel:
                 paged_kernel=None,
                 tp_shards=1,
             )
+        layers = config.num_layers
+        if getattr(config, "mixers", None) is not None:
+            # the hybrid family: KV rows in its sparse layers alone (the
+            # linear-attention layers' state does not grow with a token)
+            layers = sum(1 for mixer in config.mixers if mixer == "sparse")
         if kv_quant:
             # int8 values + one f32 scale per (layer, pos, kv_head) for
             # each of k and v
-            kv_row_bytes = 2 * config.num_layers * config.num_kv_heads * (
+            kv_row_bytes = 2 * layers * config.num_kv_heads * (
                 head_dim + 4
             )
         else:
             kv_row_bytes = (
-                2 * config.num_layers * config.num_kv_heads * head_dim * 2
+                2 * layers * config.num_kv_heads * head_dim * 2
             )  # k+v, bf16
         return cls(
             params=params,
-            num_layers=config.num_layers,
+            num_layers=layers,
             num_heads=config.num_heads,
             num_kv_heads=config.num_kv_heads,
             head_dim=head_dim,

@@ -370,14 +370,21 @@ def _dims(result_type):
     ]
 
 
-def _compile_decode_chunk(
+def _compile_decode_chunk(*args, **kwargs):
+    """:func:`_lower_decode_chunk`, compiled for the described chip.
+    Returns (compiled, the cache's shapes)."""
+    lowered, cache = _lower_decode_chunk(*args, **kwargs)
+    return lowered.compile(), cache
+
+
+def _lower_decode_chunk(
     monkeypatch, preset, slots, kv_quant, place, mesh=None, int8_weights=False
 ):
     """The engine's dense chunk at a preset's published widths and full
     depth, cut to what touches the cache (a 4-step scan of decode_step +
-    greedy pick, the cache donated: engine._get_decode), compiled for the
+    greedy pick, the cache donated: engine._get_decode), lowered for the
     described chip. ``place(tree, axes)`` gives shapes their shardings.
-    Returns (compiled, the cache's shapes)."""
+    Returns (lowered, the cache's shapes)."""
     import langstream_tpu.ops.flash_attention as flash_attention
     from langstream_tpu.ops.rope import rope_frequencies
     from langstream_tpu.providers.jax_local import model as model_lib
@@ -425,7 +432,144 @@ def _compile_decode_chunk(
         place(jax.ShapeDtypeStruct((slots,), dtype), None)
         for dtype in (jnp.int32, jnp.int32, jnp.bool_)
     ]
-    return chunk.lower(params, cache, *per_slot).compile(), cache
+    return chunk.lower(params, cache, *per_slot), cache
+
+
+# sha256 of the lowered text (a kernel's serialised body, which carries
+# its call stack's line numbers, blanked) of the two cells' decode chunks
+# as PR 32's tree lowers them with this jax. A PR that means to change
+# these programs replaces the hash; one that does not has left them alone.
+GQA_CHUNKS = {
+    "qwen25_7b": (32, True, "03ef2c2b5293a23fc5607ed83876889d257ad98a1d7dae2a6435069ee0dc771a"),
+    "qwen25_0_5b": (128, False, "d518356c34948acbd2ba4853a1a7e4f062c4db7b036349530925e30bc90aeeb3"),
+}
+
+
+@pytest.mark.parametrize("preset", list(GQA_CHUNKS))
+def test_the_gqa_decode_chunks_lower_as_they_did(one_chip, monkeypatch, preset):
+    """The flagship's and the 0.5B's decode chunks are byte for byte what
+    they were before the layer loop learned to run layers of different
+    kinds (debug locations inside a kernel's body aside)."""
+    import hashlib
+
+    slots, int8_weights, golden = GQA_CHUNKS[preset]
+    if jax.__version__ != "0.9.0":
+        pytest.skip("the hashes are of the text jax 0.9.0 lowers")
+
+    def place(tree, axes):
+        del axes
+        return jax.tree_util.tree_map(
+            lambda leaf: _spec(leaf.shape, leaf.dtype, one_chip), tree
+        )
+
+    lowered, _ = _lower_decode_chunk(
+        monkeypatch, preset, slots, False, place, int8_weights=int8_weights
+    )
+    text = re.sub(
+        r'backend_config = "(?:[^"\\]|\\.)*"', 'backend_config = "<kernel>"',
+        lowered.as_text(),
+    )
+    assert 'kernel_name = "flash_decode"' in text
+    assert hashlib.sha256(text.encode()).hexdigest() == golden
+
+
+HYBRID_SLOTS, HYBRID_LEN = 8, 16384
+
+
+def _compile_hybrid(monkeypatch, one_chip, program):
+    """MiniCPM-SALA whole, in int8, as ``minicpm-sala-int8.longdocs`` runs
+    it (32 layers, 8 slots x 16,384): the decode chunk (a 4-step scan of
+    decode_step + greedy pick) or a 2,048-token prefill window at an
+    offset, the cache donated, for the described chip. Returns (lowered,
+    compiled, the cache's shapes)."""
+    import langstream_tpu.ops.flash_attention as flash_attention
+    from langstream_tpu.providers.jax_local import hybrid_sparse_linear
+    from langstream_tpu.providers.jax_local import model as model_lib
+    from langstream_tpu.providers.jax_local.quant import init_quantized_params
+
+    monkeypatch.setattr(flash_attention, "on_tpu", lambda: True)
+    monkeypatch.setattr(hybrid_sparse_linear, "on_tpu", lambda: True)
+    config = model_lib.LlamaConfig.minicpm_sala(HYBRID_LEN)
+    freqs = model_lib.model_freqs(config)
+
+    def place(tree):
+        return jax.tree_util.tree_map(
+            lambda leaf: _spec(leaf.shape, leaf.dtype, one_chip), tree
+        )
+
+    params = place(jax.eval_shape(lambda: init_quantized_params(config, seed=0)))
+    cache = place(jax.eval_shape(
+        lambda: model_lib.init_cache(config, HYBRID_SLOTS, HYBRID_LEN)
+    ))
+    if program == "decode_chunk":
+
+        @functools.partial(jax.jit, donate_argnums=(1,))
+        def run(params, cache, tokens, lengths, active):
+            def body(carry, _):
+                cache, tokens, lengths, counted = carry
+                cache, logits, step = model_lib.decode_step(
+                    config, params, cache, tokens, lengths, freqs, active
+                )
+                picked = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                picked = jnp.where(active, picked, 0)
+                lengths = jnp.where(active, lengths + 1, lengths)
+                return (cache, picked, lengths, counted + step), picked
+
+            (cache, _, _, counted), out = jax.lax.scan(
+                body, (cache, tokens, lengths, model_lib.zero_counters(config)),
+                None, length=4,
+            )
+            return cache, out.T, counted
+
+        args = [
+            place(jax.ShapeDtypeStruct((HYBRID_SLOTS,), dtype))
+            for dtype in (jnp.int32, jnp.int32, jnp.bool_)
+        ]
+    else:
+
+        @functools.partial(jax.jit, donate_argnums=(1,))
+        def run(params, cache, tokens, lengths, offsets, slot_ids):
+            return model_lib.prefill_at_offset(
+                config, params, cache, tokens, lengths, offsets, slot_ids, freqs
+            )
+
+        args = [
+            place(jax.ShapeDtypeStruct(shape, jnp.int32))
+            for shape in ((1, 2048), (1,), (1,), (1,))
+        ]
+    lowered = run.lower(params, cache, *args)
+    return lowered, lowered.compile(), cache
+
+
+@pytest.mark.parametrize("program,kernels", [
+    ("decode_chunk", ("lightning_decode", "sparse_block_decode")),
+    ("prefill_window", ("lightning_prefill", "sparse_block_prefill")),
+])
+def test_hybrid_family_compiles_at_the_cells_shapes(
+    one_chip, monkeypatch, program, kernels
+):
+    """``minicpm-sala-int8.longdocs``' two hot programs pass the chip's
+    compiler at published widths and full depth and fit beside 9.8 GB of
+    int8 weights: the whole hybrid cache (recurrent state, K, V,
+    compressed keys) is an aliased output, the program's temp stays under
+    1.5 GB (no weight stack sliced into a copy, no layer's K or V slab
+    taken out of the stack), and the kernels reach the lowered text under
+    the names the trace reduction looks for: one call a run of layers (4
+    lightning runs, 5 sparse)."""
+    lowered, compiled, cache = _compile_hybrid(monkeypatch, one_chip, program)
+    text = lowered.as_text()
+    for kernel in kernels:
+        assert f'kernel_name = "{kernel}"' in text, kernel
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 9
+    memory = compiled.memory_analysis()
+    cache_bytes = sum(
+        int(np.prod(leaf.shape)) * leaf.dtype.itemsize for leaf in cache.values()
+    )
+    assert cache["state"].dtype == jnp.float32
+    assert cache["state"].shape == (24, HYBRID_SLOTS, 32, 128, 128)
+    assert memory.alias_size_in_bytes >= cache_bytes, memory
+    assert memory.temp_size_in_bytes < 1.5 * 2 ** 30, memory
+    assert memory.argument_size_in_bytes < 11.5 * 2 ** 30, memory
 
 
 @pytest.mark.parametrize(

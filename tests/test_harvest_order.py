@@ -277,3 +277,54 @@ def test_the_join_counters_are_exported(engine):
         round(engine.stats["prefill_join_wait"], 6) - 1e-6
     )
     assert engine.stats["prefill_join_wait"] > 0
+
+
+# ------------------------------------------------------------------ #
+# (4) prompts past the largest bucket: one a cycle while slots decode
+# ------------------------------------------------------------------ #
+def _long(seed):
+    return prompt(seed, 70)  # three windows of the 32 bucket
+
+
+@pytest.fixture(scope="module")
+def cold_engine():
+    # no prefix reuse: a prompt asked twice is prefilled cold twice
+    engine = make_engine(prefix_cache=False)
+    yield engine
+    engine.stop()
+
+
+@pytest.mark.parametrize("decoding", [True, False], ids=["beside-a-stream", "idle"])
+def test_decoding_slots_wait_for_one_long_prompt_a_cycle(cold_engine, decoding):
+    """Two prompts past the largest bucket find two free slots at once.
+    Beside a decoding stream the second waits for the cycle's chunk, so
+    the stream stalls for one prompt's windows and not for both; with
+    nothing decoding there is no one to stall and both go at once. Either
+    way each decodes what it decodes alone."""
+    engine = cold_engine
+    alone = [solo(engine, _long(seed), 1 + CHUNK) for seed in (31, 32)]
+    held = engine.stats["long_prompts_held"]
+    log = len(engine.dispatch_log)
+    pair = [Stream(_long(seed), 1 + CHUNK) for seed in (31, 32)]
+
+    def submit_both():
+        for stream in pair:
+            engine.submit(stream.request)
+
+    if decoding:
+        runner = Stream(prompt(93, 6), 1 + 6 * CHUNK, on_first=submit_both)
+        engine.submit(runner.request)
+        runner.wait()
+    else:
+        submit_both()
+    assert [stream.wait() for stream in pair] == alone
+    kinds = [entry["kind"] for entry in engine.dispatch_log[log:]]
+    runs = [len(run) for run in "".join(kind[0] for kind in kinds).split("d") if run]
+    if decoding:
+        assert engine.stats["long_prompts_held"] - held == 1
+        assert runs == [1, 3, 3]  # the runner's bucket, then a prompt a cycle
+    else:
+        assert engine.stats["long_prompts_held"] == held
+        assert sorted(runs) in ([6], [3, 3])  # one drain took both, or one each
+    gauges = engine_lib.engines_snapshot()
+    assert gauges["jax_engine_long_prompts_held_total"] >= engine.stats["long_prompts_held"]
