@@ -251,11 +251,7 @@ class RemoteUserAgent:
                 # /info for every normal shutdown) — but in-flight RPCs
                 # (a service join() blocking in the child) must still
                 # resolve or their awaiters hang forever
-                closed = RuntimeError("isolated agent closed")
-                for future in self._pending.values():
-                    if not future.done():
-                        future.set_exception(closed)
-                self._pending.clear()
+                self._fail_pending(RuntimeError("isolated agent closed"))
                 return
             # must fail fast: a decode error (oversized frame, bad JSON)
             # that killed only the reader task would leave every
@@ -277,10 +273,13 @@ class RemoteUserAgent:
             self._crashed = AgentProcessCrashed(
                 f"isolated agent process died ({detail})"
             )
-            for future in self._pending.values():
-                if not future.done():
-                    future.set_exception(self._crashed)
-            self._pending.clear()
+            self._fail_pending(self._crashed)
+
+    def _fail_pending(self, error: BaseException) -> None:
+        for future in self._pending.values():
+            if not future.done():
+                future.set_exception(error)
+        self._pending.clear()
 
     async def _call(self, method: str, **kwargs) -> Any:
         if self._crashed is not None:
@@ -387,6 +386,10 @@ class RemoteUserAgent:
                     pass
         if self._reader_task is not None:
             self._reader_task.cancel()
+        # the read loop fails what is in flight when it sees the child's
+        # EOF, but the cancel above can reach it first (a busy machine):
+        # a service join() blocking in the child would then never resolve
+        self._fail_pending(RuntimeError("isolated agent closed"))
         try:
             os.unlink(self._socket_path)
             os.rmdir(os.path.dirname(self._socket_path))

@@ -164,7 +164,7 @@ def engines_snapshot() -> Dict[str, float]:
     decode_time = prefill_time = 0.0
     prefill_rows = prefill_join_rows = 0
     prefill_join_wait = 0.0
-    long_prompts_held = 0
+    long_prompts_held = prompts_windowed = 0
     loop_seconds = {"idle": 0.0, "admit": 0.0, "dispatch": 0.0, "emit": 0.0}
     active_slot_steps = total_slot_steps = 0
     paged_engines = 0
@@ -237,6 +237,7 @@ def engines_snapshot() -> Dict[str, float]:
         prefill_join_rows += stats["prefill_join_rows"]
         prefill_join_wait += stats["prefill_join_wait"]
         long_prompts_held += stats["long_prompts_held"]
+        prompts_windowed += stats["prompts_windowed"]
         for phase_name in loop_seconds:
             loop_seconds[phase_name] += stats[phase_name + "_time"]
         active_slot_steps += stats["active_slot_steps"]
@@ -455,6 +456,8 @@ def engines_snapshot() -> Dict[str, float]:
     # cycles in which a prompt past the largest bucket waited for the
     # decode chunk behind another's windows (_admit: one a cycle)
     out["jax_engine_long_prompts_held_total"] = float(long_prompts_held)
+    # cold prompts that fit a bucket, taught in windows of a smaller one
+    out["jax_engine_prompts_windowed_total"] = float(prompts_windowed)
     if steps:
         out["jax_engine_decode_ms_per_step"] = round(
             decode_time / steps * 1e3, 4
@@ -661,6 +664,58 @@ def _bucket(length: int, buckets: List[int]) -> int:
         if length <= size:
             return size
     return buckets[-1]
+
+
+# A stretch that fits a bucket is taught in windows of a smaller one only
+# when the windows' rows are at most this share of the bucket's. What the
+# rows saved have to pay for: every window streams the weights again
+# (7.6 GB on Qwen-2.5-7B int8), attends over the slot's whole slab in XLA
+# and not in the flash kernel, costs the host a dispatch of some 3 ms, and
+# leaves the batched cold dispatch its neighbours share. At half, the
+# default doubling bucket set never meets the rule (rows >= length > half
+# the bucket), nor does a prompt that fills most of its bucket. A constant
+# and not a setting; the chip's readings behind it are in PERF.md
+# (section 6, PR 34).
+WINDOWED_ROWS_SHARE = 0.5
+
+
+def _prefill_windows(
+    total: int, reused: int, buckets: List[int], stateful: bool
+) -> List[Tuple[int, int]]:
+    """The ``(offset, bucket)`` windows that teach ``total - reused``
+    tokens from position ``reused``, left to right: the cover of a prompt
+    by the buckets the engine has, and the only place that plans one.
+    Whole windows of the largest bucket while more than it is left; what
+    is left then fits a bucket and is one window of it, or, where that
+    wastes at least half its rows (``WINDOWED_ROWS_SHARE``), windows of
+    the smaller bucket that computes the fewest (the larger on a tie). The
+    last window is shifted left to end at the prompt's last token:
+    re-teaching a few positions (identical tokens, identical KV) is
+    cheaper than a ragged-tail program, and it never writes past
+    ``max_seq_len``. A recurrent state cannot be taught a position twice:
+    a ``stateful`` family's last window starts where the one before it
+    ended, right-padded (the write drops rows past ``max_seq_len``)."""
+    largest = buckets[-1]
+    windows: List[Tuple[int, int]] = []
+    position = reused
+    while total - position > largest:
+        windows.append((position, largest))
+        position += largest
+    left = total - position
+    fits = bucket = _bucket(left, buckets)
+    count = 1
+    rows = WINDOWED_ROWS_SHARE * fits  # the most a cover may compute
+    for smaller in buckets:  # ascending: on a tie the larger wins
+        covering = -(-left // smaller)
+        if smaller < fits and covering * smaller <= rows:
+            bucket, count, rows = smaller, covering, covering * smaller
+    for _ in range(count - 1):
+        windows.append((position, bucket))
+        position += bucket
+    windows.append(
+        (position if stateful else max(0, total - bucket), bucket)
+    )
+    return windows
 
 
 class DecodeEngine:
@@ -1261,6 +1316,11 @@ class DecodeEngine:
             # slot and waited all the same, for the decode chunk behind
             # another such prompt's windows (_admit)
             "long_prompts_held": 0,
+            # cold prompts that fit a bucket and were taught in windows of
+            # a smaller one, which computes at most half its rows
+            # (_prefill_windows); over prefill_rows: the share of prompts
+            # the cover engages on
+            "prompts_windowed": 0,
             "active_slot_steps": 0,  # sum of active slots over decode steps
             # wall-clock breakdown of everything OUTSIDE device dispatches,
             # so "unaccounted" time has a name (VERDICT r2 weak #1)
@@ -3309,8 +3369,16 @@ class DecodeEngine:
                         continue
                     # needs_long with an in-round source: the source's
                     # prefill hasn't dispatched yet — fall through cold
-                if prompt_len > largest:
-                    if long_cold and self._any_ready():
+                windows = _prefill_windows(
+                    prompt_len, 0, self.prefill_buckets, self.stateful
+                )
+                if len(windows) > 1:
+                    if prompt_len <= largest:
+                        # it fits a bucket and would waste most of it:
+                        # windows of a smaller one, and not rationed as
+                        # below (a few short windows are no stall)
+                        self.stats["prompts_windowed"] += 1
+                    elif long_cold and self._any_ready():
                         # a chunked prompt holds the device for all its
                         # windows, and every decoding slot waits them
                         # out: while slots decode, ONE such prompt a
@@ -3322,13 +3390,14 @@ class DecodeEngine:
                         held = True
                         self.stats["long_prompts_held"] += 1
                         break
+                    else:
+                        long_cold = True
                     self._pending.pop(position)
                     self.slots[index].request = request  # reserve the slot
                     self._prefill_long(index, request, 0)
-                    long_cold = True
                     progressed = True
                     continue
-                bucket = _bucket(prompt_len, self.prefill_buckets)
+                bucket = windows[0][1]
                 if cold_bucket is None:
                     cold_bucket = bucket
                 elif bucket != cold_bucket:
@@ -4064,38 +4133,23 @@ class DecodeEngine:
     def _prefill_long(
         self, index: int, request: GenerationRequest, reused: int
     ) -> None:
-        """Chunked prefill for a prompt (or warm-session suffix) longer
-        than the largest bucket: write it in bucket-sized windows, left to
+        """A prompt (or warm-session suffix) taught in windows, left to
         right, each one a prefill-at-offset dispatch (non-blocking, like
-        the batched paths). The FINAL window is shifted left to end
-        exactly at the prompt's last token — re-teaching a few
-        already-written positions (identical tokens → identical KV) is
-        cheaper than a dedicated ragged-tail compilation, and it
-        guarantees the window never writes past ``max_seq_len``. This is
-        what lets long-context prompts (ring/Ulysses scale) enter the
-        slot cache without a giant single-dispatch bucket."""
+        the batched paths): one longer than the largest bucket, which is
+        what lets long-context prompts (ring/Ulysses scale) enter the slot
+        cache without a giant single-dispatch bucket, and a cold one that
+        would waste most of the bucket it fits. :func:`_prefill_windows`
+        says which windows."""
         faults.check("dispatch_error")
-        prompt = request.prompt_tokens
-        total = len(prompt)
-        largest = self.prefill_buckets[-1]
         self._assign_slot(index, request, reused)
         self.slots[index].prefilling = True
-        windows: List[Tuple[int, int]] = []  # (offset, bucket)
-        position = reused
-        while total - position > largest:
-            windows.append((position, largest))
-            position += largest
-        tail_bucket = _bucket(total - position, self.prefill_buckets)
-        if self.stateful:
-            # a recurrent state cannot be taught a position twice: the
-            # tail window starts where the last one ended, right-padded
-            # (rows past max_seq_len are dropped by the write)
-            windows.append((position, tail_bucket))
-        else:
-            # shift the tail window left so offset + bucket == total
-            windows.append((max(0, total - tail_bucket), tail_bucket))
+        windows = _prefill_windows(
+            len(request.prompt_tokens), reused, self.prefill_buckets,
+            self.stateful,
+        )
         with self._prefill_phase(
-            "long", tail_bucket, [index], offset=reused, windows=len(windows),
+            "long", windows[-1][1], [index],
+            offset=reused, windows=len(windows),
         ) as batch_id:
             self._dispatch_long(
                 index, request, reused, windows, batch_id

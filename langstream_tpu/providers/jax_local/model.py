@@ -699,10 +699,14 @@ def _unpack_kv(config: LlamaConfig, rows: jnp.ndarray) -> jnp.ndarray:
     heads = (config.num_kv_heads, config.dims_per_head)
     if rows.shape[-2:] == heads:
         return rows
-    rows = with_layout_constraint(
+    return _row_major(rows).reshape(rows.shape[:-2] + heads)
+
+
+def _row_major(rows: jnp.ndarray) -> jnp.ndarray:
+    """``rows`` pinned to lie as their shape reads, the last axis minor."""
+    return with_layout_constraint(
         rows, Layout(major_to_minor=tuple(range(rows.ndim)))
     )
-    return rows.reshape(rows.shape[:-2] + heads)
 
 
 def cache_logical_axes(
@@ -1746,6 +1750,13 @@ def _offset_attend(config, freqs, seq, lengths, offsets, slot_ids):
         stacked, _ = jax.lax.scan(body, stacked, (new, offsets, slot_ids))
         return stacked
 
+    # The rows the attention reads are pinned as the stack lies
+    # (_row_major), packed or not, int8 or not: unpinned, XLA's einsum
+    # asks for them position-minor, the wish runs back through the slice
+    # to the carried stack, and the whole K and V stacks are copied at
+    # the program's entry and back at its exit (Qwen-2.5-7B, 32 x 2,048:
+    # four copies of 1.9 GB; a 256-token window took 47 ms on the chip
+    # where it takes 25).
     def attend(normed, weights, index, win, kv):
         q, k, v = _rotated_heads(config, normed, weights, freqs, positions)
         if len(kv) == 4:
@@ -1758,9 +1769,9 @@ def _offset_attend(config, freqs, seq, lengths, offsets, slot_ids):
             vs = write_rows(vs, index, v_s)
             with jax.named_scope("attention"):
                 attn = chunk_attention_quant(
-                    q, kc[index, slot_ids], ks[index, slot_ids],
-                    vc[index, slot_ids], vs[index, slot_ids], offsets, totals,
-                    window=win, **family,
+                    q, _row_major(kc[index, slot_ids]), ks[index, slot_ids],
+                    _row_major(vc[index, slot_ids]), vs[index, slot_ids],
+                    offsets, totals, window=win, **family,
                 )
             return attn, (kc, vc, ks, vs), None
         kc, vc = kv
@@ -1768,9 +1779,9 @@ def _offset_attend(config, freqs, seq, lengths, offsets, slot_ids):
         vc = write_rows(vc, index, _pack_kv(v, vc))
         with jax.named_scope("attention"):
             attn = chunk_attention(
-                q, _unpack_kv(config, kc[index, slot_ids]),
-                _unpack_kv(config, vc[index, slot_ids]), offsets, totals,
-                window=win, **family,
+                q, _unpack_kv(config, _row_major(kc[index, slot_ids])),
+                _unpack_kv(config, _row_major(vc[index, slot_ids])),
+                offsets, totals, window=win, **family,
             )
         return attn, (kc, vc), None
 

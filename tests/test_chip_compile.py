@@ -646,6 +646,66 @@ def test_dense_decode_chunk_copies_no_cache_slab(
     assert temp < slab_bytes, (temp, slab_bytes)
 
 
+@pytest.mark.parametrize(
+    "preset,slots,kv_quant",
+    [("qwen25_7b", 32, False), ("qwen25_7b", 32, True),
+     ("qwen25_0_5b", 128, False)],
+    ids=["7b-bf16kv", "7b-int8kv", "0.5b-packed"],
+)
+def test_a_prefill_window_copies_no_cache_stack(
+    one_chip, monkeypatch, preset, slots, kv_quant
+):
+    """One 256-token window of ``prefill_at_offset`` into one slot, as a
+    cold prompt between the two cells' buckets is taught since PR 34: the
+    rows the attention reads are pinned as the stack lies, so the program
+    makes no copy of a K or V stack (unpinned, XLA's einsum laid the 7B's
+    whole stacks out position-minor at entry and back at exit: four
+    copies of 1.9 GB, a window of 47 ms on the chip where it takes 25)
+    and its temp stays under one layer's slab."""
+    import langstream_tpu.ops.flash_attention as flash_attention
+    from langstream_tpu.providers.jax_local import model as model_lib
+    from langstream_tpu.providers.jax_local.quant import init_quantized_params
+
+    monkeypatch.setattr(flash_attention, "on_tpu", lambda: True)
+    config = getattr(model_lib.LlamaConfig, preset)(2048)
+    freqs = model_lib.model_freqs(config)
+
+    def place(tree):
+        return jax.tree_util.tree_map(
+            lambda leaf: _spec(leaf.shape, leaf.dtype, one_chip), tree
+        )
+
+    init = init_quantized_params if preset == "qwen25_7b" else model_lib.init_params
+    params = place(jax.eval_shape(lambda: init(config, seed=0)))
+    cache = place(jax.eval_shape(
+        lambda: model_lib.init_cache(config, slots, 2048, kv_quant=kv_quant)
+    ))
+
+    @functools.partial(jax.jit, donate_argnums=(1,))
+    def run(params, cache, tokens, lengths, offsets, slot_ids):
+        return model_lib.prefill_at_offset(
+            config, params, cache, tokens, lengths, offsets, slot_ids, freqs
+        )
+
+    args = [
+        place(jax.ShapeDtypeStruct(shape, jnp.int32))
+        for shape in ((1, 256), (1,), (1,), (1,))
+    ]
+    compiled = run.lower(params, cache, *args).compile()
+    stack = ",".join(map(str, cache["k"].shape))
+    copies = re.findall(
+        rf"= \w+\[{stack}\]\{{[^}}]*\}} (?:copy|transpose)\(", compiled.as_text()
+    )
+    assert not copies, copies
+    slab_bytes = int(np.prod(cache["k"].shape[1:])) * cache["k"].dtype.itemsize
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < slab_bytes, memory
+    cache_bytes = sum(
+        int(np.prod(leaf.shape)) * leaf.dtype.itemsize for leaf in cache.values()
+    )
+    assert memory.alias_size_in_bytes >= cache_bytes, memory
+
+
 def test_dense_decode_chunk_tp4_copies_no_cache_slab(tp_mesh, monkeypatch):
     """The same chunk under tp=4 (chip_smoke.py --chips 4): a shard holds
     ONE kv head, and the leaf then lies with T and D as its tiled axes. The
