@@ -411,6 +411,9 @@ async def serve_main(args) -> None:
         gauges=engines_snapshot, histograms=engines_histograms,
     )
     await server.start()
+    from langstream_tpu.runtime.local import settle_collector
+
+    settle_collector()
     port = server.addresses[0][1] if server.addresses else args.port
     flight.record("phase", name="serving", port=port)
     flight.flush()

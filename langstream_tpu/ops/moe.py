@@ -277,6 +277,29 @@ def group_limited_routing(
     return weights * scaling_factor, chosen
 
 
+def sigmoid_bias_routing(
+    logits: jnp.ndarray,  # [T, E] float32 router logits over ALL experts
+    bias: jnp.ndarray,    # [E] float32: moves the selection, never a weight
+    *,
+    num_selected: int,
+    scaling_factor: float,
+    renormalise: bool,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Sigmoid routing with a selection bias: every expert's score is
+    ``s_e = sigmoid(logit_e)`` on its own (no softmax over the experts),
+    the token's experts are the ``num_selected`` largest of ``s + bias``,
+    and their weights are the UNBIASED ``s_e``, divided (``renormalise``)
+    by ``sum of the chosen s_e + 1e-6``, times ``scaling_factor``. The
+    bias balances the experts' load without touching the mixture.
+    Returns (weights [T, k] float32, experts [T, k])."""
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, chosen = jax.lax.top_k(scores + bias.astype(jnp.float32), num_selected)
+    weights = jnp.take_along_axis(scores, chosen, axis=-1)
+    if renormalise:
+        weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-6)
+    return weights * scaling_factor, chosen
+
+
 def _grouped_kernel(group_ref, active_ref, layer_ref, x_ref, w_ref, out_ref):
     """One row tile of one expert against one column tile of its weight
     (the whole contraction at once); tiles past the last active one are
@@ -389,15 +412,14 @@ def moe_mlp_held(
     *,
     layer=0,                # [] int32: which layer of the stacks
     held_first: int,
-    groups: int,
-    groups_kept: int,
-    num_selected: int,
-    scaling_factor: float,
+    route,                  # the routing rule: logits [T, E] float32 ->
+                            # (weights [T, k] float32, experts [T, k])
     valid: Optional[jnp.ndarray] = None,  # [T] bool; False = padding
     interpret: bool = False,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """A chip's share of a routed mixture: the router scores all ``E``
-    experts, this chip computes ``sum_e w_e SwiGLU_e(x)`` over the experts
+    experts by the rule ``route`` (:func:`group_limited_routing` or
+    :func:`sigmoid_bias_routing`, its sizes bound), this chip computes ``sum_e w_e SwiGLU_e(x)`` over the experts
     it holds, for the tokens routed to them and for no others. No token is
     dropped and nothing stands in for the absent experts.
 
@@ -417,10 +439,8 @@ def moe_mlp_held(
     logits = jnp.einsum(
         "th,he->te", x2.astype(jnp.float32), router_w.astype(jnp.float32)
     )
-    weights, chosen = group_limited_routing(
-        logits, groups=groups, groups_kept=groups_kept,
-        num_selected=num_selected, scaling_factor=scaling_factor,
-    )
+    weights, chosen = route(logits)
+    num_selected = chosen.shape[-1]
     local = chosen - held_first                       # [T, k]
     met = (local >= 0) & (local < held)
     if valid is not None:

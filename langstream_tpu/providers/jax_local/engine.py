@@ -269,7 +269,8 @@ def engines_snapshot() -> Dict[str, float]:
                 + "x".join(map(str, engine.cache_leaf_shape)) + '"}'
             )
             out[key] = out.get(key, 0.0) + 1.0
-        experts = getattr(getattr(engine, "config", None), "experts", None)
+        config = getattr(engine, "config", None)
+        experts = getattr(config, "experts", None)
         if experts is not None:
             # routed experts held here: what the router assigned, what
             # met a held expert, what the expert matmuls computed
@@ -292,12 +293,14 @@ def engines_snapshot() -> Dict[str, float]:
             ]
             for key, count in counted:
                 out[key] = out.get(key, 0.0) + float(count)
-        if getattr(getattr(engine, "config", None), "mixers", None) is not None:
-            # block selection and recurrent state (the hybrid family)
-            for name in (
-                "sparse_kept", "sparse_visible", "sparse_queries",
-                "state_resets",
-            ):
+        if getattr(config, "mixers", None) is not None:
+            # a carried state's resets (recurrent, conv) and, for the
+            # hybrid family, its block selection
+            selection = (
+                ("sparse_kept", "sparse_visible", "sparse_queries")
+                if config.hybrid is not None else ()
+            )
+            for name in selection + ("state_resets",):
                 key = f"jax_engine_{name}_total"
                 out[key] = out.get(key, 0.0) + float(stats[name])
         if getattr(engine, "spec", False):
@@ -776,30 +779,25 @@ class DecodeEngine:
                                           # QueueTimeoutError (None=off)
     ) -> None:
         self.config = config
-        if config.mla is not None:
-            # what the latent/routed family cannot take yet is refused
-            # here, by the switch's name: nothing falls through to a
-            # GQA path
-            self._refuse_for_latent(
-                params, mesh_config, quantize=quantize, kv_quant=kv_quant,
-                kv_layout=kv_layout, kv_host_blocks=kv_host_blocks,
-                spec_decode=spec_decode, prefill_mode=prefill_mode,
-            )
-        # the hybrid family (a recurrent state beside its KV rows) cannot
+        # a family whose mixers carry a state that no position addresses
+        # (a recurrent state, a conv state) beside its KV rows cannot
         # reuse rows across slots or turns: a state has no snapshot at a
         # prefix's end
         self.stateful = config.mixers is not None
+        # what the config's layers cannot take yet is refused here, by
+        # the switch's name: nothing falls through to a GQA path
+        self._refuse_unsupported(
+            config, params, mesh_config, quantize=quantize,
+            kv_quant=kv_quant, kv_layout=kv_layout,
+            kv_host_blocks=kv_host_blocks, spec_decode=spec_decode,
+            prefill_mode=prefill_mode,
+        )
         if self.stateful:
-            self._refuse_for_hybrid(
-                mesh_config, kv_quant=kv_quant, kv_layout=kv_layout,
-                kv_host_blocks=kv_host_blocks, spec_decode=spec_decode,
-                prefill_mode=prefill_mode,
-            )
             if prefix_cache:
                 logger.info(
-                    "prefix-cache and session warm reuse are off for the "
-                    "hybrid family: its recurrent state has no snapshot at "
-                    "a prefix's end (every prefill is cold)"
+                    "prefix-cache and session warm reuse are off for a "
+                    "model with a carried state: it has no snapshot at a "
+                    "prefix's end (every prefill is cold)"
                 )
             prefix_cache = False
         self.max_slots = max_slots
@@ -1205,81 +1203,80 @@ class DecodeEngine:
         _LIVE_ENGINES.add(self)
 
     @staticmethod
-    def _refuse_for_latent(
-        params, mesh_config, *, quantize, kv_quant, kv_layout,
+    def _refuse_unsupported(
+        config, params, mesh_config, *, quantize, kv_quant, kv_layout,
         kv_host_blocks, spec_decode, prefill_mode,
     ) -> None:
-        """The latent-attention, routed-experts family runs the dense
-        layout's three programs in the weights' own precision on one
-        chip; every other switch is refused by name when the engine is
-        built. The programs and the layer loop are every family's
-        (``model._run_layers``): what the paged layout, the mixed
-        dispatch and speculation lack is an ``attend`` over latents
-        (``latent_moe.py`` has three), the rest a cache or weight format
-        (ROADMAP R-M1 / R-M3 say what each needs)."""
+        """Every switch the config's layers cannot take, refused by name
+        when the engine is built, each with the reason of what the config
+        HAS: latent attention (``mla``), a carried state (per-layer
+        ``mixers``: the hybrid family's recurrent state, the
+        short-convolution family's conv state), routed experts. Such a
+        model runs the dense layout's three programs on one chip; the
+        programs and the layer loop are every family's
+        (``model._run_layers``), what is missing is an ``attend``, a
+        cache format or a weight format (ROADMAP R-M1 .. R-M6 say what
+        each needs). Prefix reuse is not a switch to refuse but a path to
+        leave: the constructor turns it off for a carried state and
+        :meth:`_session_warm` answers cold."""
         from langstream_tpu.providers.jax_local.quant import QTensor
 
+        latent = config.mla is not None
+        state = config.mixers is not None
+        routed = config.experts is not None
+        int8_weights = bool(quantize) or any(
+            isinstance(v, QTensor) for v in params.values()
+        )
+        # switch -> (is it on, [(the config has this, so why not)])
         refused = {
-            "kv-layout: paged (no attend over a pool of latents yet)":
-                kv_layout != "dense",
-            "prefill-mode: mixed (a paged dispatch)":
-                prefill_mode != "split",
-            "kv-host-blocks (the host tier holds paged GQA rows)":
-                bool(kv_host_blocks),
-            "kv-quant (the latent cache has no int8 form)":
-                bool(kv_quant),
-            "quantization (quant.py has no int8 form of the expert "
-            "stacks or the low-rank projections)":
-                bool(quantize) or any(
-                    isinstance(v, QTensor) for v in params.values()
-                ),
-            "spec-decode (no verify attend over latents yet)":
-                spec_decode != "off",
-            "mesh (tp / ep / any axis > 1: the latent cache has one head "
-            "and the expert stacks hold this chip's share)":
-                mesh_config is not None and mesh_config.size > 1,
+            "kv-layout: paged": (kv_layout != "dense", [
+                (latent, "no attend over a pool of latents yet"),
+                (state, "the pool holds one kind of row; the model carries "
+                        "a state beside K and V"),
+            ]),
+            "prefill-mode: mixed": (prefill_mode != "split", [
+                (latent or state, "a paged dispatch"),
+            ]),
+            "kv-host-blocks": (bool(kv_host_blocks), [
+                (latent or state, "the host tier holds paged GQA rows"),
+            ]),
+            "kv-quant": (bool(kv_quant), [
+                (latent, "the latent cache has no int8 form"),
+                (state, "no int8 form of the carried state or of what "
+                        "lies beside K and V"),
+            ]),
+            "quantization": (int8_weights, [
+                (routed, "quant.py has no int8 form of the expert stacks"),
+                (latent, "nor of the low-rank projections"),
+            ]),
+            "spec-decode": (spec_decode != "off", [
+                (latent, "no verify attend over latents yet"),
+                (state, "a rejected draft cannot be rolled out of a "
+                        "carried state"),
+            ]),
+            "mesh (tp / ep / any axis > 1)": (
+                mesh_config is not None and mesh_config.size > 1, [
+                    (latent, "the latent cache has one head"),
+                    (state, "the carried state is not sharded"),
+                    (routed, "the expert stacks hold this chip's share"),
+                ],
+            ),
         }
-        named = [switch for switch, on in refused.items() if on]
+        named = [
+            f"{switch} ({'; '.join(why for has, why in reasons if has)})"
+            for switch, (on, reasons) in refused.items()
+            if on and any(has for has, _ in reasons)
+        ]
         if named:
+            has = [
+                name for name, there in (
+                    ("latent attention", latent), ("a carried state", state),
+                    ("routed experts", routed),
+                ) if there
+            ]
             raise ValueError(
-                "the latent-attention, routed-experts family does not "
-                "support: " + "; ".join(named)
-            )
-
-    @staticmethod
-    def _refuse_for_hybrid(
-        mesh_config, *, kv_quant, kv_layout, kv_host_blocks, spec_decode,
-        prefill_mode,
-    ) -> None:
-        """The hybrid family (hybrid_sparse_linear.py) runs the dense
-        layout's three programs on one chip, in bf16 or int8 weights;
-        every other switch is refused by name when the engine is built
-        (ROADMAP R-M2 / R-M4 / R-M6 say what each needs). Prefix reuse is
-        not a switch to refuse but a path to leave: the constructor turns
-        it off and :meth:`_session_warm` answers cold."""
-        refused = {
-            "kv-layout: paged (the pool holds one kind of row; the family "
-            "has a recurrent state and compressed keys beside K and V)":
-                kv_layout != "dense",
-            "prefill-mode: mixed (a paged dispatch)":
-                prefill_mode != "split",
-            "kv-host-blocks (the host tier holds paged GQA rows)":
-                bool(kv_host_blocks),
-            "kv-quant (no int8 form of the state or of the selection's "
-            "compressed keys)":
-                bool(kv_quant),
-            "spec-decode (a rejected draft cannot be rolled out of a "
-            "recurrent state)":
-                spec_decode != "off",
-            "mesh (tp / any axis > 1: two kv heads, and a state that is "
-            "not sharded)":
-                mesh_config is not None and mesh_config.size > 1,
-        }
-        named = [switch for switch, on in refused.items() if on]
-        if named:
-            raise ValueError(
-                "the hybrid (linear + block-sparse attention) family does "
-                "not support: " + "; ".join(named)
+                f"a model with {', '.join(has)} does not support: "
+                + "; ".join(named)
             )
 
     def _new_stats(self) -> Dict[str, Any]:
@@ -1390,9 +1387,9 @@ class DecodeEngine:
             "moe_tokens_by_expert": [],
             # the hybrid family: key blocks its sparse layers attended
             # and had in context and the queries that chose (summed over
-            # sparse layers; every prefill and decode chunk returns them),
-            # and the slots whose recurrent state a prefill at position 0
-            # started from zeros
+            # sparse layers; every prefill and decode chunk returns them);
+            # any model with a carried state (recurrent, conv): the slots
+            # whose state a prefill at position 0 started from zeros
             "sparse_kept": 0,
             "sparse_visible": 0,
             "sparse_queries": 0,
@@ -1409,7 +1406,7 @@ class DecodeEngine:
         if isinstance(counters, list):  # a chunked prompt's windows
             counters = sum(np.asarray(each) for each in counters)
         counters = np.asarray(counters)
-        if self.stateful:
+        if self.config.experts is None:  # the block selection's
             kept, visible, queries = (int(n) for n in counters)
             self.stats["sparse_kept"] += kept
             self.stats["sparse_visible"] += visible
@@ -2738,8 +2735,9 @@ class DecodeEngine:
             request.export_handoff or request.kv_import is not None
         ):
             raise ValueError(
-                "the hybrid family has no handoff rows (export_handoff / "
-                "kv_import: the payload holds paged GQA rows and no state)"
+                "a model with a carried state has no handoff rows "
+                "(export_handoff / kv_import: the payload holds paged GQA "
+                "rows and no state)"
             )
         if self.config.mla is not None:
             # what a REQUEST can ask of the latent family that it cannot
@@ -3071,8 +3069,8 @@ class DecodeEngine:
             and slot.session_id == request.session_id
             and slot.history
         ):
-            # (a recurrent state has no snapshot at the shared prefix's
-            # end: the hybrid family's follow-ups prefill cold)
+            # (a carried state has no snapshot at the shared prefix's
+            # end: such a model's follow-ups prefill cold)
             return None
         lcp = self._lcp(prompt, slot.history)
         if lcp == len(prompt):
@@ -3876,8 +3874,8 @@ class DecodeEngine:
                     min(cached, len(request.prompt_tokens)) - reused,
                 )
         if self.stateful and not reused:
-            # the prefill at position 0 starts the slot's recurrent state
-            # from zeros (hybrid_sparse_linear.window_attends)
+            # the prefill at position 0 starts the slot's carried state
+            # (recurrent, conv) from zeros (the family's window_attends)
             self.stats["state_resets"] += 1
         slot.generated = []
         slot.logprobs = []
@@ -5927,11 +5925,22 @@ class DecodeEngine:
         self._prefill_inflight = []
         return requests
 
+    # lint: allow(owned-by-violation) -- supervisor heal arc: runs on the
+    #   dying engine thread itself (the crash hook) or once the condemned
+    #   thread was joined; drain_for_recovery has fenced a wedged survivor
+    #   off its slots, and one that wakes finds no cache and ends
     def retire(self) -> None:
-        """Drop this engine from the /metrics aggregation immediately
-        (a superseded engine must not double-count against its
-        replacement while awaiting GC)."""
+        """Superseded (the supervisor's heal arc, after the drain): leave
+        the /metrics aggregation at once (a superseded engine must not
+        double-count against its replacement) and hand the device its
+        memory back BEFORE the replacement asks for its own. The engine
+        itself sits in cycles (its programs' closures, the crash hook)
+        and goes whenever the collector next looks; its cache, a tenth
+        of the chip or more, must not wait for that. The weights are the
+        replacement's too and stay."""
         _LIVE_ENGINES.discard(self)
+        self.cache = None
+        self._counts = None
 
     # lint: allow(owned-by-violation) -- supervisor heal arc: runs on
     #   the rebuilt engine BEFORE start(), so its device thread does not

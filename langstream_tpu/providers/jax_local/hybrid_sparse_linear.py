@@ -260,7 +260,7 @@ def validate_params(config, params: Dict[str, Any]) -> None:
 def layer_runs(config, params):
     """The layers as ``model._run_layers`` takes them: one ``(kind, the
     run's stacked layers, the index of its first layer in the kind's own
-    stack of state)`` for every run. A layer is ``(attn_norm, the mixer's
+    stack of state, no routed experts)`` for every run. A layer is ``(attn_norm, the mixer's
     weights, wo, None, mlp_norm, None, SwiGLU weights)``."""
     validate_params(config, params)
 
@@ -276,7 +276,7 @@ def layer_runs(config, params):
         )
 
     return [
-        (kind, stack(number, kind), first)
+        (kind, stack(number, kind), first, None)
         for number, (kind, _, _, first) in enumerate(runs_of(config))
     ]
 

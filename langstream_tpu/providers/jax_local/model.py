@@ -18,9 +18,12 @@ cache (projects, ropes, writes, attends). An attention kind is a set of
 attends and a cache layout: GQA's are in this file, the latent kind's in
 ``latent_moe.py``, the two kinds a hybrid config mixes layer by layer
 (``config.mixers``: linear attention with a recurrent state, block-sparse
-GQA) in ``hybrid_sparse_linear.py``; :func:`_kinds` says which a config
-has and :func:`_layers_of` cuts its layers into runs of one kind. Every
-serving program returns ``(cache, logits, counters)``: the routed
+GQA) in ``hybrid_sparse_linear.py``, the gated short convolution that a
+config with ``short_conv`` mixes with GQA layers (q and k normed a head)
+in ``short_conv_gqa.py``; :func:`_kinds` says which a config has and
+:func:`_layers_of` cuts its layers into runs of one mixer kind and one
+feed-forward kind (dense, or routed experts: the two are independent).
+Every serving program returns ``(cache, logits, counters)``: the routed
 experts', the block selection's, or None (an empty pytree).
 
 Logical sharding axes per parameter feed the mesh rules in
@@ -30,6 +33,7 @@ Logical sharding axes per parameter feed the mesh rules in
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
@@ -52,13 +56,19 @@ from langstream_tpu.ops.attention import (
     quantize_kv,
 )
 from langstream_tpu.ops.flash_attention import flash_prefill_attention, use_flash
-from langstream_tpu.ops.moe import moe_mlp, moe_mlp_held
+from langstream_tpu.ops.moe import (
+    group_limited_routing,
+    moe_mlp,
+    moe_mlp_held,
+    sigmoid_bias_routing,
+)
 from langstream_tpu.ops.norms import rms_norm
 from langstream_tpu.ops.rope import apply_rope, rope_frequencies
 from langstream_tpu.parallel.mesh import L
 from langstream_tpu.ops.block_sparse_attention import Selection
 from langstream_tpu.providers.jax_local import hybrid_sparse_linear as hybrid
 from langstream_tpu.providers.jax_local import latent_moe
+from langstream_tpu.providers.jax_local import short_conv_gqa as short_conv
 from langstream_tpu.providers.jax_local.quant import qeinsum
 
 
@@ -76,10 +86,15 @@ class LatentAttention:
 
 @dataclasses.dataclass(frozen=True)
 class RoutedExperts:
-    """A group-limited router over ``routed`` experts of which THIS chip
-    holds ``[held_first, held_first + held)`` and computes only where
-    routed, ``shared`` experts every token meets, after ``leading_dense``
-    layers with a plain SwiGLU of the config's ``intermediate_size``."""
+    """A router over ``routed`` experts of which THIS chip holds
+    ``[held_first, held_first + held)`` and computes only where routed,
+    ``shared`` experts every token meets (0: none), after ``leading_dense``
+    layers with a plain SwiGLU of the config's ``intermediate_size``.
+    ``routing`` names the rule (``ops/moe.py``): ``group_limited`` (softmax
+    scores, the ``groups_kept`` best of ``groups`` groups, weights not
+    renormalised) or ``sigmoid_bias`` (sigmoid scores, the selection over
+    score + a bias vector, weights from the unbiased scores, renormalised
+    where ``renormalise``)."""
     routed: int
     held_first: int
     held: int
@@ -90,6 +105,8 @@ class RoutedExperts:
     groups: int
     groups_kept: int
     scaling_factor: float
+    routing: str = "group_limited"
+    renormalise: bool = False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -103,16 +120,25 @@ class HybridMixers:
     selection: Selection = Selection()
 
 
+@dataclasses.dataclass(frozen=True)
+class ShortConv:
+    """The gated short convolution of a config whose per-layer ``mixers``
+    are ``conv`` and ``attention`` (short_conv_gqa.py): a causal depthwise
+    filter of ``taps`` taps a channel, so ``taps - 1`` columns of state a
+    slot a conv layer."""
+    taps: int = 3
+
+
 def zero_counters(config: "LlamaConfig"):
     """The counters a program returns with its outputs, as a scan over a
-    chunk's steps starts them: the routed experts' (int32 ``[3 +
-    held]``), the block selection's (int32 ``[3]``), or None, an empty
-    pytree, for a family with neither."""
-    if config.mixers is not None:
+    chunk's steps starts them, by what the layers hold: the routed
+    experts' (int32 ``[3 + held]``), the block selection's (int32
+    ``[3]``), or None, an empty pytree, for a config with neither."""
+    if config.experts is not None:
+        return jnp.zeros((3 + config.experts.held,), jnp.int32)
+    if config.hybrid is not None:
         return hybrid.zero_counters()
-    if config.experts is None:
-        return None
-    return jnp.zeros((3 + config.experts.held,), jnp.int32)
+    return None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -148,15 +174,18 @@ class LlamaConfig:
     # low_freq_factor, high_freq_factor, original_max_positions) — the
     # Llama-3.1/3.2 long-context recipe (ops/rope.py). None = plain.
     rope_scaling: Optional[Tuple] = None
-    # The latent-attention, routed-experts family (latent_moe.py): both
-    # set, or both None.
+    # Latent attention (latent_moe.py) comes with routed experts; routed
+    # experts come behind latent attention or behind the mixers of the
+    # short-convolution family.
     mla: Optional[LatentAttention] = None
     experts: Optional[RoutedExperts] = None
-    # The hybrid family (hybrid_sparse_linear.py): the mixer of every
-    # layer by kind ("sparse" | "lightning") and what they need; both set,
-    # or both None (every layer GQA, or latent with ``mla``).
+    # The mixer of every layer by kind, with what the kinds need: "sparse"
+    # | "lightning" with ``hybrid`` (hybrid_sparse_linear.py), "conv" |
+    # "attention" with ``short_conv`` (short_conv_gqa.py); all None where
+    # every layer is GQA, or latent with ``mla``.
     mixers: Optional[Tuple[str, ...]] = None
     hybrid: Optional[HybridMixers] = None
+    short_conv: Optional[ShortConv] = None
     # muP scalings (MiniCPM): x *= embedding_scale after the lookup, both
     # residual branches times residual_scale, the final hidden state over
     # logit_divisor before the head. None leaves the program as it is.
@@ -366,6 +395,54 @@ class LlamaConfig:
         )
 
     @classmethod
+    def lfm2_24b_a2b(cls, max_seq_len: int = 4096) -> "LlamaConfig":
+        """LFM2-24B-A2B (HF LiquidAI/LFM2-24B-A2B): 30 gated
+        short-convolution layers (3 taps) beside 10 GQA layers (32/8 heads
+        of 64, q and k normed a head) at 2, 6, ..., 38; two leading dense
+        layers of width 11,776, then 64 routed experts of width 1,536
+        (sigmoid scores, a selection bias, 4 a token, renormalised), none
+        shared; tied head. All 40 layers: a cut is set beside the preset
+        (``num-layers`` cuts ``mixers`` with the depth)."""
+        return cls(
+            vocab_size=65536, hidden_size=2048, intermediate_size=11776,
+            num_layers=40, num_heads=32, num_kv_heads=8, head_dim=64,
+            rope_theta=1e6, max_seq_len=max_seq_len, norm_eps=1e-5,
+            tie_embeddings=True,
+            mixers=tuple(
+                "attention" if i % 4 == 2 else "conv" for i in range(40)
+            ),
+            short_conv=ShortConv(taps=3),
+            experts=RoutedExperts(
+                routed=64, held_first=0, held=64, intermediate_size=1536,
+                per_token=4, shared=0, leading_dense=2, groups=1,
+                groups_kept=1, scaling_factor=1.0, routing="sigmoid_bias",
+                renormalise=True,
+            ),
+        )
+
+    @classmethod
+    def tiny_conv_moe(cls, max_seq_len: int = 256) -> "LlamaConfig":
+        """Test-size shape of the short-convolution family: 6 layers (conv
+        conv | attention conv conv attention), 4 heads of 16 over 2 kv
+        heads, 2 leading dense layers, then 8 routed experts (3 a token):
+        all three combinations of mixer and feed-forward."""
+        return cls(
+            vocab_size=512, hidden_size=64, intermediate_size=128,
+            num_layers=6, num_heads=4, num_kv_heads=2, head_dim=16,
+            rope_theta=10000.0, max_seq_len=max_seq_len, norm_eps=1e-5,
+            tie_embeddings=True,
+            mixers=("conv", "conv", "attention", "conv", "conv", "attention"),
+            short_conv=ShortConv(taps=3),
+            experts=RoutedExperts(
+                routed=8, held_first=0, held=8, intermediate_size=32,
+                per_token=3, shared=0, leading_dense=2, groups=1,
+                groups_kept=1, scaling_factor=1.0, routing="sigmoid_bias",
+                renormalise=True,
+            ),
+            dtype=jnp.float32,
+        )
+
+    @classmethod
     def tiny_qwen2(cls, max_seq_len: int = 256) -> "LlamaConfig":
         """Test-size Qwen-2 shape (qkv biases on)."""
         return dataclasses.replace(cls.tiny(max_seq_len), qkv_bias=True)
@@ -409,6 +486,8 @@ class LlamaConfig:
             "deepseek-v2": cls.deepseek_v2,
             "tiny-deepseek-v2": cls.tiny_deepseek_v2,
             "minicpm-sala": cls.minicpm_sala, "tiny-hybrid": cls.tiny_hybrid,
+            "lfm2-24b-a2b": cls.lfm2_24b_a2b,
+            "tiny-conv-moe": cls.tiny_conv_moe,
         }
         preset = clean.pop("preset", None)
         # a chip's share of the routed experts, beside the preset
@@ -436,12 +515,22 @@ class LlamaConfig:
                     for k, v in sizes["selection"].items()
                 })
             clean["hybrid"] = HybridMixers(**sizes)
+        if isinstance(clean.get("short_conv"), dict):
+            clean["short_conv"] = ShortConv(**{
+                k.replace("-", "_"): v for k, v in clean["short_conv"].items()
+            })
         if clean.get("mixers") is not None:
             clean["mixers"] = tuple(clean["mixers"])
         if preset:
+            config = presets[preset]()
+            if (
+                config.mixers is not None and "mixers" not in clean
+                and clean.get("num_layers", config.num_layers) < config.num_layers
+            ):
+                # a cut in depth keeps the first layers' kinds
+                clean["mixers"] = config.mixers[: clean["num_layers"]]
             config = dataclasses.replace(
-                presets[preset](),
-                **{k: v for k, v in clean.items() if k in known},
+                config, **{k: v for k, v in clean.items() if k in known},
             )
         else:
             config = cls(**{k: v for k, v in clean.items() if k in known})
@@ -454,30 +543,42 @@ class LlamaConfig:
             config = dataclasses.replace(
                 config, experts=dataclasses.replace(config.experts, **share)
             )
-        if (config.mla is None) != (config.experts is None):
+        if (config.mla is not None and config.experts is None) or (
+            config.experts is not None
+            and config.mla is None and config.short_conv is None
+        ):
             raise ValueError(
                 "latent attention and routed experts come together "
-                "(latent_moe.py): set both `mla` and `experts`, or neither"
+                "(latent_moe.py): set both `mla` and `experts`, or neither; "
+                "routed experts without it come behind per-layer `mixers` "
+                "with `short_conv` (short_conv_gqa.py)"
             )
-        if (config.mixers is None) != (config.hybrid is None):
+        sizes = [
+            name for name in ("hybrid", "short_conv")
+            if getattr(config, name) is not None
+        ]
+        if len(sizes) != (config.mixers is not None):
             raise ValueError(
-                "per-layer mixers come with their sizes "
-                "(hybrid_sparse_linear.py): set both `mixers` and `hybrid`, "
-                "or neither"
+                "per-layer mixers come with their sizes: set `mixers` with "
+                "`hybrid` (hybrid_sparse_linear.py) or with `short_conv` "
+                f"(short_conv_gqa.py), or none of them (got mixers with {sizes})"
             )
         if config.mixers is not None:
+            kinds = hybrid.KINDS if config.hybrid is not None else short_conv.KINDS
             if (
                 len(config.mixers) != config.num_layers
-                or set(config.mixers) - set(hybrid.KINDS)
+                or set(config.mixers) - set(kinds)
                 or config.mla is not None
-                or config.hybrid.lightning_head_dim != config.dims_per_head
+                or config.hybrid is not None
+                and config.hybrid.lightning_head_dim != config.dims_per_head
             ):
                 raise ValueError(
                     f"inconsistent mixers for {config.num_layers} layers: "
-                    f"{config.mixers} (kinds: {hybrid.KINDS}; no latent "
+                    f"{config.mixers} (kinds: {kinds}; no latent "
                     "attention; one head dim for both kinds)"
                 )
-            config.hybrid.selection.check()
+            if config.hybrid is not None:
+                config.hybrid.selection.check()
         experts = config.experts
         if experts is not None and not (
             0 <= experts.held_first
@@ -485,6 +586,7 @@ class LlamaConfig:
             and experts.held_first + experts.held <= experts.routed
             and experts.routed % experts.groups == 0
             and 0 < experts.leading_dense < config.num_layers
+            and experts.routing in ("group_limited", "sigmoid_bias")
         ):
             raise ValueError(f"inconsistent routed experts: {experts}")
         return config
@@ -649,7 +751,10 @@ def init_cache(
 
     The latent family's cache is one leaf of latents instead
     (``latent_moe.init_cache``), the hybrid family's a recurrent state
-    beside K, V and compressed keys (``hybrid_sparse_linear.init_cache``)."""
+    beside K, V and compressed keys (``hybrid_sparse_linear.init_cache``);
+    the short-convolution family's is K and V as here (:func:`kv_leaves`)
+    for its ATTENTION layers only, beside the conv state
+    (``short_conv_gqa.init_cache``)."""
     max_len = max_len or config.max_seq_len
     family = _family_of(config)
     if family is not None:
@@ -658,9 +763,19 @@ def init_cache(
                 f"the {_kinds(config).attention} cache has no int8 form"
             )
         return family.init_cache(config, batch, max_len)
+    return kv_leaves(config, config.num_layers, batch, max_len, kv_quant, tp)
+
+
+def kv_leaves(
+    config: LlamaConfig, layers: int, batch: int, max_len: int,
+    kv_quant: bool = False, tp: int = 1,
+) -> Dict[str, jnp.ndarray]:
+    """K and V (and their scales) of ``layers`` GQA layers, as
+    :func:`init_cache` describes them: every family whose attention
+    layers are GQA's lays them so."""
     pack = flash_decode_pack(config, max_len, kv_quant, tp) or 1
     shape = (
-        config.num_layers, batch, max_len, config.num_kv_heads // pack,
+        layers, batch, max_len, config.num_kv_heads // pack,
         config.dims_per_head * pack,
     )
     if kv_quant:
@@ -865,9 +980,10 @@ def validate_family_params(
 
 
 class Kinds(NamedTuple):
-    attention: str           # "gqa" | "latent" (latent_moe.py) | "hybrid"
-                             # (hybrid_sparse_linear.py: a kind a layer,
-                             # ``config.mixers``)
+    attention: str           # "gqa" | "latent" (latent_moe.py) | a kind a
+                             # layer (``config.mixers``): "hybrid"
+                             # (hybrid_sparse_linear.py) | "conv"
+                             # (short_conv_gqa.py)
     cache: Tuple[str, ...]   # the cache's leaves, as every program carries them
 
 
@@ -880,7 +996,9 @@ class Run(NamedTuple):
                                  # brings one a kind
     unroll: bool = False
     experts: Any = None   # the routed experts' stacks of the run's
-                          # feed-forward (None: :func:`_mlp_block`'s)
+                          # feed-forward (None: :func:`_mlp_block`'s); the
+                          # run's ``first`` is then its first layer's
+                          # index in the MODEL
 
 
 def _kinds(
@@ -889,9 +1007,11 @@ def _kinds(
     """What a config's layers are made of, asked here and nowhere else:
     the attention kind and, with it, the cache's leaves (an int8 GQA
     cache is told by its ``k_scale`` leaf). A config with per-layer
-    ``mixers`` answers ``hybrid``: its layers' kinds are that list, and
-    :func:`_layers_of` cuts it into runs. The feed-forward kinds follow
-    the runs of :func:`_layers_of`."""
+    ``mixers`` answers by the sizes beside them, ``hybrid`` or ``conv``:
+    its layers' kinds are that list, and :func:`_layers_of` cuts it into
+    runs. The feed-forward kinds follow the runs of :func:`_layers_of`."""
+    if config.short_conv is not None:
+        return Kinds("conv", ("conv", "k", "v"))
     if config.mixers is not None:
         return Kinds("hybrid", hybrid.CACHE)
     if config.mla is not None:
@@ -903,9 +1023,11 @@ def _kinds(
 
 def _family_of(config: LlamaConfig):
     """The file that holds a family's parameters, cache and attends where
-    it is not this one: ``latent_moe``, ``hybrid_sparse_linear``, or None
-    for the uniform GQA stack."""
-    return {"latent": latent_moe, "hybrid": hybrid}.get(_kinds(config).attention)
+    it is not this one: ``latent_moe``, ``hybrid_sparse_linear``,
+    ``short_conv_gqa``, or None for the uniform GQA stack."""
+    return {
+        "latent": latent_moe, "hybrid": hybrid, "conv": short_conv,
+    }.get(_kinds(config).attention)
 
 
 def _stack_layer_params(params: Dict[str, jnp.ndarray], config=None):
@@ -917,14 +1039,15 @@ def _stack_layer_params(params: Dict[str, jnp.ndarray], config=None):
     untouched. With ``config`` given, validates the family tensors are
     actually present first (see :func:`validate_family_params`)."""
     if config is not None and _kinds(config).attention != "gqa":
-        # the programs that come through here have no attend for the
-        # latent or the hybrid family's cache yet (the dense layout's
-        # three do, through :func:`_layers_of`)
+        # the programs that come through here have no attend for
+        # another family's cache yet (the dense layout's three do,
+        # through :func:`_layers_of`)
         raise NotImplementedError(
             f"this program has no {_kinds(config).attention}-attention "
             "form: the family runs the dense layout's prefill, "
-            "prefill_at_offset and decode_step only (latent_moe.py and "
-            "hybrid_sparse_linear.py hold their attends)"
+            "prefill_at_offset and decode_step only (latent_moe.py, "
+            "hybrid_sparse_linear.py and short_conv_gqa.py hold their "
+            "attends)"
         )
     if config is not None:
         validate_family_params(config, params)
@@ -947,8 +1070,11 @@ def _layers_of(config: LlamaConfig, params) -> Tuple[Run, ...]:
     """A config's layers as :func:`_run_layers` takes them, a :class:`Run`
     for every stretch of one kind: the uniform stack is one; the latent
     family's leading dense layers (unrolled) and its expert layers are
-    two; the hybrid family's follow ``config.mixers``, each run a stack
-    of its own (a lone layer is unrolled)."""
+    two; a family with per-layer ``mixers`` cuts where the mixer's kind
+    changes and, with routed experts behind some layers, where the
+    feed-forward's does (conv then dense, attention then experts, conv
+    then experts): each run a stack of its own (a lone layer is
+    unrolled), a run of expert layers with the experts' stacks."""
     kind = _kinds(config).attention
     if kind == "latent":
         lead, layers, experts = latent_moe.layer_stacks(config, params)
@@ -956,14 +1082,16 @@ def _layers_of(config: LlamaConfig, params) -> Tuple[Run, ...]:
             Run(list(lead), unroll=True),
             Run(layers, first=len(lead), experts=experts),
         )
-    if kind == "hybrid":
+    if kind in ("hybrid", "conv"):
         runs = []
-        for mixer, layers, first in hybrid.layer_runs(config, params):
+        for mixer, layers, first, experts in _family_of(config).layer_runs(
+            config, params
+        ):
             # a lone layer is its stack's one row (a bitcast), unrolled
             lone = jax.tree_util.tree_leaves(layers)[0].shape[0] == 1
             if lone:
                 layers = [jax.tree_util.tree_map(lambda x: x[0], layers)]
-            runs.append(Run(layers, first, mixer, unroll=lone))
+            runs.append(Run(layers, first, mixer, unroll=lone, experts=experts))
         return tuple(runs)
     return (Run(_stack_layer_params(params, config)),)
 
@@ -1450,21 +1578,37 @@ def _paged_attn_quant(config, q, k_pool, k_scale, v_pool, v_scale, tables,
 
 @jax.named_scope("mlp")
 def _expert_block(config, normed, weights, stacks, layer, valid):
-    """The feed-forward of the latent/routed family's expert layers on
-    normed [B, T, h]: the routed experts held here (``stacks[..][layer]``),
-    where routed, plus the shared experts. Returns (delta, counters)."""
+    """The feed-forward of an expert layer on normed [..., h]: the routed
+    experts held here (``stacks[..][layer]``), where the config's routing
+    rule sends a token, plus the shared experts where the config has any.
+    ``weights`` is the router, the selection bias (the ``sigmoid_bias``
+    rule's), then the shared experts' SwiGLU. Returns (delta, counters)."""
     experts = config.experts
-    router, s_gate, s_up, s_down = weights
+    router, *rest = weights
+    sizes = dict(
+        num_selected=experts.per_token, scaling_factor=experts.scaling_factor
+    )
+    if experts.routing == "sigmoid_bias":
+        bias, *rest = rest
+        route = functools.partial(
+            sigmoid_bias_routing, bias=bias, renormalise=experts.renormalise,
+            **sizes,
+        )
+    else:
+        route = functools.partial(
+            group_limited_routing, groups=experts.groups,
+            groups_kept=experts.groups_kept, **sizes,
+        )
     shape = normed.shape
     routed, counters = moe_mlp_held(
         normed.reshape(-1, shape[-1]), router, *stacks, layer=layer,
-        held_first=experts.held_first, groups=experts.groups,
-        groups_kept=experts.groups_kept, num_selected=experts.per_token,
-        scaling_factor=experts.scaling_factor,
+        held_first=experts.held_first, route=route,
         valid=None if valid is None else valid.reshape(-1),
         interpret=config.flash_interpret,
     )
-    shared, _ = _mlp_block(config, normed, (s_gate, s_up, s_down))
+    if not experts.shared:
+        return routed.reshape(shape), counters
+    shared, _ = _mlp_block(config, normed, tuple(rest))
     return routed.reshape(shape) + shared, counters
 
 
@@ -1539,10 +1683,13 @@ def _run_layers(config, runs, x, attend, *, state=None, per_layer=None,
         if run.unroll:
             each = []
             for index, layer in enumerate(run.layers, run.first):
-                x, state, out, _ = _block(
+                x, state, out, counted = _block(
                     config, x, layer, attend_run, jnp.int32(index), None,
                     state, valid=valid, dropless=dropless,
+                    experts=run.experts,
                 )
+                if run.experts is not None:
+                    total = total + counted
                 each.append(out)
             outs.append(
                 jax.tree_util.tree_map(lambda *parts: jnp.stack(parts), *each)
@@ -1556,7 +1703,11 @@ def _run_layers(config, runs, x, attend, *, state=None, per_layer=None,
                 config, x, layer, attend_run, index, inputs, state,
                 valid=valid, dropless=dropless, experts=run.experts,
             )
-            if total is not None:
+            # the routed experts' counters come from the expert layers
+            # alone (a dense run beside them counts nothing)
+            if total is not None and (
+                run.experts is not None or config.experts is None
+            ):
                 total = total + counted
             return (x, state, total), out
 
@@ -1576,12 +1727,18 @@ def _run_layers(config, runs, x, attend, *, state=None, per_layer=None,
 def _rotated_heads(config, normed, weights, freqs, positions):
     """GQA's input side on normed ``[..., H]``: q ``[..., heads, D]`` and
     k, v ``[..., kv_heads, D]``, q and k rotated at ``positions`` (a
-    decode step's ``[S]``, one a slot; else ``[B, T]``)."""
+    decode step's ``[S]``, one a slot; else ``[B, T]``). ``weights`` is
+    ``(wq, wk, wv, biases)`` and, for a family that norms every q and k
+    head before the rotation, the two norms' scales ``[D]`` behind them."""
     hd = config.dims_per_head
-    q, k, v = _project_qkv(normed, *weights)
+    wq, wk, wv, biases, *norms = weights
+    q, k, v = _project_qkv(normed, wq, wk, wv, biases)
     q = q.reshape(normed.shape[:-1] + (config.num_heads, hd))
     k = k.reshape(normed.shape[:-1] + (config.num_kv_heads, hd))
     v = v.reshape(normed.shape[:-1] + (config.num_kv_heads, hd))
+    if len(norms):
+        q = rms_norm(q, norms[0], config.norm_eps)
+        k = rms_norm(k, norms[1], config.norm_eps)
     if normed.ndim == 2:
         q = apply_rope(q[:, None], freqs, positions[:, None])[:, 0]
         k = apply_rope(k[:, None], freqs, positions[:, None])[:, 0]
@@ -1687,9 +1844,9 @@ def prefill(
 ):
     """Run the prompt through the model, write the KV cache at the given
     slots, return logits of each prompt's last real token [B, V]."""
-    if _kinds(config).attention == "hybrid":
-        # the family's cold prefill is its window at offset 0, where the
-        # recurrent state starts from zeros
+    if _kinds(config).attention in ("hybrid", "conv"):
+        # a family with a carried state: its cold prefill is its window at
+        # offset 0, where the state starts from zeros
         return prefill_at_offset(
             config, params, cache, tokens, lengths, jnp.zeros_like(lengths),
             slot_ids, freqs,
@@ -1716,10 +1873,22 @@ def prefill(
     return out, _last_token_logits(config, params, x, lengths), counters
 
 
-def _offset_attend(config, freqs, seq, lengths, offsets, slot_ids):
+# float32 scores a window's attention holds at a time (heads x queries x
+# keys x 4 B): 32 heads x 2 rows x 2,048 queries over 4,096 keys are 2.1 GB
+# whole, 4.3 GB of temp on the described v5e
+SCORES_IN_FLIGHT_BYTES = 1 << 28
+
+
+def _offset_attend(config, freqs, seq: int, lengths, offsets, slot_ids, *,
+                   drop: bool = False):
     """GQA's attend for a suffix into warm dense slots: new KV written at
-    ``offset..offset+len-1``, attention over prefix + suffix. Returns
-    (attend, the suffix's valid mask [B, T])."""
+    ``offset..offset+len-1``, attention over prefix + suffix, a block of
+    each row's queries at a time where the whole window's scores pass
+    ``SCORES_IN_FLIGHT_BYTES``. ``drop`` writes the rows by position and
+    drops those past the cache's end (a right-padded window that passes
+    it, which a family with a carried state sends; else the caller keeps
+    the window inside). Returns (attend, the suffix's valid mask
+    [B, T])."""
     positions = offsets[:, None] + jnp.arange(seq)[None, :]  # [B, T] global
     mask = jnp.arange(seq)[None, :] < lengths[:, None]       # [B, T] valid
     totals = offsets + lengths                               # [B]
@@ -1739,7 +1908,16 @@ def _offset_attend(config, freqs, seq, lengths, offsets, slot_ids):
         # carry a head_dim axis, scale leaves don't). Padding positions
         # beyond the suffix length land past ``totals`` where content is
         # dead. One dynamic_update_slice per row: in place on the carry
-        # (a scatter makes XLA re-lay-out, i.e. copy, the whole cache).
+        # whatever the stack's layout (a scatter wants it row-major: in
+        # place where it lies so, the packed and the 128-wide rows the
+        # decode kernel reads; else XLA re-lays-out, i.e. copies, the
+        # whole cache). A slice that passes the end is clamped onto live
+        # rows, a scattered row there is dropped.
+        if drop:
+            return stacked.at[layer_index, slot_ids[:, None], positions].set(
+                new.astype(stacked.dtype), mode="drop"
+            )
+
         def body(stacked, args):
             row_new, off, slot = args
             start = (layer_index, slot, off) + (0,) * (stacked.ndim - 3)
@@ -1749,6 +1927,24 @@ def _offset_attend(config, freqs, seq, lengths, offsets, slot_ids):
 
         stacked, _ = jax.lax.scan(body, stacked, (new, offsets, slot_ids))
         return stacked
+
+    def over_blocks(q, keys: int, attention):
+        """``attention(queries [B, block, H, D], their offsets [B])`` over
+        the window's queries ``q``, a block of each row's at a time where
+        the scores of all of them over ``keys`` pass the bytes in flight."""
+        rows = SCORES_IN_FLIGHT_BYTES // (4 * q.shape[2] * keys)
+        block_q = seq
+        while block_q * q.shape[0] > rows and block_q % 2 == 0 and block_q > 8:
+            block_q //= 2
+        if block_q == seq:
+            return attention(q, offsets)
+
+        def block(start):
+            part = jax.lax.dynamic_slice_in_dim(q, start, block_q, axis=1)
+            return attention(part, offsets + start)
+
+        blocks = jax.lax.map(block, jnp.arange(0, seq, block_q))
+        return blocks.swapaxes(0, 1).reshape(q.shape)
 
     # The rows the attention reads are pinned as the stack lies
     # (_row_major), packed or not, int8 or not: unpinned, XLA's einsum
@@ -1767,22 +1963,31 @@ def _offset_attend(config, freqs, seq, lengths, offsets, slot_ids):
             ks = write_rows(ks, index, k_s)
             vc = write_rows(vc, index, v_q)
             vs = write_rows(vs, index, v_s)
+            k_rows, v_rows = (
+                _row_major(stack[index, slot_ids]) for stack in (kc, vc)
+            )
+            k_scale, v_scale = ks[index, slot_ids], vs[index, slot_ids]
             with jax.named_scope("attention"):
-                attn = chunk_attention_quant(
-                    q, _row_major(kc[index, slot_ids]), ks[index, slot_ids],
-                    _row_major(vc[index, slot_ids]), vs[index, slot_ids],
-                    offsets, totals, window=win, **family,
-                )
+                attn = over_blocks(q, k_rows.shape[1], lambda part, at: (
+                    chunk_attention_quant(
+                        part, k_rows, k_scale, v_rows, v_scale, at, totals,
+                        window=win, **family,
+                    )
+                ))
             return attn, (kc, vc, ks, vs), None
         kc, vc = kv
         kc = write_rows(kc, index, _pack_kv(k, kc))
         vc = write_rows(vc, index, _pack_kv(v, vc))
+        k_rows, v_rows = (
+            _unpack_kv(config, _row_major(stack[index, slot_ids]))
+            for stack in (kc, vc)
+        )
         with jax.named_scope("attention"):
-            attn = chunk_attention(
-                q, _unpack_kv(config, _row_major(kc[index, slot_ids])),
-                _unpack_kv(config, _row_major(vc[index, slot_ids])),
-                offsets, totals, window=win, **family,
-            )
+            attn = over_blocks(q, k_rows.shape[1], lambda part, at: (
+                chunk_attention(
+                    part, k_rows, v_rows, at, totals, window=win, **family
+                )
+            ))
         return attn, (kc, vc), None
 
     return attend, mask
@@ -1835,6 +2040,11 @@ def prefill_at_offset(
     window = (config, freqs, tokens.shape[1], lengths, offsets, slot_ids)
     if kinds.attention == "hybrid":
         attend, mask = hybrid.window_attends(*window, cache["k"].shape[3])
+    elif kinds.attention == "conv":
+        gqa, mask = _offset_attend(*window, drop=True)
+        attend = short_conv.window_attends(
+            config, lengths, offsets, slot_ids, gqa
+        )
     elif kinds.attention == "latent":
         attend, mask = latent_moe.offset_attend(*window)
     else:
@@ -2155,6 +2365,14 @@ def decode_step(
         # the family's attends are written over [B, T, h]: a step is T = 1;
         # slots that ride along (inactive, lengths 0) are routed nowhere
         x, valid = x[:, None], write_mask[:, None]
+    elif kinds.attention == "conv":
+        attend = short_conv.decode_attends(
+            config, write_mask, _decode_attend(
+                config, freqs, stacked[1:], lengths, positions, write_mask,
+                mesh,
+            ),
+        )
+        valid = write_mask  # a riding slot is routed to no expert
     else:
         attend = _decode_attend(
             config, freqs, stacked, lengths, positions, write_mask, mesh
@@ -2464,7 +2682,58 @@ def _hybrid_from_hf(hf_config) -> Dict[str, Any]:
     )
 
 
+def _short_conv_from_hf(hf_config) -> "LlamaConfig":
+    """The short-convolution family's config from a published ``lfm2_moe``
+    config: the layers' kinds from ``layer_types``, the filter's taps from
+    ``conv_L_cache``, the sigmoid router with its selection bias. A switch
+    the family does not compute is refused by its name. The head is tied
+    (the family's convention) unless the config says otherwise."""
+    wanted = dict(conv_bias=False, use_expert_bias=True)
+    wrong = [
+        f"{name}={getattr(hf_config, name, None)!r}"
+        for name, value in wanted.items()
+        if getattr(hf_config, name, value) != value
+    ]
+    kinds = {"conv": "conv", "full_attention": "attention"}
+    wrong += [
+        f"layer_types has {name!r}"
+        for name in sorted(set(hf_config.layer_types) - set(kinds))
+    ]
+    if wrong:
+        raise ValueError("unsupported lfm2_moe switches: " + ", ".join(wrong))
+    rope = getattr(hf_config, "rope_parameters", None) or {}
+    return LlamaConfig(
+        vocab_size=hf_config.vocab_size,
+        hidden_size=hf_config.hidden_size,
+        intermediate_size=hf_config.intermediate_size,
+        num_layers=hf_config.num_hidden_layers,
+        num_heads=hf_config.num_attention_heads,
+        num_kv_heads=hf_config.num_key_value_heads,
+        head_dim=hf_config.hidden_size // hf_config.num_attention_heads,
+        rope_theta=float(
+            rope.get("rope_theta", getattr(hf_config, "rope_theta", 1e6))
+        ),
+        norm_eps=hf_config.norm_eps,
+        max_seq_len=hf_config.max_position_embeddings,
+        tie_embeddings=getattr(hf_config, "tie_word_embeddings", True),
+        mixers=tuple(kinds[name] for name in hf_config.layer_types),
+        short_conv=ShortConv(taps=hf_config.conv_L_cache),
+        experts=RoutedExperts(
+            routed=hf_config.num_experts, held_first=0,
+            held=hf_config.num_experts,
+            intermediate_size=hf_config.moe_intermediate_size,
+            per_token=hf_config.num_experts_per_tok, shared=0,
+            leading_dense=hf_config.num_dense_layers, groups=1, groups_kept=1,
+            scaling_factor=float(hf_config.routed_scaling_factor),
+            routing="sigmoid_bias",
+            renormalise=bool(hf_config.norm_topk_prob),
+        ),
+    )
+
+
 def config_from_hf(hf_config) -> LlamaConfig:
+    if getattr(hf_config, "model_type", "") == "lfm2_moe":
+        return _short_conv_from_hf(hf_config)
     rope_scaling = normalize_rope_scaling(
         getattr(hf_config, "rope_scaling", None)
     )

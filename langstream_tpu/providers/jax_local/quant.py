@@ -124,7 +124,7 @@ def init_quantized_params(
 
     from langstream_tpu.providers.jax_local import model as model_lib
 
-    if config.mixers is not None:
+    if config.hybrid is not None:
         # the hybrid family draws its int8 form itself, layer by layer
         from langstream_tpu.providers.jax_local import hybrid_sparse_linear
 

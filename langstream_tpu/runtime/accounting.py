@@ -134,26 +134,29 @@ class CostModel:
         paged_kernel: Optional[str] = None,
         tp: int = 1,
     ) -> "CostModel":
-        params = config.num_params()
+        params = held = config.num_params()
         head_dim = config.dims_per_head
         tp = max(1, int(tp))
-        if getattr(config, "mla", None) is not None:
-            # latent attention: one row of latents a token a layer, and a
-            # token meets the routed experts it is sent to among the held
-            # ones (in expectation), not every expert the chip holds
-            experts = config.experts
-            expert = 3 * config.hidden_size * experts.intermediate_size
+        experts = getattr(config, "experts", None)
+        if experts is not None:
+            # routed experts: a token meets the experts it is sent to among
+            # the held ones (in expectation), not every expert the chip
+            # holds; a step's bytes are still all the weights held (a batch
+            # of slots touches nearly every expert)
             idle = experts.held - experts.per_token * experts.held / experts.routed
-            met = params - int(
-                (config.num_layers - experts.leading_dense) * idle * expert
+            params -= int(
+                (config.num_layers - experts.leading_dense) * idle
+                * 3 * config.hidden_size * experts.intermediate_size
             )
+        if getattr(config, "mla", None) is not None:
+            # latent attention: one row of latents a token a layer
             return cls(
-                params=met,
+                params=params,
                 num_layers=config.num_layers,
                 num_heads=config.num_heads,
                 num_kv_heads=1,
                 head_dim=config.mla.kv_lora_rank + config.mla.qk_rope_head_dim,
-                weight_bytes=params * 2,
+                weight_bytes=held * 2,
                 kv_row_bytes=config.num_layers * 2 * (
                     config.mla.kv_lora_rank + config.mla.qk_rope_head_dim
                 ),
@@ -163,9 +166,12 @@ class CostModel:
             )
         layers = config.num_layers
         if getattr(config, "mixers", None) is not None:
-            # the hybrid family: KV rows in its sparse layers alone (the
-            # linear-attention layers' state does not grow with a token)
-            layers = sum(1 for mixer in config.mixers if mixer == "sparse")
+            # per-layer mixers: KV rows in the attention layers alone (a
+            # linear-attention or conv layer's state does not grow with a
+            # token)
+            layers = sum(
+                1 for mixer in config.mixers if mixer in ("sparse", "attention")
+            )
         if kv_quant:
             # int8 values + one f32 scale per (layer, pos, kv_head) for
             # each of k and v
@@ -182,7 +188,7 @@ class CostModel:
             num_heads=config.num_heads,
             num_kv_heads=config.num_kv_heads,
             head_dim=head_dim,
-            weight_bytes=params * (1 if weight_quant == "int8" else 2) // tp,
+            weight_bytes=held * (1 if weight_quant == "int8" else 2) // tp,
             kv_row_bytes=kv_row_bytes // tp,
             kv_block_size=max(1, int(kv_block_size)),
             paged_kernel=paged_kernel,

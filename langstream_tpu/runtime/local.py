@@ -14,6 +14,7 @@ the reference's test strategy (``AbstractApplicationRunner.java:58``).
 from __future__ import annotations
 
 import asyncio
+import gc
 import logging
 import os
 import tempfile
@@ -263,6 +264,10 @@ class LocalApplicationRunner:
         # always release engines/brokers, even when a runner died — the
         # engine thread and device HBM must not outlive the app
         await self._service_provider_registry.close()
+        # what ``settle_collector`` froze is the collector's again: a
+        # stopped engine's weights and cache go with the cycles that hold
+        # them (the benchmark makes its reference on the same device)
+        gc.unfreeze()
         await self.topic_runtime.close()
         if failure is not None:
             raise failure
@@ -301,6 +306,20 @@ class LocalApplicationRunner:
         )
 
 
+def settle_collector() -> None:
+    """For a process's entry point (``run_application``, ``serve``, a pod's
+    ``agent_runner_main``), once it is warm: take what start-up left (modules,
+    the precompiled programs' traces: millions of long-lived objects) out
+    of Python's cyclic collector's way. A gen-2 pass over them holds the
+    GIL, and with it the engine's thread, for 0.3 s every 25-40 s under
+    load (``PERF.md`` section 6, PR 35). Frozen objects still go when
+    their last reference does; only cycles among them stay, so whoever
+    stops or supersedes what was alive here unfreezes
+    (``LocalApplicationRunner.stop``, ``EngineSupervisor._restart``)."""
+    gc.collect()
+    gc.freeze()
+
+
 async def run_application(
     app_dir: str,
     *,
@@ -323,4 +342,5 @@ async def run_application(
     plan = build_execution_plan(application)
     runner = LocalApplicationRunner(plan, tracer=tracer)
     await runner.start()
+    settle_collector()
     return runner

@@ -232,7 +232,10 @@ async def agent_runner_main(
 
     Reference: ``AgentRunnerStarter.java:39`` → ``AgentRunner.run``.
     """
-    from langstream_tpu.runtime.local import LocalApplicationRunner
+    from langstream_tpu.runtime.local import (
+        LocalApplicationRunner,
+        settle_collector,
+    )
 
     # pods can override the port via env without changing the manifest
     # command line (tests use this to avoid :8080 collisions)
@@ -300,6 +303,7 @@ async def agent_runner_main(
             pass
     try:
         await runner.start()
+        settle_collector()
         http.ready = True
         join = asyncio.ensure_future(runner.join())
         stop_task = asyncio.ensure_future(stop.wait())

@@ -42,6 +42,7 @@ supervisor fails the drained waiters once and goes ``failed``.
 from __future__ import annotations
 
 import collections
+import gc
 import logging
 import threading
 import time
@@ -239,6 +240,12 @@ class EngineSupervisor:
             requests = engine.drain_for_recovery()
             replayed = sum(1 for r in requests if r.replay_tokens)
             engine.retire()
+            # a process that froze its start-up objects out of the
+            # collector's way (runtime/local.py::settle_collector) froze
+            # this engine with them, and nothing frozen is ever examined
+            # again: hand everything back, so that an ordinary pass frees
+            # the superseded engine. The process runs unfrozen from here.
+            gc.unfreeze()
             old_stats = engine.stats
             if self.watchdog is not None:
                 self.watchdog.stop()

@@ -901,3 +901,111 @@ def test_latent_share_compiles_at_published_widths(
         )
     ]
     assert not scores, scores
+
+
+# ---------------------------------------------------------------------- #
+# The short-convolution, routed-experts cut at published widths (ISSUE 35)
+# ---------------------------------------------------------------------- #
+CONV_CUT = {"preset": "lfm2-24b-a2b", "num-layers": 10, "max_seq_len": 4096}
+CONV_SLOTS = 64
+
+
+def _compile_conv(monkeypatch, one_chip, program):
+    """The first 10 layers of LFM2-24B-A2B as ``lfm2-24b-a2b.gen`` runs
+    them (64 experts held, the whole vocabulary, 64 slots x 4,096): the
+    decode chunk (a 4-step scan of decode_step + greedy pick) or a cold
+    prefill of ``program`` = (rows, bucket), the cache donated, for the
+    described chip. Returns (lowered, compiled, params' and cache's
+    shapes)."""
+    import langstream_tpu.ops.flash_attention as flash_attention
+    from langstream_tpu.providers.jax_local import model as model_lib
+
+    monkeypatch.setattr(flash_attention, "on_tpu", lambda: True)
+    config = model_lib.LlamaConfig.from_dict(dict(CONV_CUT))
+    freqs = model_lib.model_freqs(config)
+
+    def place(tree):
+        return jax.tree_util.tree_map(
+            lambda leaf: _spec(leaf.shape, leaf.dtype, one_chip), tree
+        )
+
+    params = place(jax.eval_shape(lambda: model_lib.init_params(config, 0)))
+    cache = place(jax.eval_shape(
+        lambda: model_lib.init_cache(config, CONV_SLOTS, config.max_seq_len)
+    ))
+    if program == "decode_chunk":
+
+        @functools.partial(jax.jit, donate_argnums=(1,))
+        def run(params, cache, tokens, lengths, active):
+            def body(carry, _):
+                cache, tokens, lengths, moe = carry
+                cache, logits, step_moe = model_lib.decode_step(
+                    config, params, cache, tokens, lengths, freqs, active
+                )
+                picked = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                picked = jnp.where(active, picked, 0)
+                lengths = jnp.where(active, lengths + 1, lengths)
+                return (cache, picked, lengths, moe + step_moe), picked
+
+            (cache, _, _, moe), out = jax.lax.scan(
+                body, (cache, tokens, lengths, model_lib.zero_counters(config)),
+                None, length=4,
+            )
+            return cache, out.T, moe
+
+        args = [
+            place(jax.ShapeDtypeStruct((CONV_SLOTS,), dtype))
+            for dtype in (jnp.int32, jnp.int32, jnp.bool_)
+        ]
+    else:
+        rows, bucket = program
+
+        @functools.partial(jax.jit, donate_argnums=(1,))
+        def run(params, cache, tokens, lengths, slot_ids):
+            return model_lib.prefill(
+                config, params, cache, tokens, lengths, slot_ids, freqs
+            )
+
+        args = [
+            place(jax.ShapeDtypeStruct(shape, jnp.int32))
+            for shape in ((rows, bucket), (rows,), (rows,))
+        ]
+    lowered = run.lower(params, cache, *args)
+    return lowered, lowered.compile(), params, cache
+
+
+@pytest.mark.parametrize(
+    "program", ["decode_chunk", (16, 256), (2, 2048)],
+    ids=["decode_chunk", "prefill_16x256", "prefill_2x2048"],
+)
+def test_conv_family_compiles_at_published_widths(one_chip, monkeypatch, program):
+    """``lfm2-24b-a2b.gen``'s hot programs pass the chip's compiler at
+    published widths, 10 layers, 64 x 4,096, and fit beside 10.53 GB of
+    weights and 1.08 GB of cache (conv state, packed K and V of the two
+    attention layers): arguments under 11.7 GB, the whole cache an aliased
+    output, temp under 1 GB (the widest prefills' attention runs in blocks
+    of queries). The grouped matmul is there three times an expert run
+    (gate, up, down: 4 runs), and in the decode chunk ``flash_decode``
+    once an attention layer over rows packed two heads to a lane row; no
+    copy of an expert stack, nor of K's or V's, is made anywhere."""
+    lowered, compiled, params, cache = _compile_conv(monkeypatch, one_chip, program)
+    text, built = lowered.as_text(), compiled.as_text()
+    decode = program == "decode_chunk"
+    assert text.count('kernel_name = "moe_grouped_matmul"') == 12
+    assert text.count('kernel_name = "flash_decode"') == (2 if decode else 0)
+    assert built.count('custom_call_target="tpu_custom_call"') == (14 if decode else 12)
+    assert cache["k"].shape == (2, CONV_SLOTS, 4096, 4, 128)
+    assert cache["conv"].shape == (8, CONV_SLOTS, 2, 2048)
+    memory = compiled.memory_analysis()
+    cache_bytes = sum(
+        int(np.prod(leaf.shape)) * leaf.dtype.itemsize for leaf in cache.values()
+    )
+    assert memory.alias_size_in_bytes >= cache_bytes, memory
+    assert memory.argument_size_in_bytes < 11.7e9, memory
+    assert memory.temp_size_in_bytes < (64e6 if decode else 1e9), memory
+    for leaf in (params["moe.w_gate"], params["moe.w_down"], cache["k"]):
+        stack = ",".join(map(str, leaf.shape))
+        copies = re.findall(
+            rf"= \w+\[{stack}\]\{{[^}}]*\}} (?:copy|transpose)\(", built
+        )
+        assert not copies, copies

@@ -14,6 +14,7 @@ switch the family cannot take to its refusal.
 """
 
 import asyncio
+import functools
 import math
 
 import jax
@@ -27,6 +28,7 @@ from langstream_tpu.ops.moe import (
     group_limited_routing,
     grouped_matmul,
     moe_mlp_held,
+    sigmoid_bias_routing,
     routed_tile,
 )
 from langstream_tpu.ops.rope import rope_frequencies, yarn_softmax_scale
@@ -482,8 +484,8 @@ def test_the_four_shares_and_one_shared_expert_are_the_uncut_layer():
 # --------------------------------------------------------------------- #
 # the grouped path against a loop over experts
 # --------------------------------------------------------------------- #
-def _loop_over_experts(x, router, w_gate, w_up, w_down, held_first, valid, **routing):
-    weights, chosen = group_limited_routing(x @ router, **routing)
+def _loop_over_experts(x, router, w_gate, w_up, w_down, held_first, valid, route):
+    weights, chosen = route(x @ router)
     out = np.zeros_like(x)
     met = 0
     by_expert = np.zeros(w_gate.shape[0], np.int64)
@@ -500,11 +502,13 @@ def _loop_over_experts(x, router, w_gate, w_up, w_down, held_first, valid, **rou
     return out, met, by_expert
 
 
+@pytest.mark.parametrize("rule", ["group_limited", "sigmoid_bias"])
 @pytest.mark.parametrize("kernel", [False, True], ids=["ragged_dot", "pallas-interpret"])
-def test_the_grouped_path_drops_no_token_under_a_skewed_router(kernel):
+def test_the_grouped_path_drops_no_token_under_a_skewed_router(kernel, rule):
     """A router that sends almost every token to the same two experts: the
     busiest held expert takes many times the mean, nothing is dropped (the
-    layout has room for the worst case), and padding tokens take no row."""
+    layout has room for the worst case), and padding tokens take no row;
+    under either routing rule (``moe_mlp_held`` takes the rule)."""
     rng = np.random.default_rng(4)
     tokens, hidden, inter, experts, held_first, held = 50, 64, 32, 8, 2, 4
     x = jnp.asarray(rng.normal(size=(tokens, hidden)), jnp.float32)
@@ -518,15 +522,25 @@ def test_the_grouped_path_drops_no_token_under_a_skewed_router(kernel):
     )
     w_down = jnp.asarray(rng.normal(size=(held, inter, hidden)) * inter ** -0.5, jnp.float32)
     valid = np.arange(tokens) < 45
-    routing = dict(groups=4, groups_kept=2, num_selected=3, scaling_factor=4.0)
+    if rule == "group_limited":
+        route = functools.partial(
+            group_limited_routing, groups=4, groups_kept=2, num_selected=3,
+            scaling_factor=4.0,
+        )
+    else:
+        route = functools.partial(
+            sigmoid_bias_routing,
+            bias=jnp.asarray(rng.normal(size=experts) * 0.05, jnp.float32),
+            num_selected=3, scaling_factor=1.0, renormalise=True,
+        )
     with jax.default_matmul_precision("highest"):
         got, counters = moe_mlp_held(
             x, router, w_gate[None], w_up[None], w_down[None],
-            held_first=held_first, valid=jnp.asarray(valid), interpret=kernel,
-            **routing,
+            held_first=held_first, route=route, valid=jnp.asarray(valid),
+            interpret=kernel,
         )
         want, met, by_expert = _loop_over_experts(
-            np.asarray(x), router, w_gate, w_up, w_down, held_first, valid, **routing
+            np.asarray(x), router, w_gate, w_up, w_down, held_first, valid, route
         )
     np.testing.assert_allclose(np.asarray(got)[valid], want[valid], atol=2e-5)
     routed, held_met, rows = (int(n) for n in counters[:3])

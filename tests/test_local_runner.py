@@ -1,4 +1,5 @@
 import asyncio
+import gc
 import textwrap
 
 import pytest
@@ -25,6 +26,44 @@ async def read_n(reader, n, timeout=5.0):
             raise TimeoutError(f"got {len(out)}/{n}: {out}")
         out.extend(await reader.read(timeout=0.2))
     return out
+
+
+def test_a_started_application_freezes_its_start_up_objects_until_it_stops(tmp_path):
+    """``run_application`` is a process's entry point: once the agents
+    are up it takes what start-up left out of the collector's way
+    (``settle_collector``), and ``stop`` hands it back, so that whatever
+    the application held is freed with the cycles that hold it."""
+    app_dir = write_app(
+        tmp_path,
+        {
+            "pipeline.yaml": """
+                topics:
+                  - name: "in"
+                    creation-mode: create-if-not-exists
+                pipeline:
+                  - id: "drop"
+                    type: "python-processor"
+                    input: "in"
+                    configuration: {className: "drop_agent.Drop"}
+            """,
+            "python/drop_agent.py": """
+                class Drop:
+                    def process(self, record):
+                        return []
+            """,
+        },
+    )
+
+    async def main():
+        gc.unfreeze()
+        runner = await run_application(app_dir)
+        try:
+            assert gc.get_freeze_count() > 10_000
+        finally:
+            await runner.stop()
+        assert gc.get_freeze_count() == 0
+
+    asyncio.run(main())
 
 
 def test_yaml_app_end_to_end(tmp_path):

@@ -221,3 +221,100 @@ def test_moe_trainer_step():
         loss2 = trainer.train_step(tokens, mask)
     assert np.isfinite(loss1) and np.isfinite(loss2)
     assert loss2 < loss1
+
+
+# --------------------------------------------------------------------- #
+# the routing rules of a chip's share (moe_mlp_held)
+# --------------------------------------------------------------------- #
+def test_sigmoid_bias_routing_is_a_plain_top_k_over_score_plus_bias():
+    """Selection by ``s + b``, weights from ``s`` alone, renormalised over
+    the chosen: against a top-k written out here. The bias is large enough
+    to change most tokens' chosen sets, so that weights read off ``s + b``,
+    or a selection without ``b``, would differ."""
+    from langstream_tpu.ops.moe import sigmoid_bias_routing
+
+    rng = np.random.default_rng(0)
+    tokens, experts, k = 40, 16, 4
+    logits = rng.normal(size=(tokens, experts)).astype(np.float32)
+    bias = (rng.normal(size=experts) * 0.1).astype(np.float32)
+    weights, chosen = sigmoid_bias_routing(
+        jnp.asarray(logits), jnp.asarray(bias), num_selected=k,
+        scaling_factor=2.5, renormalise=True,
+    )
+    scores = 1.0 / (1.0 + np.exp(-logits.astype(np.float64)))
+    moved = 0
+    for token in range(tokens):
+        order = np.argsort(-(scores[token] + bias))[:k]
+        assert sorted(chosen[token].tolist()) == sorted(order.tolist())
+        picked = scores[token][np.asarray(chosen[token])]
+        want = 2.5 * picked / (picked.sum() + 1e-6)
+        np.testing.assert_allclose(weights[token], want, rtol=1e-5)
+        moved += set(order) != set(np.argsort(-scores[token])[:k])
+    assert moved > tokens // 4  # the bias changes the chosen set
+    plain, _ = sigmoid_bias_routing(
+        jnp.asarray(logits), jnp.asarray(bias), num_selected=k,
+        scaling_factor=1.0, renormalise=False,
+    )
+    np.testing.assert_allclose(
+        plain, np.take_along_axis(scores, np.asarray(chosen), -1), rtol=1e-5
+    )
+
+
+@pytest.mark.parametrize("rule", ["group_limited", "sigmoid_bias"])
+def test_the_shares_of_a_routed_layer_add_up_to_the_uncut_layer(rule):
+    """Four chips hold 2 of 8 experts each (``held`` < ``routed``): the
+    parts ``moe_mlp_held`` computes for the four held ranges add up to
+    what it gives with all 8 held, and to a loop over every token's
+    experts written out here; under either routing rule."""
+    import functools
+
+    from langstream_tpu.ops.moe import (
+        group_limited_routing,
+        moe_mlp_held,
+        sigmoid_bias_routing,
+    )
+
+    rng = np.random.default_rng(3)
+    tokens, hidden, inter, experts, k = 37, 64, 32, 8, 3
+    x = jnp.asarray(rng.normal(size=(tokens, hidden)), jnp.float32)
+    router = jnp.asarray(rng.normal(size=(hidden, experts)) * hidden ** -0.5, jnp.float32)
+    w_gate, w_up = (
+        jnp.asarray(rng.normal(size=(experts, hidden, inter)) * hidden ** -0.5, jnp.float32)
+        for _ in range(2)
+    )
+    w_down = jnp.asarray(rng.normal(size=(experts, inter, hidden)) * inter ** -0.5, jnp.float32)
+    if rule == "group_limited":
+        route = functools.partial(
+            group_limited_routing, groups=4, groups_kept=2, num_selected=k,
+            scaling_factor=4.0,
+        )
+    else:
+        route = functools.partial(
+            sigmoid_bias_routing,
+            bias=jnp.asarray(rng.normal(size=experts) * 0.05, jnp.float32),
+            num_selected=k, scaling_factor=1.0, renormalise=True,
+        )
+
+    def share(first, held):
+        part, counters = moe_mlp_held(
+            x, router, w_gate[None, first:first + held],
+            w_up[None, first:first + held], w_down[None, first:first + held],
+            held_first=first, route=route,
+        )
+        return np.asarray(part), counters
+
+    with jax.default_matmul_precision("highest"):
+        parts = [share(first, 2) for first in (0, 2, 4, 6)]
+        uncut, counters = share(0, experts)
+        weights, chosen = route(x @ router)
+        want = np.zeros((tokens, hidden), np.float64)
+        for token in range(tokens):
+            for weight, expert in zip(np.asarray(weights[token]), np.asarray(chosen[token])):
+                hidden_row = jax.nn.silu(x[token] @ w_gate[expert]) * (x[token] @ w_up[expert])
+                want[token] += float(weight) * np.asarray(hidden_row @ w_down[expert])
+    np.testing.assert_allclose(sum(part for part, _ in parts), uncut, atol=2e-5)
+    np.testing.assert_allclose(uncut, want, atol=2e-5)
+    assert float(np.abs(uncut).max()) > 0.1
+    # every assignment meets exactly one share
+    assert sum(int(c[1]) for _, c in parts) == int(counters[1]) == tokens * k
+    assert all(0 < int(c[1]) < tokens * k for _, c in parts)

@@ -16,7 +16,10 @@ watchdog escalation, the paged-allocator and dispatch fault points, and
 the OpenAI surface's sibling-cancellation error propagation."""
 
 import asyncio
+import gc
+import logging
 import time
+import weakref
 
 import pytest
 
@@ -29,6 +32,7 @@ from langstream_tpu.providers.jax_local.engine import (
 )
 from langstream_tpu.providers.jax_local.model import LlamaConfig, init_params
 from langstream_tpu.runtime import faults
+from langstream_tpu.runtime.local import settle_collector
 from langstream_tpu.runtime.supervisor import EngineSupervisor
 
 
@@ -490,6 +494,49 @@ def test_escalation_restart_resurrects_live_session(tiny):
     assert supervisor.engine is not first_engine
     assert result.tokens == expected.tokens
     supervisor.stop()
+
+
+@pytest.mark.parametrize("arm", ["crash", "escalation"])
+def test_a_heal_in_a_frozen_process_frees_the_superseded_engine(tiny, arm):
+    """A serving process freezes its start-up objects out of the
+    collector's way once it is warm (``settle_collector``), the first
+    engine among them, and nothing frozen is ever examined again. A heal
+    must all the same leave nothing of the superseded engine: its cache
+    is the device's again before the replacement is built, and the engine
+    itself (it sits in cycles) goes with the collector's next pass."""
+    config, params = tiny
+
+    def factory():
+        engine = _factory(config, params)()
+        engine.precompile()
+        return engine
+
+    if arm == "crash":
+        faults.configure("engine_thread_crash@step=2")
+    supervisor = EngineSupervisor(factory)
+    first = weakref.ref(supervisor.engine)
+    settle_collector()
+    # pytest keeps every log record of a test, and "engine loop crashed"
+    # carries the traceback, whose frames hold the engine
+    logging.disable(logging.ERROR)
+    try:
+        if arm == "crash":  # the heal runs on the dying engine's thread
+            _run(supervisor.engine, [1, 2, 3, 4, 5], GREEDY)
+        else:
+            supervisor.request_restart("watchdog_escalation:test")
+        assert supervisor.restarts == 1
+        assert supervisor.engine is not first()
+        assert supervisor.engine.cache is not None
+        deadline = time.monotonic() + 10.0
+        while first() is not None and time.monotonic() < deadline:
+            assert first().cache is None  # not the collector's to free
+            time.sleep(0.02)  # the dying thread still holds it
+            gc.collect()
+        assert first() is None
+    finally:
+        logging.disable(logging.NOTSET)
+        gc.unfreeze()
+        supervisor.stop()
 
 
 # ---------------------------------------------------------------------- #
