@@ -13,9 +13,13 @@ Design (TPU-first, see SURVEY.md §7 phase 4/5):
   immediately. No batch barrier — exactly the property the runner's
   emit-as-you-complete contract preserves upstream.
 - **Dedicated device thread**: the asyncio side enqueues requests
-  (thread-safe) and receives per-token callbacks via
-  ``loop.call_soon_threadsafe``; device dispatch never blocks the event
-  loop.
+  (thread-safe) and receives per-token callbacks on its own loop. The
+  engine thread records a harvested chunk's callbacks while it does the
+  chunk's bookkeeping and hands them over with ONE
+  ``loop.call_soon_threadsafe`` a loop when the bookkeeping is done
+  (:meth:`DecodeEngine._hand_over`); the loop then runs one request's
+  callbacks an iteration (:class:`_LoopInbox`). Device dispatch never
+  blocks the event loop.
 - **Session KV reuse** (BASELINE config #5): a finished request may pin its
   slot under a session id; a follow-up with the same session id whose
   prompt extends the pinned history skips re-prefilling the shared prefix
@@ -31,6 +35,7 @@ Design (TPU-first, see SURVEY.md §7 phase 4/5):
 from __future__ import annotations
 
 import asyncio
+import collections
 import contextlib
 import dataclasses
 import logging
@@ -159,7 +164,7 @@ def engines_snapshot() -> Dict[str, float]:
     (reference: AgentRunner.java:99-113 exposes runtime internals the
     same way; here the runtime internal is the TPU engine)."""
     out: Dict[str, float] = {}
-    tokens = steps = chunks = 0
+    tokens = handovers = steps = chunks = 0
     session_hits = prefix_hits = prefix_tokens = 0
     decode_time = prefill_time = 0.0
     prefill_rows = prefill_join_rows = 0
@@ -229,6 +234,7 @@ def engines_snapshot() -> Dict[str, float]:
     for engine in live_engines:
         stats = engine.stats
         tokens += stats["tokens_generated"]
+        handovers += stats["emit_handovers"]
         steps += stats["decode_steps"]
         chunks += stats["decode_chunks"]
         decode_time += stats["decode_time"]
@@ -439,6 +445,8 @@ def engines_snapshot() -> Dict[str, float]:
     out["jax_engine_prefix_hits"] = float(prefix_hits)
     out["jax_engine_prefix_tokens_reused"] = float(prefix_tokens)
     out["jax_engine_tokens_generated"] = float(tokens)
+    # cross-thread posts that carried them to their callers' loops
+    out["jax_engine_emit_handovers_total"] = float(handovers)
     out["jax_engine_decode_steps"] = float(steps)
     out["jax_engine_decode_chunks"] = float(chunks)
     out["jax_engine_decode_time_seconds"] = round(decode_time, 6)
@@ -529,8 +537,9 @@ class GenerationRequest:
     prompt_tokens: List[int]
     sampling: SamplingParams
     stop_tokens: Set[int] = dataclasses.field(default_factory=set)
-    # called from the engine thread via call_soon_threadsafe(loop) with
-    # (token_id, is_last)
+    # called with (token_id, is_last): on ``loop``, a harvested chunk's
+    # calls for this request in one callback (DecodeEngine._hand_over);
+    # on the engine thread at the token's bookkeeping when loop is None
     on_token: Optional[Callable[[int, bool], None]] = None
     session_id: Optional[str] = None
     future: Optional[Any] = None  # asyncio.Future or concurrent future
@@ -636,30 +645,111 @@ class _Slot:
         return self.request is not None and not self.prefilling
 
 
+class _Delivery:
+    """One request's part of a hand-over to its loop: its ``on_token``
+    arguments in order, then its result (or the error that fails it)."""
+
+    __slots__ = ("request", "calls", "result", "error")
+
+    def __init__(
+        self, request: "GenerationRequest",
+        error: Optional[BaseException] = None,
+    ) -> None:
+        self.request = request
+        self.calls: List[Tuple[int, bool]] = []
+        self.result: Optional[GenerationResult] = None
+        self.error = error
+
+    def run(self) -> None:
+        """On the request's loop: one callback a token, then the future.
+        A callback that raises loses its own token only, as when each
+        was a callback of the loop's."""
+        request = self.request
+        for token, done in self.calls:
+            try:
+                request.on_token(token, done)
+            except Exception:  # noqa: BLE001
+                logger.exception("on_token callback raised")
+        future = request.future
+        if future is None or future.done():
+            return
+        if self.error is not None:
+            future.set_exception(self.error)
+        elif self.result is not None:
+            future.set_result(self.result)
+
+
+class _LoopInbox:
+    """The loop's end of every hand-over to it: deliveries in the order
+    they were posted, ONE run an iteration of the loop. asyncio runs
+    every callback that is ready when an iteration starts before it
+    polls its sockets and timers again, so a chunk's deliveries posted
+    as so many ``call_soon`` would hold the loop for the whole chunk
+    (64 requests x 32 tokens); chained, the loop polls between two
+    requests, and the select it makes there is where the engine thread
+    gets the GIL back. Touched on the loop's thread only."""
+
+    def __init__(self, loop) -> None:
+        self.loop = loop
+        self.deliveries: "collections.deque[_Delivery]" = collections.deque()
+        self.pumping = False
+
+    def accept(self, deliveries: List[_Delivery]) -> None:
+        """The one callable a hand-over posts across threads."""
+        self.deliveries.extend(deliveries)
+        if not self.pumping:
+            self.pumping = True
+            self._pump()
+
+    def _pump(self) -> None:
+        try:
+            self.deliveries.popleft().run()
+        finally:
+            if self.deliveries:
+                self.loop.call_soon(self._pump)
+            else:
+                self.pumping = False
+
+
+# loop -> its inbox, for every engine of the process: a request that a
+# rebuilt engine resumes gets its tokens behind those the dead one posted
+_INBOXES: Dict[Any, _LoopInbox] = {}
+
+
+def _post_to_loop(loop, deliveries: List[_Delivery]) -> None:
+    """Hand ``deliveries`` to ``loop`` from any thread with ONE
+    ``call_soon_threadsafe``, behind everything posted to it before.
+    Raises RuntimeError if the loop is closed."""
+    inbox = _INBOXES.get(loop)
+    if inbox is None:
+        for known in list(_INBOXES):
+            if known.is_closed():
+                _INBOXES.pop(known, None)
+        inbox = _INBOXES.setdefault(loop, _LoopInbox(loop))
+    loop.call_soon_threadsafe(inbox.accept, deliveries)
+
+
 def fail_request_future(
     request: "GenerationRequest", error: BaseException
 ) -> None:
     """Deliver ``error`` to a request's waiter from any thread — the ONE
     future-failing path shared by crash fail-fast, load shedding, the
     retired-queue straggler sweep, and the supervisor's give-up handling
-    (a fix to the loop-closed race must land once, not four times)."""
+    (a fix to the loop-closed race must land once, not four times). On a
+    loop it goes through the loop's inbox, so it lands behind the tokens
+    already handed over."""
     future = request.future
     if future is None:
         return
-
-    def resolve() -> None:
-        if not future.done():
-            future.set_exception(error)
-
     if request.loop is not None:
         try:
-            request.loop.call_soon_threadsafe(resolve)
+            _post_to_loop(request.loop, [_Delivery(request, error=error)])
         except RuntimeError:
             # waiter's loop already closed (caller gave up) — must not
             # abort failing any REMAINING waiters
             pass
-    else:
-        resolve()
+    elif not future.done():
+        future.set_exception(error)
 
 
 def _bucket(length: int, buckets: List[int]) -> int:
@@ -1146,6 +1236,9 @@ class DecodeEngine:
         # prefill dispatches whose first tokens are not yet harvested
         # (FIFO — the device executes dispatches in order)
         self._prefill_inflight: List[Dict[str, Any]] = []  # owned-by: _run_loop
+        # what the bookkeeping in progress owes each loop: loop ->
+        # id(request) -> its delivery, posted by _hand_over
+        self._outbox: Dict[Any, Dict[int, _Delivery]] = {}  # owned-by: _run_loop
         # decode dispatches so far: a prefill harvested at the count it
         # was launched at rides the first dispatch behind it
         # (stats["prefill_join_rows"])
@@ -1291,6 +1384,9 @@ class DecodeEngine:
     def _fresh_stats() -> Dict[str, Any]:
         return {
             "tokens_generated": 0,
+            # cross-thread posts that carried them (and the results) to
+            # their loops: one a loop a harvested chunk or prefill record
+            "emit_handovers": 0,
             "requests": 0,
             "prefill_calls": 0,
             "warm_prefill_calls": 0,
@@ -1324,7 +1420,7 @@ class DecodeEngine:
             # (each sum is taken at its phase span's own boundaries —
             # tracing.phase in _run_loop — so /metrics and a trace agree)
             "idle_time": 0.0,        # engine thread blocked on empty queue
-            "emit_time": 0.0,        # host token bookkeeping + callbacks
+            "emit_time": 0.0,        # host token bookkeeping + hand-overs
             "admit_time": 0.0,       # admission: slots, batch builds, prefill dispatches
             "dispatch_time": 0.0,    # building and dispatching decode chunks
             # goodput ledger: tokens that reached a live caller vs tokens
@@ -3175,6 +3271,7 @@ class DecodeEngine:
                 else:
                     keep.append(queued)
             self._pending = keep
+            self._hand_over()
 
     def _shed_expired(self) -> None:
         """Admission deadlines (serve ``--queue-timeout-s``): a pending
@@ -4310,7 +4407,11 @@ class DecodeEngine:
                 "engine.harvest_prefills",
                 rows=len(record["group"]), batch=record["batch"],
             ) as span:
-                self._harvest_record(record)
+                try:
+                    self._harvest_record(record)
+                finally:
+                    # the first tokens go now, not with the chunk's
+                    span.set(handovers=self._hand_over())
                 self._note_counters(span, record.get("moe"))
                 # rows still live after their first token, with no decode
                 # dispatch since their launch: they ride the next one
@@ -5071,19 +5172,28 @@ class DecodeEngine:
 
     @contextlib.contextmanager
     def _emit_span(self):
-        """A harvested chunk's bookkeeping and its tokens' hand-over as
-        ONE ``engine.emit`` span (tokens as an attribute, no span a
-        token), timed into ``emit_time`` at the same boundaries."""
+        """A harvested chunk's bookkeeping, then its tokens' hand-over,
+        as ONE ``engine.emit`` span (``tokens`` and the ``handovers``
+        that carried them as attributes, no span a token), timed into
+        ``emit_time`` at the same boundaries. The hand-over is made
+        whatever the bookkeeping raised: a token that reached
+        ``slot.generated`` has reached its caller's loop by the time
+        anyone else can see the slot."""
         with self._phase("engine.emit", "emit_time") as span:
             before = self.stats["tokens_generated"]
-            yield span
-            span.set(tokens=self.stats["tokens_generated"] - before)
+            try:
+                yield span
+            finally:
+                span.set(
+                    tokens=self.stats["tokens_generated"] - before,
+                    handovers=self._hand_over(),
+                )
 
     def _account_mixed(
         self, inflight: Dict[str, Any], sampled, lps, tops, ended: float
     ) -> None:
-        """A harvested mixed step's bookkeeping and its tokens' hand-over
-        (the body of its ``engine.emit`` span)."""
+        """A harvested mixed step's bookkeeping (the body of its
+        ``engine.emit`` span, which hands its tokens over at its end)."""
         wall = ended - inflight["started"]
         decode_mask = inflight["decode_mask"]
         completes = inflight["completes"]
@@ -5276,8 +5386,9 @@ class DecodeEngine:
         self, inflight: Dict[str, Any], out_host, lps_host, tops,
         ended: float,
     ) -> None:
-        """A harvested chunk's bookkeeping and the per-token loop (the
-        body of its ``engine.emit`` span: ONE span a chunk, none a
+        """A harvested chunk's bookkeeping and the per-token loop, which
+        records each token's callback for the hand-over and makes none
+        (the body of its ``engine.emit`` span: ONE span a chunk, none a
         token)."""
         steps = inflight["steps"]
         active = inflight["active"]
@@ -5482,7 +5593,7 @@ class DecodeEngine:
             or slot.length + 1 >= self.max_seq_len
         )
         if request.on_token is not None and not hit_stop:
-            self._post(request, request.on_token, token, done)
+            self._post_token(request, token, done)
         if done:
             if hit_stop:
                 reason = "stop"
@@ -5799,21 +5910,59 @@ class DecodeEngine:
                 ),
             )
 
-    def _post(self, request: GenerationRequest, fn, *args) -> None:
+    def _delivery(self, request: GenerationRequest) -> _Delivery:
+        """The request's entry in what the bookkeeping in progress owes
+        its loop."""
+        owed = self._outbox.get(request.loop)
+        if owed is None:
+            owed = self._outbox[request.loop] = {}
+        delivery = owed.get(id(request))
+        if delivery is None:
+            delivery = owed[id(request)] = _Delivery(request)
+        return delivery
+
+    def _post_token(
+        self, request: GenerationRequest, token: int, done: bool
+    ) -> None:
+        """A token's callback: recorded for the hand-over that ends the
+        bookkeeping in progress, or made here where the request has no
+        loop."""
         if request.loop is not None:
-            request.loop.call_soon_threadsafe(fn, *args)
+            self._delivery(request).calls.append((token, done))
         else:
-            fn(*args)
+            request.on_token(token, done)
 
     def _post_future(self, request: GenerationRequest, result) -> None:
-        def resolve():
-            if not request.future.done():
-                request.future.set_result(result)
-
+        """A finished request's result, behind its last token's callback
+        in the same delivery."""
         if request.loop is not None:
-            request.loop.call_soon_threadsafe(resolve)
+            self._delivery(request).result = result
         else:
             request.future.set_result(result)
+
+    def _hand_over(self) -> int:
+        """Post everything the bookkeeping recorded since the last
+        hand-over: ONE ``call_soon_threadsafe`` for each loop that is
+        owed something, whatever the number of requests and tokens.
+        Returns the posts made (``stats["emit_handovers"]``)."""
+        if not self._outbox:
+            return 0
+        outbox, self._outbox = self._outbox, {}
+        posts = 0
+        for loop, owed in outbox.items():
+            try:
+                _post_to_loop(loop, list(owed.values()))
+            except RuntimeError:
+                # the callers' loop is closed: nobody is left to tell,
+                # and the other loops are still owed theirs
+                logger.warning(
+                    "dropped a hand-over to a closed loop (%d requests)",
+                    len(owed),
+                )
+                continue
+            posts += 1
+        self.stats["emit_handovers"] += posts
+        return posts
 
     def _fail_all_pending(self) -> None:
         """Fail EVERY waiter promptly: queued, pending, and in-flight.
