@@ -39,6 +39,7 @@ from langstream_tpu.gateway.auth import (
     create_auth_provider,
 )
 from langstream_tpu.model.application import Application, Gateway
+from langstream_tpu.runtime import tracing
 from langstream_tpu.runtime.tracing import (
     TRACE_ID_HEADER,
     get_tracer,
@@ -591,8 +592,19 @@ class GatewayServer:
             while not ws.closed:
                 batch = await reader.read(timeout=0.2)
                 for record in batch:
-                    if self._matches(record, filters):
-                        await ws.send_json(self._record_to_json(record))
+                    if not self._matches(record, filters):
+                        continue
+                    # the frame's build on the loop's thread, up to the
+                    # send's await (a span on that thread wraps none);
+                    # every socket's reader sees every record, so the
+                    # filter above stays outside: one span a frame
+                    with tracing.phase(
+                        "loop.gateway_out",
+                        trace_id=str(record.header(TRACE_ID_HEADER) or ""),
+                        index=str(record.header("stream-index", "")),
+                    ):
+                        frame = self._record_to_json(record)
+                    await ws.send_json(frame)
         except (ConnectionResetError, asyncio.CancelledError):
             pass
         finally:
@@ -665,19 +677,24 @@ class GatewayServer:
                 if message.type != WSMsgType.TEXT:
                     continue
                 try:
-                    key, value, user_headers = self._parse_produce(message.data)
-                    chat_headers, trace_id = self._stamp_trace(
-                        tuple(user_headers) + tuple(headers)
-                    )
+                    # the frame's synchronous part on the loop's thread,
+                    # up to the produce's await (which it does not wrap)
+                    with tracing.phase("loop.gateway_in") as span:
+                        key, value, user_headers = self._parse_produce(
+                            message.data
+                        )
+                        chat_headers, trace_id = self._stamp_trace(
+                            tuple(user_headers) + tuple(headers)
+                        )
+                        record = Record(value=value, key=key, headers=chat_headers)
+                        span.set(trace_id=trace_id)
                     with self.tracer.span(
                         "gateway.chat.produce", trace_id=trace_id,
                         gateway=gateway.id, topic=questions_topic,
                     ):
                         await (
                             await registered.producer(questions_topic)
-                        ).write(
-                            Record(value=value, key=key, headers=chat_headers)
-                        )
+                        ).write(record)
                     self.metrics.counter("records_produced").count()
                 except GatewayError as error:
                     await ws.send_json({"status": "BAD_REQUEST", "reason": str(error)})

@@ -171,6 +171,7 @@ def engines_snapshot() -> Dict[str, float]:
     prefill_join_wait = 0.0
     long_prompts_held = prompts_windowed = 0
     loop_seconds = {"idle": 0.0, "admit": 0.0, "dispatch": 0.0, "emit": 0.0}
+    loop_cpu = dict.fromkeys(loop_seconds, 0.0)
     active_slot_steps = total_slot_steps = 0
     paged_engines = 0
     kv_blocks_in_use = kv_blocks_total = 0
@@ -246,6 +247,7 @@ def engines_snapshot() -> Dict[str, float]:
         prompts_windowed += stats["prompts_windowed"]
         for phase_name in loop_seconds:
             loop_seconds[phase_name] += stats[phase_name + "_time"]
+            loop_cpu[phase_name] += stats[phase_name + "_cpu"]
         active_slot_steps += stats["active_slot_steps"]
         total_slot_steps += stats["decode_steps"] * engine.max_slots
         session_hits += stats["session_hits"]
@@ -457,6 +459,12 @@ def engines_snapshot() -> Dict[str, float]:
         out[
             f'jax_engine_loop_seconds_total{{phase="{phase_name}"}}'
         ] = round(seconds, 6)
+    # the thread's CPU seconds at the same boundaries: wall minus CPU is
+    # what it spent off the CPU inside a phase (the GIL, a blocking call)
+    for phase_name, seconds in loop_cpu.items():
+        out[
+            f'jax_engine_loop_cpu_seconds_total{{phase="{phase_name}"}}'
+        ] = round(seconds, 6)
     # split prefills and how many rode the decode dispatch behind them
     # (docs/observability.md §1: the loop harvests before it dispatches)
     out["jax_engine_prefill_rows_total"] = float(prefill_rows)
@@ -580,6 +588,10 @@ class GenerationRequest:
     # engine can emit a ``handoff_transit`` stage — fabric time between
     # the export and this replica's import — in its journey record
     handoff_export_ts: Optional[float] = None
+    # tokens its ``on_token`` has been called with on ``loop``
+    # (``_Delivery.run``): the delivery that starts at 0 carries the
+    # first token (``loop.deliver``'s ``first``)
+    delivered: int = 0
 
     def cancel(self) -> None:
         self.cancelled = True
@@ -665,6 +677,7 @@ class _Delivery:
         A callback that raises loses its own token only, as when each
         was a callback of the loop's."""
         request = self.request
+        request.delivered += len(self.calls)
         for token, done in self.calls:
             try:
                 request.on_token(token, done)
@@ -702,8 +715,25 @@ class _LoopInbox:
             self._pump()
 
     def _pump(self) -> None:
+        """Run the oldest delivery under ONE ``loop.deliver`` span: the
+        loop thread's share of a token's path (``on_token``, the
+        provider's stream decoder and stop watch, the chunk batcher's
+        emit), with the thread's CPU milliseconds in ``cpu_ms``. The span
+        holds no ``await``, as no span on the loop's thread may: it is
+        the thread's, and one held across an ``await`` would interleave
+        with other tasks' spans on the same line."""
         try:
-            self.deliveries.popleft().run()
+            delivery = self.deliveries.popleft()
+            request = delivery.request
+            finished = delivery.result is not None or delivery.error is not None
+            with tracing.phase(
+                "loop.deliver", tokens=len(delivery.calls),
+                first=int(request.delivered == 0 and bool(delivery.calls)),
+                done=int(finished), trace_id=request.trace_id or "",
+            ) as span:
+                cpu = time.thread_time()
+                delivery.run()
+                span.set(cpu_ms=(time.thread_time() - cpu) * 1e3)
         finally:
             if self.deliveries:
                 self.loop.call_soon(self._pump)
@@ -1423,6 +1453,12 @@ class DecodeEngine:
             "emit_time": 0.0,        # host token bookkeeping + hand-overs
             "admit_time": 0.0,       # admission: slots, batch builds, prefill dispatches
             "dispatch_time": 0.0,    # building and dispatching decode chunks
+            # the engine thread's CPU seconds inside the same four spans
+            # (time.thread_time() at the same boundaries)
+            "idle_cpu": 0.0,
+            "emit_cpu": 0.0,
+            "admit_cpu": 0.0,
+            "dispatch_cpu": 0.0,
             # goodput ledger: tokens that reached a live caller vs tokens
             # burned on cancelled requests / eviction-induced re-prefill
             "tokens_useful": 0,
@@ -2973,7 +3009,7 @@ class DecodeEngine:
                     # slot at its watermark — the windows ride the decode
                     # steps below)
                     with self._phase(
-                        "engine.admit", "admit_time",
+                        "engine.admit", "admit",
                         pending=len(self._pending),
                     ):
                         self._admit()
@@ -3048,15 +3084,22 @@ class DecodeEngine:
 
     @contextlib.contextmanager
     def _phase(self, name: str, stat: Optional[str] = None, **attributes):
-        """One phase of the loop: its span (``tracing.phase``) and, at
-        the same two boundaries, its running sum in ``self.stats``."""
-        started = time.perf_counter()
+        """One phase of the loop: its span (``tracing.phase``) with the
+        thread's CPU milliseconds inside it (``cpu_ms``) and, at the same
+        two boundaries, the running sums ``<stat>_time`` (wall) and
+        ``<stat>_cpu`` in ``self.stats``."""
+        started, cpu = time.perf_counter(), time.thread_time()
         try:
             with tracing.phase(name, self.tracer, **attributes) as span:
-                yield span
+                try:
+                    yield span
+                finally:
+                    cpu = time.thread_time() - cpu
+                    span.set(cpu_ms=cpu * 1e3)
         finally:
             if stat is not None:
-                self.stats[stat] += time.perf_counter() - started
+                self.stats[stat + "_time"] += time.perf_counter() - started
+                self.stats[stat + "_cpu"] += cpu
 
     def _any_active(self) -> bool:
         return any(slot.active for slot in self.slots)
@@ -3079,7 +3122,7 @@ class DecodeEngine:
                 # mixed step's inter-dispatch gap would measure idle
                 # time, not the per-step host tax (see _process_mixed)
                 self._last_mixed_end = 0.0
-                with self._phase("engine.wait_for_work", "idle_time"):
+                with self._phase("engine.wait_for_work", "idle"):
                     item = self._queue.get(timeout=0.05)
             else:
                 item = self._queue.get_nowait()
@@ -4605,7 +4648,7 @@ class DecodeEngine:
     ) -> Dict[str, Any]:
         """The run loop's one way to a decode dispatch (a chunk, or with
         ``plan_next`` a chained mixed step), under its phase span."""
-        with self._phase("engine.dispatch_decode", "dispatch_time") as span:
+        with self._phase("engine.dispatch_decode", "dispatch") as span:
             if plan_next is not None:
                 record = self._dispatch_mixed(
                     carry=carry, plan_next=plan_next
@@ -5179,7 +5222,7 @@ class DecodeEngine:
         whatever the bookkeeping raised: a token that reached
         ``slot.generated`` has reached its caller's loop by the time
         anyone else can see the slot."""
-        with self._phase("engine.emit", "emit_time") as span:
+        with self._phase("engine.emit", "emit") as span:
             before = self.stats["tokens_generated"]
             try:
                 yield span

@@ -81,6 +81,12 @@ def _sample_exposition() -> str:
         'jax_engine_loop_seconds_total{phase="admit"}': 0.75,
         'jax_engine_loop_seconds_total{phase="dispatch"}': 0.25,
         'jax_engine_loop_seconds_total{phase="emit"}': 3.5,
+        # the engine thread's CPU seconds in the same phases (ISSUE 37):
+        # wall minus CPU is the phase's time off the CPU
+        'jax_engine_loop_cpu_seconds_total{phase="idle"}': 0.125,
+        'jax_engine_loop_cpu_seconds_total{phase="admit"}': 0.5,
+        'jax_engine_loop_cpu_seconds_total{phase="dispatch"}': 0.25,
+        'jax_engine_loop_cpu_seconds_total{phase="emit"}': 3.25,
         # request-journey ledger (ISSUE 20): per-stage SLO blame —
         # violating requests counted by their dominant journey stage
         'jax_engine_slo_blame_total{kind="ttft",stage="queue"}': 2.0,
@@ -159,6 +165,9 @@ def _sample_exposition() -> str:
             "jax_engine_loop_seconds_total":
                 "engine thread seconds by loop phase (idle, admit,"
                 " dispatch, emit), at the phase spans' boundaries",
+            "jax_engine_loop_cpu_seconds_total":
+                "engine thread CPU seconds by loop phase, at the same"
+                " boundaries (wall minus CPU: off the CPU in the phase)",
             "jax_engine_slo_blame_total":
                 "SLO-violating requests by kind (ttft/tpot) and the"
                 " journey stage that dominated the violated window",
