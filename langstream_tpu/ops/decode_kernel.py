@@ -8,31 +8,43 @@ streams all ``T`` allocated rows per slot from HBM. At serving contexts
 is dead rows — and decode is the HBM-bound hot loop, so dead traffic is
 lost tokens/sec.
 
-This kernel makes decode-attention HBM traffic proportional to the
-LIVE context instead of the allocated buffer:
+This kernel makes decode-attention HBM traffic, and its step count,
+proportional to the LIVE context instead of the allocated buffer:
 
-- grid = (slot, T/block_k); the kv-block axis is innermost/sequential,
-  so VMEM scratch carries the online-softmax state across a slot's
-  blocks (same recurrence as ``ops/flash_attention.py``).
+- grid = (slot groups,): a grid step takes ``G`` slots' queries and
+  outputs as ``[G, H, D]`` blocks (``pick_slot_group``: every slot in
+  one step wherever the blocks fit their VMEM budget), so the grid does
+  not grow with ``T`` or with the kv blocks. A grid step has a fixed
+  cost whatever it computes, and a grid of (slot, kv block) paid it for
+  every allocated block, live or not.
+- inside a step a loop walks the group's LIVE kv blocks only, slot after
+  slot: a slot's ``first .. end - 1`` blocks of ``block_k`` rows, where
+  ``end`` follows its length and ``first`` the sliding window
+  (``_first_valid_block``). Each block is fetched by a manual DMA into
+  one of two VMEM buffers, block n + 1's fetch issued before block n's
+  compute, across slot boundaries too. The online-softmax state (same
+  recurrence as ``ops/flash_attention.py``) resets at a slot's first
+  block and its output is written at its last. A dead slot (length 0)
+  costs no DMA and no compute and reads zeros.
 - the cache operands are the engine's STACKED leaves
-  ``[L, S, T, KVH, D]``, seen as ``[L*S, T, KVH, D]`` (a bitcast), and
-  the layer is a scalar-prefetch operand that the K/V (and scale)
-  index maps turn into the row ``layer*S + slot``: the kernel streams
-  its layer's slab where it lies. A custom call's operand is a
-  materialised buffer, so handing it ``stack[layer]`` made XLA copy
-  the slab out of the stack every layer of every step — and handing it
-  a leaf in another layout than the one it lies in makes XLA re-lay-out
+  ``[L, S, T, KVH, D]``, seen as ``[L*S, T, KVH, D]`` (a bitcast) and
+  left in HBM (``pl.ANY``); the layer is a scalar-prefetch operand and
+  a block's DMA reads row ``layer*S + slot`` of the stack: the kernel
+  streams its layer's slab where it lies. A custom call's operand is a
+  materialised buffer, so handing it ``stack[layer]`` made XLA copy the
+  slab out of the stack every layer of every step — and handing it a
+  leaf in another layout than the one it lies in makes XLA re-lay-out
   the whole stack instead, which is why the scales go in position-last
   and a single kv head goes in squeezed (see the operands below).
-- per-slot lengths ride as a scalar-prefetch operand: they are
-  available to the BlockSpec index maps BEFORE the pipeline issues
-  each block's DMA. Blocks past a slot's last live block clamp their
-  index to that last block — Pallas elides the copy when the mapped
-  block indices repeat, so skipped blocks cost neither HBM reads nor
-  MXU time (their compute is ``pl.when``-gated off).
-- GQA runs as one small MXU matmul per kv head against the block's
-  ``[block_k, D]`` slab (a static python loop — KVH is a config
-  constant); q is tiny ([H, D]) and loaded once per slot.
+- per-slot lengths and the window ride as scalar-prefetch operands in
+  SMEM, where the walk reads them.
+- GQA: where the cache is bf16 and its kv heads a power of two, a
+  block is read as ``[block_k * KVH, D]`` rows (the stack's own bytes:
+  a position's kv heads follow each other) and all its heads go through
+  one MXU matmul, each query row masked to its own kv head's rows
+  (``_rows_of_heads``); the int8 twin runs one small matmul per kv head
+  against the block's ``[block_k, D]`` slice. q is tiny ([H, D]) and
+  read once per block.
 - heads narrower than a lane row (D = 64, 32, 16) are read PACKED: the
   cache then lies as ``[L, S, T, KVH / pack, 128]`` with ``pack = 128 //
   D`` kv heads side by side in one 128-lane row (``kv_pack`` has the
@@ -43,10 +55,10 @@ LIVE context instead of the allocated buffer:
   same lanes: the body above runs unchanged with ``KVH / pack`` kv
   heads and ``pack`` times the group, at ``pack`` times the MXU flops
   of a step that is bound by bytes.
-- the int8-cache twin streams int8 k/v tiles (half the bytes — the
-  kv-quant win compounds with block skipping) and folds the
-  per-(position, head) scales exactly like the XLA quant path:
-  k_scale AFTER q·kᵀ, v_scale into the probs BEFORE p·v.
+- the int8-cache twin streams int8 k/v tiles (half the bytes) with
+  their per-(position, head) scales and folds the scales exactly like
+  the XLA quant path: k_scale AFTER q·kᵀ, v_scale into the probs
+  BEFORE p·v.
 
 Reference parity: none to port — the reference's decode loop lives
 server-side behind provider HTTPS (SURVEY §2.4, `OpenAICompletionService
@@ -72,6 +84,11 @@ LANES = 128  # a vector register's minor axis: what a cache row must fill
 # must divide evenly (no padding — padding would copy the cache)
 _BLOCK_CANDIDATES = (512, 256, 128, 64, 32)
 
+# VMEM a grid step's query and output blocks may take, each block held
+# twice by the pipeline; the kv blocks' two buffers and the softmax
+# state come on top
+_GROUP_VMEM_BYTES = 4 * 2 ** 20
+
 
 def pick_block_k(max_len: int) -> Optional[int]:
     for cand in _BLOCK_CANDIDATES:
@@ -80,10 +97,25 @@ def pick_block_k(max_len: int) -> Optional[int]:
     return None
 
 
+def pick_slot_group(slots: int, heads: int, dim: int, itemsize: int) -> int:
+    """Slots a grid step takes: the most that divide ``slots`` and whose
+    query and output blocks (heads padded to a full sublane tile, two
+    buffers each) fit ``_GROUP_VMEM_BYTES``. One group is one step a
+    layer; every group more pays one more exposed first fetch."""
+    per_slot = 4 * -(-heads // 16) * 16 * dim * itemsize
+    fit = max(1, _GROUP_VMEM_BYTES // per_slot)
+    return max(g for g in range(1, min(slots, fit) + 1) if slots % g == 0)
+
+
+def _live_blocks(length, block_k: int):
+    """Blocks holding live rows: 0 for an empty slot."""
+    return (length + block_k - 1) // block_k
+
+
 def _num_valid_blocks(length, block_k: int):
-    """Blocks holding live rows (≥1 so empty slots still touch block 0 —
-    their scores are fully masked and finalize emits zeros)."""
-    return jnp.maximum(1, (length + block_k - 1) // block_k)
+    """Blocks holding live rows, at least 1 (``ops/mla_attention.py``'s
+    grid visits block 0 of an empty slot and emits zeros there)."""
+    return jnp.maximum(1, _live_blocks(length, block_k))
 
 
 def _first_valid_block(length, window, block_k: int):
@@ -97,103 +129,94 @@ def _first_valid_block(length, window, block_k: int):
     )
 
 
-def _decode_kernel_body(
-    lens_ref,   # SMEM scalar-prefetch [S] int32
-    win_ref,    # SMEM scalar-prefetch [1] int32 (0 = full attention)
-    layer_ref,  # SMEM scalar-prefetch [1] int32 — index maps only
-    q_ref,      # VMEM [1, H, D]
-    k_ref,      # VMEM [1, block_k, KVH, D] (cache dtype, or int8);
-                # [1, block_k, D] when KVH is 1
-    v_ref,      # VMEM [1, block_k, KVH, D]
-    ks_ref,     # VMEM [1, KVH, block_k] f32, or None (bf16 cache)
-    vs_ref,     # VMEM [1, KVH, block_k] f32, or None
-    out_ref,    # VMEM [1, H, D]
-    m_scratch,  # VMEM [H, 128] f32 — running row max
-    l_scratch,  # VMEM [H, 128] f32 — running row sum
-    acc_scratch,  # VMEM [H, D] f32
-    *,
-    scale: float,
-    block_k: int,
-    kv_heads: int,
-    group: int,
-    softcap: Optional[float],
+def _rows_of_heads(quantized: bool, kv_heads: int) -> bool:
+    """Whether a block's kv heads are read as ROWS ``[block_k * KVH, D]``
+    (row r is position r // KVH of kv head r % KVH): one matmul over all
+    of a block's heads, each query row masked to its own kv head's rows,
+    in place of one ``[block_k, D]`` slice per kv head, a
+    sublane-strided gather of the block that cost twice the block's DMA
+    at Qwen-2.5-7B's shapes on a TPU v5e. The rows are the stack's own
+    bytes (a bitcast).
+    Wants a bf16 cache (an int8 block's scales lie per kv head) and a
+    power-of-two KVH (the row's head and position are a mask and a
+    shift)."""
+    return not quantized and (kv_heads & (kv_heads - 1)) == 0
+
+
+def _attend_block(
+    q, k, v, ks, vs, m_ref, l_ref, acc_ref, start, length, window, *,
+    scale: float, kv_heads: int, group: int, softcap: Optional[float],
 ):
-    """One online-softmax recurrence for both cache dtypes. The int8
-    mode (``ks_ref``/``vs_ref`` present) streams int8 k/v from HBM (the
-    bandwidth halving is the whole point) and folds the scales exactly
-    like ``ops/attention.py::decode_attention_quant``: k_scale
-    multiplies the scores after q·kᵀ, v_scale folds into the probs
-    before p·v, and — matching the XLA quant path, which contracts
-    f32 probs against f32 values — the p·v dot runs in f32 (no bf16
-    round-trip on the scale-folded probs). The bf16 mode contracts
-    bf16 probs with the bf16 cache, matching ``decode_attention``'s
-    ``weights.astype(v_cache.dtype)``.
-
-    A sliding window (Gemma-2) tightens the live block range from BOTH
-    ends — blocks below the window skip compute exactly like dead
-    blocks past the length (and their DMAs are clamp-elided by the
-    index maps); ``softcap`` caps the scores before masking."""
-    quantized = ks_ref is not None
-    s_i = pl.program_id(0)
-    j = pl.program_id(1)
-    num_blocks = pl.num_programs(1)
-
-    @pl.when(j == 0)
-    def _init():
-        m_scratch[:] = jnp.full_like(m_scratch, NEG_INF)
-        l_scratch[:] = jnp.zeros_like(l_scratch)
-        acc_scratch[:] = jnp.zeros_like(acc_scratch)
-
-    length = lens_ref[s_i]
-    window = win_ref[0]
-    first = _first_valid_block(length, window, block_k)
-
-    @pl.when((j >= first) & (j < _num_valid_blocks(length, block_k)))
-    def _compute():
-        q = q_ref[0]  # [H, D]
-        # int8 values are exactly representable in bf16, so the MXU
-        # sees the same numbers the XLA quant path computes
-        k = k_ref[0].astype(q.dtype) if quantized else k_ref[0]
-        ks = ks_ref[0] if quantized else None  # [KVH, block_k] f32
+    """One kv block of one slot's online-softmax recurrence, for both
+    cache dtypes: ``q`` [H, D], ``k``/``v`` the block whose first
+    position is ``start`` — rows ``[block_k * KVH, D]`` where
+    :func:`_rows_of_heads`, else ``[block_k, KVH, D]`` sliced per kv head
+    — and ``ks``/``vs`` [KVH, block_k] f32 or None. The int8 mode
+    streams int8 k/v from HBM (the bandwidth halving is the whole point)
+    and folds the scales exactly like
+    ``ops/attention.py::decode_attention_quant``: k_scale multiplies the
+    scores after q·kᵀ, v_scale folds into the probs before p·v, and —
+    matching the XLA quant path, which contracts f32 probs against f32
+    values — the p·v dot runs in f32 (no bf16 round-trip on the
+    scale-folded probs). The bf16 mode contracts bf16 probs with the
+    bf16 cache, matching ``decode_attention``'s
+    ``weights.astype(v_cache.dtype)``. ``softcap`` caps the scores
+    before masking; the mask keeps positions under ``length`` and, with
+    a sliding ``window`` (Gemma-2), inside it."""
+    quantized = ks is not None
+    rows = _rows_of_heads(quantized, kv_heads)
+    if rows:
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale  # [H, block_k * KVH]
+        cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        head = (cols & (kv_heads - 1)) * group
+        query = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        own = (query >= head) & (query < head + group)
+        pos = start + (cols >> (kv_heads.bit_length() - 1))
+    else:
+        if quantized:
+            # int8 values are exactly representable in bf16, so the MXU
+            # sees the same numbers the XLA quant path computes
+            k = k.astype(q.dtype)
         parts = []
         for h in range(kv_heads):
-            q_h = q[h * group:(h + 1) * group]  # [G, D]
-            k_h = k if k.ndim == 2 else k[:, h, :]  # [block_k, D]
             s_h = jax.lax.dot_general(
-                q_h, k_h, (((1,), (1,)), ((), ())),
+                q[h * group:(h + 1) * group], k if k.ndim == 2 else k[:, h, :],
+                (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            ) * scale
+            ) * scale  # [G, block_k]
             if quantized:
                 s_h = s_h * ks[h:h + 1, :]
             parts.append(s_h)
         s = jnp.concatenate(parts, axis=0)  # [H, block_k]
-        if softcap is not None:
-            s = softcap * jnp.tanh(s / softcap)
+        own = True
+        pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    if softcap is not None:
+        s = softcap * jnp.tanh(s / softcap)
 
-        cols = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1
-        )
-        mask = cols < length
-        mask = jnp.logical_and(
-            mask, (window <= 0) | (cols > (length - 1) - window)
-        )
-        s = jnp.where(mask, s, NEG_INF)
+    mask = own & (pos < length) & ((window <= 0) | (pos > (length - 1) - window))
+    s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_scratch[:, :1]
-        row_max = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, row_max)
-        p = jnp.exp(s - m_new) * mask.astype(jnp.float32)
-        alpha = jnp.exp(m_prev - m_new)
-        l_scratch[:] = jnp.broadcast_to(
-            l_scratch[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True),
-            l_scratch.shape,
-        )
+    m_prev = m_ref[:, :1]
+    row_max = jnp.max(s, axis=-1, keepdims=True)
+    m_new = jnp.maximum(m_prev, row_max)
+    p = jnp.exp(s - m_new) * mask.astype(jnp.float32)
+    alpha = jnp.exp(m_prev - m_new)
+    l_ref[:] = jnp.broadcast_to(
+        l_ref[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True),
+        l_ref.shape,
+    )
 
+    if rows:
+        pv = jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+    else:
         if quantized:
-            v = v_ref[0].astype(jnp.float32)  # f32 contraction, as XLA
-            vs = vs_ref[0]                    # [KVH, block_k] f32
-        else:
-            v = v_ref[0]
+            v = v.astype(jnp.float32)  # f32 contraction, as XLA
         pv_parts = []
         for h in range(kv_heads):
             p_h = p[h * group:(h + 1) * group]  # [G, block_k] f32
@@ -201,38 +224,133 @@ def _decode_kernel_body(
                 p_h = p_h * vs[h:h + 1, :]
             else:
                 p_h = p_h.astype(v.dtype)
-            v_h = v if v.ndim == 2 else v[:, h, :]  # [block_k, D]
             pv_parts.append(
                 jax.lax.dot_general(
-                    p_h, v_h, (((1,), (0,)), ((), ())),
+                    p_h, v if v.ndim == 2 else v[:, h, :],
+                    (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32,
                 )
             )
         pv = jnp.concatenate(pv_parts, axis=0)  # [H, D]
-        acc_scratch[:] = acc_scratch[:] * alpha + pv
-        m_scratch[:] = jnp.broadcast_to(m_new, m_scratch.shape)
-
-    @pl.when(j == num_blocks - 1)
-    def _finalize():
-        l = l_scratch[:, :1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        out_ref[0] = (acc_scratch[:] / l_safe).astype(out_ref.dtype)
+    acc_ref[:] = acc_ref[:] * alpha + pv
+    m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
 
 
-def _decode_kernel(lens_ref, win_ref, layer_ref, q_ref, k_ref, v_ref,
-                   out_ref, m_scratch, l_scratch, acc_scratch, **kw):
-    _decode_kernel_body(
-        lens_ref, win_ref, layer_ref, q_ref, k_ref, v_ref, None, None,
-        out_ref, m_scratch, l_scratch, acc_scratch, **kw,
-    )
+def _live_span(length, window, block_k: int, num_blocks: int):
+    """A slot's live blocks ``[first, end)``: past the sliding window's
+    start and never past the cache (empty when the slot is)."""
+    first = _first_valid_block(length, window, block_k)
+    return first, jnp.minimum(_live_blocks(length, block_k), num_blocks)
 
 
-def _decode_kernel_quant(lens_ref, win_ref, layer_ref, q_ref, k_ref, v_ref,
-                         ks_ref, vs_ref, out_ref, m_scratch, l_scratch,
-                         acc_scratch, **kw):
-    _decode_kernel_body(
-        lens_ref, win_ref, layer_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-        out_ref, m_scratch, l_scratch, acc_scratch, **kw,
+def _decode_kernel(
+    lens_ref,   # SMEM scalar-prefetch [S] int32
+    win_ref,    # SMEM scalar-prefetch [1] int32 (0 = full attention)
+    layer_ref,  # SMEM scalar-prefetch [1] int32
+    order_ref,  # SMEM scalar-prefetch [S] int32: each group's live slots
+                # first, in slot order
+    live_ref,   # SMEM scalar-prefetch [S / G] int32: live slots a group
+    q_ref,      # VMEM [G, H, D]
+    k_hbm,      # HBM [L*S, T * KVH, D] rows (``_rows_of_heads``), else
+                # [L*S, T, KVH, D] (cache dtype, or int8)
+    v_hbm,
+    *rest,      # (ks_hbm, vs_hbm: HBM [L*S, KVH, T] f32, int8 mode),
+                # out_ref VMEM [G, H, D], then the scratch: k and v
+                # buffers [2, block of k / v] (and ks, vs [2, KVH,
+                # block_k]), DMA semaphores [streams, 2], m and l
+                # [H, 128] f32, acc [H, D] f32
+    quantized: bool,
+    block_k: int,
+    block_rows: int,
+    num_blocks: int,
+    slots: int,
+    group_slots: int,
+    **attend,
+):
+    if quantized:
+        (ks_hbm, vs_hbm, out_ref, k_buf, v_buf, ks_buf, vs_buf, sems,
+         m_ref, l_ref, acc_ref) = rest
+        streams = ((k_hbm, k_buf), (v_hbm, v_buf), (ks_hbm, ks_buf),
+                   (vs_hbm, vs_buf))
+    else:
+        out_ref, k_buf, v_buf, sems, m_ref, l_ref, acc_ref = rest
+        streams = ((k_hbm, k_buf), (v_hbm, v_buf))
+    group = pl.program_id(0)
+    base = group * group_slots
+    live = live_ref[group]
+    window = win_ref[0]
+
+    def slot(n):
+        # the group's n-th live slot (n may be ``live``: its value is
+        # then read but never used)
+        return order_ref[base + jnp.minimum(n, group_slots - 1)]
+
+    def span(s):
+        length = lens_ref[s]
+        return (length,) + _live_span(length, window, block_k, num_blocks)
+
+    def copies(s, j, buf):
+        # block j of slot s into buffer ``buf``: for K and V its rows,
+        # for the scales its columns
+        row = layer_ref[0] * slots + s
+        kv_rows = pl.ds(pl.multiple_of(j * block_rows, block_rows), block_rows)
+        cols = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+        return [
+            pltpu.make_async_copy(
+                src.at[(row, kv_rows) if n < 2 else (row, slice(None), cols)],
+                dst.at[buf], sems.at[n, buf],
+            )
+            for n, (src, dst) in enumerate(streams)
+        ]
+
+    out_ref[...] = jnp.zeros_like(out_ref)  # dead slots read zeros
+
+    def step(carry):
+        n, j, buf = carry
+        s = slot(n)
+        length, first, end = span(s)
+        last = j + 1 == end
+        next_n = jnp.where(last, n + 1, n)
+        next_j = jnp.where(last, span(slot(next_n))[1], j + 1)
+
+        @pl.when(next_n < live)
+        def _prefetch():
+            for copy in copies(slot(next_n), next_j, 1 - buf):
+                copy.start()
+
+        for copy in copies(s, j, buf):
+            copy.wait()
+
+        @pl.when(j == first)
+        def _reset():
+            m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+            l_ref[:] = jnp.zeros_like(l_ref)
+            acc_ref[:] = jnp.zeros_like(acc_ref)
+
+        _attend_block(
+            q_ref[s - base], k_buf[buf], v_buf[buf],
+            ks_buf[buf] if quantized else None,
+            vs_buf[buf] if quantized else None,
+            m_ref, l_ref, acc_ref, j * block_k, length, window, **attend,
+        )
+
+        @pl.when(last)
+        def _finalize():
+            l = l_ref[:, :1]
+            l_safe = jnp.where(l == 0.0, 1.0, l)
+            out_ref[s - base] = (acc_ref[:] / l_safe).astype(out_ref.dtype)
+
+        return next_n, next_j, 1 - buf
+
+    first0 = span(slot(0))[1]
+
+    @pl.when(live > 0)
+    def _first_fetch():
+        for copy in copies(slot(0), first0, 0):
+            copy.start()
+
+    jax.lax.while_loop(
+        lambda carry: carry[0] < live, step, (jnp.int32(0), first0, jnp.int32(0))
     )
 
 
@@ -253,18 +371,21 @@ def flash_decode_attention(
 ) -> jnp.ndarray:
     """:func:`langstream_tpu.ops.attention.decode_attention` (or
     ``decode_attention_quant`` when scales are given) over slab
-    ``layer`` of the stacked cache, with HBM traffic ∝ live context and
-    no copy of the slab: the layer is one more scalar in the index maps.
-    Caller gates via :func:`use_flash_decode`; shapes must satisfy
-    :func:`decode_shapes_ok`, and ``block_k`` must divide T
-    (``pick_block_k``). A sliding ``window`` (Gemma-2) bounds the
-    traffic by the window instead — blocks below it clamp-elide their
-    DMA just like dead blocks past the length.
+    ``layer`` of the stacked cache, with HBM traffic and work ∝ live
+    context and no copy of the slab: the layer is one more scalar the
+    kernel's DMAs read. Caller gates via :func:`use_flash_decode`;
+    shapes must satisfy :func:`decode_shapes_ok`, and ``block_k`` must
+    divide T (``pick_block_k``). A sliding ``window`` (Gemma-2) bounds
+    the traffic by the window instead: blocks below it are never
+    fetched, like dead blocks past the length. An empty slot's output
+    is zeros.
 
     A PACKED stack ``[L, S, T, KVH / pack, pack * D]`` (heads narrower
     than 128 lanes, :func:`kv_pack`) is told by its row being wider than
     q's heads: the kernel then runs on 128-lane rows with q zero-padded
-    into its kv head's lanes, and each head's own D lanes come back."""
+    into its kv head's lanes, and each head's own D lanes come back.
+    ``interpret`` runs the kernel in the TPU interpreter (its DMAs and
+    semaphores simulated), for CPU tests."""
     slots, heads, head_dim = q.shape
     num_layers, max_len, kv_heads, dim = (
         k_cache.shape[0], k_cache.shape[2], k_cache.shape[3],
@@ -282,60 +403,64 @@ def flash_decode_attention(
     block_k = block_k or pick_block_k(max_len)
     if block_k is None:
         raise ValueError(f"no kv block size divides max_len={max_len}")
-    num_blocks = max_len // block_k
     quantized = k_scale is not None
+    group_slots = pick_slot_group(slots, heads, dim, q.dtype.itemsize)
     lengths = lengths.astype(jnp.int32)
     window_arr = jnp.reshape(
         jnp.asarray(0 if window is None else window, dtype=jnp.int32), (1,)
     )
     layer_arr = jnp.reshape(jnp.asarray(layer, dtype=jnp.int32), (1,))
 
-    def block_index(s, j, lens, win):
-        # clamp dead blocks (past the length OR below the sliding
-        # window) into the live range: the mapped indices repeat, so
-        # the pipeline skips their DMA entirely
-        first = _first_valid_block(lens[s], win[0], block_k)
-        last = _num_valid_blocks(lens[s], block_k) - 1
-        return jnp.clip(j, first, last)
+    # each group's live slots first, in slot order, and how many: the
+    # walk visits those alone. A compare against every position is one
+    # small fusion where a sort or a scatter would be ops of their own,
+    # once a layer (nothing hoists them out of the layer loop)
+    first, end = _live_span(lengths, window_arr[0], block_k, max_len // block_k)
+    is_live = (end > first).reshape(-1, group_slots).astype(jnp.int32)
+    live = is_live.sum(axis=1)
+    dest = (
+        jnp.cumsum(is_live, axis=1) - 1
+        + np.arange(0, slots, group_slots)[:, None]
+    ).reshape(slots)
+    order = jnp.sum(
+        jnp.where(
+            (dest[None, :] == np.arange(slots)[:, None])
+            & (is_live.reshape(1, slots) > 0),
+            np.arange(slots, dtype=np.int32)[None, :], 0,
+        ),
+        axis=1, dtype=jnp.int32,
+    )
 
     # layer and slot merge into one leading axis: slab ``layer``'s slot
-    # s is row ``layer * S + s``, and the pipeline moves the blocks it
-    # moved when it was handed a slab. With ONE kv head (a tp shard of
-    # a 4-kv-head model on four chips) the leaf lies with T and D as
-    # its tiled axes, which is [rows, T, D]; asked for [rows, T, 1, D]
+    # s is row ``layer * S + s``, a bitcast of the stack. Read as rows
+    # (``_rows_of_heads``) a position's kv heads follow each other, which
+    # is how the stack lies ([.., T, KVH, D] tiles KVH rows of D lanes).
+    # Otherwise, with ONE kv head (a tp shard of a 4-kv-head model on
+    # four chips, or two 64-wide heads packed) the leaf lies with T and D
+    # as its tiled axes, which is [rows, T, D]; asked for [rows, T, 1, D]
     # the operand would be the stack re-tiled over (1, D), a copy.
-    kv_tail = (kv_heads, dim) if kv_heads > 1 else (dim,)
-
-    def kv_index(s, j, lens, win, lyr):
-        block = block_index(s, j, lens, win)
-        return (lyr[0] * slots + s, block) + (0,) * len(kv_tail)
-
-    def scale_index(s, j, lens, win, lyr):
-        return (lyr[0] * slots + s, 0, block_index(s, j, lens, win))
-
-    def q_index(s, j, lens, win, lyr):
-        return (s, 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, heads, dim), q_index),
-        pl.BlockSpec((1, block_k) + kv_tail, kv_index),
-        pl.BlockSpec((1, block_k) + kv_tail, kv_index),
-    ]
+    if _rows_of_heads(quantized, kv_heads):
+        kv_tail, block_rows = (max_len * kv_heads, dim), block_k * kv_heads
+        kv_block = (block_rows, dim)
+    else:
+        kv_tail = (max_len,) + ((kv_heads, dim) if kv_heads > 1 else (dim,))
+        block_rows, kv_block = block_k, (block_k,) + kv_tail[1:]
     rows = num_layers * slots
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+
+    def group_index(g, *prefetched):
+        return (g, 0, 0)
+
+    in_specs = [pl.BlockSpec((group_slots, heads, dim), group_index),
+                in_hbm, in_hbm]
     operands = [
         q,
-        k_cache.reshape((rows, max_len) + kv_tail),
-        v_cache.reshape((rows, max_len) + kv_tail),
+        k_cache.reshape((rows,) + kv_tail),
+        v_cache.reshape((rows,) + kv_tail),
     ]
+    buffers = [pltpu.VMEM((2,) + kv_block, k_cache.dtype)] * 2
     if quantized:
-        kernel = functools.partial(
-            _decode_kernel_quant, scale=scale, block_k=block_k,
-            kv_heads=kv_heads, group=group, softcap=softcap,
-        )
-        in_specs += [
-            pl.BlockSpec((1, kv_heads, block_k), scale_index),
-            pl.BlockSpec((1, kv_heads, block_k), scale_index),
-        ]
+        in_specs += [in_hbm, in_hbm]
         # the scales go in with the position axis LAST: that is how the
         # f32[L, S, T, KVH] leaf lies on the chip (a kv-head axis 4 wide
         # is no lane axis, so the device's layout has T minor-most) and
@@ -348,22 +473,26 @@ def flash_decode_attention(
             )
             for leaf in (k_scale, v_scale)
         ]
+        buffers += [pltpu.VMEM((2, kv_heads, block_k), jnp.float32)] * 2
         stack_bytes = (
             k_cache.size + v_cache.size + (k_scale.size + v_scale.size) * 4
         )
     else:
-        kernel = functools.partial(
-            _decode_kernel, scale=scale, block_k=block_k,
-            kv_heads=kv_heads, group=group, softcap=softcap,
-        )
         stack_bytes = (k_cache.size + v_cache.size) * k_cache.dtype.itemsize
 
+    kernel = functools.partial(
+        _decode_kernel, quantized=quantized, block_k=block_k,
+        block_rows=block_rows, num_blocks=max_len // block_k, slots=slots,
+        group_slots=group_slots, scale=scale, kv_heads=kv_heads,
+        group=group, softcap=softcap,
+    )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(slots, num_blocks),
+        num_scalar_prefetch=5,
+        grid=(slots // group_slots,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, heads, dim), q_index),
-        scratch_shapes=[
+        out_specs=pl.BlockSpec((group_slots, heads, dim), group_index),
+        scratch_shapes=buffers + [
+            pltpu.SemaphoreType.DMA((len(buffers), 2)),
             pltpu.VMEM((heads, 128), jnp.float32),
             pltpu.VMEM((heads, 128), jnp.float32),
             pltpu.VMEM((heads, dim), jnp.float32),
@@ -384,8 +513,8 @@ def flash_decode_attention(
             ),
             transcendentals=slots * heads * max_len,
         ),
-        interpret=interpret,
-    )(lengths, window_arr, layer_arr, *operands)
+        interpret=pltpu.InterpretParams() if interpret else False,
+    )(lengths, window_arr, layer_arr, order, live, *operands)
     if pack > 1:
         out = jnp.where(
             own, out.reshape(slots, heads, pack, head_dim), 0

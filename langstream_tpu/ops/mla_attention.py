@@ -20,8 +20,8 @@ the DeepSeek-V2 sizes, a v5e's ridge.
   prefill's suffix over a cached prefix, in blocks of queries so that the
   ``[heads, queries, keys]`` scores stay small).
 - :func:`mla_decode_attention` is the Pallas TPU kernel ``mla_decode`` for
-  one query a slot: the flash-decode schedule of ``ops/decode_kernel.py``
-  (grid = (slot, T / block_k), online softmax in VMEM scratch, per-slot
+  one query a slot: a flash-decode schedule (grid = (slot, T /
+  block_k), online softmax in VMEM scratch, per-slot
   lengths as scalar prefetch so that blocks past a slot's length are
   neither moved nor computed, the STACKED cache ``[L, S, T, latent +
   rope]`` with the layer as one more prefetched scalar so that no slab is

@@ -437,11 +437,13 @@ def _lower_decode_chunk(
 
 # sha256 of the lowered text (a kernel's serialised body, which carries
 # its call stack's line numbers, blanked) of the two cells' decode chunks
-# as PR 32's tree lowers them with this jax. A PR that means to change
-# these programs replaces the hash; one that does not has left them alone.
+# as this jax lowers them since the decode kernel walks live blocks (its
+# operands: the live slots' order and count beside the lengths). A change
+# that means to change these programs replaces the hash; one that does
+# not has left them alone.
 GQA_CHUNKS = {
-    "qwen25_7b": (32, True, "03ef2c2b5293a23fc5607ed83876889d257ad98a1d7dae2a6435069ee0dc771a"),
-    "qwen25_0_5b": (128, False, "d518356c34948acbd2ba4853a1a7e4f062c4db7b036349530925e30bc90aeeb3"),
+    "qwen25_7b": (32, True, "80e1edd4be85fca9c31e8fac59f8295e7386062133bdbd63219bf7cbfe5483d2"),
+    "qwen25_0_5b": (128, False, "07b892b015dc7548cb8044ff5fc2af51e865138f9e8f03a0a9898c5b78dc3638"),
 }
 
 

@@ -1,7 +1,8 @@
 """Flash-decode kernel (interpret mode / virtual CPU mesh) against the
 plain-XLA decode attention, incl. the int8-cache twin and the tp-sharded
 wrapper. Lengths cover full, partial-block, single-token, and empty
-slots — the block-skipping index map must stay numerically invisible.
+slots — the walk over live blocks must stay numerically invisible, and
+an empty slot reads zeros.
 The kernel takes the STACKED cache and a layer index (it reads its slab
 where it lies); every case hands it a three-layer stack and asks for the
 middle slab, the reference gets that slab sliced out."""
@@ -51,42 +52,147 @@ def _packed(stack, pack):
     )
 
 
-# (heads, kv heads, head dim, Gemma-2's window + softcap + scale). Head
-# dims under 128 go in PACKED, the reference reads the heads apart.
+# (heads, kv heads, head dim, mode): ``plain``, ``window`` (Gemma-2's
+# window + softcap + scale) or ``int8kv`` (the quantized cache and its
+# scales). Head dims under 128 go in PACKED, the reference reads the heads
+# apart. The first cases hand four slots: a full one, a ragged one, a
+# single token and an empty one.
+FIRST_LENGTHS = (256, 100, 1, 0)
+MODES = {
+    "bf16": (8, 4, 128, "plain"),
+    "packed": (14, 2, 64, "plain"),   # Qwen-2.5-0.5B: one row
+    "int8kv": (8, 4, 128, "int8kv"),
+    "window-softcap": (8, 4, 128, "window"),
+}
+# lengths the walk over live blocks must keep invisible (``block_k`` 64,
+# ``max_len`` 256); ``groups`` cuts the slots into grid steps of two
+# (the VMEM budget of ``pick_slot_group`` lowered to fit two slots)
+LENGTH_PATTERNS = {
+    "dead-among-live": dict(lengths=(0, 200, 0, 0, 77, 0)),
+    "all-dead": dict(lengths=(0, 0, 0)),
+    "block-edges": dict(lengths=(64, 65, 256, 128)),
+    "one-live-in-a-group": dict(lengths=(0, 0, 131, 0, 0, 0), groups=True),
+    # window 40: the first live block is 3 and 2 for the first two slots
+    "window-past-block-0": dict(lengths=(256, 190, 100, 45), window=40),
+}
+
+
 @pytest.mark.parametrize(
-    "heads,kv_heads,dim,family",
+    "heads,kv_heads,dim,mode,pattern",
     [
-        (8, 8, 128, False), (8, 4, 128, False), (8, 2, 128, False),
-        (8, 1, 128, False),
-        (14, 2, 64, False), (14, 2, 64, True),    # Qwen-2.5-0.5B: one row
-        (32, 8, 64, False), (32, 8, 64, True),    # Llama-3.2-1B: four rows
-        (8, 4, 32, False),                        # four heads to a row
+        pytest.param(
+            heads, kv_heads, dim, mode, "first",
+            id=f"{heads}-{kv_heads}-{dim}-{mode == 'window'}",
+        )
+        for heads, kv_heads, dim, mode in (
+            (8, 8, 128, "plain"), (8, 4, 128, "plain"), (8, 2, 128, "plain"),
+            (8, 1, 128, "plain"),
+            (14, 2, 64, "plain"), (14, 2, 64, "window"),  # Qwen-2.5-0.5B: one row
+            (32, 8, 64, "plain"), (32, 8, 64, "window"),  # Llama-3.2-1B: four rows
+            (8, 4, 32, "plain"),                          # four heads to a row
+            (6, 3, 128, "plain"),                         # sliced per kv head
+        )
+    ] + [
+        pytest.param(*MODES[mode], pattern, id=f"{pattern}-{mode}")
+        for pattern in LENGTH_PATTERNS for mode in MODES
     ],
 )
-def test_flash_decode_matches_reference(heads, kv_heads, dim, family):
-    slots, max_len = 4, 256
+def test_flash_decode_matches_reference(
+    monkeypatch, heads, kv_heads, dim, mode, pattern
+):
+    import langstream_tpu.ops.decode_kernel as decode_kernel
+
+    case = LENGTH_PATTERNS.get(pattern, dict(lengths=FIRST_LENGTHS))
+    lengths = jnp.array(case["lengths"], dtype=jnp.int32)
+    slots, max_len = len(case["lengths"]), 256
+    if case.get("groups"):
+        per_slot = 4 * 16 * dim * 4  # two f32 q and out blocks of 16 rows
+        monkeypatch.setattr(decode_kernel, "_GROUP_VMEM_BYTES", 2 * per_slot)
+        assert decode_kernel.pick_slot_group(slots, heads, dim, 4) == 2
     q, k, v = _make_inputs(slots, max_len, heads, kv_heads, dim)
-    # a full slot, a ragged one, a single token, an empty slot
-    lengths = jnp.array([256, 100, 1, 0], dtype=jnp.int32)
     kw = (
         dict(softcap=30.0, window=jnp.asarray(40, jnp.int32), scale=0.17)
-        if family else {}
+        if mode == "window" else {}
     )
+    if "window" in case:
+        kw["window"] = jnp.asarray(case["window"], jnp.int32)
 
-    ref = decode_attention(q, k[LAYER], v[LAYER], lengths, **kw)
-    pack = kv_pack(dim, kv_heads)
-    out = flash_decode_attention(
-        q, _packed(k, pack), _packed(v, pack), lengths, LAYER, block_k=64,
-        interpret=True, **kw,
-    )
+    if mode == "int8kv":
+        k_q, k_s = quantize_kv(k)
+        v_q, v_s = quantize_kv(v)
+        ref = decode_attention_quant(
+            q, k_q[LAYER], k_s[LAYER], v_q[LAYER], v_s[LAYER], lengths, **kw
+        )
+        out = flash_decode_attention_quant(
+            q, k_q, k_s, v_q, v_s, lengths, LAYER, block_k=64,
+            interpret=True, **kw,
+        )
+        tol = 2e-4
+    else:
+        ref = decode_attention(q, k[LAYER], v[LAYER], lengths, **kw)
+        pack = kv_pack(dim, kv_heads)
+        out = flash_decode_attention(
+            q, _packed(k, pack), _packed(v, pack), lengths, LAYER,
+            block_k=64, interpret=True, **kw,
+        )
+        tol = 2e-5
     assert out.shape == q.shape
-    # empty slots are garbage in both paths; compare live rows only
     for s in range(slots):
         if int(lengths[s]) == 0:
+            # an empty slot is neither fetched nor computed: it reads zeros
+            np.testing.assert_array_equal(np.asarray(out[s]), 0.0)
             continue
         np.testing.assert_allclose(
-            np.asarray(out[s]), np.asarray(ref[s]), rtol=2e-5, atol=2e-5
+            np.asarray(out[s]), np.asarray(ref[s]), rtol=tol, atol=tol
         )
+
+
+def _pallas_grid(fn, *shapes):
+    """The grid of the one ``pallas_call`` that ``fn`` traces to."""
+    jaxpr = jax.make_jaxpr(fn)(*shapes)
+    grids = [
+        eqn.params["grid_mapping"].grid for eqn in jaxpr.jaxpr.eqns
+        if eqn.primitive.name == "pallas_call"
+    ]
+    assert len(grids) == 1, grids
+    return grids[0]
+
+
+@pytest.mark.parametrize(
+    "slots,heads,kv_heads,dim,quantized",
+    [(32, 28, 4, 128, False), (32, 28, 4, 128, True),
+     (128, 14, 1, 128, False), (256, 32, 8, 128, False)],
+    ids=["7b", "7b-int8kv", "0.5b-packed", "256-slots"],
+)
+def test_the_grid_does_not_grow_with_the_cache(
+    slots, heads, kv_heads, dim, quantized
+):
+    """The kernel's grid runs over groups of slots, never over kv blocks:
+    the same at 2,048 and 4,096 allocated rows (a grid of (slot, block)
+    would double), and one step a layer wherever the group's query and
+    output blocks fit their VMEM budget (two steps for 256 slots of 32
+    heads)."""
+    grids = []
+    for max_len in (2048, 4096):
+        stack = jax.ShapeDtypeStruct(
+            (LAYERS, slots, max_len, kv_heads, dim),
+            jnp.int8 if quantized else jnp.bfloat16,
+        )
+        shapes = [
+            jax.ShapeDtypeStruct((slots, heads, dim), jnp.bfloat16),
+            stack, stack, jax.ShapeDtypeStruct((slots,), jnp.int32),
+        ]
+        if quantized:
+            scales = jax.ShapeDtypeStruct(stack.shape[:-1], jnp.float32)
+            shapes += [scales, scales]
+
+        def fn(q, k, v, lengths, *sc):
+            extra = {"k_scale": sc[0], "v_scale": sc[1]} if sc else {}
+            return flash_decode_attention(q, k, v, lengths, LAYER, **extra)
+
+        grids.append(_pallas_grid(fn, *shapes))
+    assert grids[0] == grids[1]
+    assert grids[0] == ((2,) if slots == 256 else (1,))
 
 
 @pytest.mark.parametrize(
