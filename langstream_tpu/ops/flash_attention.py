@@ -9,17 +9,28 @@ q/k/v tile reads and one output tile write per q block.
 
 Kernel layout (the canonical TPU flash schedule):
 
-- grid = (batch, q_heads, Tq/block_q, Tk/block_k); the last grid axis is
-  innermost and sequential on TPU, so VMEM scratch carries the online
-  softmax state across k blocks of the same q block.
+- grid = (batch, q_heads, steps): a row's steps are its (q block, k
+  block) pairs, read from a scalar-prefetched table (``_step_table``),
+  a q block's k blocks consecutive; the last grid axis is innermost and
+  sequential on TPU, so VMEM scratch carries the online softmax state
+  across the k blocks of one q block. The axis is as long as a full
+  row's causal pairs, the most any row needs.
 - q/k/v tiles are MXU-shaped ([block, head_dim], 128-aligned); the two
   matmuls (q·kᵀ and p·v) run on the MXU in the input dtype with f32
   accumulation; masking and the softmax recurrence run on the VPU in f32.
 - GQA is folded into the k/v BlockSpec index maps (query head h reads kv
   head h // group) — no materialized head repetition.
-- causal blocks strictly above the diagonal skip their compute entirely
-  via ``pl.when`` (they still prefetch, which the pipeline overlaps).
-- per-batch valid lengths ride in SMEM (right-padding mask).
+- per-batch valid lengths ride in SMEM (right-padding mask), and every
+  step is classed from them, its position against the diagonal and the
+  window (``_block_class``): empty (above the diagonal, outside every
+  row's window, a q block at or past the row's length, or padding past
+  the row's last pair) computes nothing, and its blocks are the ones
+  the step before it held, so it fetches nothing new either; full
+  (wholly below the diagonal, inside the length and the window) runs
+  the body without a mask; partial (the diagonal's block, the length's,
+  a window's edge) runs the masked body. The table gives a row only
+  its live pairs, and one step to each q block past its length, which
+  writes that block's zeros: rows at or past the length read zeros.
 
 Reference parity: this replaces the HBM-bound attention inside what the
 reference would run as a remote model call (it has no kernels of its own —
@@ -31,10 +42,11 @@ delegates to a provider); the kernel is the TPU-native interior of the
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -50,13 +62,50 @@ def compat_shard_map(f, mesh, in_specs, out_specs):
     )
 
 
-def _flash_kernel(
+def _kv_span(q_start, length, window, block_q: int, block_k: int, xp=jnp):
+    """The k blocks a q block starting at ``q_start`` reads, ``first`` to
+    ``last`` inclusive: none past the diagonal or the row's ``length``,
+    none before every row's window (``window`` <= 0: no window). A q block
+    at or past the length reads none; its span is then the row's last k
+    block alone, the one the step before it held, so that its one step
+    fetches nothing new. ``xp`` is ``jnp`` in the kernel and ``np`` on
+    the host."""
+    last = xp.maximum(xp.minimum(q_start + block_q, length) - 1, 0) // block_k
+    first = xp.where(
+        window > 0, xp.maximum(q_start - window + 1, 0) // block_k, 0
+    )
+    return xp.where(q_start < length, first, last), last
+
+
+def _block_class(length, window, q_start, kj, *, block_q: int, block_k: int):
+    """What the step of q block ``q_start`` and k block ``kj`` needs, as
+    ``(full, partial)``. Full: the block lies wholly below the diagonal,
+    inside the length and inside every row's window, so its mask is all
+    ones and the body runs unmasked. Partial: any other block of the span
+    (the diagonal's, the length's, a window's edge), which runs the masked
+    body. Neither: the step is empty and computes nothing."""
+    first, last = _kv_span(q_start, length, window, block_q, block_k)
+    live = (q_start < length) & (first <= kj) & (kj <= last)
+    k_start = kj * block_k
+    full = (
+        live
+        & (k_start + block_k - 1 <= q_start)
+        & (k_start + block_k <= length)
+        & ((window <= 0) | (k_start >= q_start + block_q - window))
+    )
+    return full, live & jnp.logical_not(full)
+
+
+def _flash_body(
     lengths_ref,  # SMEM [B] — valid length per batch row (scalar prefetch)
     window_ref,   # SMEM [1] — sliding window (0 = full attention)
+    steps_ref,    # SMEM [B, 3, P] — a step's q block, k block, and 1 if
+                  # the step is the row's (0: padding past its last)
     q_ref,        # VMEM [1, 1, block_q, d]
-    k_ref,        # VMEM [1, 1, block_k, d]
+    k_ref,        # VMEM [1, 1, block_k, d] (int8 with scales)
     v_ref,        # VMEM [1, 1, block_k, dv] (dv = d but for latent
                   # attention's expanded heads: keys 192, values 128)
+    scale_refs,   # None, or VMEM [1, 1, 1, block_k] f32 k and v scales
     out_ref,      # VMEM [1, 1, block_q, dv]
     m_scratch,    # VMEM [block_q, 128] f32 — running row max
     l_scratch,    # VMEM [block_q, 128] f32 — running row sum
@@ -70,96 +119,113 @@ def _flash_kernel(
     # program ids are read at the kernel's top level: inside a pl.when
     # body the interpreter has no rule for them
     b = pl.program_id(0)
-    qi = pl.program_id(2)
-    kj = pl.program_id(3)
-    num_k = pl.num_programs(3)
+    step = pl.program_id(2)
+    last_step = pl.num_programs(2) - 1
+    qi = steps_ref[b, 0, step]
+    kj = steps_ref[b, 1, step]
+    # a q block's steps are consecutive: it starts where the step before
+    # was another q block's, and ends where the step after is
+    starts = (step == 0) | (steps_ref[b, 0, jnp.maximum(step - 1, 0)] != qi)
+    ends = (step == last_step) | (
+        steps_ref[b, 0, jnp.minimum(step + 1, last_step)] != qi
+    )
 
-    @pl.when(kj == 0)
+    @pl.when(starts)
     def _init():
         m_scratch[:] = jnp.full_like(m_scratch, NEG_INF)
         l_scratch[:] = jnp.zeros_like(l_scratch)
         acc_scratch[:] = jnp.zeros_like(acc_scratch)
 
     q_start = qi * block_q
-    k_start = kj * block_k
-    window = window_ref[0]
-    # Causal: skip blocks entirely in the future of the q block; with a
-    # sliding window (Gemma-2) also skip blocks entirely BEFORE every
-    # row's window (earliest window start in the block is
-    # q_start - window + 1)
-    relevant = k_start <= q_start + block_q - 1
-    relevant = jnp.logical_and(
-        relevant,
-        (window <= 0) | (k_start + block_k - 1 >= q_start - window + 1),
+    length, window = lengths_ref[b], window_ref[0]
+    full, partial = _block_class(
+        length, window, q_start, kj, block_q=block_q, block_k=block_k
     )
+    own = steps_ref[b, 2, step] > 0
+    full, partial = full & own, partial & own
 
-    @pl.when(relevant)
-    def _compute():
-        length = lengths_ref[b]
+    def attend(masked: bool):
         q = q_ref[0, 0]
         k = k_ref[0, 0]
+        v = v_ref[0, 0]
+        if scale_refs is not None:
+            k, v = k.astype(q.dtype), v.astype(q.dtype)  # int8: exact in bf16
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        ) * scale  # [block_q, block_k]
+        )  # [block_q, block_k]
+        if scale_refs is not None:
+            s = s * (scale_refs[0][0, 0] * scale)  # fold k scales per row
+        else:
+            s = s * scale
         if softcap is not None:
             s = softcap * jnp.tanh(s / softcap)
 
-        rows = q_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0
-        )
-        cols = k_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1
-        )
-        mask = jnp.logical_and(cols <= rows, cols < length)
-        mask = jnp.logical_and(
-            mask, (window <= 0) | (cols > rows - window)
-        )
-        s = jnp.where(mask, s, NEG_INF)
+        if masked:
+            rows = q_start + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 0
+            )
+            cols = kj * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 1
+            )
+            mask = jnp.logical_and(cols <= rows, cols < length)
+            mask = jnp.logical_and(
+                mask, (window <= 0) | (cols > rows - window)
+            )
+            s = jnp.where(mask, s, NEG_INF)
 
         m_prev = m_scratch[:, :1]                      # [block_q, 1]
         row_max = jnp.max(s, axis=-1, keepdims=True)   # [block_q, 1]
         m_new = jnp.maximum(m_prev, row_max)
-        # p is zeroed (not just -inf shifted) so fully-masked rows stay 0.
-        p = jnp.exp(s - m_new) * mask.astype(jnp.float32)
+        p = jnp.exp(s - m_new)
+        if masked:
+            # p is zeroed (not just -inf shifted) so fully-masked rows stay 0
+            p = p * mask.astype(jnp.float32)
         alpha = jnp.exp(m_prev - m_new)                # [block_q, 1]
 
         l_prev = l_scratch[:, :1]
         l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
 
-        v = v_ref[0, 0]
+        if scale_refs is not None:
+            p = p * scale_refs[1][0, 0]  # fold v scales into probs
         pv = jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )  # [block_q, d]
+        )  # [block_q, dv]
         acc_scratch[:] = acc_scratch[:] * alpha + pv
         m_scratch[:] = jnp.broadcast_to(m_new, m_scratch.shape)
         l_scratch[:] = jnp.broadcast_to(l_new, l_scratch.shape)
 
-    @pl.when(kj == num_k - 1)
+    pl.when(full)(lambda: attend(masked=False))
+    pl.when(partial)(lambda: attend(masked=True))
+
+    @pl.when(ends)
     def _finalize():
         l = l_scratch[:, :1]
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        out_ref[0, 0] = (acc_scratch[:] / l_safe).astype(out_ref.dtype)
+        # rows at or past the length read zeros, whatever a straddling
+        # block's softmax left in them
+        rows = q_start + jax.lax.broadcasted_iota(
+            jnp.int32, acc_scratch.shape, 0
+        )
+        out_ref[0, 0] = jnp.where(
+            rows < length, acc_scratch[:] / l_safe, 0.0
+        ).astype(out_ref.dtype)
+
+
+def _flash_kernel(
+    lengths_ref, window_ref, steps_ref, q_ref, k_ref, v_ref, *rest, **kw
+):
+    """The bf16 kernel: :func:`_flash_body` with no scales."""
+    _flash_body(
+        lengths_ref, window_ref, steps_ref, q_ref, k_ref, v_ref, None,
+        *rest, **kw,
+    )
 
 
 def _flash_kernel_quant(
-    lengths_ref,  # SMEM [B] (scalar prefetch)
-    window_ref,   # SMEM [1] — sliding window (0 = full attention)
-    q_ref,        # VMEM [1, 1, block_q, d]
-    k_ref,        # VMEM [1, 1, block_k, d] int8
-    v_ref,        # VMEM [1, 1, block_k, d] int8
-    ks_ref,       # VMEM [1, 1, 1, block_k] f32 — per-row k scales
-    vs_ref,       # VMEM [1, 1, 1, block_k] f32 — per-row v scales
-    out_ref,      # VMEM [1, 1, block_q, d]
-    m_scratch,
-    l_scratch,
-    acc_scratch,
-    *,
-    scale: float,
-    block_q: int,
-    block_k: int,
-    softcap: Optional[float],
+    lengths_ref, window_ref, steps_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
+    *rest, **kw,
 ):
     """Int8-cache flash: k/v tiles stream from HBM as int8 (half the
     bandwidth of bf16 — the whole point), upcast in VMEM (int8 values
@@ -169,77 +235,40 @@ def _flash_kernel_quant(
     score AFTER the q·kᵀ contraction, v_scale folds into the probs
     BEFORE p·v — neither contraction ever touches a dequantized
     cache-sized tensor."""
-    # program ids are read at the kernel's top level: inside a pl.when
-    # body the interpreter has no rule for them
-    b = pl.program_id(0)
-    qi = pl.program_id(2)
-    kj = pl.program_id(3)
-    num_k = pl.num_programs(3)
-
-    @pl.when(kj == 0)
-    def _init():
-        m_scratch[:] = jnp.full_like(m_scratch, NEG_INF)
-        l_scratch[:] = jnp.zeros_like(l_scratch)
-        acc_scratch[:] = jnp.zeros_like(acc_scratch)
-
-    q_start = qi * block_q
-    k_start = kj * block_k
-    window = window_ref[0]
-    relevant = k_start <= q_start + block_q - 1
-    relevant = jnp.logical_and(
-        relevant,
-        (window <= 0) | (k_start + block_k - 1 >= q_start - window + 1),
+    _flash_body(
+        lengths_ref, window_ref, steps_ref, q_ref, k_ref, v_ref,
+        (ks_ref, vs_ref), *rest, **kw,
     )
 
-    @pl.when(relevant)
-    def _compute():
-        length = lengths_ref[b]
-        q = q_ref[0, 0]
-        k = k_ref[0, 0].astype(q.dtype)   # int8 → exact in bf16
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        s = s * (ks_ref[0, 0] * scale)  # fold k scales per row
-        if softcap is not None:
-            s = softcap * jnp.tanh(s / softcap)
 
-        rows = q_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0
-        )
-        cols = k_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1
-        )
-        mask = jnp.logical_and(cols <= rows, cols < length)
-        mask = jnp.logical_and(
-            mask, (window <= 0) | (cols > rows - window)
-        )
-        s = jnp.where(mask, s, NEG_INF)
-
-        m_prev = m_scratch[:, :1]
-        row_max = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, row_max)
-        p = jnp.exp(s - m_new) * mask.astype(jnp.float32)
-        alpha = jnp.exp(m_prev - m_new)
-
-        l_prev = l_scratch[:, :1]
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-
-        v = v_ref[0, 0].astype(q.dtype)
-        p_scaled = p * vs_ref[0, 0]  # fold v scales into probs
-        pv = jax.lax.dot_general(
-            p_scaled.astype(q.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        acc_scratch[:] = acc_scratch[:] * alpha + pv
-        m_scratch[:] = jnp.broadcast_to(m_new, m_scratch.shape)
-        l_scratch[:] = jnp.broadcast_to(l_new, l_scratch.shape)
-
-    @pl.when(kj == num_k - 1)
-    def _finalize():
-        l = l_scratch[:, :1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        out_ref[0, 0] = (acc_scratch[:] / l_safe).astype(out_ref.dtype)
+def _step_table(lengths, window, seq: int, block_q: int, block_k: int):
+    """[B, 3, P] int32: for each row the (q block, k block) pairs the
+    kernel visits, in order. A q block's live span comes whole
+    (``_kv_span``); a q block with none, at or past the length, takes
+    one step on its span, in which it writes its zeros; the steps past
+    the row's last repeat that last pair and are marked padding, so that
+    they fetch nothing and compute nothing. ``P``, the grid's steps a
+    (row, head), is a full row's count of causal pairs, the most any row
+    needs."""
+    num_q = seq // block_q
+    _, full_last = _kv_span(
+        np.arange(num_q) * block_q, seq, 0, block_q, block_k, xp=np
+    )
+    total = int(np.sum(full_last + 1))
+    q_start = jnp.arange(num_q, dtype=jnp.int32)[None, :] * block_q
+    first, last = _kv_span(
+        q_start, lengths[:, None], window, block_q, block_k
+    )  # [B, num_q]
+    count = last - first + 1  # one step for a q block past the length
+    ends = jnp.cumsum(count, axis=1)                       # [B, num_q]
+    step = jnp.arange(total, dtype=jnp.int32)[None, :]     # [1, P]
+    qi = jnp.minimum(
+        jnp.sum(step[:, :, None] >= ends[:, None, :], axis=2), num_q - 1
+    ).astype(jnp.int32)                                     # [B, P]
+    take = functools.partial(jnp.take_along_axis, indices=qi, axis=1)
+    kj = jnp.minimum(take(first) + step - take(ends - count), take(last))
+    own = (step < ends[:, -1:]).astype(jnp.int32)
+    return jnp.stack([qi, kj, own], axis=1)
 
 
 def _pallas_flash(
@@ -262,22 +291,29 @@ def _pallas_flash(
     v_dim = v.shape[3]  # the values' width; the keys' is the queries'
     group = heads // kv_heads
     scale = dim ** -0.5 if scale is None else scale
-    grid = (batch, heads, seq // block_q, seq // block_k)
     quantized = k_scale is not None
 
-    # lengths/window ride as scalar-prefetch operands (whole arrays in
-    # SMEM): a (1, 1) SMEM block over [B, 1] is refused by the Mosaic
+    # lengths/window/steps ride as scalar-prefetch operands (whole arrays
+    # in SMEM): a (1, 1) SMEM block over [B, 1] is refused by the Mosaic
     # lowering for any B > 1.
     lengths = lengths.astype(jnp.int32)
     window_arr = jnp.reshape(
         jnp.asarray(0 if window is None else window, dtype=jnp.int32), (1,)
     )
+    steps = _step_table(lengths, window_arr[0], seq, block_q, block_k)
+    grid = (batch, heads, steps.shape[2])
 
-    def q_index(b, h, i, j, lens, win):
-        return (b, h, i, 0)
+    def out_index(b, h, step, lens, win, steps):
+        return (b, h, steps[b, 0, step], 0)
 
-    def kv_index(b, h, i, j, lens, win):
-        return (b, h // group, j, 0)
+    # a q block past the length reads the row's last live one, so that
+    # its step fetches nothing new
+    def q_index(b, h, step, lens, win, steps):
+        last = jnp.maximum(lens[b] - 1, 0) // block_q
+        return (b, h, jnp.minimum(steps[b, 0, step], last), 0)
+
+    def kv_index(b, h, step, lens, win, steps):
+        return (b, h // group, steps[b, 1, step], 0)
 
     in_specs = [
         pl.BlockSpec((1, 1, block_q, dim), q_index),
@@ -285,33 +321,28 @@ def _pallas_flash(
         pl.BlockSpec((1, 1, block_k, v_dim), kv_index),
     ]
     operands = [q, k, v]
+    kernel = _flash_kernel_quant if quantized else _flash_kernel
     if quantized:
-        kernel = functools.partial(
-            _flash_kernel_quant, scale=scale,
-            block_q=block_q, block_k=block_k, softcap=softcap,
-        )
         # scales ride as [B, KVH, 1, T]: Mosaic wants the block's
         # second-to-last dim a multiple of 8 or the whole axis, and a
         # (1, block_k) tile over [KVH, T] is neither
         scale_spec = pl.BlockSpec(
             (1, 1, 1, block_k),
-            lambda b, h, i, j, lens, win: (b, h // group, 0, j),
+            lambda b, h, step, lens, win, steps: (
+                b, h // group, 0, steps[b, 1, step]
+            ),
         )
         in_specs += [scale_spec, scale_spec]
         operands += [k_scale[:, :, None, :], v_scale[:, :, None, :]]
         kv_bytes = k.size + v.size + k_scale.size * 4 + v_scale.size * 4
     else:
-        kernel = functools.partial(
-            _flash_kernel, scale=scale, block_q=block_q, block_k=block_k,
-            softcap=softcap,
-        )
         kv_bytes = (k.size + v.size) * k.dtype.itemsize
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, block_q, v_dim), q_index),
+        out_specs=pl.BlockSpec((1, 1, block_q, v_dim), out_index),
         scratch_shapes=[
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
@@ -319,7 +350,10 @@ def _pallas_flash(
         ],
     )
     return pl.pallas_call(
-        kernel,
+        functools.partial(
+            kernel, scale=scale, block_q=block_q, block_k=block_k,
+            softcap=softcap,
+        ),
         name="flash_prefill_int8kv" if quantized else "flash_prefill",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((batch, heads, seq, v_dim), q.dtype),
@@ -332,7 +366,7 @@ def _pallas_flash(
             transcendentals=batch * heads * seq * seq,
         ),
         interpret=interpret,
-    )(lengths, window_arr, *operands)
+    )(lengths, window_arr, steps, *operands)
 
 
 def flash_prefill_attention(
@@ -353,7 +387,9 @@ def flash_prefill_attention(
 ) -> jnp.ndarray:
     """Causal flash attention over right-padded prompts ([B, T, H, D] in
     and out; ``v`` may be narrower than ``q`` and ``k``, and the output
-    is as wide as ``v``: latent attention's expanded heads). ``mask`` must be CONTIGUOUS right-padding (True for the
+    is as wide as ``v``: latent attention's expanded heads; rows at or
+    past a prompt's length come back as zeros, and no block of them is
+    computed). ``mask`` must be CONTIGUOUS right-padding (True for the
     first ``lengths[b]`` positions, False after) — it is collapsed to
     per-row lengths for the kernel's SMEM masking, so a non-contiguous
     (packed / loss-style) mask would be silently misapplied; use
@@ -373,9 +409,7 @@ def flash_prefill_attention(
             else jnp.full((batch,), seq, dtype=jnp.int32)
         )
 
-    block_q = min(block_q, _round_up(seq, 128))
-    block_k = min(block_k, _round_up(seq, 128))
-    padded = _round_up(seq, max(block_q, block_k))
+    block_q, block_k, padded = _block_sizes(seq, block_q, block_k)
 
     # [B, T, H, D] → [B, H, T, D]; pad T to a block multiple (the length
     # mask keeps padded keys out of the softmax).
@@ -507,6 +541,38 @@ def flash_prefill_attention_quant_sharded(
 
 def _round_up(n: int, multiple: int) -> int:
     return ((n + multiple - 1) // multiple) * multiple
+
+
+def _block_sizes(seq: int, block_q: int, block_k: int) -> Tuple[int, int, int]:
+    """The blocks the kernel runs a ``seq``-token call with, no longer
+    than the call, and the length it pads the call to."""
+    block_q = min(block_q, _round_up(seq, 128))
+    block_k = min(block_k, _round_up(seq, 128))
+    return block_q, block_k, _round_up(seq, max(block_q, block_k))
+
+
+def attention_blocks(
+    lengths: Sequence[int],
+    seq: int,
+    block_q: int = 256,
+    block_k: int = 256,
+    window: int = 0,
+) -> Tuple[int, int]:
+    """How often the kernel's skip engages, counted on the host: the
+    (q block, k block) steps :func:`flash_prefill_attention` computes for
+    prompts of ``lengths`` in a ``seq``-token call, and the steps of the
+    causal bucket (what it would compute were every row ``seq`` long),
+    each summed over the rows, per head and per layer, under the same
+    ``window`` (0: none)."""
+    block_q, block_k, padded = _block_sizes(seq, block_q, block_k)
+    q_start = np.arange(padded // block_q)[None, :] * block_q
+
+    def computed(lens) -> int:
+        lens = np.asarray(lens, dtype=np.int64)[:, None]
+        first, last = _kv_span(q_start, lens, window, block_q, block_k, xp=np)
+        return int(np.sum(np.where(q_start < lens, last - first + 1, 0)))
+
+    return computed(lengths), computed([seq] * len(lengths))
 
 
 def on_tpu() -> bool:

@@ -1470,6 +1470,11 @@ class DecodeEngine:
             "decode_flops": 0.0,
             "decode_bytes": 0.0,
             "prefill_flops": 0.0,
+            # the flash prefill's (q block, k block) steps computed and
+            # those of the causal buckets, per head per layer, summed over
+            # the cold dispatches whose attention is the kernel
+            "prefill_attn_blocks": 0.0,
+            "prefill_attn_blocks_causal": 0.0,
             # speculative decoding: drafted candidates vs candidates the
             # verify pass accepted (rejected = the new wasted reason)
             "tokens_drafted": 0,
@@ -4096,7 +4101,11 @@ class DecodeEngine:
         faults.check("dispatch_error")
         for group in self._pow2_groups(batch, bucket):
             with self._prefill_phase(
-                "cold", bucket, [index for index, _ in group]
+                "cold", bucket, [index for index, _ in group],
+                **self._attention_blocks(
+                    [len(request.prompt_tokens) for _, request in group],
+                    bucket,
+                ),
             ) as batch_id:
                 started = time.perf_counter()
                 size = len(group)
@@ -4393,20 +4402,39 @@ class DecodeEngine:
 
     @contextlib.contextmanager
     def _prefill_phase(self, kind: str, bucket: int, slot_ids: List[int],
-                       **chunked):
+                       **attributes):
         """One prefill dispatch (batch build and jit call) as a child
         span of ``engine.admit``; yields the batch's number, which its
         requests' ring records carry too (``runtime/journey.py``). A
         chunked prompt's span also says where it starts (``offset``) and
-        in how many ``windows`` it is taught."""
+        in how many ``windows`` it is taught; a cold one whose attention
+        is the flash kernel, how many blocks the kernel computes
+        (``attn_blocks``, ``attn_blocks_causal``: :meth:`_attention_blocks`)."""
         self._prefill_batches += 1
         with self._phase(
             "engine.prefill_dispatch",
             kind=kind, bucket=bucket, rows=len(slot_ids),
             batch=self._prefill_batches,
-            slots=":".join(map(str, slot_ids)), **chunked,
+            slots=":".join(map(str, slot_ids)), **attributes,
         ):
             yield self._prefill_batches
+
+    def _attention_blocks(
+        self, lengths: List[int], bucket: int
+    ) -> Dict[str, float]:
+        """A cold prefill's ``attn_blocks`` and ``attn_blocks_causal``
+        (``model.prefill_attention_blocks``: the flash kernel's steps
+        computed and those of the causal bucket), added to the stats;
+        none where its attention is not the flash kernel."""
+        counts = model_lib.prefill_attention_blocks(
+            self.config, lengths, bucket,
+            self.paged_kernel if self.paged else None,
+        )
+        if counts is None:
+            return {}
+        self.stats["prefill_attn_blocks"] += counts[0]
+        self.stats["prefill_attn_blocks_causal"] += counts[1]
+        return {"attn_blocks": counts[0], "attn_blocks_causal": counts[1]}
 
     @staticmethod
     def _stamp_dispatch(

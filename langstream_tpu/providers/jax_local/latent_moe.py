@@ -397,17 +397,26 @@ def _expanded_attention(config, q_nope, q_pe, rows, wk_b, wv_b, mask):
     )
     q = jnp.concatenate([q_nope, q_pe], axis=-1)
     scale = softmax_scale(config)
+    blocks = prefill_flash_blocks(config, q.shape[1])
+    if blocks is not None:
+        return flash_prefill_attention(
+            q, k, v, mask=mask, scale=scale, block_q=blocks[0],
+            block_k=blocks[1], interpret=config.flash_interpret,
+        )
+    return prefill_attention(q, k, v, mask=mask, scale=scale)
+
+
+def prefill_flash_blocks(config, seq: int):
+    """The flash kernel's (block_q, block_k) where cold prefill's
+    attention over ``seq`` tokens runs through it, else None (XLA)."""
     if config.use_flash and (
-        use_flash(q.shape[1], v.shape[-1]) or config.flash_interpret
+        use_flash(seq, config.mla.v_head_dim) or config.flash_interpret
     ):
         # 512-row blocks: at 128 heads a 4,096-token call is 32,768 grid
         # steps of 256 x 256, and the steps' overhead is most of its time
         # (31.3 ms against 17.0 ms on a v5e: PERF.md section 6, PR 29)
-        return flash_prefill_attention(
-            q, k, v, mask=mask, scale=scale, block_q=512, block_k=512,
-            interpret=config.flash_interpret,
-        )
-    return prefill_attention(q, k, v, mask=mask, scale=scale)
+        return 512, 512
+    return None
 
 
 def _absorb(q_nope, wk_b):

@@ -32,6 +32,7 @@ Logical sharding axes per parameter feed the mesh rules in
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 import math
@@ -55,7 +56,11 @@ from langstream_tpu.ops.attention import (
     prefill_attention,
     quantize_kv,
 )
-from langstream_tpu.ops.flash_attention import flash_prefill_attention, use_flash
+from langstream_tpu.ops.flash_attention import (
+    attention_blocks,
+    flash_prefill_attention,
+    use_flash,
+)
 from langstream_tpu.ops.moe import (
     group_limited_routing,
     moe_mlp,
@@ -1127,12 +1132,15 @@ def layer_windows(config: LlamaConfig) -> Optional[jnp.ndarray]:
     attention ops skip the window masking entirely."""
     if not config.sliding_window:
         return None
-    return jnp.array(
-        [
-            config.sliding_window if i % 2 == 0 else 0
-            for i in range(config.num_layers)
-        ],
-        dtype=jnp.int32,
+    return jnp.array(_window_sizes(config), dtype=jnp.int32)
+
+
+def _window_sizes(config: LlamaConfig) -> Tuple[int, ...]:
+    """:func:`layer_windows` on the host: one size a layer, 0 on every
+    layer when the family has no sliding window."""
+    return tuple(
+        config.sliding_window if i % 2 == 0 else 0
+        for i in range(config.num_layers)
     )
 
 
@@ -1201,11 +1209,55 @@ def _flash_path(config, q, mesh):
     the MXU-alignment heuristic and the SPMD rule so the two paths
     cannot diverge. Softcap / sliding window (Gemma-2) ride INTO the
     kernels as a static cap and a traced per-layer window scalar."""
-    flash_ok = config.use_flash and (
-        use_flash(q.shape[1], q.shape[3]) or config.flash_interpret
-    )
     tp_sharded = mesh is not None and dict(mesh.shape).get("tp", 1) > 1
-    return flash_ok, tp_sharded
+    return _flash_ok(config, q.shape[1]), tp_sharded
+
+
+def _flash_ok(config: LlamaConfig, seq: int) -> bool:
+    """Whether GQA's cold prefill over ``seq`` tokens attends through the
+    flash kernel."""
+    return config.use_flash and (
+        use_flash(seq, config.dims_per_head) or config.flash_interpret
+    )
+
+
+def prefill_attention_blocks(
+    config: LlamaConfig,
+    lengths,
+    bucket: int,
+    paged_kernel: Optional[str] = None,
+) -> Optional[Tuple[float, float]]:
+    """How often the flash kernel's skip engages in a cold prefill of
+    prompts ``lengths`` long in a ``bucket``: the (q block, k block)
+    steps it computes and those of the causal bucket
+    (``flash_attention.attention_blocks``), summed over the rows, per
+    head and per layer (a mean over the layers, whose windows may
+    differ). None where that prefill's attention is not the flash
+    kernel. ``paged_kernel`` is a paged engine's attention kernel, None
+    for the dense cache."""
+    kind = _kinds(config).attention
+    if kind == "latent":
+        blocks = latent_moe.prefill_flash_blocks(config, bucket)
+    elif kind == "gqa" and _flash_ok(config, bucket) and not (
+        paged_kernel == "fused" and _use_fused_paged(
+            config, config.dims_per_head, config.num_heads,
+            config.num_kv_heads, None,
+        )
+    ):
+        blocks = ()  # the kernel's default blocks, as _prefill_attn runs it
+    else:  # a window at offset 0 (hybrid, conv), or the fused paged kernel
+        blocks = None
+    if blocks is None:
+        return None
+    counts = [
+        (layers, attention_blocks(lengths, bucket, *blocks, window=window))
+        for window, layers in collections.Counter(_window_sizes(config)).items()
+    ]
+    return tuple(
+        sum(layers * count[i] for layers, count in counts)
+        / config.num_layers
+        for i in (0, 1)
+    )
 
 
 @jax.named_scope("attention")

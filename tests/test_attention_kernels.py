@@ -459,3 +459,120 @@ def test_flash_prefill_window_softcap_matches_reference():
             np.asarray(out[b, :n]), np.asarray(ref[b, :n]),
             rtol=2e-5, atol=2e-5,
         )
+
+
+# ---------------- flash prefill: the step classes ----------------------- #
+EDGE_BLOCK = 64
+# a row of one token, rows one short of, at and one past a block's edge,
+# and a whole row, in one batch
+EDGE_LENGTHS = (1, EDGE_BLOCK - 1, EDGE_BLOCK, EDGE_BLOCK + 1, 4 * EDGE_BLOCK)
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8kv"])
+@pytest.mark.parametrize(
+    "family",
+    [{}, {"window": 128, "softcap": 30.0, "scale": 0.2}],
+    ids=["full", "window_softcap"],
+)
+@pytest.mark.parametrize(
+    "dims", [(4, 2, 128, 128), (2, 2, 192, 128)], ids=["gqa", "latent"]
+)
+def test_flash_classes_match_reference_and_zero_past_length(
+    quantized, family, dims
+):
+    """Every step class (empty past the length or the window, full, and
+    partial at the diagonal, the length or a window's edge) against the
+    XLA attention on the valid rows, and rows at or past the length read
+    exactly zero. The 128-token window leaves a full block inside it and
+    a full row fewer steps than the grid has, so padding steps run."""
+    from langstream_tpu.ops.attention import quantize_kv
+
+    heads, kv_heads, dim, v_dim = dims
+    seq = EDGE_LENGTHS[-1]
+    batch = len(EDGE_LENGTHS)
+    q, k, v = _make_qkv(batch, seq, heads, kv_heads, dim, seed=11)
+    v = v[..., :v_dim]
+    lengths = jnp.array(EDGE_LENGTHS, dtype=jnp.int32)
+    mask = jnp.arange(seq)[None, :] < lengths[:, None]
+    family = dict(family)
+    if "window" in family:
+        family["window"] = jnp.asarray(family["window"], dtype=jnp.int32)
+    blocks = dict(block_q=EDGE_BLOCK, block_k=EDGE_BLOCK, interpret=True)
+    if quantized:
+        # the int8 twin against the plain attention over the dequantized
+        # cache: the same values, the scales folded elsewhere
+        k_q, k_s = quantize_kv(k)
+        v_q, v_s = quantize_kv(v)
+        ref = prefill_attention(
+            q, _dequant(k_q, k_s), _dequant(v_q, v_s), mask=mask, **family
+        )
+        out = flash_prefill_attention(
+            q, k_q, v_q, k_scale=k_s, v_scale=v_s, lengths=lengths,
+            **family, **blocks,
+        )
+    else:
+        ref = prefill_attention(q, k, v, mask=mask, **family)
+        out = flash_prefill_attention(q, k, v, mask=mask, **family, **blocks)
+    assert out.shape == (batch, seq, heads, v_dim)
+    for b, n in enumerate(EDGE_LENGTHS):
+        np.testing.assert_allclose(
+            np.asarray(out[b, :n]), np.asarray(ref[b, :n]),
+            rtol=2e-5, atol=2e-5,
+        )
+        np.testing.assert_array_equal(np.asarray(out[b, n:]), 0.0)
+
+
+def _live_pairs(length, padded, block_q, block_k, window):
+    """The (q block, k block) pairs of a ``padded`` call in which some
+    (row, column) is causal, inside the row's window and inside the
+    length, by brute force over every element."""
+    rows = np.arange(padded)[:, None]
+    cols = np.arange(padded)[None, :]
+    visible = (
+        (cols <= rows) & ((window <= 0) | (cols > rows - window))
+        & (rows < length) & (cols < length)
+    )
+    blocks = visible.reshape(
+        padded // block_q, block_q, padded // block_k, block_k
+    ).any(axis=(1, 3))
+    return set(zip(*np.nonzero(blocks)))
+
+
+@pytest.mark.parametrize(
+    "seq,block_q,block_k,window",
+    [
+        (256, 64, 64, 0),
+        (256, 64, 64, 48),
+        (256, 64, 64, 150),
+        (512, 128, 64, 0),
+        (512, 64, 128, 100),
+        (384, 256, 256, 0),
+    ],
+)
+def test_attention_blocks_counts_brute_force(seq, block_q, block_k, window):
+    """The host counter against brute force, and the kernel's step table:
+    every live pair once, in q order, and one step for each q block that
+    has none."""
+    from langstream_tpu.ops.flash_attention import (
+        _step_table,
+        attention_blocks,
+    )
+
+    lengths = [0, 1, block_q - 1, block_q, block_q + 1, seq - 1, seq]
+    padded = -(-seq // max(block_q, block_k)) * max(block_q, block_k)
+    live = [_live_pairs(n, padded, block_q, block_k, window) for n in lengths]
+    full = _live_pairs(seq, padded, block_q, block_k, window)
+    assert attention_blocks(lengths, seq, block_q, block_k, window) == (
+        sum(map(len, live)), len(full) * len(lengths)
+    )
+    table = np.asarray(_step_table(
+        jnp.asarray(lengths, jnp.int32), window, padded, block_q, block_k
+    ))
+    for (qi, kj, own), pairs in zip(table, live):
+        assert np.all(np.diff(qi) >= 0)
+        visited = [(q, k) for q, k, o in zip(qi, kj, own) if o]
+        assert len(set(visited)) == len(visited)
+        assert pairs <= set(visited)
+        idle = set(range(padded // block_q)) - {q for q, _ in pairs}
+        extra = set(visited) - pairs
+        assert {q for q, _ in extra} == idle and len(extra) == len(idle)

@@ -100,6 +100,31 @@ def test_flash_prefill_compiles(one_chip, batch, seq, quant):
     assert "tpu_custom_call" in _compiled_text(fn, *shapes)
 
 
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8kv"])
+def test_flash_prefill_compiles_at_latent_widths(one_chip, quant):
+    """The latent family's prefill call: 128 expanded heads, keys 192 and
+    values 128 wide, 512 x 512 blocks over a 4,096-token bucket."""
+    heads, seq = 128, 4096
+    s = functools.partial(_spec, sharding=one_chip)
+    kv_dtype = jnp.int8 if quant else jnp.bfloat16
+    shapes = [
+        s((1, seq, heads, 192), jnp.bfloat16),
+        s((1, seq, heads, 192), kv_dtype),
+        s((1, seq, heads, 128), kv_dtype),
+        s((1,), jnp.int32),
+    ]
+    if quant:
+        shapes += [s((1, seq, heads), jnp.float32)] * 2
+
+    def fn(q, k, v, lengths, *scales):
+        kw = {"k_scale": scales[0], "v_scale": scales[1]} if scales else {}
+        return flash_prefill_attention(
+            q, k, v, lengths=lengths, block_q=512, block_k=512, **kw
+        )
+
+    assert "tpu_custom_call" in _compiled_text(fn, *shapes)
+
+
 STACK = 3         # layers in the stacked cache the decode kernel is handed
 
 

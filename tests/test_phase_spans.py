@@ -337,3 +337,46 @@ def test_every_program_lowers_to_its_own_module_name(layout):
     # a second engine gives the same names: they are stable
     again = make_engine(decode_chunk=4, **options)
     assert {fn.__name__ for fn, _ in again._variant_jobs()} <= set(names)
+
+
+@pytest.mark.parametrize("flash", [True, False], ids=["flash", "xla"])
+def test_prefill_dispatch_counts_flash_blocks(flash):
+    """A cold dispatch whose attention is the flash kernel (interpret
+    mode) carries the blocks the kernel computes and those of the causal
+    bucket, as the host helper counts them for the prompts' lengths, and
+    the stats add them up; where the attention is XLA, neither is set."""
+    import dataclasses
+
+    from langstream_tpu.ops.flash_attention import attention_blocks
+
+    config = dataclasses.replace(
+        LlamaConfig.tiny(max_seq_len=512), flash_interpret=flash
+    )
+    engine = DecodeEngine(
+        config, init_params(config), max_slots=2, max_seq_len=512,
+        prefill_buckets=[512], decode_chunk=4, prefix_cache=False,
+    )
+    engine.tracer = tracing.Tracer("flash-blocks")
+    lengths = (20, 300)
+    engine.start()
+    try:
+        generate(engine, [[1 + i % 200 for i in range(n)] for n in lengths], 2)
+    finally:
+        engine.stop()
+    spans = [
+        span.attributes for span in engine.tracer._spans
+        if span.name == "engine.prefill_dispatch"
+    ]
+    assert spans and all(span["kind"] == "cold" for span in spans)
+    computed, causal = attention_blocks(lengths, 512)
+    if not flash:
+        assert not any("attn_blocks" in span for span in spans)
+        assert engine.stats["prefill_attn_blocks_causal"] == 0
+        return
+    assert computed < causal  # the 20-token row skips its second q block
+    assert sum(span["attn_blocks"] for span in spans) == computed
+    assert sum(span["attn_blocks_causal"] for span in spans) == causal
+    for span in spans:
+        assert span["attn_blocks"] <= span["attn_blocks_causal"]
+    assert engine.stats["prefill_attn_blocks"] == computed
+    assert engine.stats["prefill_attn_blocks_causal"] == causal

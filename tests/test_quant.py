@@ -182,11 +182,16 @@ def test_weights_cache_roundtrip(tmp_path):
     assert changed
 
 
-def test_compile_cache_placed_from_outside(monkeypatch):
+def test_compile_cache_placed_from_outside(monkeypatch, request):
     """One rule: with JAX_COMPILATION_CACHE_DIR set the code sets
     nothing; unset, the only value ever set is the checkout's."""
     from langstream_tpu.runtime import compile_cache
 
+    # an earlier test of this process (an engine's precompile) may have
+    # placed the checkout's cache already: start from none placed
+    was, update = jax.config.jax_compilation_cache_dir, jax.config.update
+    update("jax_compilation_cache_dir", None)
+    request.addfinalizer(lambda: update("jax_compilation_cache_dir", was))
     calls = []
     monkeypatch.setattr(
         jax.config, "update", lambda name, value: calls.append((name, value))
